@@ -224,16 +224,21 @@ def _cmd_bulk_predict(args) -> int:
     import os
     from ..io.bulk import bulk_predict
     from ..obs.registry import registry
+    from ..utils.device import DevicePolicyError
 
-    result = bulk_predict(
-        args.algo, args.input, args.output,
-        options=args.options or "",
-        bundle=args.bundle, checkpoint_dir=args.checkpoint_dir,
-        backend=args.backend, precision=args.precision,
-        workers=args.workers, batch_size=args.batch_size or None,
-        cache_dir=args.cache_dir, top_k=args.top_k,
-        group_col=args.group_col, feature_col=args.feature_col,
-        label_col=args.label_col)
+    try:
+        result = bulk_predict(
+            args.algo, args.input, args.output,
+            options=args.options or "",
+            bundle=args.bundle, checkpoint_dir=args.checkpoint_dir,
+            backend=args.backend, precision=args.precision,
+            workers=args.workers, batch_size=args.batch_size or None,
+            cache_dir=args.cache_dir, top_k=args.top_k,
+            group_col=args.group_col, feature_col=args.feature_col,
+            label_col=args.label_col)
+    except DevicePolicyError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     result["snapshot"] = registry.snapshot()
     print(json.dumps(result, default=str))
     return 0
@@ -1229,6 +1234,12 @@ def main(argv=None) -> int:
     h.set_defaults(fn=_cmd_help)
 
     args = p.parse_args(argv)
+    if args.cmd not in ("define-all", "help", "obs", "mixserv"):
+        # every command that can jit shares the one persistent compile
+        # cache (sets config only; the backend stays uninitialised, so
+        # `serve --replicas N` still leaves the chips to its replicas)
+        from ..utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
     return args.fn(args)
 
 

@@ -31,16 +31,6 @@ __all__ = ["read_parquet", "read_csv", "read_arrow", "table_to_dataset",
            "ParquetStream", "write_parquet_shards"]
 
 
-def _pa():
-    try:
-        import pyarrow
-        return pyarrow
-    except ImportError as e:            # pragma: no cover - baked in here
-        raise ImportError(
-            "pyarrow is required for Arrow/Parquet ingest; use the LIBSVM "
-            "reader (io.libsvm) where it is unavailable") from e
-
-
 def _parse_string_features(flat: np.ndarray, *, dims: Optional[int],
                            ffm: bool, num_fields: int
                            ) -> Tuple[np.ndarray, np.ndarray,
@@ -93,7 +83,7 @@ def table_to_dataset(table, *, feature_col: str = "features",
                      dims: Optional[int] = None, ffm: bool = False,
                      num_fields: int = 64) -> SparseDataset:
     """One pyarrow Table -> SparseDataset (schemas per module docstring)."""
-    pa = _pa()
+    import pyarrow as pa
     names = set(table.column_names)
     labels = table.column(label_col).to_numpy(
         zero_copy_only=False).astype(np.float32)
@@ -143,7 +133,7 @@ def read_parquet(path: str, **kw) -> SparseDataset:
     """Read one Parquet file or a shard directory fully into RAM.
     For larger-than-RAM corpora use ParquetStream instead."""
     import pyarrow.parquet as pq
-    pa = _pa()
+    import pyarrow as pa
     files = _parquet_files(path)
     ds = table_to_dataset(pa.concat_tables([pq.read_table(f)
                                             for f in files]), **kw)
@@ -217,7 +207,7 @@ def write_parquet_shards(ds: SparseDataset, out_dir: str, *,
                          rows_per_shard: int = 1 << 20) -> List[str]:
     """Spill a SparseDataset to a directory of CSR-schema Parquet shards
     (the inverse of ParquetStream; used to stage out-of-core corpora)."""
-    pa = _pa()
+    import pyarrow as pa
     import pyarrow.parquet as pq
     os.makedirs(out_dir, exist_ok=True)
     paths = []

@@ -27,6 +27,10 @@ dir>`` CLI plumbing:
   worker processes (spawn — JAX is fork-unsafe once initialized), each
   building its scorer once and streaming its shards; ``workers=1`` runs
   inline. Memory is bounded by (workers × one shard), never the table.
+  Pool workers score on the host CPU on purpose: a chip takes one
+  process and the master holds it, so on an accelerator host the
+  ``kernel`` backend runs with ``workers=1`` only and a process pool
+  scores through the arena twins (utils/device.py).
 - **One pass** writes scored Parquet (one output shard per input shard,
   same basenames so sorted order is row order), folds the evaluation
   UDAFs (logloss/AUC/rmse via :mod:`~..frame.evaluation` — AUC exact up
@@ -468,6 +472,15 @@ def _register_progress(prog: BulkProgress) -> None:
     registry.register("bulk", _obs)
 
 
+def _pool_worker_init() -> None:
+    """Process-pool initializer (before anything imports jax): the host
+    CPU by name, and the shared compile cache for kernel scorers."""
+    from ..utils.compile_cache import enable_compile_cache
+    from ..utils.device import host_only_worker
+    host_only_worker()
+    enable_compile_cache()
+
+
 # --------------------------------------------------------------------------
 # backend probe
 
@@ -580,6 +593,17 @@ def bulk_predict(algo: str, input_path: str,
     workers = max(1, int(workers))
     if workers == 1:
         pool = "inline"
+    from ..utils.device import DevicePolicyError, cpu_requested
+    # process workers run on the host CPU; unless this whole job was
+    # asked onto the CPU by name, the jitted kernel is a different device
+    # there than in the master — so it is not a backend they can run
+    host_pool = pool == "process" and not cpu_requested()
+    if host_pool and backend == "kernel":
+        raise DevicePolicyError(
+            f"--backend kernel with {workers} worker processes needs a "
+            "chip per process and this process holds it: use --workers 1 "
+            "(one process per chip), --backend arena (host scoring), or "
+            "JAX_PLATFORMS=cpu for a CPU kernel pool")
     cfg: Dict[str, Any] = {
         "algo": algo, "options": options or "", "bundle": bundle_path,
         "backend": backend, "precision": precision,
@@ -608,10 +632,10 @@ def bulk_predict(algo: str, input_path: str,
 
     with hold_bundle(bundle_path):      # retention must not GC it mid-run
         probe_info = None
-        if backend == "auto" and precision == "f32":
+        if backend == "auto" and precision == "f32" and not host_pool:
             backend, probe_info = _probe_backends(cfg, kind, files[0])
         elif backend == "auto":
-            backend = "arena"           # quantized tiers are arena-only
+            backend = "arena"           # quantized tiers and host pools
         cfg["backend"] = backend
         cfg["digest"] = json.dumps(
             {k: v for k, v in cfg.items() if k != "digest"},
@@ -673,7 +697,8 @@ def bulk_predict(algo: str, input_path: str,
                     import multiprocessing as mp
                     ex = cf.ProcessPoolExecutor(
                         max_workers=workers,
-                        mp_context=mp.get_context("spawn"))
+                        mp_context=mp.get_context("spawn"),
+                        initializer=_pool_worker_init)
                 else:
                     ex = cf.ThreadPoolExecutor(
                         max_workers=workers, thread_name_prefix="bulk")

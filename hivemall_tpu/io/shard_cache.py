@@ -102,7 +102,7 @@ class _Counters:
         # never trigger the first-use g++ build from a scrape thread
         from ..utils import native as _n
         lib = _n._LIB
-        canon = ("native" if lib is not None and hasattr(lib, "canon_measure")
+        canon = ("native" if lib is not None
                  else ("python" if _n._TRIED else "unresolved"))
         with self._lock:
             return {
@@ -633,12 +633,6 @@ def _smoke() -> int:                      # pragma: no cover - exercised by sh
         # while preserving mtime/size — a warm traversal must still serve
         # the original bytes (proof the mmap'd cache, not the source, is
         # what epoch >= 2 reads).
-        try:
-            import pyarrow  # noqa: F401
-        except ImportError:
-            print("shard-cache smoke: pyarrow absent, decode-cache leg "
-                  "skipped", file=sys.stderr)
-            return failures
         from .arrow import ParquetStream, write_parquet_shards
         pq_dir = f"{tmp}/pq"
         write_parquet_shards(ds, pq_dir, rows_per_shard=256)

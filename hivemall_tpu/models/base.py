@@ -185,7 +185,7 @@ def learner_option_spec(name: str, *, classification: bool,
 def _identity_prep(batch):
     """Module-level identity prep — the picklable stand-in for trainers
     whose parallel prep leg is the base no-op, so ``-ingest_pool process``
-    works for every trainer (a bound method would not cross the fork)."""
+    works for every trainer (a bound method would not cross the process boundary)."""
     return batch
 
 
@@ -670,7 +670,7 @@ class LearnerBase:
     def _picklable_prep(self):
         """The parallel prep leg as a PICKLABLE callable for
         ``-ingest_pool process`` (a bound trainer method cannot cross the
-        fork: it would drag the whole trainer — device arrays included —
+        process boundary: it would drag the whole trainer — device arrays included —
         through pickle per task). Base trainers' parallel leg is the
         identity, which is trivially picklable; trainers that override the
         leg must also override this (FFM builds one from a plain prep

@@ -585,7 +585,7 @@ class FMTrainer(LearnerBase):
 # (thread pools, sequential fallback) and a PICKLABLE config-built callable
 # for -ingest_pool process — a bound method would drag the whole trainer
 # (device tables included) through pickle per task and cannot cross the
-# fork. Both forms call the module functions below, so they can never
+# process boundary. Both forms call the module functions below, so they can never
 # drift; tests/test_pipeline.py pins process == thread == sequential
 # bit-exact.
 
@@ -792,7 +792,8 @@ class FFMTrainer(FMTrainer):
                 "w0": self.optimizer.init(()),
                 "T2": {"gg": jnp.zeros((self.F * self.MRF * self.HP, 128),
                                        jnp.float32)}}
-            interp = jax.default_backend() != "tpu"
+            from ..utils.device import pallas_interpret
+            interp = pallas_interpret()
             lamt = (o.lambda0, o.lambda_w, o.lambda_v)
             eta_key = (str(o.eta), float(o.eta0), o.total_steps, o.power_t)
             self._step = None
@@ -874,10 +875,10 @@ class FFMTrainer(FMTrainer):
         gathers stay rank-local), batch over 'dp' with a G psum before the
         optimizer tail (ops.fm_pallas.make_parts_step_sharded). The fused
         single-chip kernel remains the mesh=None path."""
-        import jax
         from ..ops.fm_pallas import make_parts_step_sharded
         from ..ops.schedules import make_eta
         from ..parallel.mesh import make_mesh, parse_mesh_spec
+        from ..utils.device import pallas_interpret
         o = self.opts
         dp, tp = parse_mesh_spec(spec)
         if self.F % tp:
@@ -894,7 +895,7 @@ class FFMTrainer(FMTrainer):
         self.mesh = make_mesh(dp=dp, tp=tp)
         eta_fn = make_eta(o.eta, o.eta0, o.total_steps, o.power_t)
         lamt = (o.lambda0, o.lambda_w, o.lambda_v)
-        interp = jax.default_backend() != "tpu"
+        interp = pallas_interpret()
         self._step_fm = make_parts_step_sharded(
             self.loss, eta_fn, lamt, self.F, self.k, self.MRF, self.mesh,
             interpret=interp)
@@ -1030,7 +1031,7 @@ class FFMTrainer(FMTrainer):
 
         DEVICE-RESIDENT replay (round 4): the reference's -iters pattern
         re-reads the corpus every epoch; the round-3 disk replay did too —
-        and through this relay every epoch re-paid the full h2d wall. When
+        and every epoch re-paid the full h2d transfer. When
         the packed input path is active and the dataset fits the HBM
         budget, epoch 1 streams normally but RETAINS its staged device
         buffers; epochs >= 2 reshuffle with ONE on-device row gather

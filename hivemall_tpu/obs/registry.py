@@ -105,6 +105,7 @@ SLO_STUB = {"configured": False, "samples": 0, "target_p99_ms": None,
 FLEET_STUB = {"replicas": 0, "ready": 0, "respawns": 0, "rolls": 0,
               "roll_failures": 0, "rejected_bundles": 0,
               "fleet_step": None, "model_steps": {},
+              "replica_platforms": {},
               "replica_rss_bytes": {}, "arena_mapped_bytes": {}}
 #: serve.promote.PromotionController.obs_section() /
 #: serve.fleet.ReplicaManager.promotion_section() in their inactive form

@@ -262,41 +262,54 @@ def _make_scatter_opt_kernel(B: int, L: int, F: int, MRF: int, HP: int,
 def _make_scatter_accum_kernel(Bd: int, Ll: int, Fl: int, MRF: int, HP: int,
                                chunk: int, interpret: bool = False):
     """Accumulate-only twin of _make_scatter_opt_kernel for the SHARDED
-    parts step: the same per-slot roll+add VMEM RMW (~17 ns/slot), but G
-    is emitted to HBM once per local field partition instead of feeding a
-    fused optimizer tail — the sharded step must psum G over 'dp' before
-    any optimizer math (per-replica AdaGrad on partial gradients is NOT
-    minibatch AdaGrad), so the tail runs as a dense XLA update on each
+    parts step: the same per-slot roll+add RMW (~17 ns/slot) into the same
+    VMEM-resident G scratch, but the tail steps copy G out to HBM instead
+    of running the optimizer — the sharded step must psum G over 'dp'
+    before any optimizer math (per-replica AdaGrad on partial gradients is
+    NOT minibatch AdaGrad), so the tail runs as a dense XLA update on each
     rank's table shard. Extra HBM traffic vs the fused kernel: one G
-    write + one read (~2 table passes, ~0.4 ms at flagship shapes against
-    819 GB/s) — the scatter itself still never materializes per-slot."""
+    write + one read (~2 table passes) — the scatter itself still never
+    materializes per-slot.
+
+    G is a scratch with small tail out blocks, not one whole-partition out
+    block: at MRF=8192 that block is 8 MiB and Pallas double-buffers it,
+    which Mosaic refuses on v5e (18 MiB against the 16 MiB scoped-VMEM
+    limit). This way the footprint matches the single-chip kernel's."""
     assert HP == 2
     m = Ll // Fl
     nc = Bd // chunk
     n_acc = m * nc
-    gt_rows = MRF * HP // 8
-    grid = (Fl, n_acc)
+    gt_rows = MRF * HP // 8          # f32 (8,128) G tiles per partition
+    t_out = min(256, gt_rows)        # G tiles copied out per tail step
+    n_out = gt_rows // t_out
+    grid = (Fl, n_acc + n_out)
 
-    def kernel(rows_ref, g_ref, G_ref):
+    def kernel(rows_ref, g_ref, out_ref, G_ref):
         c = pl.program_id(1)
 
         @pl.when(c == 0)
         def _():
             G_ref[...] = jnp.zeros_like(G_ref)
 
-        cc = c % nc
-        base = (c // nc) * Bd
+        @pl.when(c < n_acc)
+        def _acc():
+            cc = c % nc
+            base = (c // nc) * Bd
 
-        def body(i, _):
-            gtile = g_ref[0, i].astype(jnp.float32)       # [16, 128]
-            for u in range(8):
-                j = base + cc * chunk + i * 8 + u
-                r = rows_ref[0, j >> 7, j & 127]
-                piece = gtile[2 * u:2 * u + 2, :]
-                G_ref[0, r >> 2] += _roll_pad8(piece, r & 3)
-            return 0
+            def body(i, _):
+                gtile = g_ref[0, i].astype(jnp.float32)       # [16, 128]
+                for u in range(8):
+                    j = base + cc * chunk + i * 8 + u
+                    r = rows_ref[0, j >> 7, j & 127]
+                    piece = gtile[2 * u:2 * u + 2, :]
+                    G_ref[r >> 2] += _roll_pad8(piece, r & 3)
+                return 0
 
-        jax.lax.fori_loop(0, chunk // 8, body, 0)
+            jax.lax.fori_loop(0, chunk // 8, body, 0)
+
+        @pl.when(c >= n_acc)
+        def _out():
+            out_ref[0] = G_ref[pl.ds((c - n_acc) * t_out, t_out)]
 
     return pl.pallas_call(
         kernel,
@@ -305,13 +318,17 @@ def _make_scatter_accum_kernel(Bd: int, Ll: int, Fl: int, MRF: int, HP: int,
             pl.BlockSpec((1, (m * Bd) // 128, 128), lambda g, c: (g, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk * HP // 16, 16, 128),
-                         lambda g, c: (g, c, 0, 0),
+                         lambda g, c: (g, jnp.minimum(c, n_acc - 1), 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, gt_rows, 8, 128),
-                               lambda g, c: (g, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
+        # parked on the partition's block 0 while accumulating; the first
+        # tail step fills that block before the index ever moves
+        out_specs=pl.BlockSpec(
+            (1, t_out, 8, 128),
+            lambda g, c: (g, jnp.maximum(c - n_acc, 0), 0, 0),
+            memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Fl, gt_rows, 8, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((gt_rows, 8, 128), jnp.float32)],
         interpret=interpret,
     )
 
@@ -499,7 +516,6 @@ def make_parts_step_sharded(loss: Loss, eta_fn: Callable, lambdas, F: int,
         mesh=None path (its rate is the flagship headline).
     """
     from jax.sharding import PartitionSpec as P
-    from ..utils.jax_compat import shard_map as _sm
     dp, tp = mesh.shape["dp"], mesh.shape["tp"]
     assert F % tp == 0, (F, tp)
     Fl = F // tp
@@ -622,8 +638,9 @@ def make_parts_step_sharded(loss: Loss, eta_fn: Callable, lambdas, F: int,
         fn = local_step
         in_specs = (param_spec, opt_spec, P(), P("dp", None),
                     P("dp", None), P("dp"), P("dp"))
-    smapped = _sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=(param_spec, opt_spec, P()), check_vma=False)
+    smapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=(param_spec, opt_spec, P()),
+                            check_vma=False)
     return jax.jit(smapped, donate_argnums=(0, 1))
 
 

@@ -34,19 +34,19 @@ to ``level_histogram_sorted`` below, whose per-level cost is n * 512 * d
 independent of M (measured on v5e at n=1e6, d=28, M=256, B=64: 142ms vs
 2208ms flat — 15x).
 
-The pure-JAX scatter path in ops/trees.py remains the CPU fallback; tests
-run this kernel in interpreter mode and assert agreement, and the same
-code compiles via Mosaic on a real chip.
+The pure-JAX scatter path in ops/trees.py is the reference the tests hold
+this kernel to (in interpret mode, on a CPU asked for by name); on a TPU
+the kernels always run, compiled via Mosaic (utils/device.py policy).
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..utils.device import pallas_interpret
 
 __all__ = ["level_histogram", "level_histogram_sorted",
            "use_pallas_default"]
@@ -60,10 +60,11 @@ _SCH = 8           # stat-channel slab (sublane tile) — S ≤ 8 per call
 
 
 def use_pallas_default() -> bool:
-    """Pallas path on real TPU, or when forced for tests (interpret mode)."""
-    if os.environ.get("HIVEMALL_TPU_FORCE_PALLAS"):
-        return True
-    return jax.default_backend() == "tpu"
+    """The Pallas kernels whenever they compile (a TPU). On a CPU that was
+    asked for by name the XLA scatter reference runs — the interpreter is
+    too slow for whole forests; tests that want the kernels pass
+    ``use_pallas=True``. Any other backend raises (pallas_interpret)."""
+    return not pallas_interpret()
 
 
 def _hist_kernel(idx_ref, ws_ref, out_ref, *, precision, tile, d):
@@ -151,7 +152,7 @@ def level_histogram(bins: jnp.ndarray, loc: jnp.ndarray, ws: jnp.ndarray,
                                lambda m, r: (0, 0, m),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((d, _SCH, mbp), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(idx_t, ws_t)
 
     # [d, _SCH, mbp] → [n_nodes, d, n_bins, S]
@@ -299,7 +300,7 @@ def level_histogram_sorted(bins: jnp.ndarray, loc: jnp.ndarray,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((d, _SCH, nw * _TW),
                                            jnp.float32),
-            interpret=jax.default_backend() != "tpu",
+            interpret=pallas_interpret(),
         )(wseq, idx_t, ws_t)
         out = jnp.where(jnp.repeat(visited, _TW)[None, None, :],
                         out, 0.0)
@@ -470,7 +471,7 @@ def level_histogram_dense(bins_t: jnp.ndarray, loc: jnp.ndarray,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_groups, dp * n_bins, cs),
                                        jnp.float32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(bins_t.astype(jnp.int32), locp, wsp)
 
     # [n_groups, dp*B, cs] -> [n_nodes, dp, B, S]

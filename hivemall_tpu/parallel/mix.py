@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import shard_map
 from ..ops.losses import Loss
 from ..ops.optimizers import Optimizer
 
@@ -78,7 +77,7 @@ def make_replica_train_step(mesh: Mesh, loss: Loss, optimizer: Optimizer,
     # check_vma off: the mix branch of lax.cond returns a pmean-replicated
     # value while the skip branch stays device-varying; that asymmetry is
     # exactly the cadence semantics we want.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None), pspec_state, P(), P("dp", None),
                   P("dp", None), P("dp")),
@@ -123,7 +122,7 @@ def make_covariance_replica_step(mesh: Mesh, rates: Callable,
             jnp.maximum(0.0, 1.0 - m).sum(), "dp")
         return w2[None], sig2[None], loss_sum
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None), P("dp", None), P(), P("dp", None),
                   P("dp", None), P("dp")),

@@ -9,8 +9,8 @@ connect to either implementation unchanged. TLS and fault injection stay
 on the Python server (they are test/ops tooling); this is the in-cluster
 plaintext data path.
 
-Build-on-first-use like utils/native.py: `g++ -O3` into
-native/mix_server_native next to the source; environments without a
+Build-on-first-use like utils/native.py: `g++ -O3` into a source-hash-keyed
+native/mix_server_native-<sha> next to the source; environments without a
 toolchain fall back to the Python server (start() raises with a clear
 message; `mixserv --impl auto` handles the fallback).
 """
@@ -25,7 +25,6 @@ from typing import Optional
 _DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SRC = os.path.join(_DIR, "mix_server.cpp")
-_BIN = os.path.join(_DIR, "mix_server_native")
 
 __all__ = ["NativeMixServer", "native_available", "build_native_server"]
 
@@ -35,9 +34,9 @@ def build_native_server() -> Optional[str]:
     toolchain or source is unavailable (callers fall back to the asyncio
     server). Shares utils.native's build-on-first-use helper and the
     single HIVEMALL_TPU_NO_NATIVE=1 switch."""
-    from ..utils.native import build_if_stale
+    from ..utils.native import build_artifact
 
-    return _BIN if build_if_stale(_SRC, _BIN, []) else None
+    return build_artifact(_SRC, "mix_server_native", "", [])
 
 
 def native_available() -> bool:

@@ -180,8 +180,13 @@ class PredictEngine:
             if m is None:                # no pointer yet: bootstrap from
                 m = self._load_newest(min_step=-1)   # the newest usable
             if m is None:
+                # a bundle that failed to LOAD is reported with its cause:
+                # a replica that lost the chip to another process dies in
+                # backend init, which is not a missing bundle
+                why = (f" (last load error: {self.last_reload_error})"
+                       if self.last_reload_error else "")
                 raise FileNotFoundError(
-                    f"no usable {algo} checkpoint bundle in {ckdir!r}")
+                    f"no usable {algo} checkpoint bundle in {ckdir!r}{why}")
             self._model = m
         else:
             raise ValueError(
@@ -473,6 +478,17 @@ class PredictEngine:
         m = self._model
         a = m.arena if m is not None else None
         return int(a.mapped_bytes) if a is not None else 0
+
+    @property
+    def platform(self) -> str:
+        """Where the serving model scores: the JAX backend of the jitted
+        scorer, or "host" for the numpy arena twin (which never touches a
+        device). /healthz reports it so a replica's device is stated."""
+        m = self._model
+        if m is not None and m.arena is not None:
+            return "host"
+        import jax
+        return jax.default_backend()
 
     @property
     def ready(self) -> bool:

@@ -107,6 +107,7 @@ def health_payload(engine, batcher) -> "tuple[bool, dict]":
         "host_rss_bytes": _host_rss(),
         "arena_mapped_bytes": engine.arena_mapped_bytes,
         "precision": engine.precision,
+        "platform": engine.platform,
         # cumulative SLO totals (latency histogram + score moments):
         # the fleet manager sums these across replicas into its SLO
         # engine every health tick

@@ -414,6 +414,16 @@ class RetrievalEngine:
         m = self._model
         return int(m.arena.mapped_bytes) if m is not None else 0
 
+    @property
+    def platform(self) -> str:
+        """/healthz peer of PredictEngine.platform: the JAX backend when
+        the rescore runs the jitted kernel, "host" for the numpy path."""
+        m = self._model
+        if m is not None and m.backend == "kernel":
+            import jax
+            return jax.default_backend()
+        return "host"
+
     def wait_ready(self, timeout: Optional[float] = None) -> bool:
         return self.ready
 

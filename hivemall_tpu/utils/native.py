@@ -1,32 +1,39 @@
 """ctypes bridge to the C++ native runtime pieces (native/hivemall_native.cpp).
 
-Build-on-first-use: the shared object compiles with g++ into
-``native/_native.so`` the first time it's needed (a few hundred ms), then
-loads via ctypes. Everything here degrades gracefully — any failure (no
-compiler, read-only checkout, HIVEMALL_TPU_NO_NATIVE=1) leaves the pure
-Python/numpy paths in charge with identical semantics; tests pin the
-bit-exact parity between the two.
+Build-on-first-use: the shared object compiles with g++ (a few hundred ms)
+the first time it's needed, then loads via ctypes. The artifact is keyed on
+the CONTENT of its source and flags (``native/_native-<sha>.so``) and
+written atomically, so a copied checkout never trusts a stale product (a
+copy does not preserve mtimes) and several replicas or pool workers can
+reach the build at once. A failed build leaves the pure Python/numpy paths
+in charge with identical semantics (tests pin the bit-exact parity) and is
+visible: :func:`status` says what loaded and why not.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import threading
 from typing import Optional, Sequence
 
 import numpy as np
 
-__all__ = ["get_lib", "mmh3_batch_native", "mhash_batch_native", "bin_columns_native",
-           "parse_libsvm_native", "canonicalize_fieldmajor_native"]
+__all__ = ["get_lib", "status", "build_artifact", "mmh3_batch_native",
+           "mhash_batch_native", "bin_columns_native", "parse_libsvm_native",
+           "canonicalize_fieldmajor_native"]
 
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+_ERROR: Optional[str] = None       # why the last build/load failed
+_LOAD_LOCK = threading.Lock()      # ingest pool threads race the first load
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native",
     "hivemall_native.cpp")
-_SO = os.path.join(os.path.dirname(_SRC), "_native.so")
 
 
 def native_disabled() -> bool:
@@ -35,45 +42,79 @@ def native_disabled() -> bool:
     return os.environ.get("HIVEMALL_TPU_NO_NATIVE") == "1"
 
 
-def build_if_stale(src: str, out: str, flags) -> bool:
-    """Shared build-on-first-use: (re)compile `src` -> `out` with g++ when
-    the artifact is missing or older than the source. Returns whether a
-    usable artifact exists; never raises (no-toolchain environments fall
-    back to the pure paths)."""
+def build_artifact(src: str, stem: str, ext: str, flags) -> Optional[str]:
+    """Shared build-on-first-use: path of ``<dir(src)>/<stem>-<sha><ext>``
+    where sha covers the source bytes and the compiler flags, compiling it
+    with g++ if absent. The product lands under a temp name and is renamed
+    into place, so concurrent builders each publish a complete file and
+    readers never see a torn one. Returns None (never raises) when native
+    is disabled or the build fails; the reason is kept for :func:`status`."""
+    global _ERROR
     if native_disabled():
-        return False
-    if not os.path.exists(src):
-        # binary-only installs (source pruned): use the shipped artifact
-        return os.path.exists(out)
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
-        return True
+        _ERROR = "HIVEMALL_TPU_NO_NATIVE=1"
+        return None
+    cmd = ["g++", "-O3", "-std=c++17", *flags]
+    base = os.path.join(os.path.dirname(src), stem)
+    tmp = f"{base}.tmp.{os.getpid()}{ext}"
     try:
-        r = subprocess.run(["g++", "-O3", "-std=c++17", *flags, src,
-                            "-o", out], capture_output=True, timeout=120)
-        return r.returncode == 0
-    except (OSError, subprocess.SubprocessError):
-        return False
+        with open(src, "rb") as f:
+            sha = hashlib.sha256(
+                f.read() + " ".join(cmd).encode()).hexdigest()
+        out = f"{base}-{sha[:16]}{ext}"
+        if os.path.exists(out):
+            return out
+        r = subprocess.run([*cmd, src, "-o", tmp], capture_output=True,
+                           timeout=120)
+        if r.returncode != 0:
+            _ERROR = "g++ failed: " + r.stderr.decode(
+                "utf8", "replace")[-400:]
+            return None
+        os.replace(tmp, out)
+    except (OSError, subprocess.SubprocessError) as e:
+        _ERROR = f"{type(e).__name__}: {e}"
+        return None
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(f"{base}-*{ext}"):
+        if stale != out:
+            try:
+                os.unlink(stale)     # products of an older source
+            except OSError:
+                pass
+    return out
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
     # toolchains without libgomp: retry single-threaded
-    return (build_if_stale(_SRC, _SO, ["-shared", "-fPIC", "-fopenmp"])
-            or build_if_stale(_SRC, _SO, ["-shared", "-fPIC"]))
+    return (build_artifact(_SRC, "_native", ".so",
+                           ["-shared", "-fPIC", "-fopenmp"])
+            or build_artifact(_SRC, "_native", ".so", ["-shared", "-fPIC"]))
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
-    _TRIED = True
-    if native_disabled():
-        return None
-    if not _build():
-        return None
+    with _LOAD_LOCK:
+        if not _TRIED:
+            try:
+                _load()
+            finally:
+                _TRIED = True
+    return _LIB
+
+
+def _load() -> None:
+    global _LIB, _ERROR
+    so = _build()
+    if so is None:
+        return
     try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
-        return None
+        lib = ctypes.CDLL(so)
+    except OSError as e:
+        _ERROR = f"dlopen: {e}"
+        return
     lib.mmh3_32.restype = ctypes.c_uint32
     lib.mmh3_32.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_uint32]
     lib.mmh3_batch.restype = None
@@ -87,11 +128,19 @@ def get_lib() -> Optional[ctypes.CDLL]:
     lib.libsvm_fill.restype = None
     lib.libsvm_free.restype = None
     lib.libsvm_free.argtypes = [ctypes.c_void_p]
-    if hasattr(lib, "canon_measure"):     # present after rebuild
-        lib.canon_measure.restype = ctypes.c_int
-        lib.canon_fill.restype = None
+    lib.canon_measure.restype = ctypes.c_int
+    lib.canon_fill.restype = None
     _LIB = lib
-    return _LIB
+    _ERROR = None
+
+
+def status() -> dict:
+    """What the native layer is: ``{"loaded": bool, "path": ..., "error":
+    ...}`` — the degradation to pure Python is allowed, not silent."""
+    lib = get_lib()
+    return {"loaded": lib is not None,
+            "path": getattr(lib, "_name", None),
+            "error": _ERROR}
 
 
 def _pack(keys: Sequence[bytes | str]):
@@ -165,7 +214,7 @@ def canonicalize_fieldmajor_native(idx: np.ndarray, val: np.ndarray,
     ``None`` if a row overflows max_m, or ``NotImplemented`` when the
     native lib is unavailable (caller falls back to numpy)."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "canon_measure"):
+    if lib is None:
         return NotImplemented
     idx = np.ascontiguousarray(idx, np.int32)
     val = np.ascontiguousarray(val, np.float32)
@@ -200,8 +249,8 @@ def bin_columns_native(X: np.ndarray, edges: np.ndarray,
     it measured 1.6-1.9 s of the 1M x 28 RF build host side). Returns the
     uint8 code matrix or NotImplemented when the lib isn't available."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "bin_columns"):
-        return NotImplemented          # stale prebuilt .so without the entry
+    if lib is None:
+        return NotImplemented
     X = np.ascontiguousarray(X, np.float32)
     edges = np.ascontiguousarray(edges, np.float32)
     n_edges = np.ascontiguousarray(n_edges, np.int32)

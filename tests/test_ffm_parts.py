@@ -18,6 +18,7 @@ from hivemall_tpu.io.sparse import SparseBatch, SparseDataset
 from hivemall_tpu.models.fm import FFMTrainer
 from hivemall_tpu.ops import fm_pallas as fp
 from hivemall_tpu.ops.losses import get_loss
+from hivemall_tpu.utils.device import pallas_interpret
 
 B, F, K, MRF = 128, 31, 8, 1 << 10   # Wp = 31*8+8 -> 256 (HP=2)
 L = F
@@ -74,7 +75,7 @@ def test_step_matches_oracle():
     mask = np.ones(B, np.float32)
     mask[-5:] = 0.0
     loss = get_loss("logloss")
-    interp = jax.default_backend() != "tpu"
+    interp = pallas_interpret()
     step = fp.make_parts_step(loss, lambda t: 0.1, (0.0, 0.0, 0.0),
                               F, K, MRF, interpret=interp)
 
@@ -194,7 +195,7 @@ def test_l2_count_lane_matches_slab_oracle():
     idx, val, lab = _mk_batch(rng, b=128)
     mask = np.ones(128, np.float32)
     loss = get_loss("logloss")
-    interp = jax.default_backend() != "tpu"
+    interp = pallas_interpret()
     lam_w, lam_v = 0.02, 0.01
     step = fp.make_parts_step(loss, lambda t: 0.1, (0.0, lam_w, lam_v),
                               F, K, MRF, interpret=interp)

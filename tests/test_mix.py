@@ -89,7 +89,6 @@ def test_argmin_kld_mix_on_mesh():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from hivemall_tpu.parallel.mesh import make_mesh
     from hivemall_tpu.parallel.mix import argmin_kld_mix
 
@@ -98,9 +97,10 @@ def test_argmin_kld_mix_on_mesh():
     covar = jnp.ones((8, 1)) * jnp.asarray(
         [0.1, 10, 10, 10, 10, 10, 10, 10]).reshape(8, 1)
 
-    f = shard_map(lambda a, c: argmin_kld_mix(a[0], c[0], "dp")[0][None],
-                  mesh=mesh, in_specs=(P("dp", None), P("dp", None)),
-                  out_specs=P("dp", None))
+    f = jax.shard_map(
+        lambda a, c: argmin_kld_mix(a[0], c[0], "dp")[0][None],
+        mesh=mesh, in_specs=(P("dp", None), P("dp", None)),
+        out_specs=P("dp", None))
     mixed = np.asarray(f(w, covar))
     assert abs(mixed[0, 0]) < 0.5     # confident replica 0 (w=0) dominates
 

@@ -325,7 +325,7 @@ def test_source_id_distinguishes_parse_configs(tmp_path):
 def test_fit_native_and_python_canonicalizer_bit_equal(tmp_path):
     """The C++ canonicalizer is the default in every prep path; a fit
     with it active must be bit-equal to the numpy fallback (the automatic
-    degradation when _native.so is absent)."""
+    degradation when the native library did not build)."""
     import hivemall_tpu.utils.native as nat
 
     ds = _ffm_unit_ds(seed=25)

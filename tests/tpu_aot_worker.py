@@ -1,0 +1,133 @@
+"""AOT-compile the repo's Pallas kernels for a device-less TPU v5e topology.
+
+Run as a subprocess by tests/test_tpu_aot_compile.py (libtpu takes a lock
+and is noisy): ``python tests/tpu_aot_worker.py CASE...`` prints one JSON
+line per case. Every kernel is lowered with ``interpret=False`` at bench
+geometry and handed to the real Mosaic/XLA TPU compiler, so a kernel that
+only ever ran in interpret mode and does not fit VMEM fails HERE, without
+chip time.
+"""
+
+import json
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from hivemall_tpu.ops import fm_pallas, pallas_hist
+from hivemall_tpu.ops.losses import get_loss
+
+# the kernels under test ask the device policy; here the target is the
+# topology, not this process's (CPU) backend
+pallas_hist.pallas_interpret = lambda: False
+
+TOPO = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+DEV0 = SingleDeviceSharding(TOPO.devices[0])
+
+F, K, MRF, HP = 40, 4, 8192, 2                  # bench_ffm_kernel geometry
+LAMS = (0.01, 0.01, 0.01)
+
+
+def _sds(shape, dtype, sharding=DEV0):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _parts_state(sh_t=DEV0, sh_0=DEV0):
+    rows = F * MRF * HP
+    params = {"T2": _sds((rows, 128), jnp.bfloat16, sh_t),
+              "w0": _sds((), jnp.float32, sh_0)}
+    opt = {"T2": {"gg": _sds((rows, 128), jnp.float32, sh_t)},
+           "w0": {"gg": _sds((), jnp.float32, sh_0)}}
+    return params, opt
+
+
+def parts_step():
+    B = 32768
+    step = fm_pallas.make_parts_step(
+        get_loss("logloss"), lambda t: 0.1, LAMS, F, K, MRF, unit_val=True,
+        interpret=False)
+    params, opt = _parts_state()
+    step.lower(params, opt, _sds((), jnp.float32),
+               _sds((B, F), jnp.int32), _sds((B,), jnp.float32),
+               _sds((B,), jnp.float32)).compile()
+
+
+def _mesh_2x2():
+    return Mesh(np.asarray(TOPO.devices).reshape(2, 2), ("dp", "tp"))
+
+
+def parts_accum_kernel_2x2():
+    """The sharded step's accumulate kernel at its per-rank flagship
+    shapes (B=32768 over dp=2, F=40 over tp=2), one instance per device."""
+    mesh = _mesh_2x2()
+    Bd, Fl, chunk = 32768 // 2, F // 2, 2048
+    kern = fm_pallas._make_scatter_accum_kernel(Bd, Fl, Fl, MRF, HP, chunk,
+                                                interpret=False)
+    every = P(("dp", "tp"))
+    fn = jax.jit(jax.shard_map(kern, mesh=mesh, in_specs=(every, every),
+                               out_specs=every, check_vma=False))
+    sh = NamedSharding(mesh, every)
+    fn.lower(_sds((4 * Fl, Bd // 128, 128), jnp.int32, sh),
+             _sds((4 * Fl, Bd * HP // 16, 16, 128), jnp.bfloat16, sh)
+             ).compile()
+
+
+def parts_step_sharded():
+    B = 32768
+    mesh = _mesh_2x2()
+    step = fm_pallas.make_parts_step_sharded(
+        get_loss("logloss"), lambda t: 0.1, LAMS, F, K, MRF, mesh,
+        unit_val=True, interpret=False)
+
+    def ns(*spec):
+        return NamedSharding(mesh, P(*spec))
+    params, opt = _parts_state(ns("tp", None), ns())
+    step.lower(params, opt, _sds((), jnp.float32, ns()),
+               _sds((B, F), jnp.int32, ns("dp", None)),
+               _sds((B,), jnp.float32, ns("dp")),
+               _sds((B,), jnp.float32, ns("dp"))).compile()
+
+
+N, D, BINS = 1 << 20, 28, 64                    # bench_trees geometry
+
+
+def _hist(fn, n_nodes, fast):
+    jax.jit(lambda b, l, w: fn(b, l, w, n_nodes, BINS, fast=fast)).lower(
+        _sds((N, D), jnp.uint8), _sds((N,), jnp.int32),
+        _sds((N, 3), jnp.float32)).compile()
+
+
+def hist_flat():
+    for m in (1, 8):
+        _hist(pallas_hist.level_histogram, m, False)
+
+
+def hist_dense():
+    dp = -(-D // 8) * 8
+    for m, fast in ((1, True), (64, True), (8, False)):
+        jax.jit(lambda b, l, w: pallas_hist.level_histogram_dense(
+            b, l, w, m, BINS, fast=fast)).lower(
+            _sds((dp, N), jnp.uint8), _sds((N,), jnp.int32),
+            _sds((N, 3), jnp.float32)).compile()
+
+
+def hist_sorted():
+    for m in (64, 256):
+        _hist(pallas_hist.level_histogram_sorted, m, False)
+
+
+CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
+                                 parts_step_sharded, hist_flat, hist_dense,
+                                 hist_sorted)}
+
+if __name__ == "__main__":
+    for name in sys.argv[1:]:
+        t0 = time.perf_counter()
+        CASES[name]()
+        print(json.dumps({"case": name, "ok": True, "seconds":
+                          round(time.perf_counter() - t0, 1)}), flush=True)
