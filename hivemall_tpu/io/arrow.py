@@ -25,6 +25,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import get_tracer
 from .sparse import SparseBatch, SparseDataset
 
 __all__ = ["read_parquet", "read_csv", "read_arrow", "table_to_dataset",
@@ -289,7 +290,8 @@ class ParquetStream:
         if self.decode_ahead <= 0:
             for f in files:
                 t0 = _time.perf_counter()
-                ds = self._shard(f)
+                with get_tracer().span("source.wait_shard"):
+                    ds = self._shard(f)     # synchronous: the wait IS the decode
                 self.stats.add(prep_seconds=_time.perf_counter() - t0,
                                batches_prepared=1)
                 yield ds
@@ -317,7 +319,8 @@ class ParquetStream:
                 pending.append(ex.submit(timed_shard, f))
             while pending:
                 t0 = _time.perf_counter()
-                ds = pending.popleft().result()
+                with get_tracer().span("source.wait_shard"):
+                    ds = pending.popleft().result()
                 self.stats.add(prep_wait_seconds=_time.perf_counter() - t0)
                 nxt = next(it, None)
                 if nxt is not None:
@@ -367,25 +370,49 @@ class ParquetStream:
                                    workers=self.decode_ahead)
         L = max_len or self.max_row_len
         rng = np.random.default_rng(seed)
+        # source.assemble: one span per batch yielded, carrying the batch's
+        # ordinal in this traversal. It covers the work (row gather and
+        # padding in SparseDataset.batches; for a shard's FIRST batch also
+        # the shard's concat, permutation and _take_rows, which run
+        # back-to-back with it), never the time this generator sits
+        # suspended at `yield` nor the wait for a decoded shard
+        # (source.wait_shard, in _iter_shards). The rng calls keep their
+        # order, so shuffles are bit-identical to the untraced loop.
+        tracer = get_tracer()
+        n_out = 0
         for ep in range(epochs):
             order = rng.permutation(len(self.files)) if shuffle \
                 else np.arange(len(self.files))
             carry: Optional[SparseDataset] = None
             for ds in self._iter_shards([self.files[fi] for fi in order]):
-                if carry is not None:
-                    ds = _concat_datasets(carry, ds)
-                    carry = None
-                n = len(ds)
-                n_full = (n // batch_size) * batch_size
-                row_order = rng.permutation(n) if shuffle else np.arange(n)
-                full = _take_rows(ds, row_order[:n_full])
-                yield from full.batches(batch_size, shuffle=False,
-                                        max_len=L, truncate=truncate)
-                if n_full < n:          # remainder rows roll into next shard
-                    carry = _take_rows(ds, row_order[n_full:])
+                with tracer.span("source.assemble", None, n_out):
+                    if carry is not None:
+                        ds = _concat_datasets(carry, ds)
+                        carry = None
+                    n = len(ds)
+                    n_batches = n // batch_size
+                    n_full = n_batches * batch_size
+                    row_order = rng.permutation(n) if shuffle \
+                        else np.arange(n)
+                    full = _take_rows(ds, row_order[:n_full])
+                    if n_full < n:      # remainder rows roll into next shard
+                        carry = _take_rows(ds, row_order[n_full:])
+                    it = full.batches(batch_size, shuffle=False,
+                                      max_len=L, truncate=truncate)
+                    b = next(it) if n_batches else None
+                for i in range(n_batches):
+                    if i:
+                        with tracer.span("source.assemble", None, n_out):
+                            b = next(it)
+                    yield b
+                    n_out += 1
             if carry is not None and len(carry):
-                yield from carry.batches(batch_size, shuffle=False,
-                                         max_len=L, truncate=truncate)
+                # the remainder is always short of a batch: one padded one
+                with tracer.span("source.assemble", None, n_out):
+                    b = next(carry.batches(batch_size, shuffle=False,
+                                           max_len=L, truncate=truncate))
+                yield b
+                n_out += 1
 
 
 def _take_rows(ds: SparseDataset, rows: np.ndarray) -> SparseDataset:

@@ -89,17 +89,18 @@ def drain_until_dead(q: "queue.Queue", thread: threading.Thread,
             break
 
 
-def _timed_call(fn, item):
+def _timed_call(fn, item, batch=None):
     """Module-level so ProcessPoolExecutor can pickle the task (a bound
     pipeline method would drag the queue/lock along). Returns (result,
     seconds) so prep time is measured in the worker, recorded by the
-    consumer. The ``ingest.prep`` span is likewise recorded IN the worker
+    consumer. ``batch`` is the item's ordinal in the source's order.
+    The ``ingest.prep`` span is likewise recorded IN the worker
     thread — the tracer's ring is thread-safe, and worker-side spans are
     what the obs rollup attributes prep time with (process pools record
     into the child's tracer, which is lost — thread pools are the default
     and the traced configuration)."""
     t0 = time.perf_counter()
-    with get_tracer().span("ingest.prep"):
+    with get_tracer().span("ingest.prep", None, batch):
         out = fn(item)
     return out, time.perf_counter() - t0
 
@@ -225,6 +226,7 @@ class IngestPipeline:
             self.stats.pool = "none"
             self._src: Optional[Iterator[Any]] = iter(src)
             self._exec = None
+            self._n = 0                 # items taken (`batch` of the span)
             return
         import concurrent.futures as cf
         import multiprocessing as mp
@@ -249,8 +251,8 @@ class IngestPipeline:
 
         def submit_loop(it: Iterator[Any]) -> None:
             try:
-                for item in it:
-                    f = ex.submit(_timed_call, fn, item)
+                for n, item in enumerate(it):
+                    f = ex.submit(_timed_call, fn, item, n)
                     t0 = time.perf_counter()
                     q.put(f)            # blocking; close() drains to wake
                     stats.add(
@@ -267,7 +269,8 @@ class IngestPipeline:
                 q.put(_STOP)
 
         self._submitter = threading.Thread(target=submit_loop,
-                                           args=(iter(src),), daemon=True)
+                                           args=(iter(src),), daemon=True,
+                                           name="ingest-source")
         self._submitter.start()
 
     # -- consumer side ------------------------------------------------------
@@ -281,12 +284,13 @@ class IngestPipeline:
             item = next(self._src)      # StopIteration ends the stream
             t0 = time.perf_counter()
             try:
-                with get_tracer().span("ingest.prep"):
+                with get_tracer().span("ingest.prep", None, self._n):
                     out = self._fn(item)
             except BaseException:
                 self.stats.add(worker_errors=1)
                 self._closed.set()
                 raise
+            self._n += 1
             self.stats.add(prep_seconds=time.perf_counter() - t0,
                            batches_prepared=1)
             return out

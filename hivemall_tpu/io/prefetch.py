@@ -45,7 +45,7 @@ _STOP = object()
 def stage_batch(b, device=None):
     """Traced wrapper (``h2d.stage`` span) over :func:`_stage_batch` —
     the transfer is the seam the obs rollup attributes h2d time with."""
-    with get_tracer().span("h2d.stage"):
+    with get_tracer().span("h2d.stage", getattr(b, "seq", None)):
         return _stage_batch(b, device)
 
 
@@ -68,10 +68,10 @@ def _stage_batch(b, device=None):
     put = (lambda a: jax.device_put(a, device)) if device is not None \
         else jax.device_put
     if isinstance(b, PackedBatch):
-        return PackedBatch(put(b.buf), b.B, b.L, b.n_valid)
+        return PackedBatch(put(b.buf), b.B, b.L, b.n_valid, seq=b.seq)
     if isinstance(b, PackedMegaBatch):
         staged = PackedMegaBatch(put(b.buf), b.B, b.L, nv=b.nv,
-                                 nv_dev=put(b.nv))
+                                 nv_dev=put(b.nv), seq=b.seq)
         jax.block_until_ready((staged.buf, staged.nv_dev))
         return staged
     if isinstance(b, MegaBatch):
@@ -80,7 +80,7 @@ def _stage_batch(b, device=None):
                            put(b.label),
                            None if b.field is None else put(b.field),
                            nv=b.nv, nv_dev=put(b.nv),
-                           fieldmajor=b.fieldmajor)
+                           fieldmajor=b.fieldmajor, seq=b.seq)
         jax.block_until_ready(
             [a for a in (staged.idx, staged.val, staged.label,
                          staged.field, staged.nv_dev) if a is not None])
@@ -89,7 +89,7 @@ def _stage_batch(b, device=None):
                        None if b.val is None else put(b.val),
                        put(b.label),
                        None if b.field is None else put(b.field),
-                       b.n_valid, fieldmajor=b.fieldmajor)
+                       b.n_valid, fieldmajor=b.fieldmajor, seq=b.seq)
 
 
 class DevicePrefetcher:
@@ -141,7 +141,8 @@ class DevicePrefetcher:
                 # drain-until-exit loop exactly like the staging put above
                 q.put(_STOP)
 
-        self._thread = threading.Thread(target=work, daemon=True)
+        self._thread = threading.Thread(target=work, daemon=True,
+                                        name="h2d-prefetch")
         self._thread.start()
 
     def close(self) -> None:
@@ -234,6 +235,8 @@ class MegabatchStager:
         self._reuse = bool(reuse) and jax.default_backend() != "cpu"
         self._rings: dict = {}          # kind key -> [bufset, bufset]
         self._ring_pos: dict = {}
+        self._n_in = 0                  # source batches taken (`batch`)
+        self._n_out = 0                 # items emitted (`seq`)
 
     # -- kind/grouping -------------------------------------------------------
     @staticmethod
@@ -270,7 +273,11 @@ class MegabatchStager:
         return bufs
 
     def _stack(self, window):
-        with get_tracer().span("stager.stack"):
+        # seq: the ordinal this dispatch gets when emitted (whatever is
+        # queued in _out goes first); batch: the window's first source
+        # batch (the window is the last len(window) batches taken)
+        with get_tracer().span("stager.stack", self._n_out + len(self._out),
+                               self._n_in - len(window)):
             return self._stack_inner(window)
 
     def _stack_inner(self, window):
@@ -335,6 +342,7 @@ class MegabatchStager:
                 self._done = True
                 self._flush(full=False)        # ragged tail -> K=1 path
                 continue
+            self._n_in += 1
             kind = self._kind(b)
             if kind is None:
                 self._flush(full=False)
@@ -347,4 +355,8 @@ class MegabatchStager:
             self._window.append(b)
             if len(self._window) >= self._k:
                 self._flush(full=True)
-        return self._out.pop(0)
+        out = self._out.pop(0)
+        if hasattr(out, "seq"):         # stacked windows and singles alike
+            out.seq = self._n_out
+        self._n_out += 1
+        return out

@@ -122,6 +122,7 @@ class SparseBatch:
     field: Optional[np.ndarray] = None  # int32 [B, L], FFM only
     n_valid: Optional[int] = None    # rows < n_valid are real; rest are padding
     fieldmajor: bool = False         # canonical slot->field layout (FFM)
+    seq: Optional[int] = None        # dispatch ordinal (see MegaBatch.seq)
 
     @property
     def batch_size(self) -> int:
@@ -166,6 +167,7 @@ class PackedBatch:
     L: int
     n_valid: Optional[int] = None
     fieldmajor: bool = True
+    seq: Optional[int] = None        # dispatch ordinal (see MegaBatch.seq)
 
     @property
     def batch_size(self) -> int:
@@ -187,7 +189,12 @@ class MegaBatch:
     (the accounting side reads it without a device sync); ``nv_dev`` is
     its staged device copy, set by ``io.prefetch.stage_batch`` so the
     scan body can rebuild each step's row mask on device (4*B fewer
-    bytes per step on the link than shipping the float masks)."""
+    bytes per step on the link than shipping the float masks).
+
+    ``seq`` is the dispatch's ordinal in its stream, given by the stager
+    as it emits (stacked windows and flushed singles alike, from 0) and
+    kept by ``stage_batch``: the id the ``stager.stack``, ``h2d.stage``
+    and ``dispatch.*`` spans of one dispatch share (obs.trace)."""
 
     idx: np.ndarray                  # int32 [K, B, L]
     val: Optional[np.ndarray]        # float32 [K, B, L]; None = unit values
@@ -196,6 +203,7 @@ class MegaBatch:
     nv: Optional[np.ndarray] = None  # int32 [K] valid rows per step (host)
     nv_dev: Optional[object] = None  # staged device copy of nv
     fieldmajor: bool = False
+    seq: Optional[int] = None        # dispatch ordinal in the stream
 
     @property
     def n_steps(self) -> int:
@@ -221,6 +229,7 @@ class PackedMegaBatch:
     L: int
     nv: np.ndarray = None            # int32 [K] (host)
     nv_dev: Optional[object] = None
+    seq: Optional[int] = None        # dispatch ordinal (see MegaBatch.seq)
 
     @property
     def n_steps(self) -> int:

@@ -182,6 +182,9 @@ def learner_option_spec(name: str, *, classification: bool,
     return s
 
 
+_END = object()          # end-of-stream sentinel for next(it, _END)
+
+
 def _identity_prep(batch):
     """Module-level identity prep — the picklable stand-in for trainers
     whose parallel prep leg is the base no-op, so ``-ingest_pool process``
@@ -402,8 +405,17 @@ class LearnerBase:
         emit ``train_step`` (the reportProgress analog) and, when tracing,
         the per-stage ``span_rollup``. ``-telemetry_every`` boundaries
         additionally emit the full registry snapshot."""
-        if self._t % 256 < window:
+        fold = self._t % 256 < window
+        every = self._telemetry_every
+        telemetry = bool(every) and self._t % every < window
+        if fold:
             self._fold_loss()
+        if fold or telemetry:
+            with self._tracer.span("loop.cadence"):
+                self._emit_cadence(fold, telemetry)
+
+    def _emit_cadence(self, fold: bool, telemetry: bool) -> None:
+        if fold:
             fl = self._flight
             if fl.enabled:
                 # the trainer's heartbeat in the black box: a fit that
@@ -422,8 +434,7 @@ class LearnerBase:
                 if self._tracer.enabled:
                     stream.emit("span_rollup", trainer=self.NAME,
                                 step=self._t, stages=self._tracer.rollup())
-        every = self._telemetry_every
-        if every and self._t % every < window:
+        if telemetry:
             # refresh the device-memory gauges FIRST so the snapshot about
             # to be emitted carries this boundary's sample (and the
             # live-bytes stream feeds the mem-drift detector at exactly
@@ -568,7 +579,7 @@ class LearnerBase:
             if prefetch:
                 it = self._wrap_prefetch(it, closers)
             try:
-                for b in it:
+                for b in self._inputs(it):
                     self._dispatch(b)
             finally:
                 for c in reversed(closers):
@@ -830,11 +841,48 @@ class LearnerBase:
             None if batch.val is None else put(batch.val, P("dp", None)),
             put(batch.label, P("dp")),
             None if batch.field is None else put(batch.field, P("dp", None)),
-            n_valid=batch.n_valid, fieldmajor=batch.fieldmajor)
+            n_valid=batch.n_valid, fieldmajor=batch.fieldmajor,
+            seq=batch.seq)
+
+    def _inputs(self, it) -> Iterator:
+        """Iterate a staged input stream with every wait for the next item
+        under a ``loop.wait_input`` span: what the train loop's thread does
+        between dispatches when it is not emitting. Every dispatch loop
+        draws its inputs through here."""
+        it = iter(it)
+        tracer = self._tracer
+        while True:
+            with tracer.span("loop.wait_input"):
+                b = next(it, _END)
+            if b is _END:
+                return
+            yield b
+
+    def _source_side(self, batches, convert_labels: bool
+                     ) -> Iterator[SparseBatch]:
+        """The serial source leg of a streamed fit: label conversion +
+        pair tracking stay on HOST arrays and in STREAM ORDER (the source
+        side of the pipeline is one thread); _preprocess_train_batch then
+        fans out over the prep workers. ``source.note_batch`` carries the
+        batch's ordinal in this stream."""
+        tracer = self._tracer
+        for n, b in enumerate(batches):
+            if convert_labels:
+                b = SparseBatch(b.idx, b.val,
+                                self._convert_labels(b.label),
+                                b.field, n_valid=b.n_valid,
+                                fieldmajor=b.fieldmajor)
+            with tracer.span("source.note_batch", None, n):
+                # ingest-side stats over HOST arrays (np.asarray of
+                # already-host data) — no device sync happens here
+                # graftcheck: disable=GC07
+                self._note_batch(b)
+            yield b
 
     def fit_stream(self, batches: Iterable[SparseBatch], *,
                    convert_labels: bool = True,
                    resume: bool = False,
+                   on_dispatch=None,
                    _emit_done: bool = True) -> "LearnerBase":
         """Out-of-core training over a stream of padded batches (e.g.
         io.arrow.ParquetStream.batches): each batch dispatches one jitted
@@ -852,7 +900,13 @@ class LearnerBase:
         stream prefix and continues; at -steps_per_dispatch 1 the
         post-restore loss trajectory is bit-exact vs. an uninterrupted
         run (the stream must be deterministic — same shard order and
-        shuffle seed)."""
+        shuffle seed).
+
+        ``on_dispatch(seq, steps, examples)`` is called on the train
+        loop's thread after each dispatch: the dispatch's ordinal in this
+        call (the ``seq`` its spans carry), the optimizer steps it fused
+        and the examples it applied. The per-step losses go to
+        :attr:`loss_sink`."""
         import jax
         self.pipeline_stats = PipelineStats()
         # HIVEMALL_TPU_PROF covers the streaming path too (the long-running
@@ -874,31 +928,23 @@ class LearnerBase:
         # readable between runs for as long as the trainer does
         autosaver = self._ck_manager = self._autosaver()
 
-        def host_side() -> Iterator[SparseBatch]:
-            # label conversion + pair tracking stay on HOST arrays and in
-            # STREAM ORDER (the source side of the pipeline is serial);
-            # _preprocess_train_batch then fans out over the prep workers
-            for b in batches:
-                if convert_labels:
-                    b = SparseBatch(b.idx, b.val,
-                                    self._convert_labels(b.label),
-                                    b.field, n_valid=b.n_valid,
-                                    fieldmajor=b.fieldmajor)
-                self._note_batch(b)
-                yield b
-
         closers: List = []
-        it: Iterable[SparseBatch] = self._ingest_iter(host_side(), closers)
+        it: Iterable[SparseBatch] = self._ingest_iter(
+            self._source_side(batches, convert_labels), closers)
         prefetch = jax.default_backend() != "cpu" and self.mesh is None
         it = self._wrap_megabatch(it, prefetch=prefetch)
         if prefetch:
             it = self._wrap_prefetch(it, closers)
         try:
-            for b in it:
+            for seq, b in enumerate(self._inputs(it)):
+                examples = self._examples
                 self._dispatch(b)
                 # stream position = SOURCE batches consumed (a fused K-step
                 # window is K source batches) — what resume() skips past
-                self._stream_pos += int(getattr(b, "n_steps", 1))
+                steps = int(getattr(b, "n_steps", 1))
+                self._stream_pos += steps
+                if on_dispatch is not None:
+                    on_dispatch(seq, steps, self._examples - examples)
                 if autosaver is not None:
                     autosaver.maybe_save(self)
         finally:
@@ -1027,6 +1073,21 @@ class LearnerBase:
     # tests pin exact batch order through it. None (default) costs one
     # attribute check per dispatch and never syncs the device.
     _trace_losses: Optional[List[float]] = None
+    #: public per-step loss sink: a list (every dispatched step appends
+    #: its loss sum, a host float) or a callable taking one. Fed where
+    #: ``_trace_losses`` is; setting it makes each dispatch fetch its
+    #: losses, so it is for checks and short runs, not for production.
+    loss_sink = None
+
+    def _feed_losses(self, losses) -> None:
+        """``losses``: the device loss sum(s) of the dispatch just made."""
+        vals = [float(v) for v in np.atleast_1d(np.asarray(losses))]
+        if self._trace_losses is not None:
+            self._trace_losses.extend(vals)
+        sink = self.loss_sink
+        if sink is not None:
+            for v in vals:
+                sink(v) if callable(sink) else sink.append(v)
 
     def _dispatch(self, batch) -> None:
         if isinstance(batch, (MegaBatch, PackedMegaBatch)):
@@ -1039,7 +1100,7 @@ class LearnerBase:
         # the next blocking boundary) — the same semantics as the bench's
         # stage decomposition
         t0 = time.perf_counter()
-        with self._tracer.span("dispatch.step"):
+        with self._tracer.span("dispatch.step", getattr(batch, "seq", None)):
             loss_sum = self._train_batch(batch)
         self._devprof.note_dispatch(time.perf_counter() - t0, 1)
         self._t += 1
@@ -1050,8 +1111,8 @@ class LearnerBase:
         self._loss_pending = self._loss_pending + loss_sum
         self._examples += nv
         self._meter.add(nv)
-        if self._trace_losses is not None:
-            self._trace_losses.append(float(loss_sum))
+        if self._trace_losses is not None or self.loss_sink is not None:
+            self._feed_losses(loss_sum)
         self._emit_cadence_events(1)        # reportProgress analog (§6)
         if self._mixer is not None:
             self._mixer.touch(batch.idx[:nv])
@@ -1069,17 +1130,15 @@ class LearnerBase:
         if self.mesh is not None:
             mb = self._shard_megabatch(mb)
         t0 = time.perf_counter()
-        with self._tracer.span("dispatch.megastep"):
+        with self._tracer.span("dispatch.megastep", mb.seq):
             losses = self._train_megabatch(mb)      # [K] device array
         self._devprof.note_dispatch(time.perf_counter() - t0, K)
         self._t += K
         self._loss_pending = self._loss_pending + losses.sum()
         self._examples += nv_total
         self._meter.add(nv_total)
-        if self._trace_losses is not None:
-            import numpy as np
-            self._trace_losses.extend(
-                float(v) for v in np.asarray(losses))
+        if self._trace_losses is not None or self.loss_sink is not None:
+            self._feed_losses(losses)
         # emit when this window crossed a multiple-of-256 step boundary
         # (the K=1 condition `t % 256 == 0` is the K=1 case of this)
         self._emit_cadence_events(K)
@@ -1145,10 +1204,13 @@ class LearnerBase:
             put(mb.label, P(None, "dp")),
             None if mb.field is None else put(mb.field,
                                               P(None, "dp", None)),
-            nv=mb.nv, nv_dev=put(mb.nv, P()), fieldmajor=mb.fieldmajor)
+            nv=mb.nv, nv_dev=put(mb.nv, P()), fieldmajor=mb.fieldmajor,
+            seq=mb.seq)
 
     def _fold_loss(self) -> None:
-        self._loss_sum += float(self._loss_pending)
+        # float() is the one place the train loop blocks on the device
+        with self._tracer.span("loop.fold_loss"):
+            self._loss_sum += float(self._loss_pending)
         self._loss_pending = 0.0
 
     @property

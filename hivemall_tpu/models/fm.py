@@ -172,24 +172,28 @@ def _packed_megawrap_cached(base_step, B: int, L: int):
     the scan carry — XLA updates the tables in place across all K
     steps."""
     core = getattr(base_step, "core", base_step)
+    from ..ops.scan import _profiled_megastep, scopes_in_name
 
     @_partial(jax.jit, donate_argnums=(0, 1))
-    def fn(params, opt_state, t0, bufs, nvs):
+    @scopes_in_name
+    def packed_megastep(params, opt_state, t0, bufs, nvs):
         def body(carry, x):
             p, s, t = carry
             idx, label, mask = _unpack_on_device(x["buf"], x["nv"], B, L)
             p, s, loss = core(p, s, t, idx, label, mask)
             return (p, s, t + 1.0), loss
 
-        (p, s, _), losses = jax.lax.scan(
-            body, (params, opt_state, t0), {"buf": bufs, "nv": nvs})
+        # the phase scopes are the core's own (ops/fm.py); the unpack and
+        # the scan's slicing stay under hm.scan alone
+        with jax.named_scope("hm.scan"):
+            (p, s, _), losses = jax.lax.scan(
+                body, (params, opt_state, t0), {"buf": bufs, "nv": nvs})
         return p, s, losses
 
     # same devprof dispatch boundary as ops.scan.megastep_for: the packed
     # flagship path must not be the one fused dispatch whose peak-bytes
     # tracking silently reads zero
-    from ..ops.scan import _profiled_megastep
-    return _profiled_megastep(fn)
+    return _profiled_megastep(packed_megastep)
 
 
 @_instrument("ffm", "packed_step", shape_args=(1, 2))
@@ -1002,7 +1006,7 @@ class FFMTrainer(FMTrainer):
         if prefetch:
             it = self._wrap_prefetch(it, closers)
         try:
-            for b in it:
+            for b in self._inputs(it):
                 self._dispatch(b)
         finally:
             for c in reversed(closers):
@@ -1019,7 +1023,7 @@ class FFMTrainer(FMTrainer):
         if prefetch:
             it = self._wrap_prefetch(it, closers)
         try:
-            for b in it:
+            for b in self._inputs(it):
                 self._dispatch(b)
         finally:
             for c in reversed(closers):
@@ -1138,7 +1142,7 @@ class FFMTrainer(FMTrainer):
         staged: list = []
         cache_on = True
         cached_bytes = 0
-        for b in it:
+        for b in self._inputs(it):
             if cache_on and isinstance(b, PackedBatch):
                 cached_bytes += int(b.buf.size)
                 if cached_bytes > budget:
@@ -1216,7 +1220,7 @@ class FFMTrainer(FMTrainer):
 
     def fit_stream(self, batches, *, convert_labels: bool = True,
                    epochs: int = 1, replay_shuffle: bool = True,
-                   resume: bool = False) -> "FFMTrainer":
+                   resume: bool = False, on_dispatch=None) -> "FFMTrainer":
         """Out-of-core epochs with the device replay cache (VERDICT r4
         weak #5: -iters over Parquet re-paid the link every epoch).
 
@@ -1230,16 +1234,19 @@ class FFMTrainer(FMTrainer):
 
         ``resume`` (docs/RELIABILITY.md) is the base single-stream
         contract; the multi-epoch replay form has no checkpointed stream
-        position to skip into, so the combination is rejected."""
+        position to skip into, so the combination is rejected. So is
+        ``on_dispatch`` there: a replayed epoch has no staged input to
+        number."""
         if epochs <= 1:
             it = batches() if callable(batches) else batches
             return super().fit_stream(it, convert_labels=convert_labels,
-                                      resume=resume)
-        if resume:
+                                      resume=resume, on_dispatch=on_dispatch)
+        if resume or on_dispatch is not None:
             raise ValueError(
-                "fit_stream(resume=True) needs the single-stream form "
-                "(epochs=1); the epochs>1 replay path has no stream "
-                "position to resume into")
+                "fit_stream(resume=True) and fit_stream(on_dispatch=...) "
+                "need the single-stream form (epochs=1); the epochs>1 "
+                "replay path has no stream position to resume into and "
+                "no staged inputs to number")
         if not callable(batches):
             raise ValueError(
                 "fit_stream(epochs>1) needs a zero-arg factory returning "
@@ -1253,23 +1260,11 @@ class FFMTrainer(FMTrainer):
             self._emit_train_done()    # ONE record for the whole run
             return self
 
-        def host_side():
-            for b in batches():
-                if convert_labels:
-                    b = SparseBatch(b.idx, b.val,
-                                    self._convert_labels(b.label),
-                                    b.field, n_valid=b.n_valid,
-                                    fieldmajor=b.fieldmajor)
-                # ingest-side stats over HOST arrays (np.asarray of
-                # already-host data) — no device sync happens here
-                # graftcheck: disable=GC07
-                self._note_batch(b)
-                yield b
-
         from ..io.pipeline import PipelineStats
         self.pipeline_stats = PipelineStats()
         closers: list = []
-        it = self._ingest_iter(host_side(), closers)
+        it = self._ingest_iter(self._source_side(batches(), convert_labels),
+                               closers)
         prefetch = jax.default_backend() != "cpu"
         if prefetch:
             it = self._wrap_prefetch(it, closers)

@@ -8,15 +8,43 @@ returns a shared no-op context manager — the hot path pays nothing.
 Span sites (the training pipeline's real seams — docs/OBSERVABILITY.md):
 
 ========================  ===================================================
+``source.wait_shard``     ParquetStream blocked on the next decoded shard
+``source.assemble``       one source batch: row gather + padding (the
+                          shard's concat/shuffle/take rides on its first
+                          batch); never the time suspended at ``yield``
+``source.note_batch``     the trainer's per-batch stream-order hook
 ``ingest.prep``           host batch prep (IngestPipeline worker fn, both
                           the pool workers and the sequential fallback)
 ``stager.stack``          K-step megabatch stacking (MegabatchStager)
 ``h2d.stage``             host->device transfer (prefetch.stage_batch)
+``loop.wait_input``       the train loop blocked on its next staged input
 ``dispatch.step``         one jitted step dispatch (host-side boundary)
 ``dispatch.megastep``     one fused K-step lax.scan dispatch
+``loop.fold_loss``        the loss fold's ``float()``: the one place the
+                          train loop blocks on the device
+``loop.cadence``          what a cadence boundary emits after the fold
 ``mix.exchange``          one MIX exchange incl. retries + fold-back
 ``checkpoint.save``       one atomic bundle save
 ========================  ===================================================
+
+Every span records its thread (id and name), a process-unique ``id`` and
+the ``parent`` id of the innermost span open on the same thread when it
+began. Two ordinals tie the spans of one unit of work together across
+threads: ``batch``, a source batch's position in its stream (each stage
+counts what passes it, in order, from 0), and ``seq``, a dispatch's
+position, given where the stager emits it and carried on the megabatch
+object through the prefetcher to the dispatch. Both ride positionally —
+``span(name, seq, batch)`` — so a disabled tracer builds nothing at the
+call site.
+
+One clock: while a ``jax.profiler`` session is live (the harness's, or
+``HIVEMALL_TPU_PROF``), every ``span()`` of an enabled tracer is also
+a ``jax.profiler.TraceAnnotation`` of the same name with ``seq`` /
+``batch`` / ``args`` as its stats, so the program's spans sit in the
+xplane's host plane on the profiler's clock beside the device ops. The
+tracer does not ask whether a session is live: an annotation outside one
+costs under a microsecond. (``add_span`` records an interval that is
+already over, which an annotation cannot; those stay Chrome-only.)
 
 Host-side semantics: a dispatch span measures the host's time in the
 dispatch call (on CPU that is the synchronous step; on accelerators it is
@@ -49,6 +77,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -78,6 +107,7 @@ _NULL_SPAN = _NullSpan()
 # host (pid alone recycles); 2 bytes is plenty for a serving fleet
 _TRACE_SALT = int.from_bytes(os.urandom(2), "big")
 _trace_seq = itertools.count(1)
+_span_ids = itertools.count(1)
 
 
 def mint_trace_id() -> str:
@@ -108,20 +138,54 @@ class _TraceCtx:
         return False
 
 
-class _Span:
-    __slots__ = ("_tracer", "name", "t0")
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` where this process has imported
+    jax, else None: without jax no profiler session can be live, and a
+    process that never needed jax (the fleet router) is not made to
+    import it for its spans."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation
 
-    def __init__(self, tracer: "Tracer", name: str):
+
+class _Span:
+    __slots__ = ("_tracer", "name", "seq", "batch", "args", "id", "parent",
+                 "t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, seq, batch, args):
         self._tracer = tracer
         self.name = name
+        self.seq = seq
+        self.batch = batch
+        self.args = args
 
     def __enter__(self):
+        tls = self._tracer._tls
+        stack = getattr(tls, "stack", None)
+        if stack is None:
+            stack = tls.stack = []
+        self.parent = stack[-1].id if stack else None
+        self.id = next(_span_ids)
+        stack.append(self)
+        cls = _annotation_cls()
+        if cls is None:
+            self._ann = None
+        else:
+            kw = dict(self.args) if self.args else {}
+            if self.seq is not None:
+                kw["seq"] = self.seq
+            if self.batch is not None:
+                kw["batch"] = self.batch
+            self._ann = cls(self.name, **kw)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._record(self.name, self.t0,
-                             time.perf_counter() - self.t0)
+        dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._tls.stack.pop()
+        self._tracer._record(self.name, self.t0, dur, span=self)
         return False
 
 
@@ -184,12 +248,18 @@ class Tracer:
             self.dropped = 0
 
     # -- recording -----------------------------------------------------------
-    def span(self, name: str):
+    def span(self, name: str, seq: Optional[int] = None,
+             batch: Optional[int] = None, args: Optional[dict] = None):
         """Context manager timing one span. ~Free when disabled: one
-        attribute check, shared no-op object, no allocation."""
+        attribute check, shared no-op object, no allocation — which is
+        why ``seq`` and ``batch`` are plain parameters (pass them
+        positionally on per-batch paths) and there is no ``**kwargs``:
+        that would build a dict per call, tracer on or off. ``args``
+        (small scalars) is for the rare site with more to say; build it
+        under an ``enabled`` check."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name)
+        return _Span(self, name, seq, batch, args)
 
     def context(self, trace_id: Optional[str]):
         """Tag every span completed in this ``with`` block (on THIS
@@ -203,16 +273,26 @@ class Tracer:
                  trace: Optional[str] = None) -> None:
         """Record an already-measured span ending ~now (the router's
         forward loop measures across retries and can't wrap a single
-        ``with``). No-op when disabled."""
+        ``with``). Its parent is the innermost span open on this thread
+        now. No-op when disabled."""
         if not self.enabled:
             return
         self._record(name, time.perf_counter() - dur_s, dur_s, trace=trace)
 
     def _record(self, name: str, t0: float, dur: float,
-                trace: Optional[str] = "\0tls") -> None:
-        tid = threading.get_ident()
+                trace: Optional[str] = "\0tls",
+                span: Optional[_Span] = None) -> None:
+        thread = threading.current_thread()
+        tid = thread.ident
         if trace == "\0tls":             # default: the thread's context tag
             trace = getattr(self._tls, "trace", None)
+        if span is not None:
+            more = (thread.name, span.id, span.parent, span.seq, span.batch,
+                    span.args)
+        else:
+            stack = getattr(self._tls, "stack", None)
+            more = (thread.name, next(_span_ids),
+                    stack[-1].id if stack else None, None, None, None)
         with self._lock:
             st = self._stages.get(name)
             if st is None:
@@ -222,7 +302,7 @@ class Tracer:
             st.durs.append(dur)
             if len(self._events) == self._events.maxlen:
                 self.dropped += 1        # ring full: the append below
-            self._events.append((name, t0, dur, tid, trace))
+            self._events.append((name, t0, dur, tid, trace) + more)
 
     # -- reading -------------------------------------------------------------
     def rollup(self) -> Dict[str, dict]:
@@ -249,19 +329,26 @@ class Tracer:
         monotonic span clock re-anchored through the paired origins), so
         exports from different processes merge onto one timeline — the
         fleet router concatenates replicas' ``traceEvents`` under their
-        own pids to render one request as one cross-process flame."""
+        own pids to render one request as one cross-process flame.
+        ``args`` carries ``id``, ``thread`` and, where set, ``parent``,
+        ``seq``, ``batch``, ``trace`` and the span's own ``args``."""
         with self._lock:
             events = list(self._events)
         pid = os.getpid()
         wall0 = self._origin_wall - self._origin
         out = []
-        for name, t0, dur, tid, trace in events:
-            ev = {"name": name, "ph": "X", "cat": "hivemall_tpu",
-                  "ts": round((wall0 + t0) * 1e6, 3),
-                  "dur": round(dur * 1e6, 3), "pid": pid, "tid": tid}
-            if trace is not None:
-                ev["args"] = {"trace": trace}
-            out.append(ev)
+        for (name, t0, dur, tid, trace, tname, sid, parent, seq, batch,
+             extra) in events:
+            args = dict(extra) if extra else {}
+            args.update(id=sid, thread=tname)
+            for key, v in (("parent", parent), ("seq", seq),
+                           ("batch", batch), ("trace", trace)):
+                if v is not None:
+                    args[key] = v
+            out.append({"name": name, "ph": "X", "cat": "hivemall_tpu",
+                        "ts": round((wall0 + t0) * 1e6, 3),
+                        "dur": round(dur * 1e6, 3), "pid": pid, "tid": tid,
+                        "args": args})
         # metadata last: consumers indexing traceEvents[0] still see the
         # first real span; viewers read ph:"M" anywhere in the list
         out.append({"name": "process_name", "ph": "M", "pid": pid,

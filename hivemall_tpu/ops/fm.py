@@ -13,6 +13,14 @@ TPU shape: the per-row loops become batched gathers + einsums —
 Gradients via jax.grad: XLA turns the gathers' adjoints into scatter-adds on
 the dense tables — the batched analog of the reference's per-entry AdaGrad
 cell updates.
+
+The fused/minibatch step bodies wrap their phases in ``jax.named_scope``
+with one family-neutral vocabulary — ``hm.gather`` (the table-row gather),
+``hm.grad`` (unpack, forward, loss, backward), ``hm.scatter`` (zeros +
+scatter-add into G, or the sparse variants' per-occurrence chain) and
+``hm.update`` (the optimizer's update of table and state, w0 included) —
+so a profiler trace reduces device time by phase, not by ``fusion.48``
+(ops/scan.py ``SCOPES`` on what the compile cache does to a renamed scope).
 """
 
 from __future__ import annotations
@@ -370,8 +378,9 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
         T, w0 = params["T"], params["w0"]
         FK = F * K
         W = T.shape[1]
-        rows = ffm_row_hash(idx, T.shape[0])
-        slab = T[rows]                               # ONE gather, own dtype
+        with jax.named_scope("hm.gather"):
+            rows = ffm_row_hash(idx, T.shape[0])
+            slab = T[rows]                           # ONE gather, own dtype
 
         def batch_loss(w0f, slabf):
             if fieldmajor:
@@ -380,27 +389,31 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                 phi = _fused_phi(w0f, slabf, val, field, F, K)
             return (loss.loss(phi, label) * row_mask).sum()
 
-        loss_sum, (g0, gslab) = jax.value_and_grad(
-            batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab)
-        gslab = gslab.astype(jnp.float32)
+        with jax.named_scope("hm.grad"):
+            loss_sum, (g0, gslab) = jax.value_and_grad(
+                batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab)
+            gslab = gslab.astype(jnp.float32)
 
-        # per-occurrence L2 on present entries (reference: -lambda* at
-        # update time on the row's features), at slab level pre-scatter
-        pm = (val != 0).astype(jnp.float32) * row_mask[:, None]
-        lam_col = jnp.concatenate([
-            jnp.full((FK,), lam_v, jnp.float32),
-            jnp.full((W - FK,), lam_w, jnp.float32)])
-        gslab = gslab + lam_col * slab.astype(jnp.float32) * pm[..., None]
-        g0 = g0 + lam0 * w0.astype(jnp.float32)
+            # per-occurrence L2 on present entries (reference: -lambda* at
+            # update time on the row's features), at slab level pre-scatter
+            pm = (val != 0).astype(jnp.float32) * row_mask[:, None]
+            lam_col = jnp.concatenate([
+                jnp.full((FK,), lam_v, jnp.float32),
+                jnp.full((W - FK,), lam_w, jnp.float32)])
+            gslab = gslab + lam_col * slab.astype(jnp.float32) \
+                * pm[..., None]
+            g0 = g0 + lam0 * w0.astype(jnp.float32)
 
-        G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
-            gslab.reshape(-1, W))                    # ONE scatter-add
-        Tn, sT = optimizer.update(T.astype(jnp.float32), G,
-                                  opt_state["T"], t)
-        w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
-                                   opt_state["w0"], t)
-        return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
-                {"T": sT, "w0": s0}, loss_sum)
+        with jax.named_scope("hm.scatter"):
+            G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
+                gslab.reshape(-1, W))                # ONE scatter-add
+        with jax.named_scope("hm.update"):
+            Tn, sT = optimizer.update(T.astype(jnp.float32), G,
+                                      opt_state["T"], t)
+            w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
+                                       opt_state["w0"], t)
+            return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
+                    {"T": sT, "w0": s0}, loss_sum)
 
     if unit_val:
         assert fieldmajor, "unit_val implies the canonical fieldmajor batch"
@@ -500,8 +513,9 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
             # (None is static under jit — a separate compiled variant)
             val = (idx != 0).astype(jnp.float32)
         T, w0 = params["T"], params["w0"]
-        rows, sub = idx // P, idx % P
-        slab128 = T[rows]                            # ONE 128-lane gather
+        with jax.named_scope("hm.gather"):
+            rows, sub = idx // P, idx % P
+            slab128 = T[rows]                        # ONE 128-lane gather
 
         # differentiate wrt the PACKED rows (see make_fm_step_minibatch:
         # the masked-sum unpack's adjoint IS the one-hot expansion), with
@@ -520,15 +534,20 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
                     * (s2 - jax.lax.stop_gradient(s2)))
             return data
 
-        loss_sum, (g0, g128) = jax.value_and_grad(
-            batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
-        g128 = g128.astype(jnp.float32)
-        g0 = g0 + lam0 * w0.astype(jnp.float32)
+        with jax.named_scope("hm.grad"):
+            loss_sum, (g0, g128) = jax.value_and_grad(
+                batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
+            g128 = g128.astype(jnp.float32)
+            g0 = g0 + lam0 * w0.astype(jnp.float32)
 
-        Tn, sT = optimizer.sparse_update(
-            T, g128.reshape(-1, P * Wf), opt_state["T"], rows.ravel(), t)
-        w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
-                                   opt_state["w0"], t)
+        # the per-occurrence chain scatters and updates in one: it is the
+        # sparse variant's hm.scatter; hm.update is what is left, w0
+        with jax.named_scope("hm.scatter"):
+            Tn, sT = optimizer.sparse_update(
+                T, g128.reshape(-1, P * Wf), opt_state["T"], rows.ravel(), t)
+        with jax.named_scope("hm.update"):
+            w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
+                                       opt_state["w0"], t)
         return ({"T": Tn, "w0": w0n.astype(w0.dtype)},
                 {"T": sT, "w0": s0}, loss_sum)
 
@@ -575,8 +594,9 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
         if val is None:
             val = (idx != 0).astype(jnp.float32)
         T, w0 = params["T"], params["w0"]
-        rows, sub = idx // P, idx % P
-        slab128 = T[rows]                            # ONE 128-lane gather
+        with jax.named_scope("hm.gather"):
+            rows, sub = idx // P, idx % P
+            slab128 = T[rows]                        # ONE 128-lane gather
 
         # differentiate wrt the PACKED rows: _fm_unpack's masked-sum
         # adjoint IS the one-hot expansion, so g128 arrives fused — no
@@ -603,19 +623,22 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
                     * (s2 - jax.lax.stop_gradient(s2)))
             return data
 
-        loss_sum, (g0, g128) = jax.value_and_grad(
-            batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
-        g128 = g128.astype(jnp.float32)
-        g0 = g0 + lam0 * w0.astype(jnp.float32)
+        with jax.named_scope("hm.grad"):
+            loss_sum, (g0, g128) = jax.value_and_grad(
+                batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
+            g128 = g128.astype(jnp.float32)
+            g0 = g0 + lam0 * w0.astype(jnp.float32)
 
-        G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
-            g128.reshape(-1, P * Wf))                # ONE scatter-add
-        Tn, sT = optimizer.update(T.astype(jnp.float32), G,
-                                  opt_state["T"], t)
-        w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
-                                   opt_state["w0"], t)
-        return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
-                {"T": sT, "w0": s0}, loss_sum)
+        with jax.named_scope("hm.scatter"):
+            G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
+                g128.reshape(-1, P * Wf))            # ONE scatter-add
+        with jax.named_scope("hm.update"):
+            Tn, sT = optimizer.update(T.astype(jnp.float32), G,
+                                      opt_state["T"], t)
+            w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
+                                       opt_state["w0"], t)
+            return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
+                    {"T": sT, "w0": s0}, loss_sum)
 
     if dyn:
         def core(params, opt_state, t, idx, val, label, row_mask, lams):

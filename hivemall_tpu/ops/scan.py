@@ -36,7 +36,24 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-__all__ = ["scannable", "make_megastep", "megastep_for"]
+__all__ = ["scannable", "make_megastep", "megastep_for", "SCOPES"]
+
+# Phase scopes (jax.named_scope) name the step's device time from inside:
+# hm.gather, hm.grad, hm.scatter, hm.update in the step bodies (ops/fm.py)
+# and hm.scan around the scan here. A scope is metadata, and the persistent
+# compile cache's key leaves metadata out: a program compiled before its
+# scopes changed is served from the cache with the OLD names in its trace.
+# So the vocabulary's version is part of the megastep's name, which the key
+# does hold. Bump it in a change that alters scopes and nothing else of the
+# program (a change to the program itself gets a new key anyway).
+SCOPES = "hm1"
+
+
+def scopes_in_name(fn):
+    """Decorator, applied UNDER ``jax.jit``: the jitted module is named
+    ``<fn>_<SCOPES>``."""
+    fn.__name__ = f"{fn.__name__}_{SCOPES}"
+    return fn
 
 
 def scannable(step, core):
@@ -67,6 +84,7 @@ def make_megastep(core, *, none_val: bool = False):
     """
 
     @partial(jax.jit, donate_argnums=(0, 1))
+    @scopes_in_name
     def megastep(s1, s2, t0, nv, idx, val, label, field, lams):
         B = label.shape[1]
         xs = {"nv": nv, "idx": idx, "label": label}
@@ -91,7 +109,9 @@ def make_megastep(core, *, none_val: bool = False):
             p, s, loss = core(p, s, t, *args)
             return (p, s, t + 1.0), loss
 
-        (s1, s2, _), losses = jax.lax.scan(body, (s1, s2, t0), xs)
+        # the slicing and stacking the scan adds is named too
+        with jax.named_scope("hm.scan"):
+            (s1, s2, _), losses = jax.lax.scan(body, (s1, s2, t0), xs)
         return s1, s2, losses
 
     return megastep

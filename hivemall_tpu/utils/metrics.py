@@ -17,7 +17,6 @@ overhead per emit.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import socket
@@ -27,7 +26,7 @@ import time
 from collections import deque
 from typing import Any, Dict, IO, Optional
 
-__all__ = ["MetricsStream", "Meter", "get_stream", "profile_trace"]
+__all__ = ["MetricsStream", "Meter", "get_stream"]
 
 
 class Meter:
@@ -206,14 +205,3 @@ def close_stream() -> None:
     if _stream is not None:
         _stream.close()
         _stream = None
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: Optional[str] = None):
-    """jax.profiler trace context; no-op when log_dir is falsy."""
-    if not log_dir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield

@@ -61,8 +61,3 @@ def test_stream_bad_path_fails_soft(capsys):
     assert not s.enabled
     s.emit("ev", a=1)            # still a no-op, no raise
     assert "metrics disabled" in capsys.readouterr().err
-
-
-def test_profile_trace_noop():
-    with M.profile_trace(None):
-        pass

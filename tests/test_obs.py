@@ -51,6 +51,7 @@ def test_tracer_disabled_is_noop():
     t = Tracer(enabled=False)
     s1, s2 = t.span("a"), t.span("b")
     assert s1 is s2                     # shared null object, no allocation
+    assert t.span("h2d.stage", 7) is s1 and t.span("c", None, 3) is s1
     with s1:
         pass
     assert t.rollup() == {}
@@ -690,7 +691,7 @@ def test_tracer_context_tags_spans_into_chrome_args(tracer):
     tracer.add_span("explicit", 0.001, trace="req-42")
     evs = tracer.chrome_dict()["traceEvents"]
     by_name = {e["name"]: e for e in evs if e.get("ph") == "X"}
-    assert "args" not in by_name["untagged"]
+    assert "trace" not in by_name["untagged"]["args"]
     assert by_name["tagged"]["args"]["trace"] == "req-42"
     assert by_name["nested"]["args"]["trace"] == "inner"
     assert by_name["tagged2"]["args"]["trace"] == "req-42"
