@@ -1,6 +1,13 @@
 """Phase breakdown of the round-5 train_fm minibatch step (411k ex/s =
 79.7 ms at B=32k, L=32, K=8, dims=2^24): where do the ~34 ms above the
-gather+scatter floor go?"""
+gather+scatter floor go?
+
+The numbers quoted here and in the comments below are round-5 probe readings
+(uniform ids, a 2^24-entry bf16 table, each phase alone in its own jit). The
+benchmark's cell reads the whole step at Criteo shape from a profiler trace
+and disagrees with the "structural floor" drawn from them: PERF.md section 5.
+The fwd/bwd phase runs today's helper (ops.fm._fm_packed_phi, PR 25), not
+the masked-sum unpack these readings were taken with."""
 import os
 import sys
 import time
@@ -73,7 +80,7 @@ G = jnp.zeros((Np, 128), jnp.float32)
 print(f"dense adagrad:  {timeit(lambda: dense(T, S, G))*1e3:7.2f} ms",
       flush=True)
 
-from hivemall_tpu.ops.fm import _fm_slab_phi, _fm_unpack  # noqa: E402
+from hivemall_tpu.ops.fm import _fm_packed_phi  # noqa: E402
 from hivemall_tpu.ops.losses import get_loss  # noqa: E402
 
 loss = get_loss("logloss")
@@ -81,16 +88,13 @@ loss = get_loss("logloss")
 
 @jax.jit
 def fwdbwd(T, idx, lab):
-    rows, sub = idx // P, idx % P
-    slab = _fm_unpack(T[rows], sub, Wf, P)
+    rows, sub = idx.T // P, idx.T % P               # slot-major [L, B]
 
-    def bl(s):
-        s32 = s.astype(jnp.float32)
-        phi = _fm_slab_phi(0.0, s32[..., K], s32[..., :K],
-                           jnp.ones((B, L)))
+    def bl(s128):
+        phi, _ = _fm_packed_phi(0.0, s128, sub, jnp.ones((L, B)), K, Wf, P)
         return (loss.loss(phi, lab)).sum()
 
-    return jax.grad(bl)(slab).sum()
+    return jax.grad(bl)(T[rows]).astype(jnp.float32).sum()
 
 
 print(f"gather+fwd/bwd: {timeit(lambda: fwdbwd(T, idx, lab))*1e3:7.2f} ms",

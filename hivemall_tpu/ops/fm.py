@@ -449,19 +449,86 @@ def fm_pack_geometry(K: int) -> Tuple[int, int]:
     return Wf, P
 
 
-def _fm_unpack(slab128, sub, Wf: int, P: int):
-    """Select each slot's [Wf] block out of its packed [P*Wf] row as a
-    one-hot masked SUM over the small static P axis — pure VPU work.
-    take_along_axis here lowered to a REAL per-slot XLA gather (and its
-    adjoint to a per-slot scatter): measured ~27 ms of fwd/bwd at
-    B=32k x L=32 (experiments/probe_fm_phases.py), i.e. a second pair of
-    table-row index ops per slot hidden inside the step. The masked sum
-    is exact (7 of the P=8 addends are true zeros; one-hot is exact in
-    bf16) and its adjoint is a broadcast multiply, not a scatter."""
-    B, L = sub.shape
-    blocks = slab128.reshape(B, L, P, Wf)
-    oh = jax.nn.one_hot(sub, P, dtype=blocks.dtype)
-    return (blocks * oh[..., None]).sum(2)
+def _fm_packed_phi(w0f, s128, sub, val, K: int, Wf: int, P: int, l2=None):
+    """FM score phi [B] straight from the packed rows, SLOT-MAJOR: s128
+    [L, B, P*Wf] with sub, val [L, B]. Every array of L*B rows keeps its
+    whole lane axis, and L is the major axis so that [L, B, .] <-> [L*B, .]
+    (the gather's output, the scatter-add's input) is a view when B is a
+    multiple of 8, not a copy through the (8, 128) tiles.
+
+    A slot's [Wf] block is picked by a lane mask (lane // Wf == sub, an
+    iota compare) times val; s = sum_L and q = sum_L of squares are taken
+    per lane on whole rows, and only the [B, P*Wf] results fold their P
+    blocks (sibling blocks hold true zeros, so the fold is exact f32):
+    phi = w0 + s[K] + 1/2 sum_{k<K} (s[k]^2 - q[k]). Viewing the rows as
+    [B, L, P, Wf] instead costs a relayout on a TPU (an 8-minor array is
+    stored padded to 128 lanes) that the compiler wrote as two 128-trip
+    loops, 43% of the step (PERF.md section 5, PR 24); take_along_axis
+    lowers to a real per-slot gather and its adjoint to a scatter.
+
+    l2 = (lam_w, lam_v, pm) adds per-occurrence L2 on the occupied block
+    THROUGH autodiff: 0.5*lam*pm*(x^2 - sg(x^2)) has value exactly 0 and
+    gradient lam*pm*x, so it folds into the same backward pass and the
+    gradient wrt s128 arrives packed, sibling blocks exact zeros.
+    Returns (phi, reg)."""
+    B = sub.shape[1]
+    x = s128.astype(jnp.float32)
+    lane = jnp.arange(P * Wf)
+    own = lane // Wf == sub[..., None]               # [L, B, P*Wf]
+    xm = jnp.where(own, x * val[..., None], 0.0)
+    s = xm.sum(0).reshape(B, P, Wf).sum(1)           # fold at [B, P*Wf]
+    q = (xm * xm).sum(0).reshape(B, P, Wf).sum(1)
+    phi = w0f + s[:, K] + 0.5 * (s[:, :K] ** 2 - q[:, :K]).sum(-1)
+    if l2 is None:
+        return phi, 0.0
+    lam_w, lam_v, pm = l2
+    col = lane % Wf
+    lam = jnp.where(col < K, lam_v, jnp.where(col == K, lam_w, 0.0))
+    x2 = x * x
+    reg = 0.5 * jnp.sum(jnp.where(own, lam * pm[..., None], 0.0)
+                        * (x2 - jax.lax.stop_gradient(x2)))
+    return phi, reg
+
+
+def _fm_packed_grad(loss: Loss, params, idx, val, label, row_mask, lams,
+                    l2_on: bool, K: int, Wf: int, P: int):
+    """The front half both packed steps share: ONE gather of 128-lane
+    rows, then loss and gradient wrt w0 and the PACKED rows — the lane
+    mask's adjoint IS the expansion to the packed row, so the gradient
+    arrives whole-row for the scatter-add: no separate expand pass, no
+    hidden per-slot gather/scatter, no relayout (_fm_packed_phi).
+    Per-occurrence L2 (lam_w, lam_v) rides the same backward pass; lam0
+    is added to g0. Returns (rows [L*B], loss_sum, g0, g128 [L*B, P*Wf]
+    float32), slot-major."""
+    lam0, lam_w, lam_v = lams
+    if val is None:
+        # unit-value elision (io.sparse.SparseBatch): categorical
+        # batches never transfer val; rebuild it from idx on device
+        # (None is static under jit — a separate compiled variant)
+        val = (idx != 0).astype(jnp.float32)
+    T, w0f = params["T"], params["w0"].astype(jnp.float32)
+    with jax.named_scope("hm.gather"):
+        idx, val = idx.T, val.T                      # slot-major [L, B]
+        rows, sub = idx // P, idx % P
+        slab128 = T[rows]                            # ONE 128-lane gather
+    pm = (val != 0).astype(jnp.float32) * row_mask
+    l2 = (lam_w, lam_v, pm) if l2_on else None
+
+    def batch_loss(w0f, s128):
+        phi, reg = _fm_packed_phi(w0f, s128, sub, val, K, Wf, P, l2)
+        return (loss.loss(phi, label) * row_mask).sum() + reg
+
+    with jax.named_scope("hm.grad"):
+        loss_sum, (g0, g128) = jax.value_and_grad(
+            batch_loss, argnums=(0, 1))(w0f, slab128)
+        # the barrier keeps the flattening to [L*B, P*Wf] OUT of the
+        # backward fusion: sunk into it, the cotangents of s and q can no
+        # longer be broadcast over L in place and are written out, two
+        # more [L, B, P*Wf] arrays (0.65 GB each in the benchmark's cell:
+        # 63.5 ms a step without, 59.0 with; chip, PERF.md PR 25)
+        g128 = jax.lax.optimization_barrier(g128.astype(jnp.float32))
+        g0 = g0 + lam0 * w0f
+    return rows.reshape(-1), loss_sum, g0, g128.reshape(-1, P * Wf)
 
 
 def make_fm_score_fused(K: int):
@@ -473,9 +540,9 @@ def make_fm_score_fused(K: int):
 
     @jax.jit
     def score(w0, T, idx, val):
-        slab = _fm_unpack(T[idx // P], idx % P, Wf, P).astype(jnp.float32)
-        return _fm_slab_phi(w0.astype(jnp.float32), slab[..., K],
-                            slab[..., :K], val)
+        idx = idx.T                                  # slot-major [L, B]
+        return _fm_packed_phi(w0.astype(jnp.float32), T[idx // P], idx % P,
+                              val.T, K, Wf, P)[0]
     return score
 
 
@@ -506,45 +573,16 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
     Wf, P = fm_pack_geometry(K)
 
     def body(params, opt_state, t, idx, val, label, row_mask, lams):
-        lam0, lam_w, lam_v = (lams[0], lams[1], lams[2]) if dyn else lambdas
-        if val is None:
-            # unit-value elision (io.sparse.SparseBatch): categorical
-            # batches never transfer val; rebuild it from idx on device
-            # (None is static under jit — a separate compiled variant)
-            val = (idx != 0).astype(jnp.float32)
+        lams = (lams[0], lams[1], lams[2]) if dyn else lambdas
         T, w0 = params["T"], params["w0"]
-        with jax.named_scope("hm.gather"):
-            rows, sub = idx // P, idx % P
-            slab128 = T[rows]                        # ONE 128-lane gather
-
-        # differentiate wrt the PACKED rows (see make_fm_step_minibatch:
-        # the masked-sum unpack's adjoint IS the one-hot expansion), with
-        # per-occurrence L2 as the same zero-valued autodiff term
-        pm = (val != 0).astype(jnp.float32) * row_mask[:, None]
-        lam_col = jnp.where(jnp.arange(Wf) < K, lam_v, lam_w)
-
-        def batch_loss(w0f, s128):
-            slab = _fm_unpack(s128, sub, Wf, P).astype(jnp.float32)
-            phi = _fm_slab_phi(w0f, slab[..., K], slab[..., :K], val)
-            data = (loss.loss(phi, label) * row_mask).sum()
-            if dyn or lam_w or lam_v:
-                s2 = slab * slab
-                data = data + 0.5 * jnp.sum(
-                    lam_col * pm[..., None]
-                    * (s2 - jax.lax.stop_gradient(s2)))
-            return data
-
-        with jax.named_scope("hm.grad"):
-            loss_sum, (g0, g128) = jax.value_and_grad(
-                batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
-            g128 = g128.astype(jnp.float32)
-            g0 = g0 + lam0 * w0.astype(jnp.float32)
+        rows, loss_sum, g0, g128 = _fm_packed_grad(
+            loss, params, idx, val, label, row_mask, lams,
+            dyn or bool(lams[1] or lams[2]), K, Wf, P)
 
         # the per-occurrence chain scatters and updates in one: it is the
         # sparse variant's hm.scatter; hm.update is what is left, w0
         with jax.named_scope("hm.scatter"):
-            Tn, sT = optimizer.sparse_update(
-                T, g128.reshape(-1, P * Wf), opt_state["T"], rows.ravel(), t)
+            Tn, sT = optimizer.sparse_update(T, g128, opt_state["T"], rows, t)
         with jax.named_scope("hm.update"):
             w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
                                        opt_state["w0"], t)
@@ -576,7 +614,9 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     share while the strictly harder FFM ran 1.145x. This step does ONE
     forward gather + ONE scatter-add of the batch gradient into a dense
     G, then the optimizer's dense elementwise update: 2 index ops per
-    slot, plus an O(table) pass that costs ~5 ms against 819 GB/s.
+    slot, plus an O(table) pass (priced at ~5 ms from a round-5 probe on
+    a small table; the benchmark's 2 GiB float32 table reads 15.9 ms,
+    step.update_ms, PERF.md section 5).
 
     Semantics delta (documented, same as the FFM fused/parts paths):
     adaptive accumulators see the square of the SUMMED minibatch
@@ -590,48 +630,15 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     Wf, P = fm_pack_geometry(K)
 
     def body(params, opt_state, t, idx, val, label, row_mask, lams):
-        lam0, lam_w, lam_v = (lams[0], lams[1], lams[2]) if dyn else lambdas
-        if val is None:
-            val = (idx != 0).astype(jnp.float32)
+        lams = (lams[0], lams[1], lams[2]) if dyn else lambdas
         T, w0 = params["T"], params["w0"]
-        with jax.named_scope("hm.gather"):
-            rows, sub = idx // P, idx % P
-            slab128 = T[rows]                        # ONE 128-lane gather
-
-        # differentiate wrt the PACKED rows: _fm_unpack's masked-sum
-        # adjoint IS the one-hot expansion, so g128 arrives fused — no
-        # separate expand pass and no hidden per-slot gather/scatter
-        # (probe_fm_phases.py: take_along_axis + manual expand cost
-        # ~38 ms of the 80 ms step)
-        pm = (val != 0).astype(jnp.float32) * row_mask[:, None]
-        lam_col = jnp.where(jnp.arange(Wf) < K, lam_v, lam_w)
-
-        def batch_loss(w0f, s128):
-            slab = _fm_unpack(s128, sub, Wf, P).astype(jnp.float32)
-            phi = _fm_slab_phi(w0f, slab[..., K], slab[..., :K], val)
-            data = (loss.loss(phi, label) * row_mask).sum()
-            # per-occurrence L2 on the occupied block THROUGH autodiff:
-            # 0.5*lam*pm*(slab^2 - sg(slab^2)) has value exactly 0 and
-            # gradient lam*pm*slab — folded into the same backward pass
-            # instead of a separate masked multiply chain over the
-            # [B, L, 128] packed grad (the one-hot mask rides the unpack
-            # adjoint, so sibling blocks get exact zeros)
-            if dyn or lam_w or lam_v:
-                s2 = slab * slab
-                data = data + 0.5 * jnp.sum(
-                    lam_col * pm[..., None]
-                    * (s2 - jax.lax.stop_gradient(s2)))
-            return data
-
-        with jax.named_scope("hm.grad"):
-            loss_sum, (g0, g128) = jax.value_and_grad(
-                batch_loss, argnums=(0, 1))(w0.astype(jnp.float32), slab128)
-            g128 = g128.astype(jnp.float32)
-            g0 = g0 + lam0 * w0.astype(jnp.float32)
+        rows, loss_sum, g0, g128 = _fm_packed_grad(
+            loss, params, idx, val, label, row_mask, lams,
+            dyn or bool(lams[1] or lams[2]), K, Wf, P)
 
         with jax.named_scope("hm.scatter"):
-            G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
-                g128.reshape(-1, P * Wf))            # ONE scatter-add
+            G = jnp.zeros(T.shape, jnp.float32).at[rows].add(
+                g128)                                # ONE scatter-add
         with jax.named_scope("hm.update"):
             Tn, sT = optimizer.update(T.astype(jnp.float32), G,
                                       opt_state["T"], t)

@@ -503,6 +503,122 @@ def test_fm_fused_rejects_dense_only_optimizer():
     assert t.fm_layout == "split"
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("K", [5, 8, 20, 31, 127])
+def test_fm_packed_phi_matches_split_score_and_grad(K, dtype):
+    """The packed helper (ops.fm._fm_packed_phi: lane mask, sums over L on
+    whole rows, fold of the P blocks last) against the split-layout
+    fm_score and its jax.grad: loss, g0 and the packed gradient scattered
+    back to per-feature rows, for every pack geometry — (8,16), (16,8),
+    (24,8) a 192-lane row, (32,4), and P = 1 — with a repeated id inside a
+    row, two ids of one packed row with different sub, id-0 padding, a
+    partial row_mask and per-occurrence L2. The occurrence step (SGD) and
+    the fused scorer run the same helper and are held to the same
+    reference."""
+    import jax
+    import jax.numpy as jnp
+    from hivemall_tpu.ops.fm import (_fm_packed_phi, fm_pack_geometry,
+                                     fm_score, make_fm_score_fused,
+                                     make_fm_step_fused)
+    from hivemall_tpu.ops.losses import get_loss
+    from hivemall_tpu.ops.optimizers import make_optimizer
+
+    Wf, P = fm_pack_geometry(K)
+    assert (Wf, P) == {5: (8, 16), 8: (16, 8), 20: (24, 8), 31: (32, 4),
+                       127: (128, 1)}[K]
+    rng = np.random.default_rng(K)
+    Np = 5
+    N = Np * P
+    dt = jnp.dtype(dtype)
+    # the tables as the trainer holds them: rounded to the table dtype;
+    # the reference reads the same values, widened
+    w = np.asarray(jnp.asarray(rng.normal(0, .3, N), dt).astype(jnp.float32))
+    V = np.asarray(jnp.asarray(rng.normal(0, .3 / np.sqrt(K), (N, K)),
+                               dt).astype(jnp.float32))
+    R = np.zeros((N, Wf), np.float32)
+    R[:, :K], R[:, K] = V, w
+    T = jnp.asarray(R.reshape(Np, P * Wf), dt)
+    w0 = jnp.asarray(0.25, dt)
+    a, b = (1, 2) if P > 1 else (1, 3)     # P > 1: one packed row, two subs
+    idx = np.array([[a, b, N - 1, a, 0],   # repeated id, shared row, padding
+                    [N - 2, 0, 0, 0, 0],
+                    [b, P + 1 if P > 1 else 2, 2 * P, b, b],
+                    [3, 4, N - 1, 0, 0],
+                    [0, 0, 0, 0, 0],
+                    [a, N - 3, 1, 2, 0]], np.int32) % N
+    val = np.where(idx != 0, rng.uniform(.5, 1.5, idx.shape), 0.).astype(
+        np.float32)
+    label = np.array([1, -1, 1, -1, 1, 1], np.float32)
+    row_mask = np.array([1, 1, 1, 0, 1, 1], np.float32)
+    lam0, lam_w, lam_v = 0.02, 0.03, 0.05
+    loss = get_loss("logloss")
+    pm = (val != 0) * row_mask[:, None]
+
+    def ref_loss(w0f, wf, Vf, with_l2):
+        phi = fm_score(w0f, wf, Vf, idx, val)
+        data = (loss.loss(phi, label) * row_mask).sum()
+        l2 = 0.5 * (pm * (lam_w * wf[idx] ** 2
+                          + lam_v * (Vf[idx] ** 2).sum(-1))).sum()
+        return data + with_l2 * l2
+
+    w0f = jnp.float32(w0)
+    want_loss = ref_loss(w0f, jnp.asarray(w), jnp.asarray(V), 0.)
+    g0, gw, gV = jax.grad(ref_loss, argnums=(0, 1, 2))(
+        w0f, jnp.asarray(w), jnp.asarray(V), 1.)
+
+    idxT, valT = jnp.asarray(idx.T), jnp.asarray(val.T)
+    rows, sub = idxT // P, idxT % P
+
+    def packed_loss(w0f, s128):
+        phi, reg = _fm_packed_phi(w0f, s128, sub, valT, K, Wf, P,
+                                  (lam_w, lam_v, jnp.asarray(pm.T)))
+        return (loss.loss(phi, label) * row_mask).sum() + reg
+
+    got_loss, (h0, g128) = jax.value_and_grad(packed_loss, argnums=(0, 1))(
+        w0f, T[rows])
+    assert g128.shape == (idx.shape[1], idx.shape[0], P * Wf)
+    assert g128.dtype == dt
+    G = np.asarray(jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
+        g128.astype(jnp.float32).reshape(-1, P * Wf))).reshape(N, Wf)
+    # a bfloat16 table hands back a gradient rounded per occurrence
+    tol = dict(rtol=1e-5, atol=1e-6) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-3)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    np.testing.assert_allclose(h0, g0, rtol=1e-5)
+    np.testing.assert_allclose(G[:, :K], gV, **tol)
+    np.testing.assert_allclose(G[:, K], gw, **tol)
+    assert not G[:, K + 1:].any()           # pad lanes: exact zeros
+    assert not G[0].any()                   # id 0 is padding (val 0)
+
+    # the fused scorer: the same helper, no gradient
+    np.testing.assert_allclose(
+        make_fm_score_fused(K)(w0, T, jnp.asarray(idx), jnp.asarray(val)),
+        fm_score(w0f, jnp.asarray(w), jnp.asarray(V), idx, val),
+        rtol=1e-5, atol=1e-6)
+
+    # -fm_update occurrence with SGD: one step is T - eta * gradient
+    # (the step donates its state: T and w0 are dead after it)
+    eta, w0_was = 0.1, np.float32(w0)
+    step = make_fm_step_fused(
+        loss, make_optimizer("sgd", eta_scheme="fixed", eta0=eta, reg="no"),
+        (lam0, lam_w, lam_v), K)
+    params, _, loss_sum = step(
+        {"T": T, "w0": w0}, {"T": {}, "w0": {}}, jnp.float32(0),
+        jnp.asarray(idx), jnp.asarray(val), jnp.asarray(label),
+        jnp.asarray(row_mask))
+    want = R.copy()
+    want[:, :K] -= eta * np.asarray(gV)
+    want[:, K] -= eta * np.asarray(gw)
+    np.testing.assert_allclose(loss_sum, want_loss, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(params["T"].astype(jnp.float32)).reshape(N, Wf), want,
+        **(tol if dtype == "float32" else dict(rtol=2e-2, atol=4e-3)))
+    np.testing.assert_allclose(
+        np.float32(params["w0"]),
+        w0_was - eta * (np.float32(g0) + lam0 * w0_was),
+        rtol=1e-2 if dtype == "bfloat16" else 1e-5)
+
+
 def test_fm_adareg_increases_lambda_on_overfit():
     """-adareg (SURVEY §3.6 train_fm row): on an overfittable task (tiny
     sample, label noise, ample capacity) held-out loss degrades as the fit

@@ -39,6 +39,14 @@ def test_parts_and_histogram_kernels_compile_for_v5e():
              "hist_dense", timeout=600)
 
 
+def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
+    """One un-scanned packed FM minibatch step at the benchmark cell's
+    geometry (-dims 2^26 -factors 5, B=32768, L=39, float32): the worker
+    asserts the compiled text holds no `while(` (PR 24's parent had two,
+    one per direction of a reshape nobody saw; ~25 s of XLA compile)."""
+    _compile("fm_minibatch_step", timeout=600)
+
+
 @pytest.mark.slow
 def test_whole_sharded_step_and_sorted_histogram_compile_for_v5e():
     """The whole make_parts_step_sharded program (~85 s of XLA compile)

@@ -1,4 +1,5 @@
-"""AOT-compile the repo's Pallas kernels for a device-less TPU v5e topology.
+"""AOT-compile the repo's Pallas kernels, and the packed FM step, for a
+device-less TPU v5e topology.
 
 Run as a subprocess by tests/test_tpu_aot_compile.py (libtpu takes a lock
 and is noisy): ``python tests/tpu_aot_worker.py CASE...`` prints one JSON
@@ -19,8 +20,9 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
-from hivemall_tpu.ops import fm_pallas, pallas_hist
+from hivemall_tpu.ops import fm, fm_pallas, pallas_hist
 from hivemall_tpu.ops.losses import get_loss
+from hivemall_tpu.ops.optimizers import make_optimizer
 
 # the kernels under test ask the device policy; here the target is the
 # topology, not this process's (CPU) backend
@@ -93,6 +95,31 @@ def parts_step_sharded():
                _sds((B,), jnp.float32, ns("dp"))).compile()
 
 
+def fm_minibatch_step():
+    """ONE un-scanned make_fm_step_minibatch step at the geometry of the
+    benchmark's cell fm_criteo.stream (-dims 2^26 -factors 5, B=32768,
+    L=39, float32, unit values elided). The compiled program must hold no
+    loop: the only `while` of the megastep is its own scan. The compiler
+    wrote the packed unpack's [B, L, P, Wf] reshape as two 128-trip loops
+    over lanes, 43% of the step's device time (PERF.md, PR 24 / PR 25),
+    and on the CPU that tier-1 runs on such a reshape costs nothing."""
+    K, B, L = 5, 32768, 39
+    Wf, Pk = fm.fm_pack_geometry(K)
+    Np = (1 << 26) // Pk
+    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                         reg="no")
+    step = fm.make_fm_step_minibatch(get_loss("logloss"), opt, LAMS, K)
+    table = _sds((Np, Pk * Wf), jnp.float32)
+    text = step.lower(
+        {"T": table, "w0": _sds((), jnp.float32)},
+        {"T": {"gg": table}, "w0": {"gg": _sds((), jnp.float32)}},
+        _sds((), jnp.float32), _sds((B, L), jnp.int32), None,
+        _sds((B,), jnp.float32), _sds((B,), jnp.float32)
+    ).compile().as_text()
+    loops = text.count(" while(")
+    assert loops == 0, f"{loops} while op(s) in the one-step FM program"
+
+
 N, D, BINS = 1 << 20, 28, 64                    # bench_trees geometry
 
 
@@ -122,8 +149,8 @@ def hist_sorted():
 
 
 CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
-                                 parts_step_sharded, hist_flat, hist_dense,
-                                 hist_sorted)}
+                                 parts_step_sharded, fm_minibatch_step,
+                                 hist_flat, hist_dense, hist_sorted)}
 
 if __name__ == "__main__":
     for name in sys.argv[1:]:
