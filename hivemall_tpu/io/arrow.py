@@ -371,13 +371,16 @@ class ParquetStream:
         L = max_len or self.max_row_len
         rng = np.random.default_rng(seed)
         # source.assemble: one span per batch yielded, carrying the batch's
-        # ordinal in this traversal. It covers the work (row gather and
-        # padding in SparseDataset.batches; for a shard's FIRST batch also
-        # the shard's concat, permutation and _take_rows, which run
-        # back-to-back with it), never the time this generator sits
-        # suspended at `yield` nor the wait for a decoded shard
-        # (source.wait_shard, in _iter_shards). The rng calls keep their
-        # order, so shuffles are bit-identical to the untraced loop.
+        # ordinal in this traversal. It covers the work (the batch's row
+        # gather and padding in SparseDataset.batches_in_order; for a
+        # shard's FIRST batch also the carry's concat, the shard's
+        # permutation and the copy of its remainder, under a batch of rows
+        # each), never the time this generator sits suspended at `yield`
+        # nor the wait for a decoded shard (source.wait_shard, in
+        # _iter_shards). A shard is gathered ONCE: its batches come
+        # straight from the decoded CSR arrays in shuffled row order, with
+        # no reordered copy of the shard in between. The rng calls keep
+        # their number and order, so a seed's batches never change.
         tracer = get_tracer()
         n_out = 0
         for ep in range(epochs):
@@ -394,11 +397,10 @@ class ParquetStream:
                     n_full = n_batches * batch_size
                     row_order = rng.permutation(n) if shuffle \
                         else np.arange(n)
-                    full = _take_rows(ds, row_order[:n_full])
                     if n_full < n:      # remainder rows roll into next shard
                         carry = _take_rows(ds, row_order[n_full:])
-                    it = full.batches(batch_size, shuffle=False,
-                                      max_len=L, truncate=truncate)
+                    it = ds.batches_in_order(row_order[:n_full], batch_size,
+                                             L, truncate=truncate)
                     b = next(it) if n_batches else None
                 for i in range(n_batches):
                     if i:

@@ -474,37 +474,51 @@ class SparseDataset:
                 truncate: bool = False) -> Iterator[SparseBatch]:
         n = len(self)
         L = max(1, max_len or self.max_row_len)
-        if max_len is not None and not truncate and self.max_row_len > L:
-            raise ValueError(
-                f"max_len={L} would drop features from rows up to "
-                f"{self.max_row_len} long; pass truncate=True to allow")
         rng = np.random.default_rng(seed)
-        lens = np.diff(self.indptr).astype(np.int64)
         for ep in range(epochs):
             order = rng.permutation(n) if shuffle else np.arange(n)
-            for s in range(0, n, batch_size):
-                take = order[s: s + batch_size]
-                nv = len(take)
-                if nv < batch_size and drop_remainder:
-                    break
-                # vectorized padding: flat CSR positions of every kept slot
-                # in one fancy index (no per-row Python — the host batch
-                # assembly is on the e2e critical path, SURVEY.md §8)
-                m = np.minimum(lens[take], L)                 # [nv]
-                pos = np.arange(L, dtype=np.int64)[None, :]   # [1, L]
-                keep = pos < m[:, None]                       # [nv, L]
-                flat = np.where(keep, self.indptr[take][:, None] + pos, 0)
-                idx = np.zeros((batch_size, L), np.int32)
-                val = np.zeros((batch_size, L), np.float32)
-                if len(self.indices):        # all-empty-rows dataset guard
-                    idx[:nv] = np.where(keep, self.indices[flat], 0)
-                    val[:nv] = np.where(keep, self.values[flat], 0.0)
-                fld = None
-                if self.fields is not None:
-                    fld = np.zeros((batch_size, L), np.int32)
-                    if len(self.fields):
-                        fld[:nv] = np.where(keep, self.fields[flat], 0)
-                lab = np.zeros(batch_size, np.float32)
-                lab[:nv] = self.labels[take]
-                yield SparseBatch(idx, val, lab, fld,
-                                  n_valid=nv if nv < batch_size else None)
+            yield from self.batches_in_order(
+                order, batch_size, L, truncate=truncate,
+                drop_remainder=drop_remainder)
+
+    def batches_in_order(self, order: np.ndarray, batch_size: int, L: int,
+                         *, truncate: bool = False,
+                         drop_remainder: bool = False
+                         ) -> Iterator[SparseBatch]:
+        """Padded ``[batch_size, L]`` batches of rows ``order``, in that
+        order, gathered straight from this dataset's CSR arrays: THE batch
+        assembly, shared by :meth:`batches` and ``ParquetStream.batches``
+        (which passes a shard's shuffled row order, so no reordered copy
+        of the shard is ever made). The last batch is short
+        (``n_valid``) when ``order`` is no multiple of ``batch_size``."""
+        lens = np.diff(self.indptr).astype(np.int64)
+        if not truncate and len(lens) and int(lens.max()) > L:
+            raise ValueError(
+                f"max_len={L} would drop features from rows up to "
+                f"{int(lens.max())} long; pass truncate=True to allow")
+        pos = np.arange(L, dtype=np.int64)[None, :]           # [1, L]
+        for s in range(0, len(order), batch_size):
+            take = order[s: s + batch_size]
+            nv = len(take)
+            if nv < batch_size and drop_remainder:
+                break
+            # vectorized padding: flat CSR positions of every kept slot
+            # in one fancy index (no per-row Python — the host batch
+            # assembly is on the e2e critical path, SURVEY.md §8)
+            m = np.minimum(lens[take], L)                 # [nv]
+            keep = pos < m[:, None]                       # [nv, L]
+            flat = np.where(keep, self.indptr[take][:, None] + pos, 0)
+            idx = np.zeros((batch_size, L), np.int32)
+            val = np.zeros((batch_size, L), np.float32)
+            if len(self.indices):        # all-empty-rows dataset guard
+                idx[:nv] = np.where(keep, self.indices[flat], 0)
+                val[:nv] = np.where(keep, self.values[flat], 0.0)
+            fld = None
+            if self.fields is not None:
+                fld = np.zeros((batch_size, L), np.int32)
+                if len(self.fields):
+                    fld[:nv] = np.where(keep, self.fields[flat], 0)
+            lab = np.zeros(batch_size, np.float32)
+            lab[:nv] = self.labels[take]
+            yield SparseBatch(idx, val, lab, fld,
+                              n_valid=nv if nv < batch_size else None)

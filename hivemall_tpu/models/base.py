@@ -860,11 +860,13 @@ class LearnerBase:
 
     def _source_side(self, batches, convert_labels: bool
                      ) -> Iterator[SparseBatch]:
-        """The serial source leg of a streamed fit: label conversion +
-        pair tracking stay on HOST arrays and in STREAM ORDER (the source
-        side of the pipeline is one thread); _preprocess_train_batch then
-        fans out over the prep workers. ``source.note_batch`` carries the
-        batch's ordinal in this stream."""
+        """The serial source leg of a streamed fit: label conversion and
+        the ``_note_batch`` hook see HOST arrays in STREAM ORDER (the
+        source side of the pipeline is one thread, the one that feeds the
+        chip: a hook does no more here than hand the batch on, as FFM's
+        pair tracking does); _preprocess_train_batch then fans out over
+        the prep workers. ``source.note_batch`` carries the batch's
+        ordinal in this stream."""
         tracer = self._tracer
         for n, b in enumerate(batches):
             if convert_labels:
@@ -1004,8 +1006,10 @@ class LearnerBase:
         return False
 
     def _note_batch(self, batch: SparseBatch) -> None:
-        """Hook for emission-time metadata on the streaming path (FFM joint
-        layout tracks observed (feature, field) pairs here)."""
+        """Hook for emission-time metadata on the streaming path, called
+        once a batch in stream order on the source thread (FFM's joint and
+        parts layouts hand the batch to their observed-pair tracker
+        here)."""
 
     # -- shared plumbing -----------------------------------------------------
     def _parse_row(self, features) -> Tuple[np.ndarray, np.ndarray]:

@@ -32,6 +32,7 @@ from ..ops.optimizers import (make_optimizer,
 from ..utils.hashing import mhash
 from ..utils.options import OptionSpec
 from .base import LearnerBase, learner_option_spec
+from .ffm_pairs import ObservedPairs
 
 __all__ = ["FMTrainer", "FFMTrainer", "fm_predict", "ffm_predict"]
 
@@ -771,6 +772,8 @@ class FFMTrainer(FMTrainer):
                              f"power-of-two -dims (got {self.dims})")
         dtype = jnp.bfloat16 if o.halffloat else jnp.float32
         key = jax.random.PRNGKey(int(o.seed))
+        self._pairs = ObservedPairs(self.F)   # seen on the stream path
+        self._fit_ds = None            # dataset ref, columnar path
         if self.layout == "parts":
             from ..ops.fm_pallas import parts_geometry, parts_supported
             if not parts_supported(self.F, self.k, self.optimizer.name,
@@ -811,8 +814,6 @@ class FFMTrainer(FMTrainer):
             self._fused_score_fm = _parts_score_cached(self.F, self.k,
                                                        self.MRF)
             self.interaction = "fieldmajor"   # parts is fieldmajor-only
-            self._pairs = set()
-            self._fit_ds = None
             return
         if self.layout == "joint":
             f_pow2 = 1
@@ -864,8 +865,6 @@ class FFMTrainer(FMTrainer):
             self._step_fm = None
             self._step_fm_unit = None
             self.interaction = "pairs"
-        self._pairs: set = set()       # (feature_id, field) seen, stream path
-        self._fit_ds = None            # dataset ref, columnar path
 
     def _apply_mesh(self, spec: str) -> None:
         if getattr(self, "layout", None) == "parts":
@@ -1236,7 +1235,19 @@ class FFMTrainer(FMTrainer):
         contract; the multi-epoch replay form has no checkpointed stream
         position to skip into, so the combination is rejected. So is
         ``on_dispatch`` there: a replayed epoch has no staged input to
-        number."""
+        number.
+
+        Observed-pair tracking (``_note_batch``) runs on a tracker thread
+        for the call's duration; it is drained and joined before this
+        returns or raises, and a fault it met is raised from here."""
+        with self._pairs.streaming(self._tracer):
+            return self._fit_stream(
+                batches, convert_labels=convert_labels, epochs=epochs,
+                replay_shuffle=replay_shuffle, resume=resume,
+                on_dispatch=on_dispatch)
+
+    def _fit_stream(self, batches, *, convert_labels, epochs, replay_shuffle,
+                    resume, on_dispatch) -> "FFMTrainer":
         if epochs <= 1:
             it = batches() if callable(batches) else batches
             return super().fit_stream(it, convert_labels=convert_labels,
@@ -1440,8 +1451,9 @@ class FFMTrainer(FMTrainer):
             val[b, :len(v)] = v
             fld[b, :len(f)] = f
             lab[b] = labels[b]
-            if self.layout == "joint":     # joint emission needs seen pairs
-                self._pairs.update(zip(i.tolist(), f.tolist()))
+        if self.layout == "joint":         # joint emission needs seen pairs
+            self._pairs.add(np.concatenate(
+                [i.astype(np.int64) * self.F + f for i, _, f in rows]))
         nv = len(rows)
         self._dispatch(self._preprocess_batch(
             SparseBatch(idx, val, lab, fld, n_valid=nv if nv < B else None)))
@@ -1514,34 +1526,28 @@ class FFMTrainer(FMTrainer):
 
     def _note_batch(self, batch) -> None:
         """Streaming path (fit_stream): record observed (feature, field)
-        pairs so joint-layout model emission keeps names/fields."""
+        pairs so joint-layout model emission keeps names/fields. Inside a
+        fit_stream this only hands the batch's host arrays to the tracker
+        thread (models/ffm_pairs.py); the unique and the merge run there."""
         if self.layout not in ("joint", "parts") or batch.field is None:
             return
-        idx = np.asarray(batch.idx)
-        fld = np.asarray(batch.field)
-        val = np.asarray(batch.val)
-        live = val != 0
-        packed = np.unique(idx[live].astype(np.int64) * self.F
-                           + fld[live].astype(np.int64))
-        ii, ff = np.divmod(packed, self.F)
-        self._pairs.update(zip(ii.tolist(), ff.tolist()))
+        self._pairs.note(batch.idx, batch.field, batch.val)
 
     def _observed_pairs(self):
         """Unique (feature_id, field) pairs seen in training as two sorted
-        arrays (ii, ff), merged from the streaming path's tracked set and
-        the columnar dataset — all vectorized (no per-pair Python)."""
-        keys = []
-        if self._pairs:
-            arr = np.fromiter((i * self.F + f for i, f in self._pairs),
-                              np.int64, len(self._pairs))
-            keys.append(arr)
+        arrays (ii, ff), merged from the streaming path's tracked keys
+        (joined first: every batch handed over is in) and the columnar
+        dataset — all vectorized (no per-pair Python)."""
+        uniq = self._pairs.keys()          # sorted and unique already
+        seen = len(uniq) > 0
         ds = self._fit_ds
         if ds is not None and ds.fields is not None:
-            keys.append(ds.indices.astype(np.int64) * self.F
-                        + ds.fields.astype(np.int64))
-        if not keys:
+            seen = True
+            uniq = np.unique(np.concatenate(
+                [uniq, ds.indices.astype(np.int64) * self.F
+                 + ds.fields.astype(np.int64)]))
+        if not seen:
             return None
-        uniq = np.unique(np.concatenate(keys))
         ii, ff = np.divmod(uniq, self.F)
         return ii.astype(np.int32), ff.astype(np.int32)
 

@@ -151,3 +151,201 @@ def test_frame_from_csv(tmp_path):
     f = Frame.from_csv(str(p))
     assert list(f["a"]) == [1, 2]
     assert list(f["b"]) == ["x", "y"]
+
+
+# -- ParquetStream.batches against the parent's double-copy loop --------------
+# A frozen copy of what PR 27 replaced: every shard reordered into a whole
+# copy (_take_rows) and then padded batch by batch. Nothing below calls the
+# program's batch assembly, so it stays the oracle whatever that becomes.
+
+def _frozen_take_rows(ds, rows):
+    lens = np.diff(ds.indptr)[rows]
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    starts = ds.indptr[rows].astype(np.int64)
+    total = int(indptr[-1])
+    flat = (np.arange(total, dtype=np.int64)
+            - np.repeat(indptr[:-1], lens)
+            + np.repeat(starts, lens)) if total else np.zeros(0, np.int64)
+    return SparseDataset(
+        ds.indices[flat], indptr, ds.values[flat], ds.labels[rows],
+        None if ds.fields is None else ds.fields[flat])
+
+
+def _frozen_concat(a, b):
+    fields = None
+    if a.fields is not None and b.fields is not None:
+        fields = np.concatenate([a.fields, b.fields])
+    return SparseDataset(
+        np.concatenate([a.indices, b.indices]),
+        np.concatenate([a.indptr, b.indptr[1:] + a.indptr[-1]]),
+        np.concatenate([a.values, b.values]),
+        np.concatenate([a.labels, b.labels]), fields)
+
+
+def _frozen_padded(ds, batch_size, L):
+    """SparseDataset.batches(shuffle=False) of the parent: rows in order."""
+    n = len(ds)
+    lens = np.diff(ds.indptr).astype(np.int64)
+    for s in range(0, n, batch_size):
+        take = np.arange(n)[s: s + batch_size]
+        nv = len(take)
+        m = np.minimum(lens[take], L)
+        pos = np.arange(L, dtype=np.int64)[None, :]
+        keep = pos < m[:, None]
+        flat = np.where(keep, ds.indptr[take][:, None] + pos, 0)
+        idx = np.zeros((batch_size, L), np.int32)
+        val = np.zeros((batch_size, L), np.float32)
+        if len(ds.indices):
+            idx[:nv] = np.where(keep, ds.indices[flat], 0)
+            val[:nv] = np.where(keep, ds.values[flat], 0.0)
+        fld = None
+        if ds.fields is not None:
+            fld = np.zeros((batch_size, L), np.int32)
+            if len(ds.fields):
+                fld[:nv] = np.where(keep, ds.fields[flat], 0)
+        lab = np.zeros(batch_size, np.float32)
+        lab[:nv] = ds.labels[take]
+        yield idx, val, lab, fld, (nv if nv < batch_size else None)
+
+
+def _frozen_stream_batches(stream, batch_size, *, epochs, shuffle, seed, L,
+                           shard_lists=None):
+    rng = np.random.default_rng(seed)
+    for _ in range(epochs):
+        order = rng.permutation(len(stream.files)) if shuffle \
+            else np.arange(len(stream.files))
+        if shard_lists is not None:
+            shard_lists.append([stream.files[fi] for fi in order])
+        carry = None
+        for fi in order:
+            ds = stream._shard(stream.files[fi])
+            if carry is not None:
+                ds = _frozen_concat(carry, ds)
+                carry = None
+            n = len(ds)
+            n_full = (n // batch_size) * batch_size
+            row_order = rng.permutation(n) if shuffle else np.arange(n)
+            full = _frozen_take_rows(ds, row_order[:n_full])
+            yield from _frozen_padded(full, batch_size, L)
+            if n_full < n:
+                carry = _frozen_take_rows(ds, row_order[n_full:])
+        if carry is not None and len(carry):
+            yield from _frozen_padded(carry, batch_size, L)
+
+
+def _ragged_ds(n=500, with_fields=False, seed=3):
+    """Rows 0..12 features long (empty rows too), ids and values distinct
+    enough that a misplaced slot shows."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(0, 13, n)
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    nnz = int(indptr[-1])
+    return SparseDataset(
+        rng.integers(1, 1 << 20, nnz).astype(np.int32), indptr,
+        rng.normal(0, 1, nnz).astype(np.float32),
+        rng.normal(0, 1, n).astype(np.float32),
+        rng.integers(0, 8, nnz).astype(np.int32) if with_fields else None)
+
+
+def _assert_same_batches(got, want):
+    assert len(got) == len(want)
+    for k, (g, w) in enumerate(zip(got, want)):
+        idx, val, lab, fld, nv = w
+        for name, a, b in (("idx", g.idx, idx), ("val", g.val, val),
+                           ("label", g.label, lab)):
+            assert a.dtype == b.dtype and a.shape == b.shape, (k, name)
+            assert a.tobytes() == b.tobytes(), (k, name)
+        assert (g.field is None) == (fld is None), k
+        if fld is not None:
+            assert g.field.dtype == fld.dtype, k
+            assert g.field.tobytes() == fld.tobytes(), (k, "field")
+        assert g.n_valid == nv, (k, "n_valid")
+
+
+# (rows a shard, batch): shards that are whole batches; shards that are not
+# (the remainder rides into the next shard and, at the epoch's end, into one
+# short padded batch); shards smaller than a batch (the remainder is carried
+# across two shards before a batch fills)
+_GEOMETRY = [(128, 64), (150, 64), (150, 400)]
+
+
+@pytest.mark.parametrize("truncate", [False, True])
+@pytest.mark.parametrize("cache", ["off", "warm"])
+@pytest.mark.parametrize("decode_ahead", [0, 2])
+@pytest.mark.parametrize("with_fields", [False, True])
+@pytest.mark.parametrize("rows_per_shard,batch", _GEOMETRY)
+@pytest.mark.parametrize("shuffle", [True, False])
+def test_stream_batches_bit_identical_to_the_double_copy_loop(
+        tmp_path, shuffle, rows_per_shard, batch, with_fields, decode_ahead,
+        cache, truncate):
+    d = str(tmp_path / "s")
+    write_parquet_shards(_ragged_ds(with_fields=with_fields), d,
+                         rows_per_shard=rows_per_shard)
+    cache_dir = str(tmp_path / "cache") if cache == "warm" else None
+    if cache_dir:                   # fill it: the run below reads it warm
+        for _ in ParquetStream(d, cache_dir=cache_dir).batches(
+                batch, shuffle=False):
+            pass
+    max_len = 5 if truncate else None
+    stream = ParquetStream(d, decode_ahead=decode_ahead, cache_dir=cache_dir)
+    got = list(stream.batches(batch, epochs=2, shuffle=shuffle, seed=1234,
+                              max_len=max_len, truncate=truncate))
+    ref = ParquetStream(d)
+    want = list(_frozen_stream_batches(
+        ref, batch, epochs=2, shuffle=shuffle, seed=1234,
+        L=max_len or ref.max_row_len))
+    _assert_same_batches(got, want)
+    if rows_per_shard % batch:      # the epoch ends on a short padded batch
+        assert got[-1].n_valid == (2 * 500 // 2) % batch
+
+
+def test_stream_rng_draws_unchanged_in_number_and_order(tmp_path,
+                                                        monkeypatch):
+    """One permutation of the files an epoch, one of the rows a shard (the
+    carry included), nothing else, in that order: so the same seed gives
+    the same file order in epoch 2 as the parent's loop drew."""
+    d = str(tmp_path / "s")
+    write_parquet_shards(_ragged_ds(), d, rows_per_shard=150)
+    real = np.random.default_rng
+
+    class Recorded:
+        def __init__(self, seed, log):
+            self._g, self._log = real(seed), log
+
+        def __getattr__(self, name):
+            fn = getattr(self._g, name)
+
+            def call(*a, **k):
+                self._log.append((name,) + tuple(int(x) for x in a))
+                return fn(*a, **k)
+            return call
+
+    logs = []
+
+    def recording_rng(seed):
+        logs.append([])
+        return Recorded(seed, logs[-1])
+
+    monkeypatch.setattr(np.random, "default_rng", recording_rng)
+    stream = ParquetStream(d)
+    seen = []
+    inner = stream._iter_shards
+    monkeypatch.setattr(stream, "_iter_shards",
+                        lambda files: seen.append(list(files))
+                        or inner(files))
+    got = list(stream.batches(64, epochs=2, shuffle=True, seed=77))
+    want_lists = []
+    want = list(_frozen_stream_batches(
+        ParquetStream(d), 64, epochs=2, shuffle=True, seed=77,
+        L=stream.max_row_len, shard_lists=want_lists))
+    # (SparseDataset.batches makes a generator for the epoch's last short
+    # batch and draws nothing from it)
+    got_log, want_log = [log for log in logs if log]
+    assert got_log == want_log
+    # 500 rows in shards of 150,150,150,50: the sizes drawn show the carry
+    assert [c[0] for c in got_log] == ["permutation"] * 10
+    assert got_log[0] == got_log[5] == ("permutation", 4)
+    assert sum(c[1] for c in got_log[1:5]) - 500 == \
+        sum(c[1] % 64 for c in got_log[1:4])
+    assert seen == want_lists and len(seen) == 2
+    _assert_same_batches(got, want)
