@@ -20,6 +20,18 @@ per slot, against a maximum saving of 17 x (1 - unique/slots) ns/slot
 (= ~12.8 ns at uniform 4.07x duplication, ~17 ns at infinite
 duplication).  If permute-gather alone costs ~>= the RMW it replaces,
 the design can NEVER win, on any duplication (Zipf included).
+
+What this probe did and did not price (PR 28). It priced ONE design, for
+the FFM parts kernel's field partitions: sort, then an EXPLICIT gather that
+permutes the whole gradient slab into sorted order, then a segment-sum and
+an RMW of the unique rows, each phase timed alone, on uniform ids (4x
+duplication) and hashed Zipf ids into 8,192-row partitions. It did not
+price: a scatter-add handed sorted indices and the permutation (XLA reads
+the updates through the permutation inside the scatter it would have written
+for itself: no extra pass, and its own sort saved); the zero-fill and dense
+optimizer pass over a 2 GiB table that a compact gradient makes unnecessary;
+or the whole step. `ops/fm.py` `rows_update` is that other design; its
+readings are `experiments/probe_distinct_tail.py`'s (PERF.md section 6).
 """
 from __future__ import annotations
 

@@ -36,6 +36,15 @@ __all__ = ["LearnerBase", "learner_option_spec",
            "add_mix_reliability_options", "sigmoid_np"]
 
 
+def _fetch(tree):
+    """Device values to host ones: THE place the train loop's dispatch
+    path converts (and so may wait for the device) — the loss fold, and
+    the per-step loss hooks when a check has set one. A test counts the
+    calls to hold the path between two folds to none."""
+    import jax
+    return jax.device_get(tree)
+
+
 def sigmoid_np(x: np.ndarray) -> np.ndarray:
     """Numerically-stable host-side sigmoid — THE margin->probability map
     of every classification scoring path (predict_proba and the serve
@@ -252,6 +261,13 @@ class LearnerBase:
         self._stream_pos = 0                  # fit_stream batches consumed
         self._loss_sum = 0.0                  # host float64, exact
         self._loss_pending = 0.0              # on-device partial, folded in
+        # what the step counts about itself (ops/fm.py TAIL_STATS), where
+        # it returns any: each dispatch's device values, kept unread (not
+        # even summed: a computation queued behind the step costs the
+        # dispatching thread 100 ms on a TPU), and the host totals they
+        # fold into with the loss (this process's steps; not checkpointed)
+        self._stats_pending: List[dict] = []
+        self._step_counts: Dict[str, int] = {}
         self._examples = 0
         self._meter = Meter()                 # rolling examples/sec (§6)
         self._tracer = get_tracer()           # span tracing (obs.trace)
@@ -362,7 +378,8 @@ class LearnerBase:
             return {"trainer": t.NAME, "step": t._t,
                     "examples": t._examples,
                     "examples_per_sec": round(t._meter.rate, 1),
-                    "avg_loss": round(t._loss_sum / max(1, t._examples), 6)}
+                    "avg_loss": round(t._loss_sum / max(1, t._examples), 6),
+                    **t._step_counts}
 
         def mix() -> dict:
             t = ref()
@@ -1085,7 +1102,7 @@ class LearnerBase:
 
     def _feed_losses(self, losses) -> None:
         """``losses``: the device loss sum(s) of the dispatch just made."""
-        vals = [float(v) for v in np.atleast_1d(np.asarray(losses))]
+        vals = [float(v) for v in np.atleast_1d(_fetch(losses))]
         if self._trace_losses is not None:
             self._trace_losses.extend(vals)
         sink = self.loss_sink
@@ -1183,10 +1200,11 @@ class LearnerBase:
         mega = megastep_for(self._step, none_val=True)
         nv = mb.nv_dev if mb.nv_dev is not None else jnp.asarray(mb.nv)
         s1, s2 = self._megastep_state()
-        s1, s2, losses = mega(s1, s2, float(self._t), nv, mb.idx, mb.val,
-                              mb.label, self._mega_field(mb),
-                              self._mega_lams())
+        s1, s2, losses, *stats = mega(s1, s2, float(self._t), nv, mb.idx,
+                                      mb.val, mb.label, self._mega_field(mb),
+                                      self._mega_lams())
         self._set_megastep_state(s1, s2)
+        self._stats_pending += stats
         return losses
 
     def _shard_megabatch(self, mb):
@@ -1212,10 +1230,17 @@ class LearnerBase:
             seq=mb.seq)
 
     def _fold_loss(self) -> None:
-        # float() is the one place the train loop blocks on the device
+        # the one place the train loop blocks on the device; the step's
+        # stats ride the same fetch
         with self._tracer.span("loop.fold_loss"):
-            self._loss_sum += float(self._loss_pending)
+            loss, stats = _fetch((self._loss_pending, self._stats_pending))
+        self._loss_sum += float(loss)
         self._loss_pending = 0.0
+        for dispatch in stats:
+            for name, v in dispatch.items():
+                self._step_counts[name] = self._step_counts.get(name, 0) \
+                    + int(v.sum())
+        self._stats_pending = []
 
     @property
     def cumulative_loss(self) -> float:

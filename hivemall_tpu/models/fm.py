@@ -66,13 +66,13 @@ def _fm_step_fused_cached(loss_name, opt, eta_scheme, eta0, total_steps,
 @_instrument("fm", "step_minibatch")
 @_lru_cache(maxsize=64)
 def _fm_step_minibatch_cached(loss_name, opt, eta_scheme, eta0, total_steps,
-                              power_t, lambdas, k):
+                              power_t, lambdas, k, distinct_tail):
     from ..ops.fm import make_fm_step_minibatch
     return make_fm_step_minibatch(
         get_loss(loss_name),
         make_optimizer_cached(opt, eta_scheme, eta0, total_steps,
                               power_t),
-        lambdas, k)
+        lambdas, k, distinct_tail)
 
 
 @_instrument("fm", "step")
@@ -338,8 +338,11 @@ class FMTrainer(LearnerBase):
             lam_key = (None if self._adareg
                        else (o.lambda0, o.lambda_w, o.lambda_v))
             if upd == "minibatch":
+                # under -mesh the dense tail stays: a sort and a cond over
+                # a row-sharded table are another question (PERF.md §7)
                 self._step = _fm_step_minibatch_cached(
-                    self._loss_name, *self._opt_key, lam_key, self.k)
+                    self._loss_name, *self._opt_key, lam_key, self.k,
+                    not o.get("mesh"))
             else:
                 self._step = _fm_step_fused_cached(
                     self._loss_name, *self._opt_key, lam_key, self.k)
@@ -400,9 +403,10 @@ class FMTrainer(LearnerBase):
         return None
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        self.params, self.opt_state, loss_sum = self._step(
+        self.params, self.opt_state, loss_sum, *stats = self._step(
             self.params, self.opt_state, float(self._t), batch.idx, batch.val,
             batch.label, batch.row_mask, *self._batch_args(batch))
+        self._stats_pending += stats
         return loss_sum
 
     # -- adaptive regularization (-adareg, SURVEY.md §3.6 train_fm row) -----

@@ -155,6 +155,14 @@ def _render(state: _TailState, path: str = "",
 
     roll = state.last.get("span_rollup")
     snap = state.snapshot
+    tail = (snap or {}).get("train") or {}
+    if tail.get("tail_distinct_steps") or tail.get("tail_dense_steps"):
+        # which tail the minibatch step took (ops/fm.py rows_update)
+        steps = tail["tail_distinct_steps"] + tail["tail_dense_steps"]
+        out.append(
+            f"tail:   distinct-row x{tail['tail_distinct_steps']}  "
+            f"dense x{tail['tail_dense_steps']}  distinct rows/step "
+            f"{tail.get('distinct_rows', 0) / max(1, steps):.0f}")
     stages = (roll or {}).get("stages") \
         or ((snap or {}).get("spans") if snap else None)
     if stages:

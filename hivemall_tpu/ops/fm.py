@@ -17,8 +17,9 @@ cell updates.
 The fused/minibatch step bodies wrap their phases in ``jax.named_scope``
 with one family-neutral vocabulary — ``hm.gather`` (the table-row gather),
 ``hm.grad`` (unpack, forward, loss, backward), ``hm.scatter`` (zeros +
-scatter-add into G, or the sparse variants' per-occurrence chain) and
-``hm.update`` (the optimizer's update of table and state, w0 included) —
+scatter-add into G, dense or compact with the ranking it needs, or the
+sparse variants' per-occurrence chain) and ``hm.update`` (the optimizer's
+update of table and state, whole or by distinct rows, w0 included) —
 so a profiler trace reduces device time by phase, not by ``fusion.48``
 (ops/scan.py ``SCOPES`` on what the compile cache does to a renamed scope).
 """
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 
 from .losses import Loss
 from .optimizers import Optimizer
+from .rows_pallas import LIST_MULTIPLE, put_rows, take_rows
 from .scan import scannable
 
 __all__ = ["fm_score", "ffm_score", "make_fm_step", "make_ffm_step",
@@ -600,9 +602,127 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
     return scannable(partial(jax.jit, donate_argnums=(0, 1))(core), core)
 
 
+# --- the distinct-row tail ----------------------------------------------------
+# A minibatch step's tail used to zero-fill a table-sized G, scatter-add the
+# batch gradient into it and run the optimizer over every table row, to
+# change the rows one batch touches (1.7% of 4,194,304 in the benchmark's
+# cell). rows_update sums duplicates into a COMPACT gradient and updates the
+# batch's distinct rows only, where the shapes and the batch say that pays:
+# up to tail_cap distinct rows, the count at which the two tails cost the
+# same by the chip's readings below (TPU v5e, a [4194304, 128] float32
+# table, 1,277,952 slots; experiments/probe_distinct_tail.py, PERF.md
+# section 6, PR 28).
+# ns per TABLE row of what only the dense tail runs: the dense AdaGrad pass
+# (15.9 ms) and what the scatter-add into a zero-filled table-sized G costs
+# over the one into a compact Gc (20.1 against 17.4 ms)
+_DENSE_NS_PER_TABLE_ROW = 4.43
+# ns per SLOT of what only the distinct tail runs: the sort that compacts
+# the distinct row ids (1.73 ms) and the running sum (0.24 ms)
+_RANK_NS_PER_SLOT = 1.54
+# ns per DISTINCT row at capacity: two row reads (17.5 each), two row
+# writes (15 each), the update and the kernels' block traffic over the
+# capacity (7); 76 puts the break-even where the chip read it, 218k rows
+_DISTINCT_NS_PER_ROW = 76.0
+
+TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows")
+
+
+def tail_cap(n: int, R: int) -> int:
+    """Most distinct rows the distinct-row tail takes on for n slots into
+    a table of R rows, a multiple of the row kernels' list tile; 0 where
+    the dense tail is the cheaper one at any count (a table small against
+    the batch: the toy config's 4,096 rows against 9,984 slots), or where
+    R + n overflows the int32 ids that pad the distinct-row list."""
+    if R + n >= 2 ** 31:
+        return 0
+    even = (R * _DENSE_NS_PER_TABLE_ROW - n * _RANK_NS_PER_SLOT) \
+        / _DISTINCT_NS_PER_ROW
+    return max(0, min(n, int(even))) // LIST_MULTIPLE * LIST_MULTIPLE
+
+
+def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
+    """The tail of a minibatch step: apply ``optimizer.update`` with the
+    gradient rows ``g`` [n, W] (float32) summed by table row ``rows`` [n]
+    into T [R, W] and its co-shaped ``state``. Returns (T, state, stats),
+    stats a dict of int32 scalars named by TAIL_STATS. Knows nothing of
+    what a row holds.
+
+    For an optimizer whose update leaves a zero-gradient entry as it was
+    (AdaGrad and SGD with reg='no', which factor trainers always use),
+    updating the distinct rows is the dense update: what differs is the
+    order in which f32 addends meet in a duplicate's sum.
+
+      1. rank: ONE key-value sort of (rows, iota) gives the rows in order
+         and the slot each came from; a flag where the sorted row changes
+         counts the distinct rows, and its running sum is each sorted
+         slot's rank.
+      2. Gc = zeros([cap, W]).at[rank].add(g[perm]), indices sorted: the
+         scatter-add XLA would write for itself (it sorts (indices, iota)
+         and reads the updates through the permutation) less its sort.
+         The gradient slab is never copied in another order.
+      3. the flagged row ids, sorted to the front, are the distinct rows:
+         take T and the state at them, the optimizer's own update on
+         [cap, W] (same function, same t), put both back in place
+         (ops/rows_pallas.py: on a TPU kernels that cost by the count of
+         distinct rows, elsewhere XLA's gather and scatter).
+
+    ``cap`` (None: tail_cap of the shapes) is static; 0 is the dense tail
+    alone, with no ranking. A batch with more distinct rows than ``cap``
+    takes the dense tail too (lax.cond), fed the same sorted rows."""
+    n, (R, W) = rows.shape[0], T.shape
+    if cap is None:
+        # rows travel as 32-bit DMA words: a bfloat16 table keeps the dense tail
+        words = all(a.dtype.itemsize == 4
+                    for a in jax.tree_util.tree_leaves((T, state)))
+        cap = tail_cap(n, R) if words else 0
+
+    def dense(T, state, rows=rows, g=g, **sorted_):
+        with jax.named_scope("hm.scatter"):
+            G = jnp.zeros((R, W), jnp.float32).at[rows].add(g, **sorted_)
+        with jax.named_scope("hm.update"):
+            Tn, sn = optimizer.update(T.astype(jnp.float32), G, state, t)
+            return Tn.astype(T.dtype), sn
+
+    if not cap:
+        return (*dense(T, state),
+                dict(zip(TAIL_STATS, jnp.asarray([0, 1, 0], jnp.int32))))
+
+    with jax.named_scope("hm.scatter"):
+        slot = jnp.arange(n, dtype=jnp.int32)
+        srows, perm = jax.lax.sort_key_val(rows, slot)
+        first = jnp.concatenate(
+            [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
+        n_distinct = first.sum(dtype=jnp.int32)
+
+    def distinct(T, state):
+        with jax.named_scope("hm.scatter"):
+            rank = jnp.cumsum(first.astype(jnp.int32)) - 1
+            Gc = jnp.zeros((cap, W), jnp.float32).at[rank].add(
+                g[perm], mode="drop", indices_are_sorted=True)
+            urows = jnp.sort(jnp.where(first, srows, R + slot))[:cap]
+        with jax.named_scope("hm.update"):
+            def take(a):
+                return take_rows(a, urows, n_distinct)
+
+            def put(a, u):
+                return put_rows(a, urows, n_distinct, u)
+            Tu, su = optimizer.update(
+                take(T).astype(jnp.float32), Gc,
+                jax.tree_util.tree_map(take, state), t)
+            return put(T, Tu), jax.tree_util.tree_map(put, state, su)
+
+    fits = n_distinct <= cap
+    Tn, sn = jax.lax.cond(
+        fits, distinct,
+        lambda T, s: dense(T, s, srows, g[perm], indices_are_sorted=True),
+        T, state)
+    took = fits.astype(jnp.int32)
+    return Tn, sn, dict(zip(TAIL_STATS, (took, 1 - took, n_distinct)))
+
+
 def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
                            lambdas: Tuple[float, float, float],
-                           K: int) -> Callable:
+                           K: int, distinct_tail: bool = True) -> Callable:
     """train_fm step over the packed fused table with MINIBATCH-summed
     accumulators — the FFM joint fused step's update shape applied to FM.
 
@@ -612,11 +732,21 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     gather), and on this hardware index ops ARE the cost (docs/
     PERFORMANCE.md cost model) — train_fm measured 0.47x of the per-chip
     share while the strictly harder FFM ran 1.145x. This step does ONE
-    forward gather + ONE scatter-add of the batch gradient into a dense
-    G, then the optimizer's dense elementwise update: 2 index ops per
-    slot, plus an O(table) pass (priced at ~5 ms from a round-5 probe on
-    a small table; the benchmark's 2 GiB float32 table reads 15.9 ms,
-    step.update_ms, PERF.md section 5).
+    forward gather + ONE scatter-add of the batch gradient, then the
+    optimizer's elementwise update, through rows_update: summed into a
+    compact gradient and applied to the batch's distinct rows where the
+    table is large against the batch and the batch repeats its rows
+    (tail_cap), else summed into a dense G with an O(table) pass (priced
+    at ~5 ms from a round-5 probe on a small table; the benchmark's 2 GiB
+    float32 table reads 15.9 ms, and 3.2 more to zero G: PERF.md section
+    6, PR 28). Round 5 ruled pre-aggregation out from experiments/
+    probe_preagg.py, which priced another design (an explicit permuting
+    copy of the whole gradient slab, uniform ids, each phase alone).
+    ``distinct_tail=False`` keeps the dense tail whatever the shapes: the
+    trainer's choice under -mesh, not a user's.
+
+    Returns (params, opt_state, loss_sum, stats): stats counts which tail
+    ran and the batch's distinct rows (TAIL_STATS).
 
     Semantics delta (documented, same as the FFM fused/parts paths):
     adaptive accumulators see the square of the SUMMED minibatch
@@ -636,16 +766,13 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
             loss, params, idx, val, label, row_mask, lams,
             dyn or bool(lams[1] or lams[2]), K, Wf, P)
 
-        with jax.named_scope("hm.scatter"):
-            G = jnp.zeros(T.shape, jnp.float32).at[rows].add(
-                g128)                                # ONE scatter-add
+        Tn, sT, stats = rows_update(T, opt_state["T"], rows, g128, optimizer,
+                                    t, None if distinct_tail else 0)
         with jax.named_scope("hm.update"):
-            Tn, sT = optimizer.update(T.astype(jnp.float32), G,
-                                      opt_state["T"], t)
             w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
                                        opt_state["w0"], t)
-            return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
-                    {"T": sT, "w0": s0}, loss_sum)
+        return ({"T": Tn, "w0": w0n.astype(w0.dtype)},
+                {"T": sT, "w0": s0}, loss_sum, stats)
 
     if dyn:
         def core(params, opt_state, t, idx, val, label, row_mask, lams):

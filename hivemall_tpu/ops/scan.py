@@ -12,13 +12,15 @@ use to hide dispatch latency.
 
 Contract: every trainer step is a pure function
 
-    (state1, state2, t, *batch_args) -> (state1, state2, loss_sum)
+    (state1, state2, t, *batch_args) -> (state1, state2, loss_sum[, stats])
 
 with ``state1`` the model params (or weight table), ``state2`` the
 optimizer state, ``t`` the float global step, and batch args in canonical
-order ``idx, [val,] label, row_mask[, field | lams]``. The jitted K=1
-wrapper and the K>1 scan body run the SAME function — :func:`scannable`
-attaches the unjitted core to its jitted wrapper, and
+order ``idx, [val,] label, row_mask[, field | lams]``. ``stats``, where a
+step returns it, is a dict of int32 scalars the step counts about itself
+(ops/fm.py ``TAIL_STATS``); the megastep stacks it [K] beside the losses.
+The jitted K=1 wrapper and the K>1 scan body run the SAME function —
+:func:`scannable` attaches the unjitted core to its jitted wrapper, and
 :func:`make_megastep` scans that core over a stacked [K, ...] window with
 the state threaded through the scan carry and ``donate_argnums`` on the
 megastep itself, so XLA updates the tables in place across all K steps
@@ -80,7 +82,8 @@ def make_megastep(core, *, none_val: bool = False):
 
     Returns ``(s1, s2, losses[K])`` — per-step loss sums, accumulated on
     device; the caller folds them at its existing cadence so no step ever
-    blocks the host.
+    blocks the host — and the core's ``stats``, stacked [K], where it
+    returns any.
     """
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -106,13 +109,13 @@ def make_megastep(core, *, none_val: bool = False):
                 args.append(x["field"])
             if lams is not None:
                 args.append(lams)
-            p, s, loss = core(p, s, t, *args)
-            return (p, s, t + 1.0), loss
+            p, s, *out = core(p, s, t, *args)
+            return (p, s, t + 1.0), tuple(out)
 
         # the slicing and stacking the scan adds is named too
         with jax.named_scope("hm.scan"):
-            (s1, s2, _), losses = jax.lax.scan(body, (s1, s2, t0), xs)
-        return s1, s2, losses
+            (s1, s2, _), out = jax.lax.scan(body, (s1, s2, t0), xs)
+        return (s1, s2, *out)
 
     return megastep
 

@@ -1,0 +1,358 @@
+"""The packed FM minibatch step's distinct-row tail (ops/fm.py `rows_update`)
+against the dense tail it replaces where a batch touches few table rows.
+
+Same mathematics, so: table and AdaGrad state agree at float32 rounding on
+the rows a batch touched (a duplicate's addends meet in another order) and
+are BIT-equal on every other row; which tail ran is what the step says it
+ran. The counters the tail feeds reach the obs registry's `train` section
+at the loss fold, and the dispatch path fetches nothing in between.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hivemall_tpu.ops import fm
+from hivemall_tpu.ops.losses import get_loss
+from hivemall_tpu.ops.optimizers import make_optimizer
+from hivemall_tpu.ops.scan import make_megastep
+
+K = 5
+WF, P = fm.fm_pack_geometry(K)
+B, L, R = 64, 32, 4096             # 2,048 slots into 4,096 packed rows
+N = B * L
+LAMS = (0.01, 0.02, 0.03)
+
+
+def _opt():
+    return make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                          reg="no")
+
+
+def _state(rows=R, seed=1):
+    rng = np.random.default_rng(seed)
+    T = jnp.asarray(0.1 * rng.normal(size=(rows, P * WF)).astype(np.float32))
+    gg = jnp.asarray(rng.random((rows, P * WF)).astype(np.float32))
+    return ({"T": T, "w0": jnp.asarray(0.05, jnp.float32)},
+            {"T": {"gg": gg}, "w0": {"gg": jnp.asarray(0.5, jnp.float32)}})
+
+
+def _ids(n_distinct, seed=0, b=B, l=L, rows=R):
+    """[b, l] feature ids over exactly `n_distinct` packed table rows."""
+    rng = np.random.default_rng(seed)
+    pool = rng.choice(np.arange(1, rows), n_distinct, replace=False)
+    r = rng.choice(pool, b * l)
+    r[rng.choice(b * l, n_distinct, replace=False)] = pool
+    return (r * P + rng.integers(0, P, b * l)).reshape(b, l).astype(np.int32)
+
+
+def _label(b=B, seed=3):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(np.where(rng.random(b) < 0.4, 1.0, -1.0)
+                       .astype(np.float32))
+
+
+def _pair(idx, *, lambdas=LAMS, mask=None, extra=(), rows=R):
+    """One step from the same state through both tails."""
+    out = []
+    for distinct in (True, False):
+        step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(),
+                                         lambdas, K, distinct)
+        params, state = _state(rows)
+        b = idx.shape[0]
+        out.append(step(params, state, 3.0, jnp.asarray(idx), None,
+                        _label(b), jnp.ones(b) if mask is None else mask,
+                        *extra))
+    return out
+
+
+def _assert_same(new, ref, idx):
+    touched = np.zeros(new[0]["T"].shape[0], bool)
+    touched[np.unique(idx // P)] = True
+    for a, b in ((new[0]["T"], ref[0]["T"]),
+                 (new[1]["T"]["gg"], ref[1]["T"]["gg"])):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(a[~touched], b[~touched])
+        np.testing.assert_allclose(a[touched], b[touched], rtol=2e-6,
+                                   atol=1e-7)
+    for a, b in ((new[0]["w0"], ref[0]["w0"]), (new[2], ref[2]),
+                 (new[1]["w0"]["gg"], ref[1]["w0"]["gg"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert {k: int(v) for k, v in ref[3].items()} == {
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+
+
+CAP = fm.tail_cap(N, R)
+
+
+def test_capacity_of_the_test_shape():
+    assert 0 < CAP < N // 2 and CAP % 128 == 0
+
+
+@pytest.mark.parametrize("case,n_distinct,distinct", [
+    ("heavy_duplication", 5, True),
+    ("half_the_capacity", CAP // 2, True),
+    ("one_under_the_capacity", CAP - 1, True),
+    ("at_the_capacity", CAP, True),
+    ("one_over_falls_through", CAP + 1, False),
+    ("far_over_falls_through", 3 * CAP, False),
+])
+def test_distinct_tail_matches_dense_tail(case, n_distinct, distinct):
+    idx = _ids(n_distinct)
+    new, ref = _pair(idx)
+    _assert_same(new, ref, idx)
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": int(distinct),
+        "tail_dense_steps": int(not distinct), "distinct_rows": n_distinct}
+
+
+def test_every_slot_distinct_within_the_capacity(monkeypatch):
+    """No duplicate to sum: the compact gradient is the slab, permuted."""
+    monkeypatch.setattr(fm, "tail_cap", lambda n, r: n)
+    idx = _ids(N)
+    new, ref = _pair(idx)
+    _assert_same(new, ref, idx)
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    assert int(new[3]["distinct_rows"]) == N
+
+
+def test_padded_batch_through_the_distinct_tail():
+    """Rows past n_valid are masked out and hold id 0: packed row 0 is
+    one more distinct row, with a zero gradient."""
+    idx = _ids(9)
+    idx[B // 2:] = 0
+    mask = (jnp.arange(B) < B // 2).astype(jnp.float32)
+    new, ref = _pair(idx, mask=mask)
+    _assert_same(new, ref, idx)
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    assert int(new[3]["distinct_rows"]) == len(np.unique(idx // P))
+    row0 = np.asarray(new[0]["T"])[0]
+    np.testing.assert_array_equal(row0, np.asarray(_state()[0]["T"])[0])
+
+
+def test_dynamic_lambdas_through_the_distinct_tail():
+    """-adareg's variant: lambdas arrive as a step argument."""
+    idx = _ids(12)
+    lams = jnp.asarray(LAMS, jnp.float32)
+    new, ref = _pair(idx, lambdas=None, extra=(lams,))
+    _assert_same(new, ref, idx)
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    fixed, _ = _pair(idx)
+    np.testing.assert_allclose(np.asarray(new[0]["T"]),
+                               np.asarray(fixed[0]["T"]), rtol=2e-6,
+                               atol=1e-7)
+
+
+def test_small_table_picks_the_dense_tail_statically():
+    """The toy config: 4,096 packed rows against 256 x 39 = 9,984 slots.
+    The step holds no ranking at all: no sort in its program."""
+    b, l = 256, 39
+    assert fm.tail_cap(b * l, R) == 0
+    idx = _ids(300, b=b, l=l)
+    new, ref = _pair(idx)
+    _assert_same(new, ref, idx)
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+    step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
+    params, state = _state()
+    text = step.lower(params, state, 3.0, jnp.asarray(idx), None, _label(b),
+                      jnp.ones(b)).as_text()
+    assert "stablehlo.sort" not in text and "stablehlo.case" not in text
+
+
+def test_capacity_follows_the_shapes():
+    n = 32768 * 39                                   # the benchmark's cell
+    cell = fm.tail_cap(n, 1 << 22)
+    assert 161_600 < cell < n // 2                   # holds Zipf 1.05's batch
+    assert fm.tail_cap(n, n // 4) == 0               # table under the batch
+    assert fm.tail_cap(n, 2 ** 31 - n) == 0          # pad ids would overflow
+    assert fm.tail_cap(n, 1 << 30) == n // 128 * 128     # never over n
+    caps = [fm.tail_cap(n, r) for r in (1 << 20, 1 << 21, 1 << 22, 1 << 23)]
+    assert caps == sorted(caps) and all(c % 128 == 0 for c in caps)
+
+
+def test_megastep_of_four_equals_four_single_steps():
+    """The K-step scan runs the step's own core, stats included."""
+    step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
+    ks = 4
+    nds = (6, CAP - 3, CAP + 5, CAP)
+    idx = np.stack([_ids(nd, seed=10 + i) for i, nd in enumerate(nds)])
+    label = jnp.stack([_label(seed=20 + i) for i in range(ks)])
+    nv = np.asarray([B, B, B - 5, B], np.int32)
+
+    params, state = _state()
+    losses, stats = [], []
+    for i in range(ks):
+        mask = (jnp.arange(B) < nv[i]).astype(jnp.float32)
+        params, state, ls, st = step(params, state, 7.0 + i,
+                                     jnp.asarray(idx[i]), None, label[i],
+                                     mask)
+        losses.append(float(ls))
+        stats.append({k: int(v) for k, v in st.items()})
+
+    mega = make_megastep(step.core, none_val=True)
+    p2, s2 = _state()
+    p2, s2, ls2, st2 = mega(p2, s2, 7.0, jnp.asarray(nv), jnp.asarray(idx),
+                            None, label, None, None)
+    np.testing.assert_array_equal(np.asarray(ls2), np.asarray(losses,
+                                                              np.float32))
+    for a, b in ((p2["T"], params["T"]), (s2["T"]["gg"], state["T"]["gg"]),
+                 (p2["w0"], params["w0"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name in fm.TAIL_STATS:
+        assert [int(v) for v in st2[name]] == [s[name] for s in stats]
+    assert [s["tail_distinct_steps"] for s in stats] == [1, 1, 0, 1]
+
+
+def test_bfloat16_table_keeps_the_dense_tail():
+    """-halffloat: the dense update rounds its float32 result to bfloat16
+    once, a row add would round twice, so the rule offers no rung."""
+    idx = _ids(11)
+    out = []
+    for distinct in (True, False):
+        step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS,
+                                         K, distinct)
+        params, state = _state()
+        params = {"T": params["T"].astype(jnp.bfloat16),
+                  "w0": params["w0"].astype(jnp.bfloat16)}
+        out.append(step(params, state, 3.0, jnp.asarray(idx), None, _label(),
+                        jnp.ones(B)))
+    assert out[0][0]["T"].dtype == jnp.bfloat16
+    assert int(out[0][3]["tail_dense_steps"]) == 1
+    assert int(out[0][3]["distinct_rows"]) == 0
+    np.testing.assert_array_equal(
+        np.asarray(out[0][0]["T"], np.float32),
+        np.asarray(out[1][0]["T"], np.float32))
+
+
+# -- the row kernels (interpret mode here; compiled for a v5e by
+# tests/test_tpu_aot_compile.py) -----------------------------------------------
+
+@pytest.mark.parametrize("cap,n_live", [(128, 0), (128, 1), (128, 128),
+                                        (384, 200), (2048, 2047),
+                                        (4096, 2049)])
+def test_row_kernels_copy_the_live_rows_and_no_others(cap, n_live):
+    from hivemall_tpu.ops.rows_pallas import put_rows, take_rows
+    rng = np.random.default_rng(cap + n_live)
+    rows_total = 8192
+    table = jnp.asarray(rng.normal(size=(rows_total, 128)).astype(np.float32))
+    live = np.sort(rng.choice(rows_total, n_live, replace=False))
+    ids = np.concatenate([live, rows_total + np.arange(cap - n_live)]) \
+        .astype(np.int32)
+    n = jnp.asarray(n_live, jnp.int32)
+    got = jax.jit(partial(take_rows, interpret=True))(
+        table, jnp.asarray(ids), n)
+    np.testing.assert_array_equal(np.asarray(got)[:n_live],
+                                  np.asarray(table)[live])
+    vals = jnp.asarray(rng.normal(size=(cap, 128)).astype(np.float32))
+    out = jax.jit(partial(put_rows, interpret=True))(
+        table, jnp.asarray(ids), n, vals)
+    want = np.asarray(table).copy()
+    want[live] = np.asarray(vals)[:n_live]
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_row_list_must_fill_whole_id_tiles():
+    from hivemall_tpu.ops.rows_pallas import take_rows
+    with pytest.raises(ValueError, match="multiple of 128"):
+        take_rows(jnp.zeros((256, 128)), jnp.zeros((100,), jnp.int32),
+                  jnp.asarray(3), interpret=True)
+
+
+def test_step_with_the_row_kernels_matches_the_xla_rows(monkeypatch):
+    """What a TPU runs: take and put by the kernels, the rest unchanged."""
+    idx = _ids(CAP - 7)
+    ref, _ = _pair(idx)
+    from hivemall_tpu.ops import rows_pallas
+    monkeypatch.setattr(fm, "take_rows",
+                        partial(rows_pallas.take_rows, interpret=True))
+    monkeypatch.setattr(fm, "put_rows",
+                        partial(rows_pallas.put_rows, interpret=True))
+    step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
+    params, state = _state()
+    new = step(params, state, 3.0, jnp.asarray(idx), None, _label(),
+               jnp.ones(B))
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    for a, b in ((new[0]["T"], ref[0]["T"]),
+                 (new[1]["T"]["gg"], ref[1]["T"]["gg"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the counters -------------------------------------------------------------
+
+def _trainer_stream(n_batches, bsz, l, dims, n_distinct):
+    from hivemall_tpu.io.sparse import SparseDataset
+    rng = np.random.default_rng(5)
+    pool = rng.choice(np.arange(P, dims), n_distinct, replace=False)
+    n = n_batches * bsz
+    idx = rng.choice(pool, (n, l)).astype(np.int32)
+    lab = (rng.integers(0, 2, n) * 2 - 1).astype(np.float32)
+    indptr = np.arange(0, n * l + 1, l, dtype=np.int64)
+    return SparseDataset(idx.ravel(), indptr, np.ones(n * l, np.float32), lab)
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_tail_counters_fold_with_the_loss_and_nothing_fetches_between(
+        k, monkeypatch):
+    """512 steps over duplicated ids: every step is counted as one tail or
+    the other, the counts reach the registry's `train` section, and the
+    dispatch path converts a device value to a host one only inside the
+    loss fold (every 256 steps, and once when the count is asked for)."""
+    from hivemall_tpu.models import base
+    from hivemall_tpu.models.fm import FMTrainer
+    from hivemall_tpu.obs.registry import registry
+
+    steps, bsz, l, dims = 512, 32, 8, R * P
+    ds = _trainer_stream(steps, bsz, l, dims, n_distinct=12)
+    t = FMTrainer(f"-dims {dims} -factors {K} -opt adagrad -classification "
+                  f"-mini_batch {bsz} -steps_per_dispatch {k}")
+
+    fetches, in_fold = [], []
+    real_fetch, real_fold = base._fetch, t._fold_loss
+
+    def fetch(tree):
+        fetches.append(bool(in_fold))
+        return real_fetch(tree)
+
+    def fold():
+        in_fold.append(1)
+        try:
+            real_fold()
+        finally:
+            in_fold.pop()
+
+    monkeypatch.setattr(base, "_fetch", fetch)
+    monkeypatch.setattr(t, "_fold_loss", fold)
+    t.fit_stream(ds.batches(bsz, shuffle=False))
+    assert t._t == steps
+    assert len(fetches) == 2 and all(fetches)      # folds at 256 and 512
+    counts = dict(t._step_counts)
+    assert counts["tail_distinct_steps"] + counts["tail_dense_steps"] == steps
+    assert counts["tail_distinct_steps"] == steps  # 12 rows fit the rung
+    assert 0 < counts["distinct_rows"] <= 12 * steps
+    snap = registry.snapshot()
+    assert {n: snap["train"][n] for n in fm.TAIL_STATS} == counts
+    assert np.isfinite(t.cumulative_loss)
+    # where an operator looks: /metrics and `obs report`
+    from hivemall_tpu.obs.http import to_prometheus
+    from hivemall_tpu.obs.report import summarize
+    assert f"hivemall_tpu_train_tail_distinct_steps {steps}" in \
+        to_prometheus(snap)
+    assert f"tail:   distinct-row x{steps}  dense x0" in summarize(
+        [{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+
+
+def test_mesh_trainer_keeps_the_dense_tail():
+    from hivemall_tpu.models.fm import FMTrainer
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    t = FMTrainer(f"-dims {R * P} -factors {K} -opt adagrad -mini_batch 32 "
+                  "-mesh dp=1,tp=2")
+    ds = _trainer_stream(4, 32, 8, R * P, n_distinct=12)
+    t.fit(ds)
+    assert t.cumulative_loss == t.cumulative_loss
+    assert t._step_counts["tail_distinct_steps"] == 0
+    assert t._step_counts["tail_dense_steps"] == t._t

@@ -43,7 +43,10 @@ def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
     """One un-scanned packed FM minibatch step at the benchmark cell's
     geometry (-dims 2^26 -factors 5, B=32768, L=39, float32): the worker
     asserts the compiled text holds no `while(` (PR 24's parent had two,
-    one per direction of a reshape nobody saw; ~25 s of XLA compile)."""
+    one per direction of a reshape nobody saw; ~25 s of XLA compile) and,
+    since PR 28, one `conditional(` between the distinct-row tail and the
+    dense one, the four row kernels of ops/rows_pallas.py compiled by
+    Mosaic, no copy of a whole table, and temporaries under 3.01 GB."""
     _compile("fm_minibatch_step", timeout=600)
 
 

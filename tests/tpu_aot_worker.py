@@ -10,6 +10,7 @@ chip time.
 """
 
 import json
+import re
 import sys
 import time
 
@@ -20,13 +21,14 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
-from hivemall_tpu.ops import fm, fm_pallas, pallas_hist
+from hivemall_tpu.ops import fm, fm_pallas, pallas_hist, rows_pallas
 from hivemall_tpu.ops.losses import get_loss
 from hivemall_tpu.ops.optimizers import make_optimizer
 
 # the kernels under test ask the device policy; here the target is the
 # topology, not this process's (CPU) backend
 pallas_hist.pallas_interpret = lambda: False
+rows_pallas.use_kernels_default = lambda: True
 
 TOPO = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 DEV0 = SingleDeviceSharding(TOPO.devices[0])
@@ -110,14 +112,28 @@ def fm_minibatch_step():
                          reg="no")
     step = fm.make_fm_step_minibatch(get_loss("logloss"), opt, LAMS, K)
     table = _sds((Np, Pk * Wf), jnp.float32)
-    text = step.lower(
+    compiled = step.lower(
         {"T": table, "w0": _sds((), jnp.float32)},
         {"T": {"gg": table}, "w0": {"gg": _sds((), jnp.float32)}},
         _sds((), jnp.float32), _sds((B, L), jnp.int32), None,
         _sds((B,), jnp.float32), _sds((B,), jnp.float32)
-    ).compile().as_text()
+    ).compile()
+    text = compiled.as_text()
     loops = text.count(" while(")
     assert loops == 0, f"{loops} while op(s) in the one-step FM program"
+    # the distinct-row tail (PR 28): one cond between it and the dense
+    # tail, the donated tables passing through both in place (the row
+    # kernels alias them), and no more temporaries than the dense tail's
+    # G beside the gradient slab (2.81 GB at PR 27)
+    assert fm.tail_cap(B * L, Np), "the cell's shape must offer the tail"
+    conds = text.count(" conditional(")
+    assert conds == 1, f"{conds} conditional op(s), expected one"
+    kernels = text.count("tpu_custom_call")
+    assert kernels >= 4, f"{kernels} row kernels, expected take and put x2"
+    copies = re.findall(r"= f32\[%d,%d\]\S* copy\(" % (Np, Pk * Wf), text)
+    assert not copies, f"{len(copies)} copies of a whole table"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 3.01e9, f"{temp / 1e9:.2f} GB of temporaries"
 
 
 N, D, BINS = 1 << 20, 28, 64                    # bench_trees geometry
