@@ -2,8 +2,9 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 Drives the main path once, in ONE process, through the entry points a user
-calls, at the full width of the flagship (bench_ffm_kernel's train_ffm
-config; depth cut to a few windows, weights random from a seed):
+calls, at the full width of the flagship (train_ffm at libffm's Criteo
+setting: 40 fields, 4 factors, 32768-row batches, a 2^24-row hashed table;
+depth cut to a few windows, weights random from a seed):
 
   train   lookup("train_ffm") -> fit on a planted-signal SparseDataset (two
           full K=8 windows + a ragged tail) -> fit_stream from a Parquet
@@ -38,7 +39,7 @@ import urllib.request
 
 import numpy as np
 
-# flagship geometry: bench.py bench_ffm_kernel
+# flagship geometry: train_ffm, 40 fields x 4 factors, 2^24 hashed rows
 FULL = dict(dims=1 << 24, fields=40, factors=4, batch=32768, vocab=1000,
             n_batches=20, stream_batches=10)
 WINDOW = 8                     # -steps_per_dispatch auto on accelerators

@@ -92,8 +92,8 @@ def main():
     print(json.dumps({
         "config": "criteo_ffm",
         "cumulative_logloss": round(tr.cumulative_loss, 5),
-        # wall time includes jit compile + host row parse; bench.py is the
-        # steady-state device-throughput measurement
+        # wall time includes jit compile + host row parse; speed is
+        # measured by the benchmark under benchmark/ (PERF.md)
         "wall_examples_per_sec": round(args.rows / max(dt, 1e-9), 1),
         "synthetic": True,
     }))

@@ -1,7 +1,9 @@
+# Ran in round 3 on one TPU v5e chip reached over the relay link that PR 21
+# retired; its readings have not been re-run on this chip (PERF.md holds those).
 """Round-3 probes: where do the flagship step's 71.7 ms actually go, and
 can a Pallas per-row DMA pipeline beat XLA's ~26 ns/row gather/scatter?
 
-Flagship shapes (bench_ffm_kernel): B=32768, L=40, F=40, K=4, dims=2^24
+Flagship shapes (chip_smoke.py FULL): B=32768, L=40, F=40, K=4, dims=2^24
 => T [Mr=262144, W=168] bf16, rows [B*L=1310720] int32.
 
 Run:  python experiments/probe_idx.py [probe ...]

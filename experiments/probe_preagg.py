@@ -1,3 +1,5 @@
+# Ran in round 5 on one TPU v5e chip reached over the relay link that PR 21
+# retired; its readings have not been re-run on this chip (PERF.md holds those).
 """Probe: would per-field duplicate pre-aggregation beat the Pallas RMW?
 
 VERDICT r4 #1: at flagship shapes (B=32768 slots into MRF=8192-row field

@@ -1,3 +1,5 @@
+# Ran in round 5 on one TPU v5e chip reached over the relay link that PR 21
+# retired; its readings have not been re-run on this chip (PERF.md holds those).
 """Phase breakdown of the WARM RandomForest fit (round 5: StagedMatrix +
 -bootstrap poisson made the bench repeat-path 1.65 s at 1M x 28 x 16
 trees — where does that go now that quantize/h2d/bootstrap-h2d are off

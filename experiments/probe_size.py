@@ -1,3 +1,5 @@
+# Ran in round 3 on one TPU v5e chip reached over the relay link that PR 21
+# retired; its readings have not been re-run on this chip (PERF.md holds those).
 """Does XLA gather/scatter per-row cost depend on the table size?
 
 If a VMEM-resident table gathers/scatters faster per row, the FFM table can

@@ -1,3 +1,5 @@
+# Ran in round 4 on one TPU v5e chip reached over the relay link that PR 21
+# retired; its readings have not been re-run on this chip (PERF.md holds those).
 """Round-4 tree-kernel probe (VERDICT r3 weak #5 / next #6).
 
 Questions:

@@ -362,8 +362,8 @@ class ParquetStream:
                 shuffle: bool = True, seed: int = 42,
                 max_len: Optional[int] = None,
                 truncate: bool = False) -> Iterator[SparseBatch]:
-        # fresh decode counters per stream traversal: repeat-fit callers
-        # (the bench's best-of-3) read a per-call snapshot, not a lifetime
+        # fresh decode counters per stream traversal: a caller that fits
+        # repeatedly reads a per-call snapshot, not a lifetime
         # accumulation masquerading as one run's decode cost
         from .pipeline import PipelineStats
         self.stats = PipelineStats(pool="decode-ahead",

@@ -1,10 +1,10 @@
 """Parallel host ingest pipeline — multi-worker batch prep, in order.
 
-BENCH_r05 context: the fused FFM step sustains ~716k examples/sec while
-end-to-end training reaches ~44k — the chip idles >90% of the wall because
-host batch prep (string parse -> pad -> ``canonicalize_fieldmajor`` ->
-``pack_unit_fieldmajor``) runs as single-threaded Python ahead of a
-depth-2 ``DevicePrefetcher``. This is SURVEY §8's hard part verbatim
+Why: host batch prep (string parse -> pad -> ``canonicalize_fieldmajor`` ->
+``pack_unit_fieldmajor``) run as single-threaded Python ahead of a depth-2
+``DevicePrefetcher`` leaves the chip waiting for its input; how much it
+waits in the benchmark's cell is PERF.md section 5's
+``loop.wait_input_share``. This is SURVEY §8's hard part verbatim
 ("the input path ... can easily be the bottleneck, not the TPU"); the
 reference never met it because Hadoop amortized ingest across mappers.
 
@@ -168,7 +168,7 @@ class PipelineStats:
                 if self.queue_samples else 0.0)
 
     def as_dict(self) -> dict:
-        """JSON-ready snapshot (bench.py embeds this in its output dict)."""
+        """JSON-ready snapshot (the registry's ``pipeline`` section)."""
         return {
             "workers": self.workers,
             "pool": self.pool,

@@ -210,8 +210,8 @@ class MegabatchStager:
     may alias host memory, so reuse could corrupt a batch mid-step.
 
     ``stats`` (PipelineStats) records stack time, megabatches staged and
-    singles flushed — the dispatch-overhead decomposition the bench
-    reads.
+    singles flushed — the dispatch-overhead decomposition the
+    benchmark's first-dispatch check and chip_smoke.py read.
 
     ``reuse=True`` is only valid when a DevicePrefetcher consumes this
     stager on its worker thread (its ``stage_batch`` provides the

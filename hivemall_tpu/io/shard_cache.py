@@ -1,10 +1,9 @@
 """Ahead-of-time packed shard cache — persist prepared batches on disk.
 
-BENCH_r05 / docs/PERFORMANCE.md context: the fused FFM kernel sustains
-~716k examples/sec but the e2e paths deliver 44.8k (in-RAM) and 39.4k
-(Parquet streaming) because the host leg — string parse -> canonicalize ->
-pack — re-runs as (mostly) single-parser Python every epoch and every
-restart. The reference never met this wall (Hadoop re-ran the scan per
+Why (docs/PERFORMANCE.md "Shard cache"): the host leg — string parse ->
+canonicalize -> pack — otherwise re-runs as (mostly) single-parser Python
+every epoch and every restart, and the device waits on it. The reference
+never met this wall (Hadoop re-ran the scan per
 query but amortized it across mappers); the TPU-native analog is a
 device-feeding data service where the host leg runs ONCE: after a shard is
 parsed/canonicalized/packed the first time, the prepared bytes persist and

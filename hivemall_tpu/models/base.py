@@ -751,7 +751,7 @@ class LearnerBase:
     def _wrap_prefetch(self, it, closers: List, depth: int = 2):
         """Stage ``it`` onto the device ahead of compute, sharing this
         trainer's PipelineStats so prep/transfer/compute waits land in one
-        struct (the bench's stage decomposition reads it)."""
+        struct (the registry's ``pipeline`` section)."""
         from ..io.prefetch import DevicePrefetcher
         pf = DevicePrefetcher(it, depth=depth, stats=self.pipeline_stats)
         closers.append(pf.close)
@@ -1118,8 +1118,7 @@ class LearnerBase:
             batch = self._shard_batch(batch)
         # the span is the HOST-side dispatch boundary: synchronous compute
         # on CPU, dispatch latency on accelerators (async tails land in
-        # the next blocking boundary) — the same semantics as the bench's
-        # stage decomposition
+        # the next blocking boundary)
         t0 = time.perf_counter()
         with self._tracer.span("dispatch.step", getattr(batch, "seq", None)):
             loss_sum = self._train_batch(batch)

@@ -9,7 +9,7 @@ FFMPredictUDF for scoring.
 TPU design: dense hashed tables w[N], V[N,K] (FM) / V[N,F,K] (FFM) in HBM,
 bf16-able; one jitted value_and_grad step per minibatch (ops.fm). The FFM
 (feature,field) table is the TP-sharding target for multi-chip (SURVEY.md §8
-M3); see parallel.dp / __graft_entry__.dryrun_multichip.
+M3); see parallel.mesh / __graft_entry__.dryrun_multichip.
 """
 
 from __future__ import annotations

@@ -34,8 +34,8 @@ recorded as a ``compile.retrace`` span, and emitted as a ``retrace``
 event into the metrics jsonl. The sentinel auto-arms at the first
 ``train_done`` (one completed fit = the process's compile warmup), so a
 second same-config trainer that re-compiles — the word2vec disease —
-flags itself in telemetry with no harness involved. ``bench.py --smoke``
-turns the sentinel into a CI guard: warm epoch, ``arm()``, second epoch
+flags itself in telemetry with no harness involved. tests/test_devprof.py
+holds the sentinel as an invariant: warm epoch, ``arm()``, second epoch
 must add ZERO compiles, and a deliberately-injected fresh-closure
 duplicate trainer must be caught.
 
@@ -249,7 +249,7 @@ class DevProf:
         every shape a config needs, so later compiles in the same process
         are exactly the duplicate-instance disease the factories exist to
         prevent. Harness code that intentionally compiles new configs
-        (benches, test suites) sees retrace COUNTERS grow, never a
+        (test suites) sees retrace COUNTERS grow, never a
         failure — the CI guard reads a delta over an explicitly armed
         window instead."""
         self.armed = True

@@ -49,10 +49,10 @@ already over, which an annotation cannot; those stay Chrome-only.)
 Host-side semantics: a dispatch span measures the host's time in the
 dispatch call (on CPU that is the synchronous step; on accelerators it is
 dispatch latency — the async compute tail lands in the NEXT blocking
-boundary, exactly like the bench's stage decomposition). Rollups emit as
-``span_rollup`` jsonl events at the trainer's loss-fold cadence; the raw
-ring exports as Chrome-trace JSON (``chrome://tracing`` / Perfetto) for
-deep dives alongside ``jax.profiler``.
+boundary). Rollups emit as ``span_rollup`` jsonl events at the trainer's
+loss-fold cadence; the raw ring exports as Chrome-trace JSON
+(``chrome://tracing`` / Perfetto) for deep dives alongside
+``jax.profiler``.
 
 Activation: ``HIVEMALL_TPU_TRACE=1`` enables the process tracer;
 ``HIVEMALL_TPU_TRACE=/path/trace.json`` additionally writes the Chrome

@@ -1,10 +1,9 @@
 """Fused multi-step dispatch — K optimizer steps in ONE jitted lax.scan.
 
-BENCH_r05 context: the fused FFM device step runs ~716k examples/sec while
-end-to-end training sustains ~44k. After PR 1 removed the host-prep wall,
-the residual gap is per-minibatch DISPATCH cost: one Python->jit call, one
-h2d transfer, and (absent donation across calls) an XLA copy of the
-dims-sized tables per step. The reference amortizes per-ROW overhead by
+Why: with host prep off the critical path (io/pipeline.py), what a training
+loop still pays per minibatch is DISPATCH: one Python->jit call, one h2d
+transfer, and (absent donation across calls) an XLA copy of the dims-sized
+tables per step. The reference amortizes per-ROW overhead by
 buffering rows into minibatches (LearnerBaseUDTF's miniBatchSize); the
 TPU-native analog amortizes per-BATCH overhead by buffering minibatches
 into device-resident megasteps — the step-fusion idiom pjit training loops

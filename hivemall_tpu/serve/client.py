@@ -1,18 +1,16 @@
 """Shared raw keep-alive HTTP client for the serving plane.
 
-One wire implementation, three historical call sites: the router's
-pooled replica connections (``serve/router.py``), the smoke/bench
-driver client (``serve/http.py`` ``KeepAliveClient``) and bench.py's
-``_RawClient`` all converged here so protocol changes — the binary
-frame Content-Type (serve/wire.py), the UDS fast path — land in ONE
-place instead of three hand-rolled copies.
+One wire implementation for every caller: the router's pooled replica
+connections (``serve/router.py``), the smokes' driver client
+(``serve/http.py`` ``KeepAliveClient``) and the tests, so protocol
+changes — the binary frame Content-Type (serve/wire.py), the UDS fast
+path — land in ONE place.
 
-Raw sockets, hand-built request heads, minimal response parse: the
-serving stack's own measurements put this ~5x cheaper per request than
-``http.client``, which matters both for the router (one Python process
-fronting many replicas) and for bench harness share (client, router
-and replicas on one host).  NOT thread-safe — one client per thread,
-by design.
+Raw sockets, hand-built request heads, minimal response parse: cheaper
+per request than ``http.client``, which matters for the router (one
+Python process fronting many replicas) and for a load generator that
+shares its host with the server.  NOT thread-safe — one client per
+thread, by design.
 """
 
 from __future__ import annotations
@@ -134,7 +132,7 @@ class RawHTTPClient:
     close``); a server actively refusing still raises.  The last
     response's headers stay readable on ``self.last_headers`` and its
     raw hop headers on ``self.last_hops`` (the trace/hop assertions in
-    smokes and bench read them)."""
+    smokes and tests read them)."""
 
     def __init__(self, host: str, port: int, timeout: float = 30.0,
                  uds: Optional[str] = None):
@@ -241,17 +239,17 @@ class RawHTTPClient:
             return decode_response_frame(payload)
         return json.loads(payload)
 
-    # -- prebuilt-request fast path (bench harness) ------------------------
+    # -- prebuilt-request fast path (load drivers) -------------------------
     @staticmethod
     def build(host: str, port: int, path: str, body: bytes,
               ctype: str = "application/json") -> bytes:
-        """Pre-build one request's bytes for ``exchange`` — the timed
-        bench loop sends static bytes so harness share stays negligible."""
+        """Pre-build one request's bytes for ``exchange`` — a load loop
+        sends static bytes so the driver's share stays negligible."""
         return build_request(host, port, path, body, ctype=ctype)
 
     def exchange(self, request: bytes) -> int:
         """Send one pre-built request, read one response, return status.
-        No retry (bench wants the failure), hop headers land raw in
+        No retry (a driver wants the failure), hop headers land raw in
         ``self.last_hops``."""
         conn = self._connect()
         conn.sock.sendall(request)

@@ -51,8 +51,7 @@ Lifecycle, all manager-owned:
   requests complete) before exiting; SIGKILL only after a timeout.
 
 ``Fleet`` bundles manager + router into one start()/stop() — the
-``serve --replicas N`` CLI surface and what bench_serve/fleet smoke
-drive.
+``serve --replicas N`` CLI surface and what the fleet smoke drives.
 """
 
 from __future__ import annotations

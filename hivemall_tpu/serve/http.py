@@ -123,8 +123,8 @@ class _ServeHandler(_ObsHandler):
     server_ref: "PredictServer" = None   # type: ignore[assignment]
 
     # HTTP/1.1 => keep-alive by default: per-request TCP setup (handshake
-    # + slow-start + a fresh connection thread) is measurable overhead in
-    # bench_serve at high concurrency, and the fleet router holds pooled
+    # + slow-start + a fresh connection thread) is measurable overhead
+    # at high concurrency, and the fleet router holds pooled
     # connections to every replica. Safe here because every response path
     # (_json, the obs handler, send_error) carries Content-Length; the
     # threaded server gives each kept-alive connection its own thread, and
@@ -358,7 +358,8 @@ class _ServeHandler(_ObsHandler):
         # per-hop latency breakdown: parts sum to the replica's measured
         # wall for THIS request ("other" closes the residual — result
         # pickup + response build). The router stacks its relay hop on
-        # top; bench_serve and the fleet smoke consume these.
+        # top; the fleet smoke and the benchmark's serve readers consume
+        # these.
         hop = getattr(fut, "hop", None) or {}
         total_ms = (time.monotonic() - t_req0) * 1000.0
         parse_ms = (t_parsed - t_req0) * 1000.0

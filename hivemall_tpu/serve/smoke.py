@@ -147,7 +147,7 @@ def _drive(args, tmp, ds, rows, ref, engine, srv, base) -> int:
 
     # -- concurrent predicts: coalescing + bit-match + latency ------------
     # each worker holds ONE keep-alive connection (HTTP/1.1 — the
-    # PredictServer reuse path runs in CI, not just in bench_serve)
+    # PredictServer reuse path runs in CI)
     from .http import KeepAliveClient
     scores = [None] * len(rows)
     lat = [0.0] * len(rows)

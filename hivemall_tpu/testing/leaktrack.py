@@ -32,8 +32,8 @@ How it works, when enabled:
   deterministic on hosts whose runtime lazily opens fds.
 
 Gating: ``HIVEMALL_TPU_LEAKTRACK=1`` turns :func:`maybe_enable` on (the
-serve/fleet/retrain smokes call it before building anything); the bench
-timed legs never enable it — a sanitizer build is never a perf build.
+serve/fleet/retrain smokes call it before building anything); the
+benchmark never enables it — a sanitizer build is never a perf build.
 
 Known limitations: resources created BEFORE :func:`enable` are
 invisible (enable first, construct second); fd-level growth without a

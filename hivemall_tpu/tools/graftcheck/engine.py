@@ -926,7 +926,7 @@ def selfcheck() -> int:
 
 def _default_paths() -> List[str]:
     """The full repo surface: the installed package tree plus the repo's
-    out-of-package Python — tests/, bench.py, the graft entry point —
+    out-of-package Python — tests/ and the graft entry point —
     so deadline idioms and thread workers in the harness obey the same
     invariants the package does (works from any cwd; paths that don't
     exist in an installed-package context are skipped)."""
@@ -934,7 +934,6 @@ def _default_paths() -> List[str]:
         os.path.abspath(__file__))))
     repo = os.path.dirname(pkg)
     extras = [os.path.join(repo, "tests"),
-              os.path.join(repo, "bench.py"),
               os.path.join(repo, "__graft_entry__.py")]
     return [pkg] + [p for p in extras if os.path.exists(p)]
 
@@ -946,7 +945,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "(docs/STATIC_ANALYSIS.md)")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: the hivemall_tpu "
-                         "package + tests/ + bench.py + the graft entry)")
+                         "package + tests/ + the graft entry)")
     ap.add_argument("--baseline", default=None,
                     help="baseline JSON (default: ./graftcheck_baseline"
                          ".json when present)")

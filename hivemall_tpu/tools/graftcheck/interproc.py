@@ -264,7 +264,7 @@ def under_lock(ctx: Any, node: ast.AST, top: Optional[ast.AST]) -> bool:
 def module_name_of(relpath: str) -> str:
     """Dotted module name a scan-root-relative path imports as:
     ``hivemall_tpu/serve/engine.py`` -> ``hivemall_tpu.serve.engine``,
-    ``bench.py`` -> ``bench``; packages drop the ``__init__``."""
+    ``chip_smoke.py`` -> ``chip_smoke``; packages drop the ``__init__``."""
     p = relpath[:-3] if relpath.endswith(".py") else relpath
     parts = [x for x in p.split("/") if x]
     if parts and parts[-1] == "__init__":

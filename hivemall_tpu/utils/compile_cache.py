@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, placed from outside the program.
 
 Every entry point calls :func:`enable_compile_cache` before its first jit
-(CLI, chip_smoke, bench children, fleet worker, retrain child, bulk
+(CLI, chip_smoke, the benchmark, fleet worker, retrain child, bulk
 workers). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself
 and nothing is set in code; otherwise the cache lives at the fixed
 ``<checkout>/.jax_cache`` (git-ignored). The path is part of the cache

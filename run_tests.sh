@@ -24,7 +24,7 @@ rm -f artifacts/graftcheck_report.json artifacts/tsan_races.jsonl \
 # silence fresh findings / flag stale entries, the tsan lockset
 # sanitizer must detect the re-seeded PR 11 last_reload_error race,
 # and the leaktrack census sanitizer must catch a seeded fd leak —
-# then the real scan (package + tests/ + bench.py + graft entry;
+# then the real scan (package + tests/ + graft entry;
 # content-hash cached, whole-scan invalidation on any edit or rule
 # bump) fails on ANY finding (the tree's contract since PR 11 is an
 # EMPTY baseline; a PR that must land with debt commits
@@ -62,14 +62,12 @@ python -m hivemall_tpu.obs.smoke || exit $?
 # class's attribute writes are lockset-checked across the HTTP handler
 # / dispatch / watch / warmup threads, and ANY write/write race fails
 # the smoke (the latency budget relaxes — a sanitizer build is never a
-# perf build; the un-instrumented budget stays pinned by bench_serve).
+# perf build).
 # HIVEMALL_TPU_LEAKTRACK=1 additionally runs the FD/socket/thread leak
 # census (hivemall_tpu.testing.leaktrack): a snapshot at smoke start
 # must match the census after the full traffic+reload+drain+shutdown
 # cycle — any tracked resource still alive fails the smoke with its
-# creation stack appended to the JSONL artifact. The bench timed legs
-# below never enable either sanitizer (a sanitizer build is never a
-# perf build).
+# creation stack appended to the JSONL artifact.
 env HIVEMALL_TPU_TSAN=1 HIVEMALL_TPU_TSAN_LOG=artifacts/tsan_races.jsonl \
     HIVEMALL_TPU_LEAKTRACK=1 \
     HIVEMALL_TPU_LEAKTRACK_LOG=artifacts/leaktrack_census.jsonl \
@@ -214,23 +212,3 @@ python -m pytest \
     2>&1 | grep -q "1 passed" || {
     echo "FAIL: canonicalizer parity test skipped/failed (the native" \
          "library did not build?)"; exit 1; }
-
-# bench harness smoke: tiny-shape runs of the ingest-path benches assert
-# every metric still emits and parses (pipeline refactors must not silently
-# break bench.py), and the dispatch-fusion microbench enforces its floor —
-# K=8 fused smoke throughput below the K=1 number fails the run (catches
-# accidental defusion of the -steps_per_dispatch path). Two ISSUE-9 guards ride in the same process (no second
-# bench pass):
-#   - no-retrace invariant (docs/OBSERVABILITY.md "Training profiling"):
-#     a warmed FFM e2e epoch must add ZERO post-warmup XLA compiles, and a
-#     deliberately-injected fresh-closure duplicate-config trainer (the
-#     compile factories bypassed) MUST be caught by the devprof sentinel —
-#     retrace counter up + a `retrace` event in the metrics jsonl;
-#   - perf-regression gate: the fresh smoke numbers diff against the
-#     newest committed smoke-shape BENCH_r*.json per benchmark key
-#     (bench.py --compare machinery; HIVEMALL_TPU_BENCH_TOLERANCE
-#     overrides the 70% CI tolerance — the 2-core container's
-#     run-to-run swings reach ~3x, so the always-on gate flags only
-#     the catastrophic class), and the gate self-tests by injecting a
-#     synthetic 10x regression that must flip it.
-python bench.py --smoke

@@ -9,6 +9,7 @@ composition semantics at suite-friendly shapes with in-process pools."""
 import os
 
 import numpy as np
+import pytest
 import pyarrow as pa
 import pyarrow.parquet as pq
 
@@ -234,3 +235,26 @@ def test_bulk_promoted_pointer_default(tmp_path):
     assert not np.array_equal(got,
                               np.asarray(new.predict_proba(test),
                                          np.float32))
+
+
+@pytest.mark.parametrize("precision,margin", [("f32", 1e-6), ("int8", 0.05)])
+def test_bulk_arena_twins_score_every_row(tmp_path, precision, margin):
+    """The mmap'd arena twins through the bulk path: every row of every
+    shard scored, in order, inside the tier's margin of predict_proba,
+    and the streamed eval metrics computed from those scores."""
+    tr, bundle = _trained(str(tmp_path / "ck"))
+    n = 300
+    test = _synth(n, DIMS, 8, seed=2)
+    in_dir = str(tmp_path / "in")
+    write_parquet_shards(test, in_dir, rows_per_shard=128)  # 128/128/44
+    out = str(tmp_path / "out")
+    r = bulk_predict("train_classifier", in_dir, out, options=OPTS,
+                     bundle=bundle, backend="arena", precision=precision,
+                     workers=2, pool="thread")
+    assert r["rows"] == n and r["shards"] == 3
+    assert r["backend"] == "arena" and r["precision"] == precision
+    want = np.asarray(tr.predict_proba(test), np.float32)
+    got = _scores(out)
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= margin
+    assert abs(r["metrics"]["logloss"] - logloss(test.labels, got)) < 1e-5

@@ -83,35 +83,76 @@ def test_shape_bucket_dedup():
 # --- no-retrace sentinel -----------------------------------------------------
 
 
+def _unwrapped(factory):
+    """The uncached builder under a config-cached step factory."""
+    while hasattr(factory, "__wrapped__"):
+        factory = factory.__wrapped__
+    return factory
+
+
+def _linear_case():
+    dims, B = 1 << 10, 64
+    opts = f"-dims {dims} -mini_batch {B} -opt adagrad"
+
+    def inject(t):
+        t._step = _unwrapped(_linear_step_cached)(
+            "hingeloss", "adagrad", str(t.opts.eta), float(t.opts.eta0),
+            t.opts.total_steps, t.opts.power_t, str(t.opts.reg),
+            t.opts["lambda"], t.opts.l1_ratio)
+
+    return _dataset(dims=dims), (lambda: GeneralClassifier(opts)), inject
+
+
+def _ffm_case():
+    """The flagship's recipe at toy size: Criteo-shaped rows (one feature
+    per field) through the joint layout's three cached steps."""
+    from hivemall_tpu.models.fm import FFMTrainer, _ffm_step_fused_cached
+    n, L, dims = 256, 8, 1 << 12
+    rng = np.random.default_rng(21)
+    ds = SparseDataset(
+        rng.integers(1, dims, n * L).astype(np.int32),
+        np.arange(0, n * L + 1, L, dtype=np.int64),
+        np.ones(n * L, np.float32),
+        (rng.integers(0, 2, n) * 2 - 1).astype(np.float32),
+        np.tile(np.arange(L, dtype=np.int32), n))
+    opts = (f"-dims {dims} -factors 2 -fields {L} -mini_batch 64 "
+            f"-opt adagrad -classification -halffloat")
+
+    def inject(t):
+        o = t.opts
+        head = (t._loss_name, *t._opt_key,
+                (o.lambda0, o.lambda_w, o.lambda_v), t.F, t.k)
+        raw = _unwrapped(_ffm_step_fused_cached)
+        t._step = raw(*head, False, False)
+        t._step_fm = raw(*head, True, False)
+        t._step_fm_unit = raw(*head, True, True)
+
+    return ds, (lambda: FFMTrainer(opts)), inject
+
+
+@pytest.mark.parametrize("case", [_linear_case, _ffm_case],
+                         ids=["linear", "ffm"])
 def test_warmed_epoch_adds_zero_compiles_and_injection_is_caught(
-        sink_stream):
+        sink_stream, case):
     """The acceptance invariant: with the config caches intact a warmed
     epoch (and a duplicate-config trainer) adds ZERO XLA compiles; a
     fresh closure bypassing the factory compiles and is flagged as a
     `retrace` — counter + jsonl event."""
     dp = get_devprof()
-    dims, B = 1 << 10, 64
-    ds = _dataset(dims=dims)
-    opts = f"-dims {dims} -mini_batch {B} -opt adagrad"
-    t = GeneralClassifier(opts)
+    ds, make, inject = case()
+    t = make()
     t.fit(ds, epochs=1, shuffle=False)          # warmup epoch
     dp.arm()
     try:
         c0, r0 = dp.compiles, dp.retraces
         t.fit(ds, epochs=1, shuffle=False)
         assert dp.compiles == c0, "warmed epoch recompiled"
-        t2 = GeneralClassifier(opts)            # dup config, caches intact
+        t2 = make()                             # dup config, caches intact
         t2.fit(ds, epochs=1, shuffle=False)
         assert dp.compiles == c0, "cached duplicate-config recompiled"
-        # the disease: a fresh jitted closure instead of the cached step
-        raw = _linear_step_cached
-        while hasattr(raw, "__wrapped__"):
-            raw = raw.__wrapped__
-        t3 = GeneralClassifier(opts)
-        t3._step = raw("hingeloss", "adagrad", str(t3.opts.eta),
-                       float(t3.opts.eta0), t3.opts.total_steps,
-                       t3.opts.power_t, str(t3.opts.reg),
-                       t3.opts["lambda"], t3.opts.l1_ratio)
+        # the disease: fresh jitted closures instead of the cached steps
+        t3 = make()
+        inject(t3)
         t3.fit(ds, epochs=1, shuffle=False)
         assert dp.compiles > c0 and dp.retraces > r0
         evs = _events(sink_stream)
@@ -218,61 +259,3 @@ def test_profile_env_routes_through_devprof(tmp_path, monkeypatch,
     assert evs and evs[0]["dir"] == prof_dir
     import os
     assert os.path.isdir(prof_dir)
-
-
-# --- perf-regression gate (bench.py --compare machinery) --------------------
-
-
-def test_compare_results_gate():
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    fresh = {"ffm_e2e": [100.0, 90.0], "ingest": [1000.0, 950.0],
-             "serve_qps": [10.0, 9.0]}
-    recorded = {"ffm_e2e": [100.0, 100.0], "ingest": [1000.0, 1000.0],
-                "serve_qps": [100.0, 100.0], "gone": [5.0, 5.0]}
-    # within tolerance: no regression; serve_qps is volatile (never gated)
-    regs, lines = bench._compare_results(fresh, recorded, tolerance=0.25)
-    assert regs == []
-    assert any("volatile" in ln for ln in lines)
-    assert any("gone" in ln and "skipped" in ln for ln in lines)
-    # a >= tolerance drop on a gated key must flag
-    fresh["ffm_e2e"] = [60.0, 60.0]
-    regs, _ = bench._compare_results(fresh, recorded, tolerance=0.25)
-    assert [r["key"] for r in regs] == ["ffm_e2e"]
-
-    # record round-trip: the v1 schema parses back with the same keys
-    rec = {"schema": bench._RECORD_SCHEMA, "chip": {"platform": "cpu"},
-           "smoke": True, "results": recorded}
-    import tempfile
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump(rec, f)
-        path = f.name
-    try:
-        loaded = bench._load_bench_record(path)
-        assert loaded["results"] == recorded
-        assert loaded["platform"] == "cpu" and loaded["smoke"] is True
-    finally:
-        os.unlink(path)
-
-
-def test_driver_capture_record_parses():
-    """The historical BENCH_r04/r05 driver captures (stdout tail with the
-    compact summary line last) must yield per-key results."""
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    root = os.path.join(os.path.dirname(__file__), "..")
-    r05 = bench._load_bench_record(os.path.join(root, "BENCH_r05.json"))
-    assert r05 and "ffm_e2e" in r05["results"]
-    assert r05["smoke"] is False       # full-shape: never gates smoke runs
-    path, newest = bench._newest_bench_record(root)
-    assert newest and newest["results"]

@@ -172,6 +172,14 @@ def _evsrv(eng, **kw):
                                **kw).start()
 
 
+def _srv(plane, eng):
+    if plane == "evloop":
+        return _evsrv(eng)
+    from hivemall_tpu.serve.http import PredictServer
+    return PredictServer(eng, port=0, max_delay_ms=1.0, watch=False,
+                         slo=False).start()
+
+
 def test_evloop_frame_bitmatches_json_and_mixed_clients(trained):
     """Binary frames and JSON strings negotiate per-request on ONE
     listener and score to identical bits — a frame client and a string
@@ -272,18 +280,12 @@ def test_hop_header_parts_sum_on_both_planes(trained):
     that sum to total on BOTH planes; the evloop plane adds a leading
     ``loop`` component (event-loop dwell) the threaded plane lacks."""
     from hivemall_tpu.serve.client import RawHTTPClient
-    from hivemall_tpu.serve.http import PredictServer
     t, ds, ckdir, _ = trained
     rows = _feat_rows(ds, 2)
     threaded_keys = {"parse", "queue", "assemble", "predict", "other",
                      "total"}
     for plane in ("threaded", "evloop"):
-        eng = _engine(ckdir)
-        if plane == "evloop":
-            srv = _evsrv(eng)
-        else:
-            srv = PredictServer(eng, port=0, max_delay_ms=1.0,
-                                watch=False, slo=False).start()
+        srv = _srv(plane, _engine(ckdir))
         cli = RawHTTPClient("127.0.0.1", srv.port)
         try:
             code, _ = cli.post_json("/predict", {"rows": rows})
@@ -339,3 +341,69 @@ def test_evloop_uds_transport_bitmatches_tcp(trained, tmp_path):
                 c.close()
         srv.stop()
     assert not os.path.exists(uds)     # teardown unlinks the socket file
+
+
+@pytest.mark.parametrize("plane,precision,wire", [
+    ("threaded", "f32", "json"), ("threaded", "int8", "json"),
+    ("evloop", "f32", "json"), ("evloop", "int8", "json"),
+    ("evloop", "int8", "frame")])
+def test_plane_tier_matrix_answers_every_request(trained, plane, precision,
+                                                 wire):
+    """Every point of the plane x tier x wire matrix, driven by concurrent
+    keep-alive clients sending pre-built requests: every request answered
+    200, nothing shed or expired, every row counted, and the scores
+    right for the tier (f32 to the bit, int8 inside its margin)."""
+    import json
+    import threading
+    from hivemall_tpu.serve.client import RawHTTPClient
+    t, ds, ckdir, _ = trained
+    rows = _feat_rows(ds, 8)
+    ref = _ref(t, rows)
+    eng = _engine(ckdir, precision=precision)
+    srv = _srv(plane, eng)
+    n_clients, per_client = 3, 8
+    try:
+        if wire == "frame":
+            ctype = CONTENT_TYPE_FRAME
+            bodies = [encode_frame([t._parse_row(r)]) for r in rows]
+        else:
+            ctype = "application/json"
+            bodies = [json.dumps({"rows": [r]}).encode() for r in rows]
+        reqs = [RawHTTPClient.build("127.0.0.1", srv.port, "/predict", b,
+                                    ctype=ctype) for b in bodies]
+        codes = []                  # (status, carried its hop header)
+
+        def client(k):
+            cli = RawHTTPClient("127.0.0.1", srv.port)
+            try:
+                for i in range(per_client):
+                    code = cli.exchange(reqs[(i + k) % len(reqs)])
+                    codes.append((code, cli.last_hops is not None))
+            finally:
+                cli.close()
+
+        ths = [threading.Thread(target=client, args=(k,))
+               for k in range(n_clients)]
+        for th in ths:
+            th.start()
+        for th in ths:
+            th.join()
+        assert codes == [(200, True)] * (n_clients * per_client)
+        st = srv.batcher.stats()
+        assert st["shed"] == 0 and st["expired"] == 0
+        assert st["rows"] == n_clients * per_client
+        assert st["mean_batch_rows"] >= 1.0
+        cli = RawHTTPClient("127.0.0.1", srv.port)
+        try:
+            code, r = cli.post_json("/predict", {"rows": rows})
+        finally:
+            cli.close()
+        got = np.asarray(r["scores"], np.float32)
+        assert code == 200
+        if precision == "f32":
+            assert np.array_equal(got, ref)
+        else:
+            assert eng.arena_mapped_bytes > 0
+            assert np.abs(got - ref).max() < 0.05
+    finally:
+        srv.stop()

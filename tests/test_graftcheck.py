@@ -29,11 +29,10 @@ PKG = os.path.join(REPO, "hivemall_tpu")
 @pytest.fixture(scope="module")
 def extended_scan():
     """ONE scan of the full default CI surface (package + tests/ +
-    bench.py + graft entry), shared by every repo-clean pin below —
+    graft entry), shared by every repo-clean pin below —
     five independent repo-wide scans cost ~75 s of tier-1 wall on the
     2-core container and the suite runs against an 870 s budget."""
     paths = [PKG, os.path.join(REPO, "tests"),
-             os.path.join(REPO, "bench.py"),
              os.path.join(REPO, "__graft_entry__.py")]
     return run_paths([p for p in paths if os.path.exists(p)], root=REPO)
 
@@ -1175,7 +1174,7 @@ def test_fix_gc06_inserts_annotation(tmp_path):
 # -- repo-level: the EXTENDED default scan gates clean --------------------
 
 def test_extended_repo_surface_gates_clean(extended_scan):
-    """tests/, bench.py and the graft entry obey the same invariants as
+    """tests/ and the graft entry obey the same invariants as
     the package (the PR 12 scan-coverage satellite): the full default
     surface carries ZERO findings."""
     assert extended_scan == [], "\n".join(

@@ -92,8 +92,9 @@ def test_stats_populated():
     assert stats.workers == 2 and stats.pool == "thread"
     d = stats.as_dict()
     for k in ("prep_seconds", "prep_wait_seconds",
-              "prep_backpressure_seconds", "avg_queue_occupancy",
-              "queue_peak"):
+              "prep_backpressure_seconds", "stage_seconds",
+              "consume_wait_seconds", "avg_queue_occupancy", "queue_peak",
+              "batches_prepared", "batches_staged"):
         assert k in d
 
 

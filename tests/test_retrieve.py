@@ -122,6 +122,35 @@ def test_srp_index_clamp_determinism_and_stats():
         assert i in c
 
 
+def test_lsh_recall_rises_with_tables_and_candidates_stay_sublinear():
+    """Seeded factors with planted rank-8 structure, seeded hyperplanes,
+    the MIPS-augmented geometry the serving tier hashes: more tables can
+    only widen the candidate union, so recall@10 against exact search
+    rises table over table while the union stays a small share of the
+    catalog."""
+    rng = np.random.default_rng(11)
+    users, items = 64, 8192
+    P = rng.standard_normal((users, 8)).astype(np.float32)
+    Q = rng.standard_normal((items, 8)).astype(np.float32)
+    bi = (0.1 * rng.standard_normal(items)).astype(np.float32)
+    aug, _m = mips_augment(Q, bi)
+    recall, frac = {}, {}
+    for n_tables in (2, 12):
+        idx = SrpIndex(aug, n_tables=n_tables)
+        recs, fracs = [], []
+        for u in range(users):
+            scores = Q @ P[u] + bi
+            cands = idx.candidates(mips_query(P[u], has_bias=True))
+            fracs.append(len(cands) / items)
+            recs.append(recall_at_k(
+                cands[exact_top_ids(scores[cands], 10)] if len(cands)
+                else [], exact_top_ids(scores, 10)))
+        recall[n_tables] = float(np.mean(recs))
+        frac[n_tables] = float(np.mean(fracs))
+    assert recall[12] >= recall[2] > 0.0, recall
+    assert frac[2] <= frac[12] < 0.25, frac
+
+
 def test_recall_at_k():
     assert recall_at_k([1, 2, 3], [1, 2, 3]) == 1.0
     assert recall_at_k([1, 9, 8], [1, 2, 3]) == pytest.approx(1 / 3)

@@ -645,6 +645,46 @@ def test_router_cache_end_to_end(router_with_replica):
         cli.close()
 
 
+def test_fleet_aggregate_counts_a_shared_arena_once(tmp_path, fitted):
+    """Two int8 replicas on one bundle map ONE published arena: the
+    fleet aggregate sums what each reports and counts the arena of one
+    model step once, with no request failed, shed or expired on the way.
+    (In-process replicas share one obs registry, so the two reports are
+    one server's; the two-process proof by inode is arena_smoke's.)"""
+    from hivemall_tpu.serve.engine import PredictEngine
+    from hivemall_tpu.serve.http import KeepAliveClient, PredictServer
+    from hivemall_tpu.serve.router import RouterServer
+    t, ds = fitted
+    p = os.path.join(str(tmp_path), f"{t.NAME}-step{t._t:010d}.npz")
+    t.save_bundle(p)
+    srvs = [PredictServer(
+        PredictEngine("train_classifier", OPTS, bundle=p, precision="int8",
+                      max_batch=16, warmup_len=ds.max_row_len),
+        watch=False, slo=False).start() for _ in range(2)]
+    router = RouterServer().start()
+    cli = KeepAliveClient("127.0.0.1", router.port)
+    try:
+        for i, srv in enumerate(srvs):
+            router.add_replica(f"r{i}", "127.0.0.1", srv.port, ready=True)
+        for _ in range(6):
+            code, _r = cli.post_json("/predict", {"rows": _rows(ds, 2)})
+            assert code == 200
+        agg = router.fleet_snapshot()["fleet"]["aggregate"]
+        one = srvs[0].engine.arena_mapped_bytes
+        assert one > 0 and srvs[1].engine.arena_mapped_bytes == one
+        assert agg["arena_mapped_bytes_unique"] == one
+        assert agg["arena_mapped_bytes"] == 2 * one
+        assert agg["requests"] > 0 and agg["errors"] == 0
+        assert agg["shed"] == 0 and agg["expired"] == 0
+        # the first replica published, the second only mapped
+        assert sorted(s.engine.arena_publishes for s in srvs) == [0, 1]
+    finally:
+        cli.close()
+        router.stop()
+        for srv in srvs:
+            srv.stop()
+
+
 def test_router_cache_disabled_stub():
     from hivemall_tpu.serve.router import RouterServer, _CACHE_STUB
     r = RouterServer()

@@ -90,7 +90,7 @@ def test_pair_generation_is_fast():
     """Host pair gen must not regress to per-token Python (VERDICT r1 weak
     #3). The vectorized path runs ~50M pairs/sec; the old scalar loop ran
     <1M. The 2M floor catches the regression with a wide margin for loaded
-    CI machines (prod target 10M+ is asserted by bench.py, not here)."""
+    CI machines."""
     import time
     import numpy as np
     from hivemall_tpu.models.word2vec import Word2VecTrainer

@@ -33,7 +33,7 @@ rows_pallas.use_kernels_default = lambda: True
 TOPO = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 DEV0 = SingleDeviceSharding(TOPO.devices[0])
 
-F, K, MRF, HP = 40, 4, 8192, 2                  # bench_ffm_kernel geometry
+F, K, MRF, HP = 40, 4, 8192, 2          # flagship geometry (chip_smoke FULL)
 LAMS = (0.01, 0.01, 0.01)
 
 
@@ -136,7 +136,7 @@ def fm_minibatch_step():
     assert temp <= 3.01e9, f"{temp / 1e9:.2f} GB of temporaries"
 
 
-N, D, BINS = 1 << 20, 28, 64                    # bench_trees geometry
+N, D, BINS = 1 << 20, 28, 64            # HIGGS-shaped trees, cut to 1M rows
 
 
 def _hist(fn, n_nodes, fast):
