@@ -1,15 +1,21 @@
 """Probe: what the distinct-row tail of the packed FM step costs on the chip,
 against the dense tail — the readings behind the constants of `ops/fm.py`
-`tail_cap` (PERF.md section 6, PR 28).
+`tail_cap` (PERF.md section 6, PR 28; read again in PR 30 with the one row
+kernel).
 
 Times the WHOLE one-step program of `make_fm_step_minibatch` at the geometry
 of the benchmark's cell `fm_criteo.stream` (-dims 2^26 -factors 5: a packed
 table of 4,194,304 x 128 float32 and its AdaGrad state, B = 32768, L = 39,
 unit values elided), never a phase alone (PR 25: phases alone are not
 floors). A variant is a capacity (the shipped rule's, or a forced one), who
-copies the distinct rows (ops/rows_pallas.py's kernels, or XLA's gather and
-scatter) and a batch with a chosen number of distinct table rows; `dense` is
-the step with no ranking at all.
+updates the distinct rows (ops/rows_pallas.py's kernel, or the XLA gather,
+update and scatter that `update_rows` is off a TPU) and a batch with a
+chosen number of distinct table rows; `dense` is the step with no ranking
+at all. The `wide_*` variants force a capacity of a third of the slots: the
+slope of their times over the distinct rows is the kernel's cost a row past
+the shipped capacity; the `prior_cap_*` variants force PR 28's capacity: the
+shipped capacity's distance from them at the same batch is what a larger
+compact gradient costs.
 For each: milliseconds a step on the host's clock around 10 steps ended by
 `block_until_ready`, then the device operations of 4 traced steps by
 `hm.*` scope and by name (the benchmark's own trace reader).
@@ -89,17 +95,20 @@ def main() -> int:
     label = jnp.asarray(np.where(rng.random(B) < 0.25, 1.0, -1.0)
                         .astype(np.float32))
     mask = jnp.ones(B, jnp.float32)
-    # (name, forced capacity or None for the shipped rule, row kernels,
+    # (name, forced capacity or None for the shipped rule, row kernel,
     #  distinct rows). The cell's batches at Zipf 1.25 / 1.5 / 1.05 hold
     # 73.0k / 27.7k / 161.6k distinct rows of 1,277,952 slots (ISSUE 28).
     shipped_cap, kernels = fm.tail_cap, rows_pallas.use_kernels_default
+    wide = N // 3 // rows_pallas.LIST_MULTIPLE * rows_pallas.LIST_MULTIPLE
+    prior = 256 if TINY else 217_088  # PR 28's capacity, in whole blocks
     variants = [("dense", 0, True, N // 17)]
     variants += [(f"shipped_n/{d}", None, True, int(N / d))
-                 for d in (46, 17.5, 10.6, 7.9, 6)]
-    variants += [("shipped_n/4.3_falls_through", None, True, int(N / 4.3)),
-                 ("forced_cap_n/3_at_n/4.3", N // 3, True, int(N / 4.3)),
-                 ("xla_rows_cap_n/16_full", N // 16, False, N // 16),
-                 ("xla_rows_cap_n/16_half", N // 16, False, N // 32)]
+                 for d in (46, 17.5, 10.6, 7.9, 6, 4.6)]
+    variants += [("shipped_n/4.3_falls_through", None, True, int(N / 4.3))]
+    variants += [(f"prior_cap_n/{d}", prior, True, int(N / d))
+                 for d in (46, 17.5, 7.9)]
+    variants += [(f"wide_n/{d}", wide, True, int(N / d)) for d in (4.3, 3.3)]
+    variants += [("xla_rows_cap_n/16_half", N // 16, False, N // 32)]
     out = []
     for name, cap, use_kernels, nd in variants:
         fm.tail_cap = shipped_cap if cap is None else (lambda n, r, c=cap: c)

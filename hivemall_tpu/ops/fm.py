@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from .losses import Loss
 from .optimizers import Optimizer
-from .rows_pallas import LIST_MULTIPLE, put_rows, take_rows
+from .rows_pallas import BLOCK_ROWS, LIST_MULTIPLE, update_rows
 from .scan import scannable
 
 __all__ = ["fm_score", "ffm_score", "make_fm_step", "make_ffm_step",
@@ -611,7 +611,7 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
 # up to tail_cap distinct rows, the count at which the two tails cost the
 # same by the chip's readings below (TPU v5e, a [4194304, 128] float32
 # table, 1,277,952 slots; experiments/probe_distinct_tail.py, PERF.md
-# section 6, PR 28).
+# section 6, PRs 28 and 30).
 # ns per TABLE row of what only the dense tail runs: the dense AdaGrad pass
 # (15.9 ms) and what the scatter-add into a zero-filled table-sized G costs
 # over the one into a compact Gc (20.1 against 17.4 ms)
@@ -619,25 +619,37 @@ _DENSE_NS_PER_TABLE_ROW = 4.43
 # ns per SLOT of what only the distinct tail runs: the sort that compacts
 # the distinct row ids (1.73 ms) and the running sum (0.24 ms)
 _RANK_NS_PER_SLOT = 1.54
-# ns per DISTINCT row at capacity: two row reads (17.5 each), two row
-# writes (15 each), the update and the kernels' block traffic over the
-# capacity (7); 76 puts the break-even where the chip read it, 218k rows
-_DISTINCT_NS_PER_ROW = 76.0
+# ns per DISTINCT row at capacity, read again in PR 30 with the one row
+# kernel: four row DMAs at 15.5 ns each (the DMA engine's rate: the same
+# from an unroll of 4 up and on either DMA priority), the update hidden
+# under them: 62.2 by the slope of hm.update over 27.8k..387k distinct
+# rows, 59.4 for the whole step (the compact scatter-add gains 2.8 as rows
+# repeat less), and 2.9 for a capacity row of compact gradient (zero-
+# filled and scattered into: the cell's step.scatter_ms 20.92 at 218,496
+# rows, 21.11 at 282,624). 58.5 puts the break-even where the chip read
+# it, ~284k rows: through a capacity of 425,984 the step takes 51.26 ms at
+# 213.0k distinct rows and 56.33 at 297.2k, through the cond's dense
+# branch 55.22; through the capacity this gives, 282,624, 54.86 at 277.8k
+# rows against the dense branch's 54.98 (PR 28's four kernels: 76, 218k)
+_DISTINCT_NS_PER_ROW = 58.5
 
 TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows")
 
 
 def tail_cap(n: int, R: int) -> int:
     """Most distinct rows the distinct-row tail takes on for n slots into
-    a table of R rows, a multiple of the row kernels' list tile; 0 where
-    the dense tail is the cheaper one at any count (a table small against
-    the batch: the toy config's 4,096 rows against 9,984 slots), or where
-    R + n overflows the int32 ids that pad the distinct-row list."""
+    a table of R rows, in whole blocks of the row kernel's (whole id tiles
+    under one block); 0 where the dense tail is the cheaper one at any
+    count (a table small against the batch: the toy config's 4,096 rows
+    against 9,984 slots), or where R + n overflows the int32 ids that pad
+    the distinct-row list."""
     if R + n >= 2 ** 31:
         return 0
     even = (R * _DENSE_NS_PER_TABLE_ROW - n * _RANK_NS_PER_SLOT) \
         / _DISTINCT_NS_PER_ROW
-    return max(0, min(n, int(even))) // LIST_MULTIPLE * LIST_MULTIPLE
+    cap = max(0, min(n, int(even)))
+    return (cap // BLOCK_ROWS * BLOCK_ROWS
+            or cap // LIST_MULTIPLE * LIST_MULTIPLE)
 
 
 def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
@@ -661,10 +673,11 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
          and reads the updates through the permutation) less its sort.
          The gradient slab is never copied in another order.
       3. the flagged row ids, sorted to the front, are the distinct rows:
-         take T and the state at them, the optimizer's own update on
-         [cap, W] (same function, same t), put both back in place
-         (ops/rows_pallas.py: on a TPU kernels that cost by the count of
-         distinct rows, elsewhere XLA's gather and scatter).
+         T and every leaf of the state are read at them, given the
+         optimizer's own update (same function, same float32, same t) and
+         written back in place by ops/rows_pallas.py `update_rows`: on a
+         TPU ONE kernel that costs by the count of distinct rows and by
+         nothing else, elsewhere XLA's gather, update and scatter.
 
     ``cap`` (None: tail_cap of the shapes) is static; 0 is the dense tail
     alone, with no ranking. A batch with more distinct rows than ``cap``
@@ -701,15 +714,15 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
                 g[perm], mode="drop", indices_are_sorted=True)
             urows = jnp.sort(jnp.where(first, srows, R + slot))[:cap]
         with jax.named_scope("hm.update"):
-            def take(a):
-                return take_rows(a, urows, n_distinct)
+            leaves, tree = jax.tree_util.tree_flatten(state)
 
-            def put(a, u):
-                return put_rows(a, urows, n_distinct, u)
-            Tu, su = optimizer.update(
-                take(T).astype(jnp.float32), Gc,
-                jax.tree_util.tree_map(take, state), t)
-            return put(T, Tu), jax.tree_util.tree_map(put, state, su)
+            def update(blocks, g, t):
+                w, s = optimizer.update(blocks[0], g,
+                                        tree.unflatten(blocks[1:]), t)
+                return (w, *jax.tree_util.tree_leaves(s))
+            Tn, *sn = update_rows((T, *leaves), urows, n_distinct, Gc, t,
+                                  update)
+            return Tn, tree.unflatten(sn)
 
     fits = n_distinct <= cap
     Tn, sn = jax.lax.cond(

@@ -1,5 +1,5 @@
-"""Copy a list of table rows out of, and back into, a table in HBM: two
-Pallas TPU kernels, one DMA a row.
+"""Read, update and write back a list of rows of co-shaped tables in HBM:
+one Pallas TPU kernel, one DMA a row a table a direction.
 
 Why not XLA's own: its gather reads a 128-lane row in ~10 ns, but its
 scatter into a table-sized operand, `add` or `set`, with or without the
@@ -7,64 +7,76 @@ sorted / unique promises, costs ~70 ns a row on a v5e (5.6 ms for 79,872
 rows of a 2 GiB float32 table; with `indices_are_sorted` a pass over the
 whole table instead, 6.4 ms + 4 ns a row), and both cost the same for a
 list's out-of-range padding as for its rows (PERF.md section 6, PR 28).
-These kernels take the COUNT of live rows as a runtime scalar and issue no
-DMA past it, so a caller sizes its list for the worst batch and pays for
-the rows the batch holds.
+The kernel takes the COUNT of live rows as a runtime scalar and does no
+work past it, so a caller sizes its list for the worst batch and pays for
+the rows the batch holds: a block of the list past the count starts no
+DMA, fetches no gradient block (the index maps clamp to the last live
+block) and runs no update, and no list-sized array is written to HBM.
 
-`take_rows` DMAs row `rows[j]` of the table into row j of a block of the
-output; `put_rows` DMAs row j of a block of the values onto row `rows[j]`
-of the table, which is aliased to the output: rows the list does not name
-are never touched. The row ids of a block are staged in SMEM; a block
-starts all its copies, then waits for them, so TB copies are in flight.
-The live rows of a list must be distinct (two copies onto one row race).
-Both are jitted, so that a caller's tables of one shape (a table and its
-optimizer state) trace and lower ONE kernel each: a kernel's trace costs
-a quarter of a second of every process's set-up.
+`update_rows` walks the list in blocks of TB rows. A block's rows of every
+table are DMAed from HBM into a VMEM slot, `fn` maps the slot's blocks and
+the gradient block to new blocks, and those are DMAed back onto the same
+table rows; the tables are aliased to the outputs, so rows the list does
+not name are never touched. Each table has two slots: block i+1's copy-in
+is started before block i's is waited for, and block i's write-back is
+waited for only when block i+2 needs the slot (or the list ends). That is
+legal because the live rows of a list must be DISTINCT (two copies onto
+one row race): no block reads a row that another block writes. A full
+block is waited for with one descriptor of the block's whole extent (a DMA
+semaphore counts bytes), so the scalar core issues starts and no per-row
+waits; only the list's one partial block waits row by row. What is left is
+the DMA engine's rate, 15.5 ns a row copy on a v5e: 62 ns a distinct row of
+two tables, 0.25 ms for the kernel alone on a list with no live row
+(PERF.md section 6, PR 30).
 
-Off a TPU `take_rows` and `put_rows` ARE the XLA gather and scatter they
-replace (`use_kernels_default`: training that runs anywhere must not start
-to depend on the interpreter), which is why a list pads with out-of-range
-ids; `interpret=True` runs the kernels there, for the tests.
+Off a TPU `update_rows` IS the XLA gather, `fn` and scatter it replaces
+(`use_kernels_default`: training that runs anywhere must not start to
+depend on the interpreter), which is why a list pads with out-of-range
+ids; `interpret=True` runs the kernel there, for the tests.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["take_rows", "put_rows", "use_kernels_default", "LIST_MULTIPLE"]
+__all__ = ["update_rows", "use_kernels_default", "LIST_MULTIPLE",
+           "BLOCK_ROWS"]
 
 #: a row list's length must be a multiple of this (one SMEM tile of ids)
 LIST_MULTIPLE = 128
-_UNROLL = 8
+#: and is best a multiple of this: the rows of a block (1 MiB of VMEM a
+#: slot at 128 float32 lanes), which must divide the list
+BLOCK_ROWS = 2048
+_UNROLL = 4
+_IN, _OUT = 0, 1
 
 
 def use_kernels_default() -> bool:
-    """The kernels where they compile (a TPU); XLA's gather and scatter on
+    """The kernel where it compiles (a TPU); XLA's gather and scatter on
     any other backend."""
     return jax.default_backend() == "tpu"
 
 
-def _xla(interpret) -> bool:
-    return interpret is None and not use_kernels_default()
-
-
 def _block_rows(cap: int) -> int:
-    """Rows a grid step copies: the largest of 2048..128 dividing `cap`
-    (2048 rows of 128 float32 lanes are 1 MiB of VMEM a buffer)."""
+    """Rows a grid step updates: BLOCK_ROWS halved until it divides `cap`."""
     if cap % LIST_MULTIPLE:
         raise ValueError(f"a row list of {cap} ids is not a multiple of "
                          f"{LIST_MULTIPLE}")
-    return next(tb for tb in (2048, 1024, 512, 256, 128) if cap % tb == 0)
+    tb = BLOCK_ROWS
+    while cap % tb:
+        tb //= 2
+    return tb
 
 
 def _each(m, body):
     """body(j) for j in [0, m), m a runtime scalar: groups of _UNROLL
-    unrolled (the loop is scalar-issue bound), then the rest."""
+    unrolled, then the rest. A loop of two DMA starts a trip is bound by
+    the scalar core's loop (21 ns a DMA; unrolled by 2, 16.4); from 4 up by
+    the DMA engine's 15.5 ns a descriptor, on either priority (a v5e, PR
+    30), and every unrolled copy is traced in every process's set-up."""
     def group(q, c):
         for u in range(_UNROLL):
             body(q * _UNROLL + u)
@@ -77,81 +89,115 @@ def _each(m, body):
     jax.lax.fori_loop((m // _UNROLL) * _UNROLL, m, one, 0)
 
 
-def _call(kernel, rows, n, operands, in_specs, out_spec, out_shape, tb,
-          aliases, interpret):
-    cap = rows.shape[0]
-    ids = pl.BlockSpec((1, tb // 128, 128), lambda i, n: (i, 0, 0),
-                       memory_space=pltpu.SMEM)
-    return pl.pallas_call(
+def update_rows(tables, rows, n, g, t, fn, *, interpret=None):
+    """`tables` (a tuple of co-shaped [R, W] arrays of 32-bit words) with
+    rows ``rows[:n]`` of every table replaced, in place (donate them), by
+    ``fn(blocks, g_block, t)``: ``blocks`` the tables' rows at a block of
+    the list, ``g_block`` the same block of ``g`` [len(rows), W], ``t`` the
+    scalar ``t`` as a float32 [1, W] (data, not a constant of the
+    program). ``fn`` is elementwise over rows: it is also handed rows past
+    ``n``, holding anything, whose results are dropped. rows[:n] distinct
+    and in range, rows[n:] out of range; len(rows) at most R."""
+    tables = tuple(tables)
+    cap, (R, W) = rows.shape[0], tables[0].shape
+    tb = _block_rows(cap)
+    if any(a.shape != (R, W) or a.dtype.itemsize != 4 for a in tables):
+        raise ValueError("tables must be co-shaped arrays of 32-bit words")
+    if cap > R:
+        raise ValueError(f"a list of {cap} rows into a table of {R}")
+    t = jnp.full((1, W), t, jnp.float32)
+    if interpret is None and not use_kernels_default():
+        new = fn(tuple(a.at[rows].get(mode="clip") for a in tables), g, t)
+        return tuple(a.at[rows].set(u.astype(a.dtype), mode="drop")
+                     for a, u in zip(tables, new))
+    nt = len(tables)
+
+    def kernel(n_ref, ids_ref, next_ids_ref, t_ref, g_ref, *refs):
+        outs, bufs, sem = refs[nt:2 * nt], refs[2 * nt:3 * nt], refs[3 * nt]
+        i, n = pl.program_id(0), n_ref[0]
+        base = i * tb
+        slot = i % 2
+
+        def copies(way, s, r, j, count=1):
+            """Table rows [r, r + count) to (_OUT) or from (_IN) rows
+            [j, j + count) of slot s, a descriptor a table."""
+            for out, buf in zip(outs, bufs):
+                hbm, vmem = out.at[pl.ds(r, count)], buf.at[s, pl.ds(j, count)]
+                src, dst = (hbm, vmem) if way == _IN else (vmem, hbm)
+                yield pltpu.make_async_copy(src, dst, sem.at[way, s])
+
+        def start(way, ids, s, m):
+            def body(j):
+                for c in copies(way, s, ids[0, j >> 7, j & 127], j):
+                    c.start()
+            _each(m, body)
+
+        def wait_whole(way, s):
+            for c in copies(way, s, 0, 0, tb):
+                c.wait()
+
+        def wait(way, s, m):
+            pl.when(m == tb)(lambda: wait_whole(way, s))
+
+            @pl.when(m < tb)
+            def _():
+                def body(j):
+                    for c in copies(way, s, 0, j):
+                        c.wait()
+                _each(m, body)
+
+        @pl.when(base < n)
+        def _():
+            m = jnp.minimum(tb, n - base)
+            more = base + tb < n
+
+            @pl.when(i == 0)
+            def _():
+                start(_IN, ids_ref, slot, m)
+
+            @pl.when(i > 0)
+            def _():                    # block i-1 was full: block i lives
+                wait_whole(_OUT, 1 - slot)
+
+            @pl.when(more)
+            def _():
+                start(_IN, next_ids_ref, 1 - slot,
+                      jnp.minimum(tb, n - base - tb))
+            wait(_IN, slot, m)
+            new = fn(tuple(buf[slot] for buf in bufs), g_ref[...],
+                     t_ref[...])
+            for buf, u in zip(bufs, new):
+                buf[slot] = u.astype(buf.dtype)
+            start(_OUT, ids_ref, slot, m)
+
+            @pl.when(jnp.logical_not(more))
+            def _():
+                wait(_OUT, slot, m)
+
+    def live(i, n):                     # no block past the last live one
+        return jnp.minimum(i, jnp.maximum(n[0] - 1, 0) // tb)
+    ids = [pl.BlockSpec((1, tb // 128, 128),
+                        lambda i, n, k=k: (live(i + k, n), 0, 0),
+                        memory_space=pltpu.SMEM) for k in (0, 1)]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rows = rows.reshape(cap // tb, tb // 128, 128)
+    return tuple(pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(cap // tb,),
-            in_specs=[ids, *in_specs], out_specs=out_spec,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((1,))]),
-        out_shape=out_shape, input_output_aliases=aliases,
-        interpret=bool(interpret),
-    )(n.astype(jnp.int32).reshape(1),
-      rows.reshape(cap // tb, tb // 128, 128), *operands)
-
-
-@functools.partial(jax.jit, static_argnames="interpret")
-def take_rows(table, rows, n, *, interpret=None):
-    """[len(rows), W] holding table[rows[j]] in row j for j < n. Rows from
-    n on hold whatever the buffer held: never read them."""
-    if _xla(interpret):
-        return table.at[rows].get(mode="clip")
-    tb = _block_rows(rows.shape[0])
-
-    def kernel(n_ref, rows_ref, t_ref, o_ref, sem):
-        base = pl.program_id(0) * tb
-
-        @pl.when(base < n_ref[0])
-        def _():
-            m = jnp.minimum(tb, n_ref[0] - base)
-            _each(m, lambda j: pltpu.make_async_copy(
-                t_ref.at[pl.ds(rows_ref[0, j >> 7, j & 127], 1)],
-                o_ref.at[pl.ds(j, 1)], sem.at[0]).start())
-            _each(m, lambda j: pltpu.make_async_copy(
-                t_ref.at[pl.ds(0, 1)], o_ref.at[pl.ds(j, 1)],
-                sem.at[0]).wait())
-
-    w = table.shape[1]
-    return _call(
-        kernel, rows, n, (table,), [pl.BlockSpec(memory_space=pl.ANY)],
-        pl.BlockSpec((tb, w), lambda i, n: (i, 0), memory_space=pltpu.VMEM),
-        jax.ShapeDtypeStruct((rows.shape[0], w), table.dtype), tb, {},
-        interpret)
-
-
-@functools.partial(jax.jit, static_argnames="interpret")
-def put_rows(table, rows, n, values, *, interpret=None):
-    """`table` with values[j] on row rows[j] for j < n, in place (donate
-    the table); rows[:n] distinct and in range, rows[n:] out of range."""
-    if _xla(interpret):
-        return table.at[rows].set(values.astype(table.dtype), mode="drop")
-    tb = _block_rows(rows.shape[0])
-
-    def kernel(n_ref, rows_ref, v_ref, t_ref, o_ref, sem):
-        del t_ref                                    # aliased to o_ref
-        base = pl.program_id(0) * tb
-
-        @pl.when(base < n_ref[0])
-        def _():
-            m = jnp.minimum(tb, n_ref[0] - base)
-            _each(m, lambda j: pltpu.make_async_copy(
-                v_ref.at[pl.ds(j, 1)],
-                o_ref.at[pl.ds(rows_ref[0, j >> 7, j & 127], 1)],
-                sem.at[0]).start())
-            _each(m, lambda j: pltpu.make_async_copy(
-                v_ref.at[pl.ds(j, 1)], o_ref.at[pl.ds(0, 1)],
-                sem.at[0]).wait())
-
-    w = table.shape[1]
-    return _call(
-        kernel, rows, n, (values.astype(table.dtype), table),
-        [pl.BlockSpec((tb, w), lambda i, n: (i, 0),
-                      memory_space=pltpu.VMEM),
-         pl.BlockSpec(memory_space=pl.ANY)],
-        pl.BlockSpec(memory_space=pl.ANY),
-        jax.ShapeDtypeStruct(table.shape, table.dtype), tb, {3: 0},
-        interpret)
+            in_specs=[*ids,
+                      pl.BlockSpec((1, W), lambda i, n: (0, 0),
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((tb, W), lambda i, n: (live(i, n), 0),
+                                   memory_space=pltpu.VMEM),
+                      *[hbm] * nt],
+            out_specs=[hbm] * nt,
+            scratch_shapes=[*[pltpu.VMEM((2, tb, W), a.dtype)
+                              for a in tables],
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in tables],
+        input_output_aliases={5 + k: k for k in range(nt)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=bool(interpret), name="update_rows",
+    )(n.astype(jnp.int32).reshape(1), rows, rows, t, g, *tables))

@@ -174,8 +174,14 @@ def test_capacity_follows_the_shapes():
     assert caps == sorted(caps) and all(c % 128 == 0 for c in caps)
 
 
-def test_megastep_of_four_equals_four_single_steps():
-    """The K-step scan runs the step's own core, stats included."""
+@pytest.mark.parametrize("rows", ["xla", "kernel"])
+def test_megastep_of_four_equals_four_single_steps(rows, monkeypatch):
+    """The K-step scan runs the step's own core, stats included; with the
+    row kernel in it (interpreted), as a TPU's megastep has."""
+    if rows == "kernel":
+        from hivemall_tpu.ops import rows_pallas
+        monkeypatch.setattr(fm, "update_rows",
+                            partial(rows_pallas.update_rows, interpret=True))
     step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
     ks = 4
     nds = (6, CAP - 3, CAP + 5, CAP)
@@ -228,49 +234,94 @@ def test_bfloat16_table_keeps_the_dense_tail():
         np.asarray(out[1][0]["T"], np.float32))
 
 
-# -- the row kernels (interpret mode here; compiled for a v5e by
+# -- the row kernel (interpret mode here; compiled for a v5e by
 # tests/test_tpu_aot_compile.py) -----------------------------------------------
+
+def _optimizer_fn(opt, names):
+    """What rows_update hands update_rows: the optimizer's own update over
+    (w, *state leaves), the leaves in `names` order."""
+    def fn(blocks, g, t):
+        w, s = opt.update(blocks[0], g, dict(zip(names, blocks[1:])), t)
+        return (w, *(s[k] for k in names))
+    return fn
+
+
+def _assert_rows_updated(opt, names, cap, n_live, rows_total=8192):
+    """The kernel against XLA's gather -> optimizer.update -> scatter: the
+    list's live rows agree to float32 rounding, every other row of every
+    table is bit-equal to what went in."""
+    from hivemall_tpu.ops.rows_pallas import update_rows
+    rng = np.random.default_rng(cap + n_live)
+    tables = tuple(
+        jnp.asarray((0.1 + rng.random((rows_total, 128))).astype(np.float32))
+        for _ in range(1 + len(names)))
+    live = np.sort(rng.choice(rows_total, n_live, replace=False))
+    ids = jnp.asarray(np.concatenate(
+        [live, rows_total + np.arange(cap - n_live)]).astype(np.int32))
+    g = np.zeros((cap, 128), np.float32)
+    g[:n_live] = rng.normal(size=(n_live, 128))
+    fn = _optimizer_fn(opt, names)
+    got = jax.jit(lambda *a: update_rows(*a, fn, interpret=True))(
+        tables, ids, jnp.asarray(n_live, jnp.int32), jnp.asarray(g),
+        jnp.asarray(3.0, jnp.float32))
+    want = [np.asarray(a).copy() for a in tables]
+    new = fn(tuple(a[live] for a in want), g[:n_live], 3.0)
+    for a, u in zip(want, new):
+        a[live] = np.asarray(u)
+    assert len(got) == len(tables)
+    untouched = np.ones(rows_total, bool)
+    untouched[live] = False
+    for a, b, before in zip(got, want, tables):
+        a = np.asarray(a)
+        np.testing.assert_array_equal(a[untouched],
+                                      np.asarray(before)[untouched])
+        np.testing.assert_allclose(a[live], b[live], rtol=2e-6, atol=1e-7)
+        assert not n_live or not np.array_equal(a[live],
+                                                np.asarray(before)[live])
+
 
 @pytest.mark.parametrize("cap,n_live", [(128, 0), (128, 1), (128, 128),
                                         (384, 200), (2048, 2047),
                                         (4096, 2049)])
-def test_row_kernels_copy_the_live_rows_and_no_others(cap, n_live):
-    from hivemall_tpu.ops.rows_pallas import put_rows, take_rows
-    rng = np.random.default_rng(cap + n_live)
-    rows_total = 8192
-    table = jnp.asarray(rng.normal(size=(rows_total, 128)).astype(np.float32))
-    live = np.sort(rng.choice(rows_total, n_live, replace=False))
-    ids = np.concatenate([live, rows_total + np.arange(cap - n_live)]) \
-        .astype(np.int32)
-    n = jnp.asarray(n_live, jnp.int32)
-    got = jax.jit(partial(take_rows, interpret=True))(
-        table, jnp.asarray(ids), n)
-    np.testing.assert_array_equal(np.asarray(got)[:n_live],
-                                  np.asarray(table)[live])
-    vals = jnp.asarray(rng.normal(size=(cap, 128)).astype(np.float32))
-    out = jax.jit(partial(put_rows, interpret=True))(
-        table, jnp.asarray(ids), n, vals)
-    want = np.asarray(table).copy()
-    want[live] = np.asarray(vals)[:n_live]
-    np.testing.assert_array_equal(np.asarray(out), want)
+def test_row_kernel_updates_the_live_rows_and_no_others(cap, n_live):
+    """0 live, 1, a full list, a partial block after full ones (three
+    blocks of 128: both slots reused), 2047 of 2048, one row into a second
+    block."""
+    _assert_rows_updated(_opt(), ("gg",), cap, n_live)
 
 
-def test_row_list_must_fill_whole_id_tiles():
-    from hivemall_tpu.ops.rows_pallas import take_rows
-    with pytest.raises(ValueError, match="multiple of 128"):
-        take_rows(jnp.zeros((256, 128)), jnp.zeros((100,), jnp.int32),
-                  jnp.asarray(3), interpret=True)
+@pytest.mark.parametrize("name,reg,names", [
+    ("sgd", "no", ()),                               # a state with no leaf
+    ("adagrad", "rda", ("gg", "u")),                 # and one with two
+])
+def test_row_kernel_is_generic_over_the_state_leaves(name, reg, names):
+    opt = make_optimizer(name, eta_scheme="inverse", eta0=0.1, reg=reg)
+    assert sorted(opt.init((1,))) == sorted(names)
+    _assert_rows_updated(opt, names, 384, 300)
 
 
-def test_step_with_the_row_kernels_matches_the_xla_rows(monkeypatch):
-    """What a TPU runs: take and put by the kernels, the rest unchanged."""
+@pytest.mark.parametrize("case,rows,dtype,n_ids,match", [
+    ("list_not_whole_id_tiles", 256, jnp.float32, 100, "multiple of 128"),
+    ("list_longer_than_the_table", 128, jnp.float32, 256, "into a table"),
+    ("rows_of_half_words", 256, jnp.bfloat16, 128, "32-bit"),
+])
+def test_row_kernel_refuses(case, rows, dtype, n_ids, match):
+    from hivemall_tpu.ops.rows_pallas import update_rows
+    with pytest.raises(ValueError, match=match):
+        update_rows((jnp.zeros((rows, 128), dtype),),
+                    jnp.zeros((n_ids,), jnp.int32), jnp.asarray(3),
+                    jnp.zeros((n_ids, 128)), 0.0, lambda b, g, t: b,
+                    interpret=True)
+
+
+def test_step_with_the_row_kernel_matches_the_xla_rows(monkeypatch):
+    """What a TPU runs: the distinct rows through the kernel, the rest
+    unchanged."""
     idx = _ids(CAP - 7)
     ref, _ = _pair(idx)
     from hivemall_tpu.ops import rows_pallas
-    monkeypatch.setattr(fm, "take_rows",
-                        partial(rows_pallas.take_rows, interpret=True))
-    monkeypatch.setattr(fm, "put_rows",
-                        partial(rows_pallas.put_rows, interpret=True))
+    monkeypatch.setattr(fm, "update_rows",
+                        partial(rows_pallas.update_rows, interpret=True))
     step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
     params, state = _state()
     new = step(params, state, 3.0, jnp.asarray(idx), None, _label(),

@@ -45,8 +45,9 @@ def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
     asserts the compiled text holds no `while(` (PR 24's parent had two,
     one per direction of a reshape nobody saw; ~25 s of XLA compile) and,
     since PR 28, one `conditional(` between the distinct-row tail and the
-    dense one, the four row kernels of ops/rows_pallas.py compiled by
-    Mosaic, no copy of a whole table, and temporaries under 3.01 GB."""
+    dense one, the ONE row kernel of ops/rows_pallas.py (PR 30; four
+    before) compiled by Mosaic, no copy of a whole table, and temporaries
+    under 2.85 GB."""
     _compile("fm_minibatch_step", timeout=600)
 
 
