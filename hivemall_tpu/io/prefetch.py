@@ -42,19 +42,24 @@ __all__ = ["DevicePrefetcher", "MegabatchStager", "stage_batch"]
 _STOP = object()
 
 
-def stage_batch(b, device=None):
+def stage_batch(b, sharding=None, span: str = "h2d.stage"):
     """Traced wrapper (``h2d.stage`` span) over :func:`_stage_batch` —
-    the transfer is the seam the obs rollup attributes h2d time with."""
-    with get_tracer().span("h2d.stage", getattr(b, "seq", None)):
-        return _stage_batch(b, device)
+    the transfer is the seam the obs rollup attributes h2d time with.
+    A trainer that places input from the thread that dispatches names
+    that span ``h2d.shard``."""
+    with get_tracer().span(span, getattr(b, "seq", None)):
+        return _stage_batch(b, sharding)
 
 
-def _stage_batch(b, device=None):
-    """device_put every array of one batch. ``val=None`` (unit-value
-    elision, see SparseBatch) and ``field=None`` are preserved — skipping
-    the val transfer is the point: it is a third of the batch bytes, and
-    the jitted unit-val step variants rebuild val from idx on device for
-    free.
+def _stage_batch(b, sharding=None):
+    """device_put every array of one batch: on the default device, or,
+    given a trainer's ``sharding(ndim, row_axis)`` (a -mesh trainer's
+    ``_input_sharding``), in the sharding it names for an array whose
+    batch rows lie along ``row_axis`` (None: no rows, replicated).
+    ``val=None`` (unit-value elision, see SparseBatch) and ``field=None``
+    are preserved — skipping the val transfer is the point: it is a third
+    of the batch bytes, and the jitted unit-val step variants rebuild val
+    from idx on device for free.
     A PackedBatch stages its single uint8 buffer — ONE transfer.
 
     Megabatches (MegaBatch / PackedMegaBatch — K stacked steps, ONE
@@ -65,8 +70,10 @@ def _stage_batch(b, device=None):
     and the next stack run on the same prefetcher worker thread, so
     blocking here is exactly that barrier). Transfer/compute overlap is
     untouched — the consumer thread keeps running the train step."""
-    put = (lambda a: jax.device_put(a, device)) if device is not None \
-        else jax.device_put
+    def put(a, row_axis=None):
+        if sharding is None:
+            return jax.device_put(a)
+        return jax.device_put(a, sharding(np.ndim(a), row_axis))
     if isinstance(b, PackedBatch):
         return PackedBatch(put(b.buf), b.B, b.L, b.n_valid, seq=b.seq)
     if isinstance(b, PackedMegaBatch):
@@ -75,20 +82,20 @@ def _stage_batch(b, device=None):
         jax.block_until_ready((staged.buf, staged.nv_dev))
         return staged
     if isinstance(b, MegaBatch):
-        staged = MegaBatch(put(b.idx),
-                           None if b.val is None else put(b.val),
-                           put(b.label),
-                           None if b.field is None else put(b.field),
+        staged = MegaBatch(put(b.idx, 1),
+                           None if b.val is None else put(b.val, 1),
+                           put(b.label, 1),
+                           None if b.field is None else put(b.field, 1),
                            nv=b.nv, nv_dev=put(b.nv),
                            fieldmajor=b.fieldmajor, seq=b.seq)
         jax.block_until_ready(
             [a for a in (staged.idx, staged.val, staged.label,
                          staged.field, staged.nv_dev) if a is not None])
         return staged
-    return SparseBatch(put(b.idx),
-                       None if b.val is None else put(b.val),
-                       put(b.label),
-                       None if b.field is None else put(b.field),
+    return SparseBatch(put(b.idx, 0),
+                       None if b.val is None else put(b.val, 0),
+                       put(b.label, 0),
+                       None if b.field is None else put(b.field, 0),
                        b.n_valid, fieldmajor=b.fieldmajor, seq=b.seq)
 
 
@@ -103,10 +110,11 @@ class DevicePrefetcher:
     ``stats`` (optional PipelineStats) records the h2d stage: batches
     staged, summed device_put seconds, and the consumer's blocked-on-get
     wait — the three numbers that say whether the wall is transfer-bound.
+    ``sharding`` is a -mesh trainer's placement (see :func:`_stage_batch`).
     """
 
     def __init__(self, src: Iterable[SparseBatch], depth: int = 2,
-                 device=None, stats=None):
+                 sharding=None, stats=None):
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._errbox: list = []         # worker's exception, surfaced on next()
         self._closed = threading.Event()
@@ -122,7 +130,7 @@ class DevicePrefetcher:
             try:
                 for b in src:
                     t0 = time.perf_counter()
-                    staged = stage_batch(b, device)
+                    staged = stage_batch(b, sharding)
                     if stats is not None:
                         stats.add(stage_seconds=time.perf_counter() - t0,
                                   batches_staged=1)
@@ -216,7 +224,7 @@ class MegabatchStager:
     ``reuse=True`` is only valid when a DevicePrefetcher consumes this
     stager on its worker thread (its ``stage_batch`` provides the
     transfer-complete barrier the ring depends on); callers feeding
-    megabatches straight into the train loop (mesh path, prefetch off)
+    megabatches straight into the train loop (prefetch off)
     must leave it False — there device_put/dispatch is async and a
     reused buffer could be rewritten mid-transfer."""
 

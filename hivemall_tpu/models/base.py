@@ -15,6 +15,7 @@ with reshuffling, the NioStatefulSegment analog.
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
@@ -201,6 +202,26 @@ def _identity_prep(batch):
     return batch
 
 
+def _default_platform() -> str:
+    """Platform of the device a new array lands on: ``jax.default_device``'s
+    where one is set (the benchmark builds one trainer on the host's CPU
+    beside a TPU), else the default backend's."""
+    import jax
+    dev = jax.config.jax_default_device
+    return jax.default_backend() if dev is None \
+        else getattr(dev, "platform", dev)
+
+
+@functools.lru_cache(maxsize=256)
+def _state_initialiser(init, sharding_leaves: tuple = (), treedef=None):
+    """jit of a state initialiser, cached on the function and on where its
+    outputs go (the flattened out_shardings; none: the default device), so
+    trainers of one configuration share one compile."""
+    import jax
+    return jax.jit(init, out_shardings=None if treedef is None else
+                   jax.tree_util.tree_unflatten(treedef, sharding_leaves))
+
+
 _STEP_BUILDER_CACHE: dict = {}
 
 
@@ -278,8 +299,13 @@ class LearnerBase:
         self._ck_manager = None               # fit_stream's autosaver (obs)
         self._fit_ds = None                   # columnar dataset ref (fit)
         self.mesh = None                      # jax Mesh when -mesh is set
+        self._state_on_mesh = False           # _make_state placed the state
         self._tp_sizes = {self.dims}          # axis sizes sharded over 'tp'
         self._elision_off = False             # set on first non-unit batch
+        if self.opts.get("mesh"):
+            # the mesh comes first: _init_state's leaves are then born in
+            # their sharding (_make_state) and no chip holds a whole table
+            self._open_mesh(self.opts.mesh)
         self._init_state()
         if self.opts.get("mix"):
             # covariance trainers (CW/AROW/SCW) mix by argmin-KLD —
@@ -317,6 +343,7 @@ class LearnerBase:
             self._warm_start(self.opts.loadmodel)
         if self.opts.get("mesh"):
             self._apply_mesh(self.opts.mesh)
+        self._state_bytes_per_chip = self._fullest_chip_bytes()
         self._telemetry_every = int(self.opts.get("telemetry_every") or 0)
         self._register_obs()
 
@@ -375,10 +402,13 @@ class LearnerBase:
             t = ref()
             if t is None:
                 return {}
+            shape = {"dp": 1, "tp": 1} if t.mesh is None else t.mesh.shape
             return {"trainer": t.NAME, "step": t._t,
                     "examples": t._examples,
                     "examples_per_sec": round(t._meter.rate, 1),
                     "avg_loss": round(t._loss_sum / max(1, t._examples), 6),
+                    "mesh_dp": int(shape["dp"]), "mesh_tp": int(shape["tp"]),
+                    "state_bytes_per_chip": t._state_bytes_per_chip,
                     **t._step_counts}
 
         def mix() -> dict:
@@ -580,14 +610,11 @@ class LearnerBase:
     def _fit_epochs(self, ds, epochs, bs, shuffle, prefetch, ckdir,
                     seed0: int = 42) -> None:
         # overlap host batch prep + h2d with compute on accelerators
-        # (the prefetcher places on the default device; under -mesh the
-        # dispatch path does its own sharded placement instead).
         # seed0: first epoch's shuffle seed — continuation callers (the
         # FFM replay cache's fallback) pass 42 + epochs_already_run so the
         # schedule matches an uninterrupted fit
         if prefetch is None:
-            import jax
-            prefetch = jax.default_backend() != "cpu" and self.mesh is None
+            prefetch = self._wants_prefetch()
         for ep in range(epochs):
             closers: List = []
             it = self._ingest_iter(
@@ -748,12 +775,23 @@ class LearnerBase:
         closers.append(pipe.close)
         return pipe
 
+    @staticmethod
+    def _wants_prefetch() -> bool:
+        """Whether fit / fit_stream stage their input from the
+        ``h2d-prefetch`` thread: on every accelerator, with or without a
+        mesh; not on the CPU, where a step owns the cores."""
+        import jax
+        return jax.default_backend() != "cpu"
+
     def _wrap_prefetch(self, it, closers: List, depth: int = 2):
-        """Stage ``it`` onto the device ahead of compute, sharing this
+        """Stage ``it`` onto the device — under -mesh, onto the mesh in
+        ``_input_sharding``'s placement — ahead of compute, sharing this
         trainer's PipelineStats so prep/transfer/compute waits land in one
         struct (the registry's ``pipeline`` section)."""
         from ..io.prefetch import DevicePrefetcher
-        pf = DevicePrefetcher(it, depth=depth, stats=self.pipeline_stats)
+        pf = DevicePrefetcher(
+            it, depth=depth, stats=self.pipeline_stats,
+            sharding=None if self.mesh is None else self._input_sharding)
         closers.append(pf.close)
         return pf
 
@@ -796,9 +834,19 @@ class LearnerBase:
             return it
         from ..io.prefetch import MegabatchStager
         return MegabatchStager(it, k, stats=self.pipeline_stats,
-                               reuse=prefetch and self.mesh is None)
+                               reuse=prefetch)
 
     # -- mesh sharding (SURVEY.md §3.17 / §8 M3) -----------------------------
+    def _open_mesh(self, spec: str) -> None:
+        """Build the (dp, tp) device mesh of a ``-mesh`` spec."""
+        from ..parallel.mesh import make_mesh, parse_mesh_spec
+        dp, tp = parse_mesh_spec(spec)
+        if int(self.opts.mini_batch) % dp:
+            raise ValueError(
+                f"-mini_batch {self.opts.mini_batch} must be divisible by "
+                f"the dp axis ({dp})")
+        self.mesh = make_mesh(dp=dp, tp=tp)
+
     def _apply_mesh(self, spec: str) -> None:
         """Shard this trainer's state over a (dp, tp) device mesh.
 
@@ -807,17 +855,66 @@ class LearnerBase:
         batch arrays sharded over 'dp' (XLA inserts the gradient psum that
         replaces MixServer averaging), every dims-sized state axis sharded
         over 'tp' (feature-dim sharding, the context-parallel analog), the
-        rest replicated. fit()/process() are unchanged."""
+        rest replicated. fit()/process() are unchanged.
+
+        State that ``_make_state`` built is on the mesh already; whatever
+        was built whole on one device, or loaded over it (-loadmodel), is
+        moved there."""
+        if self.mesh is None:
+            self._open_mesh(spec)
+        if not self._state_on_mesh or self.opts.loadmodel:
+            self._reshard_state()
+
+    def _make_state(self, init, *args):
+        """``init(*args)``, a pytree of state leaves, made where the leaves
+        live. Under -mesh: ONE jitted program whose outputs are born in
+        ``_state_sharding``'s sharding, each chip drawing and zeroing its
+        own rows (the random bits are partitionable: a shard's numbers are
+        the whole draw's, to the float32 ulp by which a fused draw rounds
+        apart from an eager one), so no chip ever holds more than its part
+        of a dims-sized leaf. Without a mesh the same program on the
+        default device — except where that device is a CPU: there its
+        operations are dispatched one by one, un-awaited, as they always
+        were (what the benchmark's one host-built trainer costs is part of
+        an accepted cell's ``setup_s``, and XLA's CPU backend is no faster
+        for the one program). Give it a function that is the same object
+        for the same configuration: the compile is cached on it."""
         import jax
-        import jax.numpy as jnp
-        from ..parallel.mesh import make_mesh, parse_mesh_spec
-        dp, tp = parse_mesh_spec(spec)
-        if int(self.opts.mini_batch) % dp:
-            raise ValueError(
-                f"-mini_batch {self.opts.mini_batch} must be divisible by "
-                f"the dp axis ({dp})")
-        self.mesh = make_mesh(dp=dp, tp=tp)
-        self._reshard_state()
+        span_args = None
+        if self.mesh is not None or self._tracer.enabled:
+            shapes = jax.eval_shape(init, *args)
+        if self._tracer.enabled:
+            span_args = {"bytes": sum(
+                l.size * l.dtype.itemsize
+                for l in jax.tree_util.tree_leaves(shapes))}
+        with self._tracer.span("init.state", None, None, span_args):
+            if self.mesh is None and _default_platform() == "cpu":
+                return init(*args)
+            if self.mesh is None:
+                make = _state_initialiser(init)
+            else:
+                leaves, treedef = jax.tree_util.tree_flatten(
+                    jax.tree_util.tree_map(self._state_sharding, shapes))
+                make = _state_initialiser(init, tuple(leaves), treedef)
+                self._state_on_mesh = True
+            return jax.block_until_ready(make(*args))
+
+    def _fullest_chip_bytes(self) -> int:
+        """Bytes of training state on the chip that holds most of it, from
+        the leaves' addressable shards as the constructor left them (the
+        registry's ``train.state_bytes_per_chip``; shapes and shardings do
+        not change afterwards). 0 for a trainer without array state."""
+        import jax
+        try:
+            leaves = jax.tree_util.tree_leaves(self._checkpoint_arrays())
+        except NotImplementedError:
+            return 0
+        per_chip: Dict[Any, int] = {}
+        for leaf in leaves:
+            for shard in getattr(leaf, "addressable_shards", ()):
+                per_chip[shard.device] = per_chip.get(shard.device, 0) \
+                    + shard.data.nbytes
+        return max(per_chip.values(), default=0)
 
     def _state_sharding(self, leaf):
         """NamedSharding for one state leaf: the first axis whose size is a
@@ -842,24 +939,23 @@ class LearnerBase:
             tree)
         self._restore_arrays(tree)
 
-    def _shard_batch(self, batch: SparseBatch) -> SparseBatch:
-        """Place one padded batch on the mesh: rows sharded over 'dp'.
-        val=None (unit-value elision) skips that transfer; the jitted
-        unit-val step rebuilds val from idx under the same sharding."""
-        import jax
-        import jax.numpy as jnp
+    def _input_sharding(self, ndim: int, row_axis: Optional[int]):
+        """NamedSharding of one input array on the mesh: the batch's rows
+        (``row_axis``: 0 of a batch's arrays, 1 of a stacked window's,
+        whose axis 0 is the scan axis) sharded over 'dp', every other axis
+        and an array without rows (nv) replicated. The scan body then
+        compiles under GSPMD exactly like the K=1 step."""
         from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P(*[
+            "dp" if ax == row_axis else None for ax in range(ndim)]))
 
-        def put(a, spec):
-            return jax.device_put(jnp.asarray(a), NamedSharding(self.mesh,
-                                                                spec))
-        return SparseBatch(
-            put(batch.idx, P("dp", None)),
-            None if batch.val is None else put(batch.val, P("dp", None)),
-            put(batch.label, P("dp")),
-            None if batch.field is None else put(batch.field, P("dp", None)),
-            n_valid=batch.n_valid, fieldmajor=batch.fieldmajor,
-            seq=batch.seq)
+    def _shard_batch(self, batch):
+        """Place one host batch or stacked window on the mesh. Where the
+        prefetcher staged it this is not called: what remains is input
+        dispatched from its own thread (process(), the CPU, a scorer),
+        under its own span ``h2d.shard``."""
+        from ..io.prefetch import stage_batch
+        return stage_batch(batch, self._input_sharding, "h2d.shard")
 
     def _inputs(self, it) -> Iterator:
         """Iterate a staged input stream with every wait for the next item
@@ -950,7 +1046,7 @@ class LearnerBase:
         closers: List = []
         it: Iterable[SparseBatch] = self._ingest_iter(
             self._source_side(batches, convert_labels), closers)
-        prefetch = jax.default_backend() != "cpu" and self.mesh is None
+        prefetch = self._wants_prefetch()
         it = self._wrap_megabatch(it, prefetch=prefetch)
         if prefetch:
             it = self._wrap_prefetch(it, closers)
@@ -1114,7 +1210,7 @@ class LearnerBase:
         if isinstance(batch, (MegaBatch, PackedMegaBatch)):
             return self._dispatch_mega(batch)
         nv = batch.n_valid or batch.batch_size
-        if self.mesh is not None:
+        if self.mesh is not None and isinstance(batch.idx, np.ndarray):
             batch = self._shard_batch(batch)
         # the span is the HOST-side dispatch boundary: synchronous compute
         # on CPU, dispatch latency on accelerators (async tails land in
@@ -1147,8 +1243,8 @@ class LearnerBase:
         step ever blocks the host."""
         K = mb.n_steps
         nv_total = mb.n_examples
-        if self.mesh is not None:
-            mb = self._shard_megabatch(mb)
+        if self.mesh is not None and isinstance(mb.idx, np.ndarray):
+            mb = self._shard_batch(mb)
         t0 = time.perf_counter()
         with self._tracer.span("dispatch.megastep", mb.seq):
             losses = self._train_megabatch(mb)      # [K] device array
@@ -1205,28 +1301,6 @@ class LearnerBase:
         self._set_megastep_state(s1, s2)
         self._stats_pending += stats
         return losses
-
-    def _shard_megabatch(self, mb):
-        """Mesh placement for one stacked window: per-step batch rows
-        sharded over 'dp' (axis 1 — axis 0 is the scan axis), nv
-        replicated. The scan body then compiles under GSPMD exactly like
-        the K=1 step (same per-step shardings)."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ..io.sparse import MegaBatch
-
-        def put(a, spec):
-            return jax.device_put(jnp.asarray(a),
-                                  NamedSharding(self.mesh, spec))
-        return MegaBatch(
-            put(mb.idx, P(None, "dp", None)),
-            None if mb.val is None else put(mb.val, P(None, "dp", None)),
-            put(mb.label, P(None, "dp")),
-            None if mb.field is None else put(mb.field,
-                                              P(None, "dp", None)),
-            nv=mb.nv, nv_dev=put(mb.nv, P()), fieldmajor=mb.fieldmajor,
-            seq=mb.seq)
 
     def _fold_loss(self) -> None:
         # the one place the train loop blocks on the device; the step's

@@ -210,6 +210,28 @@ def _packed_wrap_cached(base_step, B: int, L: int):
 
     return fn
 
+
+@_lru_cache(maxsize=256)
+def _fused_state_init(optimizer, rows: int, pack: int, k: int, width: int,
+                      dtype):
+    """Initialiser of a fused table's whole state, for
+    ``LearnerBase._make_state``: ``init(key, sigma) -> (params,
+    opt_state)`` with ``T`` of ``[rows, pack * width]`` — ``pack`` features
+    a row, each ``normal * sigma`` in its first ``k`` lanes and zeros in
+    the rest — ``w0``, and the optimizer's state for both. One function
+    object per configuration, so that its compile is shared."""
+    def init(key, sigma):
+        T = jnp.concatenate([
+            jax.random.normal(key, (rows * pack, k)) * sigma,
+            jnp.zeros((rows * pack, width - k)),
+        ], axis=1).astype(dtype).reshape(rows, pack * width)
+        params = {"w0": jnp.zeros((), dtype), "T": T}
+        opt_state = {"w0": optimizer.init(()),
+                     "T": optimizer.init((rows, pack * width))}
+        return params, opt_state
+    return init
+
+
 def _factor_spec(name: str, default_factors: int, default_opt: str
                  ) -> OptionSpec:
     s = learner_option_spec(name, classification=True,
@@ -302,15 +324,10 @@ class FMTrainer(LearnerBase):
             # instead of two tables' worth of narrow-row chains
             self.W, self.P = fm_pack_geometry(self.k)
             self.Np = -(-self.dims // self.P)
-            Tinit = jnp.concatenate([
-                jax.random.normal(key, (self.Np * self.P, self.k)) *
-                float(o.sigma),
-                jnp.zeros((self.Np * self.P, self.W - self.k)),
-            ], axis=1).astype(dtype).reshape(self.Np, self.P * self.W)
-            self.params = {"w0": jnp.zeros((), dtype), "T": Tinit}
-            self.opt_state = {
-                "w0": self.optimizer.init(()),
-                "T": self.optimizer.init((self.Np, self.P * self.W))}
+            self._tp_sizes.add(self.Np)    # mesh: shard packed rows over tp
+            self.params, self.opt_state = self._make_state(
+                _fused_state_init(self.optimizer, self.Np, self.P, self.k,
+                                  self.W, dtype), key, float(o.sigma))
             self._adareg = bool(getattr(o, "adareg", False))
             self._va_ratio = float(getattr(o, "va_ratio", 0.05))
             if self._adareg:
@@ -347,7 +364,6 @@ class FMTrainer(LearnerBase):
                 self._step = _fm_step_fused_cached(
                     self._loss_name, *self._opt_key, lam_key, self.k)
             self._fused_score = _fm_score_fused_cached(self.k)
-            self._tp_sizes.add(self.Np)    # mesh: shard packed rows over tp
             self.UNIT_VAL_ELISION = True   # fused step accepts val=None
         else:
             if bool(getattr(o, "adareg", False)):
@@ -826,13 +842,10 @@ class FFMTrainer(FMTrainer):
             self.Mr = max(1 << 10, self.dims // f_pow2)
             FK = self.F * self.k
             self.W = FK + 8            # [V(F*K) | w | pad] fused row
-            Tinit = jnp.concatenate([
-                jax.random.normal(key, (self.Mr, FK)) * float(o.sigma),
-                jnp.zeros((self.Mr, self.W - FK)),
-            ], axis=1).astype(dtype)
-            self.params = {"w0": jnp.zeros((), dtype), "T": Tinit}
-            self.opt_state = {"w0": self.optimizer.init(()),
-                              "T": self.optimizer.init((self.Mr, self.W))}
+            self._tp_sizes.add(self.Mr)     # mesh: shard T rows over tp
+            self.params, self.opt_state = self._make_state(
+                _fused_state_init(self.optimizer, self.Mr, 1, FK, self.W,
+                                  dtype), key, float(o.sigma))
             opt_key = self._opt_key
             lamt = (o.lambda0, o.lambda_w, o.lambda_v)
             self._step = _ffm_step_fused_cached(
@@ -849,7 +862,6 @@ class FFMTrainer(FMTrainer):
             self._fused_score = _ffm_score_fused_cached(self.F, self.k)
             self._fused_score_fm = _ffm_score_fieldmajor_cached(self.F,
                                                                 self.k)
-            self._tp_sizes.add(self.Mr)     # mesh: shard T rows over tp
         else:
             self.params = {
                 "w0": jnp.zeros((), dtype),

@@ -7,6 +7,8 @@ an 8-device virtual CPU mesh, no TPU pod needed. Must run before jax imports.
 
 import os
 
+import pytest
+
 # CPU by name + 8 virtual devices, set before jax is imported: the suite
 # checks arithmetic and control flow on a virtual mesh, and naming the CPU
 # is what lets Pallas kernels run in interpret mode (utils/device.py); the
@@ -23,6 +25,24 @@ def pytest_configure(config):
         "markers",
         "slow: long soak variants (fault-injection soaks etc.); excluded "
         "from the tier-1 `-m 'not slow'` run")
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, empty and on for one test."""
+    from hivemall_tpu.obs.trace import get_tracer
+    t = get_tracer()
+    t.reset()
+    t.enable()
+    yield t
+    t.disable()
+    t.reset()
+
+
+def tracer_spans(tracer, name=None):
+    """The tracer's completed spans as Chrome events, all or one name's."""
+    evs = [e for e in tracer.chrome_dict()["traceEvents"] if e["ph"] == "X"]
+    return [e for e in evs if name is None or e["name"] == name]
 
 
 def assert_batches_equal(a, b):

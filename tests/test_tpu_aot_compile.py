@@ -51,6 +51,14 @@ def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
     _compile("fm_minibatch_step", timeout=600)
 
 
+def test_state_initialiser_compiles_for_v5e_within_a_chip():
+    """The fused tables' jitted initialiser at the size no one chip holds
+    (PR 31): `train_ffm -dims 2^30 -halffloat` over tp=4, every output
+    born in its row sharding, a quarter of the 16.5 GB a chip, no
+    collective."""
+    _compile("state_init", timeout=600)
+
+
 @pytest.mark.slow
 def test_whole_sharded_step_and_sorted_histogram_compile_for_v5e():
     """The whole make_parts_step_sharded program (~85 s of XLA compile)

@@ -17,6 +17,7 @@ import tracemalloc
 import jax
 import numpy as np
 import pytest
+from conftest import tracer_spans as _spans
 
 from hivemall_tpu.io.arrow import (ParquetStream, _concat_datasets,
                                    _take_rows, write_parquet_shards)
@@ -26,20 +27,6 @@ from hivemall_tpu.models.fm import FFMTrainer, FMTrainer
 from hivemall_tpu.models.linear import GeneralClassifier
 from hivemall_tpu.obs.trace import _NULL_SPAN, Tracer, get_tracer
 
-
-@pytest.fixture
-def tracer():
-    t = get_tracer()
-    t.reset()
-    t.enable()
-    yield t
-    t.disable()
-    t.reset()
-
-
-def _spans(tracer, name=None):
-    evs = [e for e in tracer.chrome_dict()["traceEvents"] if e["ph"] == "X"]
-    return [e for e in evs if name is None or e["name"] == name]
 
 
 def _ds(n=2200, L=8, dims=1 << 12, seed=0):

@@ -138,6 +138,39 @@ def fm_minibatch_step():
     assert temp <= 2.85e9, f"{temp / 1e9:.2f} GB of temporaries"
 
 
+def state_init():
+    """The fused tables' initialiser (models/fm.py `_fused_state_init`,
+    the function `LearnerBase._make_state` jits) at the size no one chip
+    holds: `train_ffm -dims 2^30 -halffloat` over tp=4, each output in its
+    row sharding. A chip holds its quarter of the table and of the AdaGrad
+    state (4.13 GB; 4.23 as the compiler lays it out) and draws it
+    locally, with no collective in the program and the whole of it inside
+    the chip."""
+    from hivemall_tpu.models.fm import _fused_state_init
+    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                         reg="no")
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    mesh = Mesh(np.asarray(TOPO.devices).reshape(1, 4), ("dp", "tp"))
+    rows, everywhere = NamedSharding(mesh, P("tp", None)), \
+        NamedSharding(mesh, P())
+    Mr, FK = 1 << 24, 39 * 4
+    init = _fused_state_init(opt, Mr, 1, FK, FK + 8, jnp.bfloat16)
+    out = ({"w0": everywhere, "T": rows},
+           {"w0": {"gg": everywhere}, "T": {"gg": rows}})
+    compiled = jax.jit(init, out_shardings=out).lower(
+        _sds(key.shape, key.dtype, everywhere),
+        _sds((), jnp.float32, everywhere)).compile()
+    text = compiled.as_text()
+    for kind in ("all-reduce", "all-gather", "collective-permute",
+                 "all-to-all", "reduce-scatter"):
+        assert f" {kind}(" not in text, f"{kind} in the sharded initialiser"
+    mem = compiled.memory_analysis()
+    quarter = Mr // 4 * (FK + 8) * (2 + 4)
+    assert abs(mem.output_size_in_bytes - quarter) < 0.05 * quarter, \
+        f"{mem.output_size_in_bytes / 1e9:.2f} GB of outputs a chip"
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
 N, D, BINS = 1 << 20, 28, 64            # HIGGS-shaped trees, cut to 1M rows
 
 
@@ -168,7 +201,8 @@ def hist_sorted():
 
 CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
                                  parts_step_sharded, fm_minibatch_step,
-                                 hist_flat, hist_dense, hist_sorted)}
+                                 hist_flat, hist_dense, hist_sorted,
+                                 state_init)}
 
 if __name__ == "__main__":
     for name in sys.argv[1:]:
