@@ -1,29 +1,39 @@
-"""Probe: what the distinct-row tail of the packed FM step costs on the chip,
-against the dense tail — the readings behind the constants of `ops/fm.py`
-`tail_cap` (PERF.md section 6, PR 28; read again in PR 30 with the one row
-kernel).
+"""Probe: what the distinct-row tail of a minibatch step costs on the chip,
+against the dense tail — the readings behind `ops/fm.py` `_TAIL_COSTS`, one
+entry a geometry (PERF.md section 6: PR 28, read again in PR 30 with the one
+row kernel; the flagship's geometry in PR 32).
 
-Times the WHOLE one-step program of `make_fm_step_minibatch` at the geometry
-of the benchmark's cell `fm_criteo.stream` (-dims 2^26 -factors 5: a packed
-table of 4,194,304 x 128 float32 and its AdaGrad state, B = 32768, L = 39,
-unit values elided), never a phase alone (PR 25: phases alone are not
-floors). A variant is a capacity (the shipped rule's, or a forced one), who
-updates the distinct rows (ops/rows_pallas.py's kernel, or the XLA gather,
-update and scatter that `update_rows` is off a TPU) and a batch with a
-chosen number of distinct table rows; `dense` is the step with no ranking
-at all. The `wide_*` variants force a capacity of a third of the slots: the
-slope of their times over the distinct rows is the kernel's cost a row past
-the shipped capacity; the `prior_cap_*` variants force PR 28's capacity: the
-shipped capacity's distance from them at the same batch is what a larger
-compact gradient costs.
-For each: milliseconds a step on the host's clock around 10 steps ended by
-`block_until_ready`, then the device operations of 4 traced steps by
-`hm.*` scope and by name (the benchmark's own trace reader).
+Times WHOLE programs, never a phase alone (PR 25: phases alone are not
+floors), at the geometry of a cell of the benchmark:
 
-Run on the chip: `python experiments/probe_distinct_tail.py`; one JSON line
-per variant, all of them in `chiprun_out/probe_distinct_tail.json`. It
-exits non-zero off a TPU (`--tiny` rehearses the script on the CPU at a toy
-size: its times mean nothing).
+  fm   `fm_criteo.stream`: `make_fm_step_minibatch`, -dims 2^26 -factors 5,
+       a packed table of 4,194,304 x 128 float32 and its AdaGrad state,
+       B = 32768, L = 39, unit values elided; the one-step program.
+  ffm  `ffm_criteo_joint.stream`: `make_ffm_step_fused` (fieldmajor, unit
+       values), -dims 2^28 -fields 39 -factors 4 -halffloat, a table of
+       4,194,304 x 164 bfloat16 and its float32 AdaGrad state; the MEGASTEP
+       of 4 steps, divided by 4: the chip keeps a 164-lane table transposed
+       (`{0,1}`), a program relayouts it on the way in and out, and the
+       cell's megastep pays that once a dispatch, a one-step program every
+       step.
+
+A variant is a capacity (the shipped rule's, or a forced one), who updates
+the distinct rows (ops/rows_pallas.py's kernel where Mosaic takes the
+tables, or XLA's gather, update and scatter in blocks of a chosen size) and
+batches with chosen numbers of distinct table rows; `dense` is the step
+with no ranking at all. A forced capacity of a third of the slots (`wide`)
+gives the cost a distinct row past the shipped capacity by the slope of its
+times; a small forced one at the same batch what a capacity row costs; one
+block as long as the list (`oneshot`) what the blocks' loop saves.
+For each: milliseconds a step on the host's clock around 10 calls ended by
+`block_until_ready`, then the device operations of 4 traced calls by `hm.*`
+scope and by name (the benchmark's own trace reader).
+
+Run on the chip: `python experiments/probe_distinct_tail.py [fm] [ffm]`
+(default: both); one JSON line per reading, all of them in
+`chiprun_out/probe_distinct_tail.json`. It exits non-zero off a TPU
+(`--tiny` rehearses the script on the CPU at a toy size: its times mean
+nothing).
 """
 from __future__ import annotations
 
@@ -44,21 +54,81 @@ import numpy as np
 from hivemall_tpu.ops import fm, rows_pallas
 from hivemall_tpu.ops.losses import get_loss
 from hivemall_tpu.ops.optimizers import make_optimizer
+from hivemall_tpu.ops.scan import make_megastep
 
 TINY = "--tiny" in sys.argv          # a rehearsal of the script on the CPU
-K, B, L = (5, 256, 8) if TINY else (5, 32768, 39)
-WF, P = fm.fm_pack_geometry(K)
-R = (1 << (17 if TINY else 26)) // P
+B, L = (256, 8) if TINY else (32768, 39)
 N = B * L
+R = 1 << (14 if TINY else 22)        # table rows, both geometries
+OPT = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1, reg="no")
+LOSS = get_loss("logloss")
 
 
-def batch_ids(rng, n_distinct: int) -> np.ndarray:
-    """[B, L] feature ids over exactly `n_distinct` table rows: every row
-    of a random pool once, the other slots Zipf(1.25) over the pool."""
-    pool = rng.choice(np.arange(1, R), n_distinct, replace=False)
-    rows = pool[(rng.zipf(1.25, N) - 1) % n_distinct]
-    rows[rng.choice(N, n_distinct, replace=False)] = pool
-    return (rows * P + rng.integers(0, P, N)).reshape(B, L).astype(np.int32)
+def zipf_over(rng, pool: np.ndarray) -> np.ndarray:
+    """N draws over `pool`, every member once, the rest Zipf(1.25)."""
+    out = pool[(rng.zipf(1.25, N) - 1) % len(pool)]
+    out[rng.choice(N, len(pool), replace=False)] = pool
+    return out
+
+
+class FM:
+    """`fm_criteo.stream`: ids over exactly n_distinct packed rows."""
+    name, steps, W, itemsize = "fm", 1, 128, 4
+    K = 5
+    WF, P = fm.fm_pack_geometry(K)
+
+    def state(self, key):
+        T = jax.jit(lambda k: 0.1 * jax.random.normal(
+            k, (R, self.P * self.WF), jnp.float32))(key)
+        return ({"T": T, "w0": jnp.zeros(())},
+                {"T": {"gg": jnp.zeros((R, self.P * self.WF))},
+                 "w0": {"gg": jnp.zeros(())}})
+
+    def program(self):
+        step = fm.make_fm_step_minibatch(LOSS, OPT, (0.0, 0.0, 0.0), self.K)
+        return lambda p, s, idx, label, mask: step(p, s, 1.0, idx, None,
+                                                   label, mask)
+
+    def ids(self, rng, n_distinct):
+        rows = zipf_over(rng, rng.choice(np.arange(1, R), n_distinct,
+                                         replace=False))
+        return (rows * self.P + rng.integers(0, self.P, N)) \
+            .reshape(B, L).astype(np.int32)
+
+
+class FFM:
+    """`ffm_criteo_joint.stream`: n_distinct feature ids, hashed into the
+    table's rows by the step (a few collide: the step's own count of
+    distinct rows is recorded beside the asked one)."""
+    name, steps, W, itemsize = "ffm", 4, 164, 2
+    F, K = (8, 4) if TINY else (39, 4)
+
+    def __init__(self):
+        self.W = self.F * self.K + 8
+
+    def state(self, key):
+        T = jax.jit(lambda k: (0.1 * jax.random.normal(
+            k, (R, self.W), jnp.float32)).astype(jnp.bfloat16))(key)
+        return ({"T": T, "w0": jnp.zeros(())},
+                {"T": {"gg": jnp.zeros((R, self.W))},
+                 "w0": {"gg": jnp.zeros(())}})
+
+    def program(self):
+        step = fm.make_ffm_step_fused(LOSS, OPT, (0.0, 0.0, 0.0), self.F,
+                                      self.K, fieldmajor=True, unit_val=True)
+        mega = make_megastep(step.core)
+        nv = jnp.full((self.steps,), B, jnp.int32)
+
+        def call(p, s, idx, label, mask):
+            p, s, losses, stats = mega(
+                p, s, 1.0, nv, jnp.broadcast_to(idx, (self.steps, B, L)),
+                None, jnp.broadcast_to(label, (self.steps, B)), None, None)
+            return p, s, losses, {k: v[-1] for k, v in stats.items()}
+        return call
+
+    def ids(self, rng, n_distinct):
+        pool = rng.choice(np.arange(1, R * 64), n_distinct, replace=False)
+        return zipf_over(rng, pool).reshape(B, L).astype(np.int32)
 
 
 def device_ops(trace_dir: str, steps: int) -> dict:
@@ -79,79 +149,93 @@ def device_ops(trace_dir: str, steps: int) -> dict:
                            for k, v in red["device_ops"]]}
 
 
+def programs(geo):
+    """(name, forced capacity or None for the shipped rule, the XLA rows'
+    block or None for the module's, [distinct rows asked])."""
+    d = lambda *fracs: [int(N / f) for f in fracs]   # noqa: E731
+    wide = N // 3 // rows_pallas.LIST_MULTIPLE * rows_pallas.LIST_MULTIPLE
+    # the cells' batches at Zipf 1.5 / 1.25 / 1.05 hold 27.7k / 73.0k /
+    # 161.6k distinct rows of 1,277,952 slots (ISSUE 28; FFM's: PERF.md §4)
+    if geo.name == "fm":
+        prior = 256 if TINY else 217_088    # PR 28's capacity
+        return [("dense", 0, None, d(17)),
+                ("shipped", None, None, d(46, 17.5, 10.6, 7.9, 6, 4.6, 4.3)),
+                ("prior_cap", prior, None, d(46, 17.5, 7.9)),
+                ("wide", wide, None, d(4.3, 3.3)),
+                ("xla_rows_cap_n/16", N // 16, 0, d(32))]
+    small = 256 if TINY else 81_920
+    return [("dense", 0, None, d(17.5)),
+            ("shipped", None, None, d(46, 17.5, 10.6, 7.9, 6.4)),
+            ("wide", wide, None, d(17.5, 6, 4.3)),
+            ("small_cap", small, None, d(17.5)),
+            ("small_cap_oneshot", small, small, d(17.5)),
+            ("shipped_block_2048", None, 2048, d(17.5))]
+
+
 def main() -> int:
     from harness import xplane
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not TINY:
         print(f"needs a TPU, found {dev.platform}", file=sys.stderr)
         return 1
-    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1, reg="no")
-    loss = get_loss("logloss")
     rng = np.random.default_rng(28)
-    key = jax.random.PRNGKey(0)
-    params = {"T": jax.jit(lambda k: 0.1 * jax.random.normal(
-        k, (R, P * WF), jnp.float32))(key), "w0": jnp.zeros(())}
-    state = {"T": {"gg": jnp.zeros((R, P * WF))}, "w0": {"gg": jnp.zeros(())}}
     label = jnp.asarray(np.where(rng.random(B) < 0.25, 1.0, -1.0)
                         .astype(np.float32))
     mask = jnp.ones(B, jnp.float32)
-    # (name, forced capacity or None for the shipped rule, row kernel,
-    #  distinct rows). The cell's batches at Zipf 1.25 / 1.5 / 1.05 hold
-    # 73.0k / 27.7k / 161.6k distinct rows of 1,277,952 slots (ISSUE 28).
     shipped_cap, kernels = fm.tail_cap, rows_pallas.use_kernels_default
-    wide = N // 3 // rows_pallas.LIST_MULTIPLE * rows_pallas.LIST_MULTIPLE
-    prior = 256 if TINY else 217_088  # PR 28's capacity, in whole blocks
-    variants = [("dense", 0, True, N // 17)]
-    variants += [(f"shipped_n/{d}", None, True, int(N / d))
-                 for d in (46, 17.5, 10.6, 7.9, 6, 4.6)]
-    variants += [("shipped_n/4.3_falls_through", None, True, int(N / 4.3))]
-    variants += [(f"prior_cap_n/{d}", prior, True, int(N / d))
-                 for d in (46, 17.5, 7.9)]
-    variants += [(f"wide_n/{d}", wide, True, int(N / d)) for d in (4.3, 3.3)]
-    variants += [("xla_rows_cap_n/16_half", N // 16, False, N // 32)]
+    shipped_block = rows_pallas.XLA_BLOCK_ROWS
+    asked = [a for a in sys.argv[1:] if not a.startswith("-")]
     out = []
-    for name, cap, use_kernels, nd in variants:
-        fm.tail_cap = shipped_cap if cap is None else (lambda n, r, c=cap: c)
-        rows_pallas.use_kernels_default = (kernels if use_kernels
-                                           else (lambda: False))
-        step = fm.make_fm_step_minibatch(loss, opt, (0.0, 0.0, 0.0), K)
-        idx = jnp.asarray(batch_ids(rng, nd))
-        t0 = time.perf_counter()
-        params, state, ls, stats = step(params, state, 1.0, idx, None, label,
-                                        mask)
-        jax.block_until_ready(ls)
-        compile_s = time.perf_counter() - t0
-        for _ in range(2):
-            params, state, ls, stats = step(params, state, 1.0, idx, None,
-                                            label, mask)
-        jax.block_until_ready(ls)
-        t0 = time.perf_counter()
-        for _ in range(10):
-            params, state, ls, stats = step(params, state, 1.0, idx, None,
-                                            label, mask)
-        jax.block_until_ready(ls)
-        ms = 1e2 * (time.perf_counter() - t0)
-        trace_dir = os.path.join(ROOT, "chiprun_out", "probe_trace", name
-                                 .replace("/", "_"))
-        xplane.start(trace_dir)
-        for _ in range(4):
-            params, state, ls, stats = step(params, state, 1.0, idx, None,
-                                            label, mask)
-        jax.block_until_ready(ls)
-        jax.profiler.stop_trace()
-        rec = {"variant": name,
-               "cap": shipped_cap(N, R) if cap is None else cap,
-               "row_kernels": use_kernels, "n_distinct": nd,
-               "step_ms": round(ms, 3), "first_call_s": round(compile_s, 1),
-               "stats": {k: int(v) for k, v in stats.items()},
-               **device_ops(trace_dir, 4)}
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        print(json.dumps(rec), flush=True)
-        out.append(rec)
+    for geo in (g() for g in (FM, FFM) if not asked or g.name in asked):
+        params, state = geo.state(jax.random.PRNGKey(0))
+        for name, cap, block, nds in programs(geo):
+            fm.tail_cap = shipped_cap if cap is None else (
+                lambda *a, c=cap: c)
+            # block 0: XLA's rows where the kernel would run, one block
+            rows_pallas.use_kernels_default = (
+                (lambda: False) if block == 0 else kernels)
+            rows_pallas.XLA_BLOCK_ROWS = (block or shipped_block
+                                          if block != 0 else N)
+            call = geo.program()
+            first = True
+            for nd in nds:
+                idx = jnp.asarray(geo.ids(rng, nd))
+                t0 = time.perf_counter()
+                for _ in range(3):
+                    params, state, ls, stats = call(params, state, idx,
+                                                    label, mask)
+                    jax.block_until_ready(ls)
+                    if first:
+                        compile_s, first = time.perf_counter() - t0, False
+                t0 = time.perf_counter()
+                for _ in range(10):
+                    params, state, ls, stats = call(params, state, idx,
+                                                    label, mask)
+                jax.block_until_ready(ls)
+                ms = 1e2 * (time.perf_counter() - t0) / geo.steps
+                trace_dir = os.path.join(ROOT, "chiprun_out", "probe_trace",
+                                         f"{geo.name}_{name}_{nd}")
+                xplane.start(trace_dir)
+                for _ in range(4):
+                    params, state, ls, stats = call(params, state, idx,
+                                                    label, mask)
+                jax.block_until_ready(ls)
+                jax.profiler.stop_trace()
+                rec = {"geometry": geo.name, "variant": name,
+                       "cap": (shipped_cap(N, R, geo.W, geo.itemsize)
+                               if cap is None else cap),
+                       "xla_block": block, "n_distinct_asked": nd,
+                       "step_ms": round(ms, 3),
+                       "first_call_s": round(compile_s, 1),
+                       "stats": {k: int(v) for k, v in stats.items()},
+                       **device_ops(trace_dir, 4 * geo.steps)}
+                shutil.rmtree(trace_dir, ignore_errors=True)
+                print(json.dumps(rec), flush=True)
+                out.append(rec)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "probe_distinct_tail.json"),
               "w") as f:
-        json.dump({"device": dev.device_kind, "variants": out}, f, indent=1)
+        json.dump({"device": dev.device_kind, "readings": out}, f, indent=1)
     return 0
 
 

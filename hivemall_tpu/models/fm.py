@@ -89,12 +89,14 @@ def _fm_step_cached(loss_name, opt, eta_scheme, eta0, total_steps,
 @_instrument("ffm", "step_fused")
 @_lru_cache(maxsize=64)
 def _ffm_step_fused_cached(loss_name, opt, eta_scheme, eta0, total_steps,
-                           power_t, lambdas, F, k, fieldmajor, unit_val):
+                           power_t, lambdas, F, k, fieldmajor, unit_val,
+                           distinct_tail=True):
     return make_ffm_step_fused(
         get_loss(loss_name),
         make_optimizer_cached(opt, eta_scheme, eta0, total_steps,
                               power_t),
-        lambdas, F, k, fieldmajor=fieldmajor, unit_val=unit_val)
+        lambdas, F, k, fieldmajor=fieldmajor, unit_val=unit_val,
+        distinct_tail=distinct_tail)
 
 
 @_instrument("ffm", "step")
@@ -181,15 +183,15 @@ def _packed_megawrap_cached(base_step, B: int, L: int):
         def body(carry, x):
             p, s, t = carry
             idx, label, mask = _unpack_on_device(x["buf"], x["nv"], B, L)
-            p, s, loss = core(p, s, t, idx, label, mask)
-            return (p, s, t + 1.0), loss
+            p, s, *out = core(p, s, t, idx, label, mask)
+            return (p, s, t + 1.0), tuple(out)
 
         # the phase scopes are the core's own (ops/fm.py); the unpack and
         # the scan's slicing stay under hm.scan alone
         with jax.named_scope("hm.scan"):
-            (p, s, _), losses = jax.lax.scan(
+            (p, s, _), out = jax.lax.scan(
                 body, (params, opt_state, t0), {"buf": bufs, "nv": nvs})
-        return p, s, losses
+        return (p, s, *out)     # losses [K] and, from a joint step, stats
 
     # same devprof dispatch boundary as ops.scan.megastep_for: the packed
     # flagship path must not be the one fused dispatch whose peak-bytes
@@ -846,19 +848,15 @@ class FFMTrainer(FMTrainer):
             self.params, self.opt_state = self._make_state(
                 _fused_state_init(self.optimizer, self.Mr, 1, FK, self.W,
                                   dtype), key, float(o.sigma))
-            opt_key = self._opt_key
-            lamt = (o.lambda0, o.lambda_w, o.lambda_v)
-            self._step = _ffm_step_fused_cached(
-                self._loss_name, *opt_key, lamt, self.F, self.k,
-                False, False)
+            # under -mesh the dense tail stays, as for train_fm
+            head = (self._loss_name, *self._opt_key,
+                    (o.lambda0, o.lambda_w, o.lambda_v), self.F, self.k)
+            tail = not o.get("mesh")
+            self._step = _ffm_step_fused_cached(*head, False, False, tail)
             self._step_fm = None if self.interaction == "pairs" else \
-                _ffm_step_fused_cached(
-                    self._loss_name, *opt_key, lamt, self.F, self.k,
-                    True, False)
+                _ffm_step_fused_cached(*head, True, False, tail)
             self._step_fm_unit = None if self.interaction == "pairs" else \
-                _ffm_step_fused_cached(
-                    self._loss_name, *opt_key, lamt, self.F, self.k,
-                    True, True)
+                _ffm_step_fused_cached(*head, True, True, tail)
             self._fused_score = _ffm_score_fused_cached(self.F, self.k)
             self._fused_score_fm = _ffm_score_fieldmajor_cached(self.F,
                                                                 self.k)
@@ -1383,38 +1381,44 @@ class FFMTrainer(FMTrainer):
             nv = (mb.nv_dev if mb.nv_dev is not None
                   else jnp.asarray(mb.nv))
             mega = _packed_megawrap_cached(self._step_fm_unit, mb.B, mb.L)
-            self.params, self.opt_state, losses = mega(
+            self.params, self.opt_state, losses, *stats = mega(
                 self.params, self.opt_state, float(self._t), mb.buf, nv)
+            self._stats_pending += stats
             return losses
         if mb.fieldmajor and self._step_fm is not None:
             step = self._step_fm_unit if mb.val is None else self._step_fm
             mega = megastep_for(step)
             nv = (mb.nv_dev if mb.nv_dev is not None
                   else jnp.asarray(mb.nv))
-            self.params, self.opt_state, losses = mega(
+            self.params, self.opt_state, losses, *stats = mega(
                 self.params, self.opt_state, float(self._t), nv, mb.idx,
                 mb.val, mb.label, None, None)
+            self._stats_pending += stats
             return losses
         return super()._train_megabatch(mb)
 
     def _train_batch(self, batch: SparseBatch) -> float:
+        # a joint step returns its tail's stats beside the loss, a parts
+        # step the loss alone
         if isinstance(batch, PackedBatch):
             nv = batch.B if batch.n_valid is None else batch.n_valid
-            self.params, self.opt_state, loss_sum = self._packed_step(
-                batch.B, batch.L)(self.params, self.opt_state,
-                                  float(self._t), batch.buf, np.int32(nv))
-            return loss_sum
-        if batch.fieldmajor and self._step_fm is not None:
+            out = self._packed_step(batch.B, batch.L)(
+                self.params, self.opt_state, float(self._t), batch.buf,
+                np.int32(nv))
+        elif batch.fieldmajor and self._step_fm is not None:
             if batch.val is None:
-                self.params, self.opt_state, loss_sum = self._step_fm_unit(
+                out = self._step_fm_unit(
                     self.params, self.opt_state, float(self._t), batch.idx,
                     batch.label, batch.row_mask)
             else:
-                self.params, self.opt_state, loss_sum = self._step_fm(
+                out = self._step_fm(
                     self.params, self.opt_state, float(self._t), batch.idx,
                     batch.val, batch.label, batch.row_mask)
-            return loss_sum
-        return super()._train_batch(batch)
+        else:
+            return super()._train_batch(batch)
+        self.params, self.opt_state, loss_sum, *stats = out
+        self._stats_pending += stats
+        return loss_sum
 
     def _parse_row(self, features):
         """Parse "field:index:value" (value defaults to 1)."""

@@ -27,7 +27,7 @@ so a profiler trace reduces device time by phase, not by ``fusion.48``
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -348,7 +348,8 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                         lambdas: Tuple[float, float, float],
                         F: int, K: int,
                         fieldmajor: bool = False,
-                        unit_val: bool = False) -> Callable:
+                        unit_val: bool = False,
+                        distinct_tail: bool = True) -> Callable:
     """The flagship train_ffm step — fused feature-row joint layout.
 
     Design (measured on v5e, B=32k L=40: 9.85 s/step -> 103 ms/step):
@@ -362,12 +363,23 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
       2. pair mixing (one-hot einsum; or, with fieldmajor=True over
          canonical batches, the static field-grouped form — pure VPU,
          no L^2 intermediate: _fused_phi_fieldmajor)
-      3. one scatter-add of the slab gradient into a dense G
-      4. a DENSE optimizer update over [Mr, W] (zero-grad rows are
-         no-ops for non-decaying optimizers; any -opt works)
+      3. one scatter-add of the slab gradient, duplicates summed by
+         table row
+      4. the optimizer's update of T and its state
+
+    Steps 3 and 4 are rows_update, the tail the packed FM minibatch step
+    has: where the optimizer leaves a zero-gradient row as it was (AdaGrad
+    and SGD: `zero_grad_noop`) and the table is large against the batch,
+    the sum goes into a compact gradient and the update to the batch's
+    distinct rows; else into a dense G with an update over [Mr, W] (any
+    -opt works there). ``distinct_tail=False`` keeps the dense tail
+    whatever the shapes: the trainer's choice under -mesh, not a user's.
 
     The fieldmajor step takes no field array (the layout IS the field
     assignment: slot s -> field s % F).
+
+    Returns (params, opt_state, loss_sum, stats): stats counts which tail
+    ran and the batch's distinct rows (TAIL_STATS).
 
     Semantics delta vs the reference's per-entry updates (documented):
     AdaGrad-family accumulators see the SQUARE OF THE SUMMED minibatch
@@ -406,16 +418,16 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                 * pm[..., None]
             g0 = g0 + lam0 * w0.astype(jnp.float32)
 
-        with jax.named_scope("hm.scatter"):
-            G = jnp.zeros(T.shape, jnp.float32).at[rows.reshape(-1)].add(
-                gslab.reshape(-1, W))                # ONE scatter-add
+        with jax.named_scope("hm.scatter"):      # the slab's relayout too
+            rows, gslab = rows.reshape(-1), gslab.reshape(-1, W)
+        Tn, sT, stats = rows_update(T, opt_state["T"], rows, gslab,
+                                    optimizer, t,
+                                    None if distinct_tail else 0)
         with jax.named_scope("hm.update"):
-            Tn, sT = optimizer.update(T.astype(jnp.float32), G,
-                                      opt_state["T"], t)
             w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
                                        opt_state["w0"], t)
-            return ({"T": Tn.astype(T.dtype), "w0": w0n.astype(w0.dtype)},
-                    {"T": sT, "w0": s0}, loss_sum)
+        return ({"T": Tn, "w0": w0n.astype(w0.dtype)},
+                {"T": sT, "w0": s0}, loss_sum, stats)
 
     if unit_val:
         assert fieldmajor, "unit_val implies the canonical fieldmajor batch"
@@ -606,47 +618,72 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
 # A minibatch step's tail used to zero-fill a table-sized G, scatter-add the
 # batch gradient into it and run the optimizer over every table row, to
 # change the rows one batch touches (1.7% of 4,194,304 in the benchmark's
-# cell). rows_update sums duplicates into a COMPACT gradient and updates the
-# batch's distinct rows only, where the shapes and the batch say that pays:
-# up to tail_cap distinct rows, the count at which the two tails cost the
-# same by the chip's readings below (TPU v5e, a [4194304, 128] float32
-# table, 1,277,952 slots; experiments/probe_distinct_tail.py, PERF.md
-# section 6, PRs 28 and 30).
-# ns per TABLE row of what only the dense tail runs: the dense AdaGrad pass
-# (15.9 ms) and what the scatter-add into a zero-filled table-sized G costs
-# over the one into a compact Gc (20.1 against 17.4 ms)
-_DENSE_NS_PER_TABLE_ROW = 4.43
-# ns per SLOT of what only the distinct tail runs: the sort that compacts
-# the distinct row ids (1.73 ms) and the running sum (0.24 ms)
-_RANK_NS_PER_SLOT = 1.54
-# ns per DISTINCT row at capacity, read again in PR 30 with the one row
-# kernel: four row DMAs at 15.5 ns each (the DMA engine's rate: the same
-# from an unroll of 4 up and on either DMA priority), the update hidden
-# under them: 62.2 by the slope of hm.update over 27.8k..387k distinct
-# rows, 59.4 for the whole step (the compact scatter-add gains 2.8 as rows
-# repeat less), and 2.9 for a capacity row of compact gradient (zero-
-# filled and scattered into: the cell's step.scatter_ms 20.92 at 218,496
-# rows, 21.11 at 282,624). 58.5 puts the break-even where the chip read
-# it, ~284k rows: through a capacity of 425,984 the step takes 51.26 ms at
-# 213.0k distinct rows and 56.33 at 297.2k, through the cond's dense
-# branch 55.22; through the capacity this gives, 282,624, 54.86 at 277.8k
-# rows against the dense branch's 54.98 (PR 28's four kernels: 76, 218k)
-_DISTINCT_NS_PER_ROW = 58.5
+# cells). rows_update sums duplicates into a COMPACT gradient and updates
+# the batch's distinct rows only, where the shapes and the batch say that
+# pays: up to tail_cap distinct rows, the count at which the two tails cost
+# the same.
+
+
+class _TailCost(NamedTuple):
+    """What one tail runs and the other does not, on a TPU v5e, for 1,277,952
+    slots into a table of 4,194,304 rows: the readings of
+    experiments/probe_distinct_tail.py (chiprun_out/probe_distinct_tail.json;
+    PERF.md section 6 has them by PR)."""
+    #: ns a TABLE row, the dense tail alone: the optimizer's pass over the
+    #: table, and what the scatter-add into a zero-filled table-sized G
+    #: costs over the one into the compact Gc
+    dense_table_row: float
+    #: ns a SLOT, the distinct tail alone: the sort that brings the
+    #: distinct row ids to the front, and the running sum that ranks
+    rank_slot: float
+    #: ns a DISTINCT row at capacity: the row's copies out of every table
+    #: and back, and a capacity row of Gc zero-filled and scattered into
+    distinct_row: float
+
+
+# by (lanes, bytes of the narrowest item): the tables' rows as
+# ops/rows_pallas.py moves them
+_TAIL_COSTS = {
+    # fm_criteo: float32 T and gg through the row kernel, four row DMAs at
+    # 15.5 ns (PRs 28 and 30: dense 15.9 + 2.7 ms, sort 1.73 + 0.24 ms; the
+    # two tails cost the same at ~284k rows)
+    (128, 4): _TailCost(4.43, 1.54, 58.5),
+    # ffm_criteo_joint: bfloat16 T and float32 gg, which Mosaic refuses,
+    # through XLA's gather and scatter in blocks, 77-79 ns a row a table
+    # (PR 32, the megastep: dense 103.98 ms a step; 85.34 at 72.4k distinct
+    # rows and 177 ns more a row, 2 a capacity row; equal at ~177k)
+    (164, 2): _TailCost(7.97, 1.54, 179.0),
+}
 
 TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows")
 
 
-def tail_cap(n: int, R: int) -> int:
+def _tail_cost(W: int, itemsize: int) -> _TailCost:
+    """The readings at (W, itemsize). A shape nobody read moves its rows
+    as the flagship's do (only (128, 4) has the kernel): the flagship's,
+    the table-row cost by the bytes of a padded row of T, gg and G."""
+    if (W, itemsize) in _TAIL_COSTS:
+        return _TAIL_COSTS[W, itemsize]
+
+    def row_bytes(W, itemsize):
+        return -(-W // 128) * 128 * (itemsize + 4 + 4)
+    c = _TAIL_COSTS[164, 2]
+    return c._replace(dense_table_row=c.dense_table_row
+                      * row_bytes(W, itemsize) / row_bytes(164, 2))
+
+
+def tail_cap(n: int, R: int, W: int = 128, itemsize: int = 4) -> int:
     """Most distinct rows the distinct-row tail takes on for n slots into
-    a table of R rows, in whole blocks of the row kernel's (whole id tiles
-    under one block); 0 where the dense tail is the cheaper one at any
-    count (a table small against the batch: the toy config's 4,096 rows
-    against 9,984 slots), or where R + n overflows the int32 ids that pad
-    the distinct-row list."""
+    a table of R rows of W lanes whose narrowest items (the table's, or a
+    state leaf's) have `itemsize` bytes, in whole blocks of the row
+    kernel's (whole id tiles under one block); 0 where the dense tail is
+    the cheaper one at any count (a table small against the batch: the toy
+    config's 4,096 rows against 9,984 slots), or where R + n overflows the
+    int32 ids that pad the distinct-row list."""
     if R + n >= 2 ** 31:
         return 0
-    even = (R * _DENSE_NS_PER_TABLE_ROW - n * _RANK_NS_PER_SLOT) \
-        / _DISTINCT_NS_PER_ROW
+    c = _tail_cost(W, itemsize)
+    even = (R * c.dense_table_row - n * c.rank_slot) / c.distinct_row
     cap = max(0, min(n, int(even)))
     return (cap // BLOCK_ROWS * BLOCK_ROWS
             or cap // LIST_MULTIPLE * LIST_MULTIPLE)
@@ -655,14 +692,17 @@ def tail_cap(n: int, R: int) -> int:
 def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
     """The tail of a minibatch step: apply ``optimizer.update`` with the
     gradient rows ``g`` [n, W] (float32) summed by table row ``rows`` [n]
-    into T [R, W] and its co-shaped ``state``. Returns (T, state, stats),
-    stats a dict of int32 scalars named by TAIL_STATS. Knows nothing of
-    what a row holds.
+    into T [R, W] and its co-shaped ``state``, whose leaves may be of
+    another dtype than T (a bfloat16 table with float32 accumulators).
+    Returns (T, state, stats), stats a dict of int32 scalars named by
+    TAIL_STATS. Knows nothing of what a row holds.
 
     For an optimizer whose update leaves a zero-gradient entry as it was
-    (AdaGrad and SGD with reg='no', which factor trainers always use),
-    updating the distinct rows is the dense update: what differs is the
-    order in which f32 addends meet in a duplicate's sum.
+    (``zero_grad_noop``: AdaGrad and SGD with reg='no', which factor
+    trainers always use), updating the distinct rows is the dense update:
+    what differs is the order in which f32 addends meet in a duplicate's
+    sum. Either way a row of T is widened to float32, updated there and
+    rounded to T's dtype once.
 
       1. rank: ONE key-value sort of (rows, iota) gives the rows in order
          and the slot each came from; a flag where the sorted row changes
@@ -675,19 +715,20 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
       3. the flagged row ids, sorted to the front, are the distinct rows:
          T and every leaf of the state are read at them, given the
          optimizer's own update (same function, same float32, same t) and
-         written back in place by ops/rows_pallas.py `update_rows`: on a
-         TPU ONE kernel that costs by the count of distinct rows and by
-         nothing else, elsewhere XLA's gather, update and scatter.
+         written back in place by ops/rows_pallas.py `update_rows`: ONE
+         kernel where Mosaic compiles it (a TPU, 128 lanes of 32-bit
+         words), else XLA's gather, update and scatter in blocks; both
+         cost by the count of distinct rows.
 
-    ``cap`` (None: tail_cap of the shapes) is static; 0 is the dense tail
-    alone, with no ranking. A batch with more distinct rows than ``cap``
-    takes the dense tail too (lax.cond), fed the same sorted rows."""
+    ``cap`` (None: tail_cap of the shapes, 0 for any other optimizer) is
+    static; 0 is the dense tail alone, with no ranking. A batch with more
+    distinct rows than ``cap`` takes the dense tail too (lax.cond), fed
+    the same sorted rows."""
     n, (R, W) = rows.shape[0], T.shape
+    leaves, tree = jax.tree_util.tree_flatten(state)
     if cap is None:
-        # rows travel as 32-bit DMA words: a bfloat16 table keeps the dense tail
-        words = all(a.dtype.itemsize == 4
-                    for a in jax.tree_util.tree_leaves((T, state)))
-        cap = tail_cap(n, R) if words else 0
+        cap = tail_cap(n, R, W, min(a.dtype.itemsize for a in (T, *leaves))
+                       ) if optimizer.zero_grad_noop else 0
 
     def dense(T, state, rows=rows, g=g, **sorted_):
         with jax.named_scope("hm.scatter"):
@@ -714,14 +755,12 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
                 g[perm], mode="drop", indices_are_sorted=True)
             urows = jnp.sort(jnp.where(first, srows, R + slot))[:cap]
         with jax.named_scope("hm.update"):
-            leaves, tree = jax.tree_util.tree_flatten(state)
-
             def update(blocks, g, t):
-                w, s = optimizer.update(blocks[0], g,
+                w, s = optimizer.update(blocks[0].astype(jnp.float32), g,
                                         tree.unflatten(blocks[1:]), t)
                 return (w, *jax.tree_util.tree_leaves(s))
-            Tn, *sn = update_rows((T, *leaves), urows, n_distinct, Gc, t,
-                                  update)
+            Tn, *sn = update_rows((T, *jax.tree_util.tree_leaves(state)),
+                                  urows, n_distinct, Gc, t, update)
             return Tn, tree.unflatten(sn)
 
     fits = n_distinct <= cap

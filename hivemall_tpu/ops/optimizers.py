@@ -52,6 +52,12 @@ class Optimizer:
     # then uses the batch-final accumulators). None = no sparse form
     # (momentum/adam/adadelta decay untouched state; use the dense update).
     sparse_update: Callable[..., Tuple[Any, Dict[str, Any]]] = None  # type: ignore[assignment]
+    # update(w, 0, s, t) == (w, s) for every entry: an entry whose gradient
+    # is zero need not be visited, so a minibatch step may update the rows
+    # its batch touched and no others (ops/fm.py rows_update). SGD and
+    # AdaGrad without a regularizer; RDA and FTRL rebuild w from their
+    # sums at every t, the others decay their state.
+    zero_grad_noop: bool = False
 
     def __post_init__(self):
         if self.finalize is None:
@@ -90,6 +96,7 @@ def make_optimizer(name: str = "adagrad", *, eta_scheme: str = "fixed",
 
     def regz(g, w):
         return _regularize(g, w, reg, lam, l1_ratio)
+    unregularized = reg in ("no", "none", "rda", None)
 
     if key == "sgd":
         def sgd_sparse(w, g, s, ix, t):
@@ -100,7 +107,7 @@ def make_optimizer(name: str = "adagrad", *, eta_scheme: str = "fixed",
             "sgd",
             init=lambda shape, dtype=jnp.float32: {},
             update=lambda w, g, s, t: (w - eta(t) * regz(g, w), s),
-            sparse_update=sgd_sparse)
+            sparse_update=sgd_sparse, zero_grad_noop=unregularized)
 
     if key in ("momentum", "nesterov"):
         nesterov = key == "nesterov"
@@ -132,7 +139,8 @@ def make_optimizer(name: str = "adagrad", *, eta_scheme: str = "fixed",
             return w.at[ix].add(step.astype(w.dtype)), {"gg": gg}
 
         return Optimizer("adagrad", ag_init, ag_update,
-                         sparse_update=ag_sparse)
+                         sparse_update=ag_sparse,
+                         zero_grad_noop=unregularized)
 
     if key == "adadelta":
         def ad_init(shape, dtype=jnp.float32):

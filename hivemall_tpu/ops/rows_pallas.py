@@ -29,10 +29,24 @@ the DMA engine's rate, 15.5 ns a row copy on a v5e: 62 ns a distinct row of
 two tables, 0.25 ms for the kernel alone on a list with no live row
 (PERF.md section 6, PR 30).
 
-Off a TPU `update_rows` IS the XLA gather, `fn` and scatter it replaces
-(`use_kernels_default`: training that runs anywhere must not start to
-depend on the interpreter), which is why a list pads with out-of-range
-ids; `interpret=True` runs the kernel there, for the tests.
+What Mosaic compiles of this is rows of exactly one lane tile of 32-bit
+words (`kernel_takes`), which lie contiguous in HBM. Asked for the
+flagship's tables, `[4194304, 164]` bfloat16 with a float32 state, it
+refuses (jax 0.9 for a v5e; tests/tpu_aot_worker.py `ffm_joint_megastep`): any
+slice of an array whose rows are not whole lane tiles ("Slice shape along
+dimension 1 must be aligned to tiling (128), but is 164", float32 too, 8-
+and 16-row groups too, through a `[R/8, 8, 164]` view too), and fewer rows
+than a tile's 8 of any other array (a bfloat16 pair, which shares its
+sublane words: "dimension 0 must be aligned to tiling (8), but is 2", at
+128 and 256 lanes; a float32 row of 256 lanes likewise).
+
+For those tables, and off a TPU (`use_kernels_default`: training that
+runs anywhere must not start to depend on the interpreter), `update_rows`
+is XLA's gather, `fn` and scatter on the same list, in blocks of at most
+XLA_BLOCK_ROWS up to the count: a loop whose trips follow the live rows,
+because the scatter's 70 ns a row would otherwise be paid for the whole
+capacity. That is why a list pads with out-of-range ids. `interpret=True`
+runs the kernel off a TPU, for the tests.
 """
 
 from __future__ import annotations
@@ -42,14 +56,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["update_rows", "use_kernels_default", "LIST_MULTIPLE",
-           "BLOCK_ROWS"]
+__all__ = ["update_rows", "use_kernels_default", "kernel_takes",
+           "LIST_MULTIPLE", "BLOCK_ROWS", "XLA_BLOCK_ROWS"]
 
 #: a row list's length must be a multiple of this (one SMEM tile of ids)
 LIST_MULTIPLE = 128
 #: and is best a multiple of this: the rows of a block (1 MiB of VMEM a
 #: slot at 128 float32 lanes), which must divide the list
 BLOCK_ROWS = 2048
+#: the most rows one trip of the XLA path gathers, updates and scatters
+XLA_BLOCK_ROWS = 8192
 _UNROLL = 4
 _IN, _OUT = 0, 1
 
@@ -60,15 +76,37 @@ def use_kernels_default() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _block_rows(cap: int) -> int:
-    """Rows a grid step updates: BLOCK_ROWS halved until it divides `cap`."""
+def kernel_takes(tables) -> bool:
+    """Whether Mosaic compiles the kernel's row copies for these tables:
+    rows of one lane tile of 32-bit words (the module's docstring has what
+    it says to the others)."""
+    return all(a.dtype.itemsize == 4 and a.shape[1] == 128 for a in tables)
+
+
+def _block_rows(cap: int, most: int = BLOCK_ROWS) -> int:
+    """Rows a block updates: `most` halved until it divides `cap`."""
     if cap % LIST_MULTIPLE:
         raise ValueError(f"a row list of {cap} ids is not a multiple of "
                          f"{LIST_MULTIPLE}")
-    tb = BLOCK_ROWS
+    tb = most
     while cap % tb:
         tb //= 2
     return tb
+
+
+def _update_rows_xla(tables, rows, n, g, t, fn):
+    """update_rows by XLA's gather and scatter, a block of the list a trip,
+    ceil(n / block) trips."""
+    tb = _block_rows(rows.shape[0], XLA_BLOCK_ROWS)
+
+    def block(i, tables):
+        ids = jax.lax.dynamic_slice_in_dim(rows, i * tb, tb)
+        new = fn(tuple(a.at[ids].get(mode="clip") for a in tables),
+                 jax.lax.dynamic_slice_in_dim(g, i * tb, tb), t)
+        return tuple(a.at[ids].set(u.astype(a.dtype), mode="drop")
+                     for a, u in zip(tables, new))
+    return jax.lax.fori_loop(0, (n.astype(jnp.int32) + tb - 1) // tb, block,
+                             tables)
 
 
 def _each(m, body):
@@ -90,26 +128,31 @@ def _each(m, body):
 
 
 def update_rows(tables, rows, n, g, t, fn, *, interpret=None):
-    """`tables` (a tuple of co-shaped [R, W] arrays of 32-bit words) with
-    rows ``rows[:n]`` of every table replaced, in place (donate them), by
-    ``fn(blocks, g_block, t)``: ``blocks`` the tables' rows at a block of
-    the list, ``g_block`` the same block of ``g`` [len(rows), W], ``t`` the
-    scalar ``t`` as a float32 [1, W] (data, not a constant of the
-    program). ``fn`` is elementwise over rows: it is also handed rows past
-    ``n``, holding anything, whose results are dropped. rows[:n] distinct
-    and in range, rows[n:] out of range; len(rows) at most R."""
+    """`tables` (a tuple of co-shaped [R, W] arrays, each of its own
+    dtype) with rows ``rows[:n]`` of every table replaced, in place
+    (donate them), by ``fn(blocks, g_block, t)`` cast to the table's
+    dtype: ``blocks`` the tables' rows at a block of the list, ``g_block``
+    the same block of ``g`` [len(rows), W], ``t`` the scalar ``t`` as a
+    float32 [1, W] (data, not a constant of the program). ``fn`` is
+    elementwise over rows: it is also handed rows past ``n``, holding
+    anything, whose results are dropped. rows[:n] distinct and in range,
+    rows[n:] out of range; len(rows) at most R. The kernel
+    (``interpret=True`` asks for it by name) takes what `kernel_takes`
+    says."""
     tables = tuple(tables)
     cap, (R, W) = rows.shape[0], tables[0].shape
     tb = _block_rows(cap)
-    if any(a.shape != (R, W) or a.dtype.itemsize != 4 for a in tables):
-        raise ValueError("tables must be co-shaped arrays of 32-bit words")
+    if any(a.shape != (R, W) for a in tables):
+        raise ValueError("tables must be co-shaped")
     if cap > R:
         raise ValueError(f"a list of {cap} rows into a table of {R}")
     t = jnp.full((1, W), t, jnp.float32)
-    if interpret is None and not use_kernels_default():
-        new = fn(tuple(a.at[rows].get(mode="clip") for a in tables), g, t)
-        return tuple(a.at[rows].set(u.astype(a.dtype), mode="drop")
-                     for a, u in zip(tables, new))
+    takes = kernel_takes(tables)
+    if interpret is None and not (takes and use_kernels_default()):
+        return _update_rows_xla(tables, rows, n, g, t, fn)
+    if not takes:
+        raise ValueError("the kernel copies rows of 128 lanes of 32-bit "
+                         "words")
     nt = len(tables)
 
     def kernel(n_ref, ids_ref, next_ids_ref, t_ref, g_ref, *refs):

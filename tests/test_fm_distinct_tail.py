@@ -1,5 +1,6 @@
-"""The packed FM minibatch step's distinct-row tail (ops/fm.py `rows_update`)
-against the dense tail it replaces where a batch touches few table rows.
+"""The distinct-row tail of the packed FM minibatch step and of the FFM joint
+step (ops/fm.py `rows_update`) against the dense tail it replaces where a
+batch touches few table rows.
 
 Same mathematics, so: table and AdaGrad state agree at float32 rounding on
 the rows a batch touched (a duplicate's addends meet in another order) and
@@ -111,7 +112,7 @@ def test_distinct_tail_matches_dense_tail(case, n_distinct, distinct):
 
 def test_every_slot_distinct_within_the_capacity(monkeypatch):
     """No duplicate to sum: the compact gradient is the slab, permuted."""
-    monkeypatch.setattr(fm, "tail_cap", lambda n, r: n)
+    monkeypatch.setattr(fm, "tail_cap", lambda n, *shape: n)
     idx = _ids(N)
     new, ref = _pair(idx)
     _assert_same(new, ref, idx)
@@ -164,9 +165,14 @@ def test_small_table_picks_the_dense_tail_statically():
 
 
 def test_capacity_follows_the_shapes():
-    n = 32768 * 39                                   # the benchmark's cell
+    n = 32768 * 39                                   # the benchmark's cells
     cell = fm.tail_cap(n, 1 << 22)
+    assert cell == 282_624                           # [4194304,128] float32
     assert 161_600 < cell < n // 2                   # holds Zipf 1.05's batch
+    flagship = fm.tail_cap(n, 1 << 22, 164, 2)       # [4194304,164] bfloat16
+    assert 73_300 < flagship < cell and flagship % 2048 == 0
+    # a shape nobody read: the flagship's readings by the bytes of a row
+    assert 0 < fm.tail_cap(n, 1 << 22, 128, 2) < flagship
     assert fm.tail_cap(n, n // 4) == 0               # table under the batch
     assert fm.tail_cap(n, 2 ** 31 - n) == 0          # pad ids would overflow
     assert fm.tail_cap(n, 1 << 30) == n // 128 * 128     # never over n
@@ -213,25 +219,48 @@ def test_megastep_of_four_equals_four_single_steps(rows, monkeypatch):
     assert [s["tail_distinct_steps"] for s in stats] == [1, 1, 0, 1]
 
 
-def test_bfloat16_table_keeps_the_dense_tail():
-    """-halffloat: the dense update rounds its float32 result to bfloat16
-    once, a row add would round twice, so the rule offers no rung."""
-    idx = _ids(11)
-    out = []
-    for distinct in (True, False):
-        step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS,
-                                         K, distinct)
-        params, state = _state()
-        params = {"T": params["T"].astype(jnp.bfloat16),
-                  "w0": params["w0"].astype(jnp.bfloat16)}
-        out.append(step(params, state, 3.0, jnp.asarray(idx), None, _label(),
-                        jnp.ones(B)))
-    assert out[0][0]["T"].dtype == jnp.bfloat16
-    assert int(out[0][3]["tail_dense_steps"]) == 1
-    assert int(out[0][3]["distinct_rows"]) == 0
-    np.testing.assert_array_equal(
-        np.asarray(out[0][0]["T"], np.float32),
-        np.asarray(out[1][0]["T"], np.float32))
+def _assert_bf16_close(new, ref, touched):
+    """A bfloat16 table through both tails: bit-equal where no slot
+    pointed, and where one did the float32 result, rounded once on either
+    side, a bfloat16 ulp apart at most (and rarely)."""
+    a, b = np.asarray(new, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_array_equal(a[~touched], b[~touched])
+    np.testing.assert_allclose(a[touched], b[touched], rtol=2.0 ** -7)
+    assert (a[touched] == b[touched]).mean() > 0.99
+
+
+@pytest.mark.parametrize("width", [128, 164])
+def test_bfloat16_table_takes_the_distinct_tail(width):
+    """-halffloat: a bfloat16 table beside its float32 accumulators. The
+    rows the list names are read, widened to float32, updated and rounded
+    ONCE on the way back, where the dense pass rounds: same table on the
+    rows no slot points to, bit for bit, and on the touched ones to the
+    order of a duplicate's float32 sum."""
+    rows_n, n = 16384, 2048
+    rng = np.random.default_rng(width)
+    T = jnp.asarray(0.1 * rng.normal(size=(rows_n, width)), jnp.bfloat16)
+    gg = jnp.asarray(rng.random((rows_n, width)).astype(np.float32))
+    pool = rng.choice(rows_n, 150, replace=False)
+    rows = jnp.asarray(rng.choice(pool, n).astype(np.int32))
+    g = jnp.asarray(rng.normal(size=(n, width)).astype(np.float32))
+    cap = fm.tail_cap(n, rows_n, width, 2)
+    assert cap > 150
+    out = [jax.jit(lambda T, s, c=c: fm.rows_update(
+        T, s, rows, g, _opt(), 3.0, c))(T, {"gg": gg}) for c in (None, 0)]
+    (Tn, sn, stats), (Td, sd, dense_stats) = out
+    assert Tn.dtype == jnp.bfloat16 and sn["gg"].dtype == jnp.float32
+    assert {k: int(v) for k, v in stats.items()} == {
+        "tail_distinct_steps": 1, "tail_dense_steps": 0,
+        "distinct_rows": len(np.unique(np.asarray(rows)))}
+    assert int(dense_stats["tail_dense_steps"]) == 1
+    touched = np.zeros(rows_n, bool)
+    touched[np.asarray(rows)] = True
+    _assert_bf16_close(Tn, Td, touched)
+    assert not np.array_equal(np.asarray(Tn, np.float32)[touched],
+                              np.asarray(T, np.float32)[touched])
+    a, b = np.asarray(sn["gg"]), np.asarray(sd["gg"])
+    np.testing.assert_array_equal(a[~touched], b[~touched])
+    np.testing.assert_allclose(a[touched], b[touched], rtol=2e-6)
 
 
 # -- the row kernel (interpret mode here; compiled for a v5e by
@@ -303,15 +332,76 @@ def test_row_kernel_is_generic_over_the_state_leaves(name, reg, names):
 @pytest.mark.parametrize("case,rows,dtype,n_ids,match", [
     ("list_not_whole_id_tiles", 256, jnp.float32, 100, "multiple of 128"),
     ("list_longer_than_the_table", 128, jnp.float32, 256, "into a table"),
+    # what Mosaic refuses (tests/tpu_aot_worker.py ffm_joint_megastep), the
+    # kernel refuses by name; update_rows takes such tables through XLA
     ("rows_of_half_words", 256, jnp.bfloat16, 128, "32-bit"),
+    ("rows_of_164_lanes", 256, jnp.float32, 128, "128 lanes"),
 ])
 def test_row_kernel_refuses(case, rows, dtype, n_ids, match):
     from hivemall_tpu.ops.rows_pallas import update_rows
+    width = 164 if "164" in case else 128
     with pytest.raises(ValueError, match=match):
-        update_rows((jnp.zeros((rows, 128), dtype),),
+        update_rows((jnp.zeros((rows, width), dtype),),
                     jnp.zeros((n_ids,), jnp.int32), jnp.asarray(3),
-                    jnp.zeros((n_ids, 128)), 0.0, lambda b, g, t: b,
+                    jnp.zeros((n_ids, width)), 0.0, lambda b, g, t: b,
                     interpret=True)
+
+
+@pytest.mark.parametrize("cap,n_live", [(384, 0), (384, 1), (384, 200),
+                                        (384, 384), (8192, 5000)])
+def test_xla_rows_update_half_word_rows_of_164_lanes(cap, n_live,
+                                                     monkeypatch):
+    """The flagship's tables, a [R, 164] bfloat16 table beside a float32
+    leaf, through update_rows' XLA blocks (three blocks of 128 here; the
+    shipped 8192 in the last case): the live rows equal gather -> update ->
+    scatter to rounding, and every other row, a live row's SUBLANE PARTNER (the row
+    that shares its 32-bit words, r ^ 1) first of all, is bit-identical to
+    what went in. No trip runs past the count."""
+    from hivemall_tpu.ops import rows_pallas
+    if cap < 8192:
+        monkeypatch.setattr(rows_pallas, "XLA_BLOCK_ROWS", 128)
+    rows_total, width = 16384, 164
+    rng = np.random.default_rng(cap + n_live)
+    T = jnp.asarray(0.1 + rng.random((rows_total, width)), jnp.bfloat16)
+    gg = jnp.asarray((0.1 + rng.random((rows_total, width)))
+                     .astype(np.float32))
+    # even rows only, so that every live row's partner is a dead row
+    live = np.sort(rng.choice(rows_total // 2, n_live, replace=False)) * 2
+    ids = jnp.asarray(np.concatenate(
+        [live, rows_total + np.arange(cap - n_live)]).astype(np.int32))
+    g = np.zeros((cap, width), np.float32)
+    g[:n_live] = rng.normal(size=(n_live, width))
+    opt = _opt()
+
+    def fn(blocks, g, t):
+        w, s = opt.update(blocks[0].astype(jnp.float32), g,
+                          {"gg": blocks[1]}, t)
+        return w, s["gg"]
+    trips = []
+    real = jax.lax.fori_loop
+    monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, body, init:
+                        trips.append(hi) or real(lo, hi, body, init))
+    Tn, ggn = rows_pallas.update_rows(
+        (T, gg), ids, jnp.asarray(n_live, jnp.int32), jnp.asarray(g), 3.0,
+        fn)
+    block = 128 if cap < 8192 else 8192
+    assert [int(h) for h in trips] == [-(-n_live // block)]
+    want_T, want_gg = fn((T[live], gg[live]), g[:n_live],
+                         jnp.full((1, width), 3.0))
+    assert Tn.dtype == jnp.bfloat16 and ggn.dtype == jnp.float32
+    # (to rounding: the loop's fused update contracts a multiply-add)
+    np.testing.assert_allclose(
+        np.asarray(Tn[live], np.float32),
+        np.asarray(want_T.astype(jnp.bfloat16), np.float32), rtol=2.0 ** -7)
+    np.testing.assert_allclose(np.asarray(ggn[live]), np.asarray(want_gg),
+                               rtol=2e-6)
+    dead = np.ones(rows_total, bool)
+    dead[live] = False
+    for new, old in ((Tn, T), (ggn, gg)):
+        new, old = np.asarray(new, np.float32), np.asarray(old, np.float32)
+        np.testing.assert_array_equal(new[live + 1], old[live + 1])
+        np.testing.assert_array_equal(new[dead], old[dead])
+        assert not n_live or not np.array_equal(new[live], old[live])
 
 
 def test_step_with_the_row_kernel_matches_the_xla_rows(monkeypatch):
@@ -329,7 +419,142 @@ def test_step_with_the_row_kernel_matches_the_xla_rows(monkeypatch):
     assert int(new[3]["tail_distinct_steps"]) == 1
     for a, b in ((new[0]["T"], ref[0]["T"]),
                  (new[1]["T"]["gg"], ref[1]["T"]["gg"])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6,
+                                   atol=1e-7)
+
+
+# -- the FFM joint step through the same tail ---------------------------------
+
+FF, FK_, FB, FL, FR = 8, 4, 64, 16, 16384    # 1,024 slots, rows of 40 lanes
+
+
+def _ffm_state(dtype=jnp.bfloat16, seed=2):
+    rng = np.random.default_rng(seed)
+    W = FF * FK_ + 8
+    T = jnp.asarray(0.1 * rng.normal(size=(FR, W)), dtype)
+    gg = jnp.asarray(rng.random((FR, W)).astype(np.float32))
+    return ({"T": T, "w0": jnp.asarray(0.05, jnp.float32)},
+            {"T": {"gg": gg}, "w0": {"gg": jnp.asarray(0.5, jnp.float32)}})
+
+
+def _ffm_batch(kind, n_ids=120, seed=4):
+    """(step kwargs, batch args after t) of one [FB, FL] batch over
+    `n_ids` feature ids: field-major with unit values elided, field-major
+    with values, the pairs path with its field array, or the unit batch
+    with its second half padding (id 0, masked out)."""
+    rng = np.random.default_rng(seed)
+    idx = rng.choice(rng.choice(np.arange(1, 1 << 20), n_ids, replace=False),
+                     (FB, FL)).astype(np.int32)
+    mask = np.ones(FB, np.float32)
+    if kind == "padded":
+        idx[FB // 2:] = 0
+        mask[FB // 2:] = 0.0
+    val = (0.5 + rng.random((FB, FL))).astype(np.float32)
+    field = np.tile(np.arange(FL, dtype=np.int32) % FF, (FB, 1))
+    label, mask = _label(FB), jnp.asarray(mask)
+    idx = jnp.asarray(idx)
+    if kind in ("unit", "padded"):
+        return dict(fieldmajor=True, unit_val=True), (idx, label, mask)
+    if kind == "valued":
+        return dict(fieldmajor=True), (idx, jnp.asarray(val), label, mask)
+    return {}, (idx, jnp.asarray(val), label, mask, jnp.asarray(field))
+
+
+def _ffm_step(opt=None, **kw):
+    return fm.make_ffm_step_fused(get_loss("logloss"), opt or _opt(), LAMS,
+                                  FF, FK_, **kw)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["halffloat", "float32"])
+@pytest.mark.parametrize("kind", ["unit", "valued", "pairs", "padded"])
+def test_ffm_joint_step_distinct_tail_matches_its_dense_tail(kind, dtype):
+    kw, args = _ffm_batch(kind)
+    new, ref = (_ffm_step(distinct_tail=d, **kw)(*_ffm_state(dtype), 3.0,
+                                                 *args)
+                for d in (True, False))
+    rows = np.unique(np.asarray(fm.ffm_row_hash(args[0], FR)))
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": 1, "tail_dense_steps": 0,
+        "distinct_rows": len(rows)}
+    assert {k: int(v) for k, v in ref[3].items()} == {
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+    touched = np.zeros(FR, bool)
+    touched[rows] = True
+    assert new[0]["T"].dtype == dtype
+    if dtype == jnp.bfloat16:
+        _assert_bf16_close(new[0]["T"], ref[0]["T"], touched)
+    else:
+        a, b = np.asarray(new[0]["T"]), np.asarray(ref[0]["T"])
+        np.testing.assert_array_equal(a[~touched], b[~touched])
+        np.testing.assert_allclose(a[touched], b[touched], rtol=2e-6,
+                                   atol=1e-7)
+    a, b = np.asarray(new[1]["T"]["gg"]), np.asarray(ref[1]["T"]["gg"])
+    np.testing.assert_array_equal(a[~touched], b[~touched])
+    np.testing.assert_allclose(a[touched], b[touched], rtol=2e-6, atol=1e-7)
+    for a, b in ((new[0]["w0"], ref[0]["w0"]), (new[2], ref[2]),
+                 (new[1]["w0"]["gg"], ref[1]["w0"]["gg"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name,reg", [("adam", "no"), ("ftrl", "no"),
+                                      ("adagrad", "rda"), ("adagrad", "l2")])
+def test_an_optimizer_that_moves_a_zero_gradient_row_keeps_the_dense_tail(
+        name, reg):
+    """`train_ffm` takes any -opt: Adam decays its moments, FTRL and RDA
+    rebuild w from their sums at every t, a regularizer pulls on every
+    weight. Their dense pass visits every row, and still does: the step's
+    program holds no ranking."""
+    opt = make_optimizer(name, eta_scheme="inverse", eta0=0.1, reg=reg,
+                         lam=1e-3)
+    assert not opt.zero_grad_noop and _opt().zero_grad_noop
+    assert make_optimizer("sgd", reg="no").zero_grad_noop
+    kw, args = _ffm_batch("unit")
+    step = _ffm_step(opt, **kw)
+    params, _ = _ffm_state(jnp.float32)
+    state = {k: opt.init(v.shape) for k, v in params.items()}
+    text = step.lower(params, state, 3.0, *args).as_text()
+    assert "stablehlo.sort" not in text and "stablehlo.case" not in text
+    before = np.asarray(params["T"]).copy()
+    new = step(params, state, 3.0, *args)
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+    assert np.isfinite(np.asarray(new[0]["T"])).all()
+    assert not np.array_equal(np.asarray(new[0]["T"]), before)
+
+
+def test_ffm_megastep_of_four_equals_four_single_steps():
+    """The K-step scan runs the joint step's own core, stats included: one
+    step of the four falls through to the dense branch."""
+    kw = dict(fieldmajor=True, unit_val=True)
+    step = _ffm_step(**kw)
+    cap = fm.tail_cap(FB * FL, FR, FF * FK_ + 8, 2)
+    nds = (30, cap - 40, cap + 200, 90)
+    batches = [_ffm_batch("unit", nd, seed=30 + i)[1]
+               for i, nd in enumerate(nds)]
+    nv = np.asarray([FB, FB, FB - 5, FB], np.int32)
+    params, state = _ffm_state()
+    losses, stats = [], []
+    for i, (idx, label, _) in enumerate(batches):
+        mask = (jnp.arange(FB) < nv[i]).astype(jnp.float32)
+        params, state, ls, st = step(params, state, 7.0 + i, idx, label,
+                                     mask)
+        losses.append(float(ls))
+        stats.append({k: int(v) for k, v in st.items()})
+    mega = make_megastep(step.core)
+    p2, s2 = _ffm_state()
+    p2, s2, ls2, st2 = mega(
+        p2, s2, 7.0, jnp.asarray(nv), jnp.stack([b[0] for b in batches]),
+        None, jnp.stack([b[1] for b in batches]), None, None)
+    np.testing.assert_array_equal(np.asarray(ls2),
+                                  np.asarray(losses, np.float32))
+    for a, b in ((p2["T"], params["T"]), (s2["T"]["gg"], state["T"]["gg"]),
+                 (p2["w0"], params["w0"])):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    for name in fm.TAIL_STATS:
+        assert [int(v) for v in st2[name]] == [s[name] for s in stats]
+    assert [s["tail_distinct_steps"] for s in stats] == [1, 1, 0, 1]
 
 
 # -- the counters -------------------------------------------------------------
@@ -407,3 +632,59 @@ def test_mesh_trainer_keeps_the_dense_tail():
     assert t.cumulative_loss == t.cumulative_loss
     assert t._step_counts["tail_distinct_steps"] == 0
     assert t._step_counts["tail_dense_steps"] == t._t
+
+
+def _ffm_trainer_stream(n_batches, bsz, n_ids, dims, fields=8):
+    """Canonical field-major unit-valued rows (one feature a field, in
+    field order) over `n_ids` feature ids."""
+    from hivemall_tpu.io.sparse import SparseDataset
+    rng = np.random.default_rng(6)
+    n = n_batches * bsz
+    idx = rng.choice(rng.choice(np.arange(1, dims), n_ids, replace=False),
+                     (n, fields)).astype(np.int32)
+    fld = np.tile(np.arange(fields, dtype=np.int32), (n, 1))
+    lab = (rng.integers(0, 2, n) * 2 - 1).astype(np.float32)
+    return SparseDataset(idx.ravel(),
+                         np.arange(0, n * fields + 1, fields, dtype=np.int64),
+                         np.ones(n * fields, np.float32), lab, fld.ravel())
+
+
+_FFM_OPTS = ("-dims 1048576 -factors 4 -fields 8 -opt adagrad "
+             "-classification -halffloat -mini_batch 32")
+
+
+@pytest.mark.parametrize("k,pack", [(1, "off"), (4, "off"), (1, "on"),
+                                    (4, "on")])
+def test_tail_counters_fold_for_train_ffm(k, pack):
+    """`train_ffm`'s joint step feeds the counters `train_fm`'s does, on
+    each of its dispatch paths: the unit field-major step alone and under
+    the megastep, and the packed wire format's two wrappers (what a TPU
+    runs)."""
+    from hivemall_tpu.models.fm import FFMTrainer
+    from hivemall_tpu.obs.registry import registry
+    steps = 264                                    # one fold at 256, one asked
+    t = FFMTrainer(f"{_FFM_OPTS} -steps_per_dispatch {k} -pack_input {pack}")
+    assert fm.tail_cap(32 * 8, t.Mr, t.W, 2) == 256
+    t.fit_stream(_ffm_trainer_stream(steps, 32, 20, 1 << 20).batches(
+        32, shuffle=False))
+    assert t._t == steps and np.isfinite(t.cumulative_loss)
+    counts = dict(t._step_counts)
+    assert counts["tail_distinct_steps"] == steps
+    assert counts["tail_dense_steps"] == 0
+    assert steps < counts["distinct_rows"] <= 20 * steps
+    snap = registry.snapshot()
+    assert {n: snap["train"][n] for n in fm.TAIL_STATS} == counts
+    from hivemall_tpu.obs.report import summarize
+    assert f"tail:   distinct-row x{steps}  dense x0" in summarize(
+        [{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+
+
+def test_ffm_mesh_trainer_keeps_the_dense_tail():
+    from hivemall_tpu.models.fm import FFMTrainer
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    t = FFMTrainer(f"{_FFM_OPTS} -mesh dp=1,tp=2")
+    t.fit(_ffm_trainer_stream(4, 32, 20, 1 << 20))
+    assert t.cumulative_loss == t.cumulative_loss
+    assert t._step_counts["tail_distinct_steps"] == 0
+    assert t._step_counts["tail_dense_steps"] == t._t == 4

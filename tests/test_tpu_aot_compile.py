@@ -51,6 +51,17 @@ def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
     _compile("fm_minibatch_step", timeout=600)
 
 
+def test_ffm_joint_megastep_compiles_for_v5e_with_the_distinct_tail():
+    """The flagship's megastep at its cell's geometry ([4194304, 164]
+    bfloat16 table, float32 AdaGrad state, B=32768, L=39, fieldmajor, unit
+    values; PR 32), its tail through rows_update: `tail_cap` offers the
+    tail, one `conditional`, the scan and the blocks' loop, NO Mosaic
+    kernel (the compiler's verdict on 164-lane and half-word row copies,
+    learned here), no whole-table copy inside the scan, temporaries no
+    more than the dense tail's (~30 s of XLA compile)."""
+    _compile("ffm_joint_megastep", timeout=600)
+
+
 def test_state_initialiser_compiles_for_v5e_within_a_chip():
     """The fused tables' jitted initialiser at the size no one chip holds
     (PR 31): `train_ffm -dims 2^30 -halffloat` over tp=4, every output

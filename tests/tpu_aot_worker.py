@@ -138,6 +138,57 @@ def fm_minibatch_step():
     assert temp <= 2.85e9, f"{temp / 1e9:.2f} GB of temporaries"
 
 
+def ffm_joint_megastep():
+    """The megastep (two steps: a scan's body compiles once) of
+    make_ffm_step_fused at the geometry of the benchmark's cell
+    ffm_criteo_joint.stream (-dims 2^28 -fields 39 -factors 4 -halffloat:
+    a [4194304, 164] bfloat16 table and its float32 AdaGrad state,
+    B=32768, L=39, fieldmajor, unit values elided), its tail through
+    rows_update (PR 32). The megastep and not the one step: the chip keeps a
+    164-lane table transposed (`{0,1}`), every program relayouts it on the
+    way in and out, and the cell pays that once a dispatch.
+
+    Mosaic's verdict on this shape was learned here: it compiles no row
+    copy of a 164-lane array and no bfloat16 pair (ops/rows_pallas.py has
+    its words), so the distinct rows go through XLA's gather and scatter
+    in blocks: one `conditional` between the tails, two `while` (the scan
+    and the blocks' loop, whose trips follow the batch's count), no Mosaic
+    kernel, the four relayouts of the tables at the megastep's two ends
+    (the dense tail's program has the same four) and none inside the scan,
+    and no more temporaries than the dense tail's program: 15.03 GB read
+    against its 15.05 (both hold the dense branch's G; the figure counts
+    buffers the chip overlays, the cell peaks at 4.95 GB)."""
+    from hivemall_tpu.ops.scan import make_megastep
+    Fj, B, L, ks = 39, 32768, 39, 2
+    Mr, W = 1 << 22, Fj * K + 8
+    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                         reg="no")
+    step = fm.make_ffm_step_fused(get_loss("logloss"), opt, LAMS, Fj, K,
+                                  fieldmajor=True, unit_val=True)
+    T, gg = _sds((Mr, W), jnp.bfloat16), _sds((Mr, W), jnp.float32)
+    assert fm.tail_cap(B * L, Mr, W, 2), "the cell's shape must offer the tail"
+    assert not rows_pallas.kernel_takes((T, gg))
+    compiled = make_megastep(step.core).lower(
+        {"T": T, "w0": _sds((), jnp.float32)},
+        {"T": {"gg": gg}, "w0": {"gg": _sds((), jnp.float32)}},
+        _sds((), jnp.float32), _sds((ks,), jnp.int32),
+        _sds((ks, B, L), jnp.int32), None, _sds((ks, B), jnp.float32), None,
+        None).compile()
+    text = compiled.as_text()
+    conds, loops = text.count(" conditional("), text.count(" while(")
+    assert conds == 1, f"{conds} conditional op(s), expected one"
+    assert loops == 2, f"{loops} while op(s), expected the scan and the blocks"
+    kernels = text.count("tpu_custom_call")
+    assert kernels == 0, f"{kernels} Mosaic kernels in the XLA-rows tail"
+    copy = r"= (?:bf16|f32)\[%d,%d\]\S* copy\(" % (Mr, W)
+    everywhere = len(re.findall(copy, text))
+    at_the_ends = len(re.findall(copy, text[text.index("\nENTRY "):]))
+    assert everywhere == at_the_ends == 4, \
+        f"{everywhere} copies of a whole table, {at_the_ends} in ENTRY"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 15.1e9, f"{temp / 1e9:.2f} GB of temporaries"
+
+
 def state_init():
     """The fused tables' initialiser (models/fm.py `_fused_state_init`,
     the function `LearnerBase._make_state` jits) at the size no one chip
@@ -201,8 +252,8 @@ def hist_sorted():
 
 CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
                                  parts_step_sharded, fm_minibatch_step,
-                                 hist_flat, hist_dense, hist_sorted,
-                                 state_init)}
+                                 ffm_joint_megastep, hist_flat, hist_dense,
+                                 hist_sorted, state_init)}
 
 if __name__ == "__main__":
     for name in sys.argv[1:]:
