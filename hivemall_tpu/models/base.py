@@ -152,6 +152,9 @@ def learner_option_spec(name: str, *, classification: bool,
            help="keep float32 weights (default); unset-able via -halffloat")
     s.flag("halffloat", help="store weights as bfloat16 (HalfFloat analog)")
     s.flag("int_feature", help="features are integer indices, no hashing")
+    s.add("seed", type=int, default=42,
+          help="seed of whatever the trainer draws (initial factors; a "
+               "table that starts at zero draws nothing)")
     s.add("mesh", default=None,
           help="device mesh spec ('dp=2,tp=4' or 'auto'): run the train "
                "step GSPMD-sharded — batch over dp, weight tables over tp")
@@ -221,6 +224,13 @@ def _state_initialiser(init, sharding_leaves: tuple = (), treedef=None):
     return jax.jit(init, out_shardings=None if treedef is None else
                    jax.tree_util.tree_unflatten(treedef, sharding_leaves))
 
+
+#: ``w`` of a flat-table family (models/linear.py, models/classifier.py):
+#: the [dims] weight table under the name its older callers use, a view of
+#: ``params``, the one name every family keeps its model state under
+weight_table_view = property(
+    lambda self: self.params,
+    lambda self, table: setattr(self, "params", table))
 
 _STEP_BUILDER_CACHE: dict = {}
 
@@ -1261,19 +1271,13 @@ class LearnerBase:
 
     def _megastep_state(self) -> Tuple[Any, Any]:
         """(model-state, optimizer-state) pair threaded through the scan
-        carry. Covers the standard attribute names; trainers with other
-        state override this and `_set_megastep_state` as a pair."""
-        s1 = getattr(self, "params", None)
-        if s1 is None:
-            s1 = self.w
-        return s1, self.opt_state
+        carry: ``params`` and ``opt_state``, the names every scannable
+        family keeps its state under; trainers with other state override
+        this and `_set_megastep_state` as a pair."""
+        return self.params, self.opt_state
 
     def _set_megastep_state(self, s1, s2) -> None:
-        if getattr(self, "params", None) is not None:
-            self.params = s1
-        else:
-            self.w = s1
-        self.opt_state = s2
+        self.params, self.opt_state = s1, s2
 
     def _mega_field(self, mb):
         """Per-step field arrays for the megastep (FFM pairs path only —
@@ -1421,20 +1425,16 @@ class LearnerBase:
     # -- sparse weight access (mix delta exchange, O(touched) not O(dims)) ---
     def _weight_table(self):
         """The [dims] device weight array, or None when the trainer's state
-        is not a flat table (then sparse access falls back to O(dims))."""
-        w = getattr(self, "w", None)
-        if w is not None:
-            return w
+        is not a flat table (then sparse access falls back to O(dims)):
+        ``params`` itself for the linear families, its "w" leaf for FM."""
         p = getattr(self, "params", None)
-        if isinstance(p, dict) and "w" in p:
-            return p["w"]
-        return None
+        return p.get("w") if isinstance(p, dict) else p
 
     def _store_weight_table(self, t) -> None:
-        if getattr(self, "w", None) is not None:
-            self.w = t
-        else:
+        if isinstance(self.params, dict):
             self.params["w"] = t
+        else:
+            self.params = t
 
     def _get_weights_at(self, keys: np.ndarray) -> np.ndarray:
         import jax.numpy as jnp
@@ -1474,7 +1474,7 @@ class LearnerBase:
         The default covers the standard attribute names; trainers with other
         state override this and `_restore_arrays` as a pair."""
         tree = {}
-        for attr in ("w", "sigma", "params", "opt_state", "u", "gg"):
+        for attr in ("params", "opt_state"):
             if getattr(self, attr, None) is not None:
                 tree[attr] = getattr(self, attr)
         if not tree:

@@ -35,7 +35,7 @@ import numpy as np
 
 from ..io.sparse import SparseBatch, SparseDataset
 from ..utils.options import OptionSpec
-from .base import LearnerBase, learner_option_spec
+from .base import LearnerBase, learner_option_spec, weight_table_view
 from .linear import _sigmoid
 
 __all__ = ["PerceptronTrainer", "PassiveAggressiveTrainer", "PA1Trainer",
@@ -71,6 +71,9 @@ def _online_spec(name: str) -> OptionSpec:
                "reference semantics at minibatch dispatch rate)")
     s.add("iters", "iterations", type=int, default=1, help="epochs")
     s.flag("int_feature", help="features are integer indices")
+    s.add("seed", type=int, default=42,
+          help="as the shared grammar's (these tables start at zero and "
+               "one: nothing is drawn)")
     s.add("mix", default=None, help="mix cohort spec")
     s.add("mix_threshold", type=int, default=16)
     s.add("mix_session", default=None)
@@ -94,10 +97,21 @@ def _online_spec(name: str) -> OptionSpec:
 
 class _OnlineBase(LearnerBase):
     """Shared scaffolding: dense w (+ optional sigma) tables and a jitted
-    closed-form aggregated step built by `_rates`."""
+    closed-form aggregated step built by `_rates`. The weight table is
+    ``params``; what the update rule keeps beside it is ``opt_state``
+    (``{"sigma": ...}`` for the covariance learners, else empty)."""
 
     HAS_COVAR = False
     CLASSIFICATION = True
+    w = weight_table_view
+
+    @property
+    def sigma(self):
+        return (self.opt_state or {}).get("sigma")
+
+    @sigma.setter
+    def sigma(self, table) -> None:
+        self.opt_state = {} if table is None else {"sigma": table}
 
     @classmethod
     def spec(cls) -> OptionSpec:
@@ -105,7 +119,7 @@ class _OnlineBase(LearnerBase):
 
     def _init_state(self) -> None:
         dtype = jnp.bfloat16 if self.opts.halffloat else jnp.float32
-        self.w = jnp.zeros(self.dims, dtype)
+        self.params = jnp.zeros(self.dims, dtype)
         self.sigma = jnp.ones(self.dims, jnp.float32) if self.HAS_COVAR \
             else None
         mode = str(getattr(self.opts, "batch_mode", "aggregate"))
@@ -235,16 +249,16 @@ class _OnlineBase(LearnerBase):
         return step
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        self.w, self.sigma, loss = self._step(
-            self.w, self.sigma, batch.idx, batch.val, batch.label,
+        self.params, self.sigma, loss = self._step(
+            self.params, self.sigma, batch.idx, batch.val, batch.label,
             batch.row_mask)
         return loss
 
     def _finalized_weights(self) -> np.ndarray:
-        return np.asarray(self.w.astype(jnp.float32))
+        return np.asarray(self.params.astype(jnp.float32))
 
     def _load_weights(self, w: np.ndarray) -> None:
-        self.w = jnp.asarray(w, self.w.dtype)
+        self.params = jnp.asarray(w, self.params.dtype)
 
     def covar_table(self) -> Optional[np.ndarray]:
         return None if self.sigma is None else np.asarray(self.sigma)
@@ -432,10 +446,9 @@ class AdaGradRDATrainer(_OnlineBase):
     NAME = "train_adagrad_rda"
 
     def _init_state(self) -> None:
-        self.w = jnp.zeros(self.dims, jnp.float32)
-        self.sigma = None
-        self.u = jnp.zeros(self.dims, jnp.float32)
-        self.gg = jnp.zeros(self.dims, jnp.float32)
+        self.params = jnp.zeros(self.dims, jnp.float32)
+        self.opt_state = {"u": jnp.zeros(self.dims, jnp.float32),
+                          "gg": jnp.zeros(self.dims, jnp.float32)}
         self._step = self._shared_step("rda", self._make_rda_step)
 
     def _make_rda_step(self):
@@ -459,9 +472,11 @@ class AdaGradRDATrainer(_OnlineBase):
         return step
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        self.w, self.u, self.gg, loss = self._step(
-            self.w, self.u, self.gg, float(self._t), batch.idx, batch.val,
-            batch.label, batch.row_mask)
+        s = self.opt_state
+        self.params, u, gg, loss = self._step(
+            self.params, s["u"], s["gg"], float(self._t), batch.idx,
+            batch.val, batch.label, batch.row_mask)
+        self.opt_state = {"u": u, "gg": gg}
         return loss
 
 

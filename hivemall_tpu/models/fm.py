@@ -248,7 +248,6 @@ def _factor_spec(name: str, default_factors: int, default_opt: str
     s.add("lambda_v", type=float, default=0.01, help="L2 for latent factors")
     s.add("min_target", type=float, default=None, help="clip regression target")
     s.add("max_target", type=float, default=None, help="clip regression target")
-    s.add("seed", type=int, default=42, help="init seed")
     s.add("fm_table", default="auto",
           help="train_fm table layout: fused (one [N, K+pad] row per "
                "feature holding V and w — half the gather/scatter index "

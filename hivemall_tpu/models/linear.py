@@ -24,7 +24,8 @@ from ..io.sparse import SparseBatch, SparseDataset
 from ..ops.linear import make_linear_predict, make_linear_step
 from ..ops.losses import get_loss
 from ..ops.optimizers import make_optimizer_cached
-from .base import LearnerBase, sigmoid_np as _sigmoid
+from .base import (LearnerBase, sigmoid_np as _sigmoid,
+                   weight_table_view)
 
 __all__ = ["GeneralClassifier", "GeneralRegressor", "LogressTrainer",
            "AdaGradLogisticTrainer", "AdaDeltaLogisticTrainer"]
@@ -52,14 +53,27 @@ def _linear_step_cached(loss_name, opt_name, eta_scheme, eta0, total_steps,
                               total_steps, power_t, reg, lam, l1_ratio))
 
 
+@_lru_cache(maxsize=128)
+def _linear_state_init(optimizer, dims, dtype):
+    """The zero table and the optimizer's zero state as ONE function of no
+    arguments, the same object for the same configuration:
+    `LearnerBase._make_state` jits it, so a table of 2^28 entries is born
+    on the chip (or on the mesh, in its shards) and never on the host."""
+    def init():
+        return jnp.zeros(dims, dtype), optimizer.init(dims)
+    return init
+
+
 @_instrument("linear", "predict")
 @_lru_cache(maxsize=1)
 def _linear_predict_cached():
     return make_linear_predict()
 
 class _LinearLearner(LearnerBase):
+    """Shared machinery for dense-table linear trainers. The [dims] weight
+    table is ``params``, the optimizer's co-shaped arrays ``opt_state``."""
     UNIT_VAL_ELISION = True      # ops.linear.make_linear_step takes val=None
-    """Shared machinery for dense-table linear trainers."""
+    w = weight_table_view
 
     FIXED_LOSS: Optional[str] = None       # set by historical subclasses
     FIXED_OPT: Optional[str] = None
@@ -76,8 +90,8 @@ class _LinearLearner(LearnerBase):
                    o.power_t, str(o.reg), o["lambda"], o.l1_ratio)
         self.optimizer = make_optimizer_cached(*opt_key)
         dtype = jnp.bfloat16 if o.halffloat else jnp.float32
-        self.w = jnp.zeros(self.dims, dtype)
-        self.opt_state = self.optimizer.init(self.dims)
+        self.params, self.opt_state = self._make_state(
+            _linear_state_init(self.optimizer, self.dims, dtype))
         self._step = _linear_step_cached(loss_name, *opt_key)
         self._predict = _linear_predict_cached()
 
@@ -88,8 +102,8 @@ class _LinearLearner(LearnerBase):
         return super()._convert_label(label)
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        self.w, self.opt_state, loss_sum = self._step(
-            self.w, self.opt_state, float(self._t),
+        self.params, self.opt_state, loss_sum = self._step(
+            self.params, self.opt_state, float(self._t),
             batch.idx, batch.val, batch.label, batch.row_mask)
         return loss_sum
 
@@ -98,14 +112,14 @@ class _LinearLearner(LearnerBase):
         finalization expression; _finalized_weights and the sharded
         margin fn must never diverge (the online/offline bit-match
         hangs on it)."""
-        return self.optimizer.finalize(self.w.astype(jnp.float32),
+        return self.optimizer.finalize(self.params.astype(jnp.float32),
                                        self.opt_state)
 
     def _finalized_weights(self) -> np.ndarray:
         return np.asarray(self._finalize_device())
 
     def _load_weights(self, w: np.ndarray) -> None:
-        self.w = jnp.asarray(w, self.w.dtype)
+        self.params = jnp.asarray(w, self.params.dtype)
 
     # -- scoring (the predict-is-a-join path, SURVEY.md §4.2) ---------------
     def _make_margin_fn(self):

@@ -43,21 +43,28 @@ def make_linear_step(loss: Loss, optimizer: Optimizer) -> Callable:
     # copying them every minibatch — O(dims) tables; the copy, not the
     # math, dominates at -dims 2^24) and -steps_per_dispatch > 1 runs the
     # SAME function as a lax.scan body (ops.scan.make_megastep) with the
-    # state threaded through the donated scan carry
+    # state threaded through the donated scan carry. The phases carry the
+    # scopes ops/fm.py's steps carry (ops/scan.py SCOPES): hm.gather the
+    # margin's gather, hm.grad loss and dloss, hm.scatter the dense
+    # gradient's zeroing and scatter-add, hm.update the optimizer's pass
     def core(w, opt_state, t, idx, val, label, row_mask):
         wf = w.astype(jnp.float32)
-        if val is None:
-            # unit-value elision (io.sparse.SparseBatch): categorical rows
-            # never transfer the val array; rebuild it from idx on device.
-            # None is static under jit, so this is a separate compiled
-            # variant, not a runtime branch.
-            val = (idx != 0).astype(jnp.float32)
-        margin = linear_margin(wf, idx, val)
-        d = loss.dloss(margin, label) * row_mask            # [B]
-        g = jnp.zeros_like(wf).at[idx.ravel()].add(
-            (d[:, None] * val).ravel())                     # dense [N] grad
-        w_new, opt_state = optimizer.update(wf, g, opt_state, t)
-        loss_sum = (loss.loss(margin, label) * row_mask).sum()
+        with jax.named_scope("hm.gather"):
+            if val is None:
+                # unit-value elision (io.sparse.SparseBatch): categorical
+                # rows never transfer the val array; rebuild it from idx on
+                # device. None is static under jit, so this is a separate
+                # compiled variant, not a runtime branch.
+                val = (idx != 0).astype(jnp.float32)
+            margin = linear_margin(wf, idx, val)
+        with jax.named_scope("hm.grad"):
+            d = loss.dloss(margin, label) * row_mask            # [B]
+            loss_sum = (loss.loss(margin, label) * row_mask).sum()
+        with jax.named_scope("hm.scatter"):
+            g = jnp.zeros_like(wf).at[idx.ravel()].add(
+                (d[:, None] * val).ravel())                     # dense [N]
+        with jax.named_scope("hm.update"):
+            w_new, opt_state = optimizer.update(wf, g, opt_state, t)
         return w_new.astype(w.dtype), opt_state, loss_sum
 
     return scannable(partial(jax.jit, donate_argnums=(0, 1))(core), core)

@@ -40,14 +40,15 @@ import jax.numpy as jnp
 __all__ = ["scannable", "make_megastep", "megastep_for", "SCOPES"]
 
 # Phase scopes (jax.named_scope) name the step's device time from inside:
-# hm.gather, hm.grad, hm.scatter, hm.update in the step bodies (ops/fm.py)
-# and hm.scan around the scan here. A scope is metadata, and the persistent
-# compile cache's key leaves metadata out: a program compiled before its
-# scopes changed is served from the cache with the OLD names in its trace.
-# So the vocabulary's version is part of the megastep's name, which the key
-# does hold. Bump it in a change that alters scopes and nothing else of the
-# program (a change to the program itself gets a new key anyway).
-SCOPES = "hm1"
+# hm.gather, hm.grad, hm.scatter, hm.update in the step bodies (ops/fm.py,
+# ops/linear.py) and hm.scan around the scan here. A scope is metadata, and
+# the persistent compile cache's key leaves metadata out: a program compiled
+# before its scopes changed is served from the cache with the OLD names in
+# its trace. So the vocabulary's version is part of the megastep's name,
+# which the key does hold. Bump it in a change that alters scopes and
+# nothing else of the program (a change to the program itself gets a new
+# key anyway).
+SCOPES = "hm2"
 
 
 def scopes_in_name(fn):

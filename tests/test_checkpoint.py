@@ -97,7 +97,7 @@ def test_rda_resume_keeps_dual_accumulators(tmp_path):
     first.save_bundle(str(p))
     second = AdaGradRDATrainer(opts)
     second.load_bundle(str(p))
-    assert float(np.abs(np.asarray(second.gg)).sum()) > 0
+    assert float(np.abs(np.asarray(second.opt_state["gg"])).sum()) > 0
     for f, lab in zip(feats[48:], y[48:]):
         second.process(f, lab)
     res_rows = dict(second.close())

@@ -70,6 +70,15 @@ def test_state_initialiser_compiles_for_v5e_within_a_chip():
     _compile("state_init", timeout=600)
 
 
+def test_linear_megastep_compiles_for_v5e_within_a_chip():
+    """`train_classifier -loss logloss -opt adagrad -dims 2^28` (the cell
+    logreg_criteo.stream, PR 33): the state's jitted initialiser (three
+    float32 [2^28] arrays, 3.22 GB) and the megastep (B=32768, L=39, unit
+    values elided), every phase scope in the compiled text, state and
+    temporaries inside one chip (~20 s of XLA compile)."""
+    _compile("linear_megastep", timeout=600)
+
+
 @pytest.mark.slow
 def test_whole_sharded_step_and_sorted_histogram_compile_for_v5e():
     """The whole make_parts_step_sharded program (~85 s of XLA compile)

@@ -279,11 +279,18 @@ def _ffm(extra):
                       f"-opt adagrad -classification {extra}")
 
 
+def _rda():
+    from hivemall_tpu.models.linear import GeneralClassifier
+    return GeneralClassifier("-loss logloss -opt adagrad -dims 4096 "
+                             "-mini_batch 64")
+
+
 @pytest.mark.parametrize("make,step", [
     (lambda: _fm("-opt adagrad"), "_step"),                # minibatch
     (lambda: _fm("-opt adagrad -fm_update occurrence"), "_step"),  # fused
     (lambda: _ffm(""), "_step_fm_unit"),                   # the flagship's
-], ids=["fm_minibatch", "fm_fused", "ffm_fused"])
+    (lambda: _rda(), "_step"),           # ops/linear.py, AdaGrad-RDA (PR 33)
+], ids=["fm_minibatch", "fm_fused", "ffm_fused", "linear_rda"])
 def test_megastep_lowering_names_the_phases(make, step):
     import jax.numpy as jnp
     from hivemall_tpu.ops.scan import SCOPES, megastep_for

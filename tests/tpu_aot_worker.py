@@ -222,6 +222,49 @@ def state_init():
     assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
 
 
+def linear_megastep():
+    """train_classifier -loss logloss -opt adagrad (AdaGrad-RDA) at the
+    geometry of the benchmark's cell logreg_criteo.stream (-dims 2^28:
+    `w`, `u`, `gg` three float32 [2^28] arrays; B=32768, L=39, unit values
+    elided): the state's jitted initialiser (models/linear.py
+    `_linear_state_init`, what `LearnerBase._make_state` jits) and the
+    megastep (two steps: a scan's body compiles once). The state is 3.22
+    GB and stays inside one chip with the step's temporaries, each of
+    the four phase scopes names ops of the step, and no Mosaic kernel is
+    in it."""
+    from hivemall_tpu.models.linear import _linear_state_init
+    from hivemall_tpu.ops.linear import make_linear_step
+    from hivemall_tpu.ops.scan import make_megastep
+    dims, B, L, ks = 1 << 28, 32768, 39, 2
+    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                         power_t=0.1, reg="rda", lam=1e-6)
+    assert opt.name == "adagrad_rda"
+    init = jax.jit(_linear_state_init(opt, dims, jnp.float32),
+                   out_shardings=(DEV0, {"u": DEV0, "gg": DEV0})).lower() \
+        .compile().memory_analysis()
+    assert 0 <= init.output_size_in_bytes - 3 * 4 * dims < 4096 \
+        and init.temp_size_in_bytes == 0, init
+    table = _sds((dims,), jnp.float32)
+    compiled = make_megastep(
+        make_linear_step(get_loss("logloss"), opt).core, none_val=True
+    ).lower(table, {"u": table, "gg": table}, _sds((), jnp.float32),
+            _sds((ks,), jnp.int32), _sds((ks, B, L), jnp.int32), None,
+            _sds((ks, B), jnp.float32), None, None).compile()
+    text = compiled.as_text()
+    for scope in ("hm.gather", "hm.grad", "hm.scatter", "hm.update"):
+        assert f"/{scope}/" in text, f"no op under {scope}"
+    assert "tpu_custom_call" not in text
+    mem = compiled.memory_analysis()
+    # donated: the three arrays are updated in place. The dense gradient
+    # needs no array of its own: RDA rebuilds `w` from `u` and `gg`, so
+    # after the margin's gather the compiler scatters into `w`'s buffer
+    # (5.9 MB of temporaries read here, not 1.07 GB)
+    assert mem.alias_size_in_bytes >= 3 * 4 * dims
+    assert mem.temp_size_in_bytes < 4 * dims, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+
+
 N, D, BINS = 1 << 20, 28, 64            # HIGGS-shaped trees, cut to 1M rows
 
 
@@ -253,7 +296,7 @@ def hist_sorted():
 CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
                                  parts_step_sharded, fm_minibatch_step,
                                  ffm_joint_megastep, hist_flat, hist_dense,
-                                 hist_sorted, state_init)}
+                                 hist_sorted, state_init, linear_megastep)}
 
 if __name__ == "__main__":
     for name in sys.argv[1:]:
