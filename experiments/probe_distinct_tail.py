@@ -149,6 +149,38 @@ def device_ops(trace_dir: str, steps: int) -> dict:
                            for k, v in red["device_ops"]]}
 
 
+def time_step(geo, call, params, state, batch, tag: str):
+    """One reading of `call` on `batch`: 3 calls each waited for (the first
+    of a program compiles: `first_call_s`), ms a step on the host's clock
+    around 10 calls ended by `block_until_ready`, then the device's
+    operations of 4 traced calls. Returns (params, state, reading)."""
+    from harness import xplane
+    t0 = time.perf_counter()
+    for i in range(3):
+        params, state, ls, stats = call(params, state, *batch)
+        jax.block_until_ready(ls)
+        if not i:
+            first_call_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(10):
+        params, state, ls, stats = call(params, state, *batch)
+    jax.block_until_ready(ls)
+    ms = 1e2 * (time.perf_counter() - t0) / geo.steps
+    trace_dir = os.path.join(ROOT, "chiprun_out", "probe_trace",
+                             f"{geo.name}_{tag}")
+    xplane.start(trace_dir)
+    for _ in range(4):
+        params, state, ls, stats = call(params, state, *batch)
+    jax.block_until_ready(ls)
+    jax.profiler.stop_trace()
+    reading = {"step_ms": round(ms, 3),
+               "first_call_s": round(first_call_s, 1),
+               "stats": {k: int(v) for k, v in stats.items()},
+               **device_ops(trace_dir, 4 * geo.steps)}
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return params, state, reading
+
+
 def programs(geo):
     """(name, forced capacity or None for the shipped rule, the XLA rows'
     block or None for the module's, [distinct rows asked])."""
@@ -173,7 +205,6 @@ def programs(geo):
 
 
 def main() -> int:
-    from harness import xplane
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not TINY:
         print(f"needs a TPU, found {dev.platform}", file=sys.stderr)
@@ -197,39 +228,17 @@ def main() -> int:
             rows_pallas.XLA_BLOCK_ROWS = (block or shipped_block
                                           if block != 0 else N)
             call = geo.program()
-            first = True
-            for nd in nds:
+            for i, nd in enumerate(nds):
                 idx = jnp.asarray(geo.ids(rng, nd))
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    params, state, ls, stats = call(params, state, idx,
-                                                    label, mask)
-                    jax.block_until_ready(ls)
-                    if first:
-                        compile_s, first = time.perf_counter() - t0, False
-                t0 = time.perf_counter()
-                for _ in range(10):
-                    params, state, ls, stats = call(params, state, idx,
-                                                    label, mask)
-                jax.block_until_ready(ls)
-                ms = 1e2 * (time.perf_counter() - t0) / geo.steps
-                trace_dir = os.path.join(ROOT, "chiprun_out", "probe_trace",
-                                         f"{geo.name}_{name}_{nd}")
-                xplane.start(trace_dir)
-                for _ in range(4):
-                    params, state, ls, stats = call(params, state, idx,
-                                                    label, mask)
-                jax.block_until_ready(ls)
-                jax.profiler.stop_trace()
+                params, state, reading = time_step(
+                    geo, call, params, state, (idx, label, mask),
+                    f"{name}_{nd}")
+                if i:                   # the program's first call compiled
+                    reading["first_call_s"] = out[-1]["first_call_s"]
                 rec = {"geometry": geo.name, "variant": name,
                        "cap": (shipped_cap(N, R, geo.W, geo.itemsize)
                                if cap is None else cap),
-                       "xla_block": block, "n_distinct_asked": nd,
-                       "step_ms": round(ms, 3),
-                       "first_call_s": round(compile_s, 1),
-                       "stats": {k: int(v) for k, v in stats.items()},
-                       **device_ops(trace_dir, 4 * geo.steps)}
-                shutil.rmtree(trace_dir, ignore_errors=True)
+                       "xla_block": block, "n_distinct_asked": nd, **reading}
                 print(json.dumps(rec), flush=True)
                 out.append(rec)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)

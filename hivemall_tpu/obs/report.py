@@ -157,12 +157,16 @@ def _render(state: _TailState, path: str = "",
     snap = state.snapshot
     tail = (snap or {}).get("train") or {}
     if tail.get("tail_distinct_steps") or tail.get("tail_dense_steps"):
-        # which tail the minibatch step took (ops/fm.py rows_update)
+        # which tail the minibatch step took (ops/fm.py rows_update), and
+        # on how many steps the gather in front read through the batch's
+        # distinct rows too (gather_rows)
         steps = tail["tail_distinct_steps"] + tail["tail_dense_steps"]
         out.append(
             f"tail:   distinct-row x{tail['tail_distinct_steps']}  "
             f"dense x{tail['tail_dense_steps']}  distinct rows/step "
-            f"{tail.get('distinct_rows', 0) / max(1, steps):.0f}")
+            f"{tail.get('distinct_rows', 0) / max(1, steps):.0f}  "
+            f"gather on the distinct rows x"
+            f"{tail.get('gather_compact_steps', 0)}")
     stages = (roll or {}).get("stages") \
         or ((snap or {}).get("spans") if snap else None)
     if stages:

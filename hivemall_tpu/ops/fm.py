@@ -15,7 +15,9 @@ the dense tables — the batched analog of the reference's per-entry AdaGrad
 cell updates.
 
 The fused/minibatch step bodies wrap their phases in ``jax.named_scope``
-with one family-neutral vocabulary — ``hm.gather`` (the table-row gather),
+with one family-neutral vocabulary — ``hm.gather`` (the table-row gather, with
+the compact table's fill and the slots' ranks where it reads through the
+batch's distinct rows),
 ``hm.grad`` (unpack, forward, loss, backward), ``hm.scatter`` (zeros +
 scatter-add into G, dense or compact with the ranking it needs, or the
 sparse variants' per-occurrence chain) and ``hm.update`` (the optimizer's
@@ -27,7 +29,7 @@ so a profiler trace reduces device time by phase, not by ``fusion.48``
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Dict, NamedTuple, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -359,7 +361,9 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     T [Mr, F*K + 8] holding every field's latent vector AND the linear
     weight of one hashed feature per row:
 
-      1. one gather  T[rows]            -> [B, L, 672B] slabs
+      1. one gather  T[rows]            -> [B, L, 672B] slabs (read
+         through the batch's distinct rows where the tail ranks them:
+         gather_rows)
       2. pair mixing (one-hot einsum; or, with fieldmajor=True over
          canonical batches, the static field-grouped form — pure VPU,
          no L^2 intermediate: _fused_phi_fieldmajor)
@@ -379,7 +383,8 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     assignment: slot s -> field s % F).
 
     Returns (params, opt_state, loss_sum, stats): stats counts which tail
-    ran and the batch's distinct rows (TAIL_STATS).
+    ran, the batch's distinct rows and whether the gather read through
+    them (TAIL_STATS).
 
     Semantics delta vs the reference's per-entry updates (documented):
     AdaGrad-family accumulators see the SQUARE OF THE SUMMED minibatch
@@ -394,7 +399,9 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
         W = T.shape[1]
         with jax.named_scope("hm.gather"):
             rows = ffm_row_hash(idx, T.shape[0])
-            slab = T[rows]                           # ONE gather, own dtype
+        ranks = rank_rows(rows.reshape(-1), T, opt_state["T"], optimizer,
+                          None if distinct_tail else 0)
+        slab, compact = gather_rows(T, rows, ranks)  # own dtype
 
         def batch_loss(w0f, slabf):
             if fieldmajor:
@@ -421,8 +428,8 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
         with jax.named_scope("hm.scatter"):      # the slab's relayout too
             rows, gslab = rows.reshape(-1), gslab.reshape(-1, W)
         Tn, sT, stats = rows_update(T, opt_state["T"], rows, gslab,
-                                    optimizer, t,
-                                    None if distinct_tail else 0)
+                                    optimizer, t, ranks)
+        stats["gather_compact_steps"] = compact
         with jax.named_scope("hm.update"):
             w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
                                        opt_state["w0"], t)
@@ -505,15 +512,18 @@ def _fm_packed_phi(w0f, s128, sub, val, K: int, Wf: int, P: int, l2=None):
 
 
 def _fm_packed_grad(loss: Loss, params, idx, val, label, row_mask, lams,
-                    l2_on: bool, K: int, Wf: int, P: int):
+                    l2_on: bool, K: int, Wf: int, P: int, rank=None):
     """The front half both packed steps share: ONE gather of 128-lane
     rows, then loss and gradient wrt w0 and the PACKED rows — the lane
     mask's adjoint IS the expansion to the packed row, so the gradient
     arrives whole-row for the scatter-add: no separate expand pass, no
     hidden per-slot gather/scatter, no relayout (_fm_packed_phi).
     Per-occurrence L2 (lam_w, lam_v) rides the same backward pass; lam0
-    is added to g0. Returns (rows [L*B], loss_sum, g0, g128 [L*B, P*Wf]
-    float32), slot-major."""
+    is added to g0. ``rank`` (the minibatch step's: rows [L*B] ->
+    `rank_rows` of them) ranks the slots in front of the gather, which
+    then reads through the batch's distinct rows (`gather_rows`). Returns
+    (rows [L*B], ranks or None, whether the gather read through them,
+    loss_sum, g0, g128 [L*B, P*Wf] float32), slot-major."""
     lam0, lam_w, lam_v = lams
     if val is None:
         # unit-value elision (io.sparse.SparseBatch): categorical
@@ -524,7 +534,8 @@ def _fm_packed_grad(loss: Loss, params, idx, val, label, row_mask, lams,
     with jax.named_scope("hm.gather"):
         idx, val = idx.T, val.T                      # slot-major [L, B]
         rows, sub = idx // P, idx % P
-        slab128 = T[rows]                            # ONE 128-lane gather
+    ranks = rank(rows.reshape(-1)) if rank else None
+    slab128, compact = gather_rows(T, rows, ranks)   # ONE 128-lane gather
     pm = (val != 0).astype(jnp.float32) * row_mask
     l2 = (lam_w, lam_v, pm) if l2_on else None
 
@@ -542,7 +553,8 @@ def _fm_packed_grad(loss: Loss, params, idx, val, label, row_mask, lams,
         # 63.5 ms a step without, 59.0 with; chip, PERF.md PR 25)
         g128 = jax.lax.optimization_barrier(g128.astype(jnp.float32))
         g0 = g0 + lam0 * w0f
-    return rows.reshape(-1), loss_sum, g0, g128.reshape(-1, P * Wf)
+    return (rows.reshape(-1), ranks, compact, loss_sum, g0,
+            g128.reshape(-1, P * Wf))
 
 
 def make_fm_score_fused(K: int):
@@ -589,7 +601,7 @@ def make_fm_step_fused(loss: Loss, optimizer: Optimizer,
     def body(params, opt_state, t, idx, val, label, row_mask, lams):
         lams = (lams[0], lams[1], lams[2]) if dyn else lambdas
         T, w0 = params["T"], params["w0"]
-        rows, loss_sum, g0, g128 = _fm_packed_grad(
+        rows, _, _, loss_sum, g0, g128 = _fm_packed_grad(
             loss, params, idx, val, label, row_mask, lams,
             dyn or bool(lams[1] or lams[2]), K, Wf, P)
 
@@ -655,7 +667,11 @@ _TAIL_COSTS = {
     (164, 2): _TailCost(7.97, 1.54, 179.0),
 }
 
-TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows")
+#: what a minibatch step counts about itself: the first three rows_update's
+#: (which tail ran, the distinct rows of a step that ranked), the last
+#: gather_rows' (whether the gather read through the distinct rows)
+TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows",
+              "gather_compact_steps")
 
 
 def _tail_cost(W: int, itemsize: int) -> _TailCost:
@@ -689,13 +705,149 @@ def tail_cap(n: int, R: int, W: int = 128, itemsize: int = 4) -> int:
             or cap // LIST_MULTIPLE * LIST_MULTIPLE)
 
 
-def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
+# --- the gather through the distinct rows --------------------------------------
+# XLA's row gather costs by the ROW and by where its operand lives: 10.2 ns a
+# row out of HBM, out of the 2 GiB table as out of a 145 MB one, and 1.8-2.0
+# ns out of an operand the compiler keeps in the chip's fast memory (`S(1)`
+# in the compiled text; 3.2 ns a row of 164 bfloat16 lanes; a v5e,
+# experiments/probe_compact_gather.py, PERF.md section 6, PR 34). A batch of
+# the benchmark's cells reads each of its ~73k distinct rows 17.5 times over,
+# so gather_rows reads them out of the table once, into a compact table that
+# fits there, and the 1,277,952 slots out of that.
+
+#: bytes of the compact table: what the v5e compiler still keeps in fast
+#: memory beside the step's other residents (it does at 112 MiB and no
+#: longer at 116, the FM step compiled here; tests/tpu_aot_worker.py holds
+#: that the cells' tables are placed there; left in HBM, at the ranking's
+#: 282,624 rows, the FM step LOSES 2.3 ms to the direct gather). 196,608
+#: rows of 128 float32 lanes or of 164 bfloat16 ones, which are padded to
+#: 256.
+COMPACT_TABLE_BYTES = 96 << 20
+#: the most distinct rows one trip of the compact table's fill reads (the
+#: whole step at 1024 / 2048 / 4096 / 8192 / 32768: FM 33.93 / 33.88 /
+#: 34.19 / 34.15 / 34.84 ms, the flagship's megastep of two 84.68 / 84.69 /
+#: 84.65 / 84.64 / 86.24 with a stable sort; one trip of the whole capacity
+#: leaves the table in HBM, 46.83)
+FILL_BLOCK_ROWS = 2048
+
+
+def gather_cap(cap: int, W: int, itemsize: int) -> int:
+    """Most distinct rows `gather_rows` reads through: the ranking's
+    capacity, or the rows of [., W] the compact table's bytes hold, whole
+    id tiles of them."""
+    row_bytes = -(-W // 128) * 128 * itemsize
+    return min(cap, COMPACT_TABLE_BYTES // row_bytes // LIST_MULTIPLE
+               * LIST_MULTIPLE)
+
+
+class RowRanks(NamedTuple):
+    """A batch's slots ranked by table row (`rank_rows`), for the gather in
+    front of the step and the tail behind it."""
+    srows: jax.Array        #: [n] the slots' rows in order
+    perm: jax.Array         #: [n] the slot each came from
+    rank: jax.Array         #: [n] a SORTED slot's rank among the distinct rows
+    urows: jax.Array        #: [cap] the distinct rows in order, then ids >= R
+    n_distinct: jax.Array   #: int32 scalar
+
+
+def rank_rows(rows, T, state, optimizer: Optimizer, cap=None):
+    """Rank the slots ``rows`` [n] of a batch by table row, once, for
+    `gather_rows` and `rows_update`; None where the step takes the dense
+    tail whatever the batch holds, and then has no ranking in its program.
+
+    ``cap`` (None: tail_cap of T [R, W] and the ``state`` leaves' shapes, 0
+    for an optimizer that moves a zero-gradient row) is static: the most
+    distinct rows the ranking lists. ONE key-value sort of (rows, iota)
+    gives the rows in order and the slot each came from; a flag where the
+    sorted row changes counts the distinct rows, and its running sum is
+    each sorted slot's rank; the flagged row ids, sorted to the front, are
+    the distinct rows, padded with out-of-range ids (the last two only for
+    a batch within the capacity: no other is read through them). All of it
+    is the tail's own and stays under ``hm.scatter``."""
+    n, (R, W) = rows.shape[0], T.shape
+    if cap is None:
+        leaves = jax.tree_util.tree_leaves(state)
+        cap = tail_cap(n, R, W, min(a.dtype.itemsize for a in (T, *leaves))
+                       ) if optimizer.zero_grad_noop else 0
+    if not cap:
+        return None
+    with jax.named_scope("hm.scatter"):
+        slot = jnp.arange(n, dtype=jnp.int32)
+        srows, perm = jax.lax.sort_key_val(rows, slot)
+        first = jnp.concatenate(
+            [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
+        n_distinct = first.sum(dtype=jnp.int32)
+
+    def listed():
+        with jax.named_scope("hm.scatter"):
+            return (jnp.cumsum(first.astype(jnp.int32)) - 1,
+                    jnp.sort(jnp.where(first, srows, R + slot))[:cap])
+    # a batch over the capacity reads neither: its step stays the dense
+    # tail's, which is fed the sorted rows alone (1.9 ms less)
+    rank, urows = jax.lax.cond(
+        n_distinct <= cap, listed,
+        lambda: (jnp.zeros((n,), jnp.int32), jnp.zeros((cap,), jnp.int32)))
+    return RowRanks(srows, perm, rank, urows, n_distinct)
+
+
+def gather_rows(T, rows, ranks: Optional[RowRanks]):
+    """``T[rows]`` (rows of any shape), bit for bit, and whether it was
+    read through the batch's distinct rows (an int32 scalar, the step's
+    ``gather_compact_steps``): where `rank_rows` ranked them and the batch
+    holds at most `gather_cap` of them, the distinct rows are read out of
+    the table ONCE into a compact C [gather_cap, W], and every slot reads
+    C at its row's rank.
+
+    The rank of a SLOT is the sorted slots' rank carried back by one more
+    key-value sort, of (perm, rank): an int32 scatter of as many scalars
+    costs seven times that. C is filled a block a trip up to the count,
+    so that the fill costs by the rows a batch holds and not by the
+    capacity (rows of C past the count are never read: a rank is under
+    the count). A batch over the capacity reads the table directly, as a
+    step without ranking does. The ``cond`` is outside ``hm.gather`` and
+    its branches inside, as the tail's: a trace reader that took the
+    ``cond`` for an operation under a scope would count the gather twice."""
+    def direct():
+        with jax.named_scope("hm.gather"):
+            return T[rows]
+
+    cap = gather_cap(ranks.urows.shape[0], T.shape[1], T.dtype.itemsize) \
+        if ranks is not None else 0
+    if not cap:
+        return direct(), jnp.zeros((), jnp.int32)
+    tb = min(cap, FILL_BLOCK_ROWS)
+
+    def compact():
+        with jax.named_scope("hm.gather"):
+            # (a permutation's keys are distinct: a stable sort would
+            # carry a third operand to break ties that cannot occur,
+            # 2.41 ms against 1.6)
+            _, rank_of_slot = jax.lax.sort_key_val(ranks.perm, ranks.rank,
+                                                   is_stable=False)
+
+            def block(i, C):
+                # the last block of a capacity that is not whole blocks
+                # ends at the capacity and reads some rows a second time
+                at = jnp.minimum(i * tb, cap - tb)
+                ids = jax.lax.dynamic_slice_in_dim(ranks.urows, at, tb)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    C, T.at[ids].get(mode="clip"), at, 0)
+            C = jax.lax.fori_loop(
+                0, (ranks.n_distinct + tb - 1) // tb, block,
+                jnp.zeros((cap, T.shape[1]), T.dtype))
+            return C[rank_of_slot.reshape(rows.shape)]
+    fits = ranks.n_distinct <= cap
+    return jax.lax.cond(fits, compact, direct), fits.astype(jnp.int32)
+
+
+def rows_update(T, state, rows, g, optimizer: Optimizer, t,
+                ranks: Optional[RowRanks]):
     """The tail of a minibatch step: apply ``optimizer.update`` with the
     gradient rows ``g`` [n, W] (float32) summed by table row ``rows`` [n]
     into T [R, W] and its co-shaped ``state``, whose leaves may be of
     another dtype than T (a bfloat16 table with float32 accumulators).
     Returns (T, state, stats), stats a dict of int32 scalars named by
-    TAIL_STATS. Knows nothing of what a row holds.
+    TAIL_STATS[:3]. Knows nothing of what a row holds.
 
     For an optimizer whose update leaves a zero-gradient entry as it was
     (``zero_grad_noop``: AdaGrad and SGD with reg='no', which factor
@@ -704,31 +856,24 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
     sum. Either way a row of T is widened to float32, updated there and
     rounded to T's dtype once.
 
-      1. rank: ONE key-value sort of (rows, iota) gives the rows in order
-         and the slot each came from; a flag where the sorted row changes
-         counts the distinct rows, and its running sum is each sorted
-         slot's rank.
+      1. ``ranks``: `rank_rows` of the same ``rows``, computed in front of
+         the step's gather, which reads through it too.
       2. Gc = zeros([cap, W]).at[rank].add(g[perm]), indices sorted: the
          scatter-add XLA would write for itself (it sorts (indices, iota)
          and reads the updates through the permutation) less its sort.
          The gradient slab is never copied in another order.
-      3. the flagged row ids, sorted to the front, are the distinct rows:
-         T and every leaf of the state are read at them, given the
-         optimizer's own update (same function, same float32, same t) and
-         written back in place by ops/rows_pallas.py `update_rows`: ONE
-         kernel where Mosaic compiles it (a TPU, 128 lanes of 32-bit
-         words), else XLA's gather, update and scatter in blocks; both
-         cost by the count of distinct rows.
+      3. T and every leaf of the state are read at the distinct rows,
+         given the optimizer's own update (same function, same float32,
+         same t) and written back in place by ops/rows_pallas.py
+         `update_rows`: ONE kernel where Mosaic compiles it (a TPU, 128
+         lanes of 32-bit words), else XLA's gather, update and scatter in
+         blocks; both cost by the count of distinct rows.
 
-    ``cap`` (None: tail_cap of the shapes, 0 for any other optimizer) is
-    static; 0 is the dense tail alone, with no ranking. A batch with more
-    distinct rows than ``cap`` takes the dense tail too (lax.cond), fed
-    the same sorted rows."""
-    n, (R, W) = rows.shape[0], T.shape
-    leaves, tree = jax.tree_util.tree_flatten(state)
-    if cap is None:
-        cap = tail_cap(n, R, W, min(a.dtype.itemsize for a in (T, *leaves))
-                       ) if optimizer.zero_grad_noop else 0
+    ``ranks`` None is the dense tail alone. A batch with more distinct
+    rows than the ranking's capacity takes the dense tail too (lax.cond),
+    fed the same sorted rows."""
+    R, W = T.shape
+    tree = jax.tree_util.tree_structure(state)
 
     def dense(T, state, rows=rows, g=g, **sorted_):
         with jax.named_scope("hm.scatter"):
@@ -737,23 +882,15 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
             Tn, sn = optimizer.update(T.astype(jnp.float32), G, state, t)
             return Tn.astype(T.dtype), sn
 
-    if not cap:
+    if ranks is None:
         return (*dense(T, state),
-                dict(zip(TAIL_STATS, jnp.asarray([0, 1, 0], jnp.int32))))
-
-    with jax.named_scope("hm.scatter"):
-        slot = jnp.arange(n, dtype=jnp.int32)
-        srows, perm = jax.lax.sort_key_val(rows, slot)
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
-        n_distinct = first.sum(dtype=jnp.int32)
+                dict(zip(TAIL_STATS[:3], jnp.asarray([0, 1, 0], jnp.int32))))
+    srows, perm, rank, urows, n_distinct = ranks
 
     def distinct(T, state):
         with jax.named_scope("hm.scatter"):
-            rank = jnp.cumsum(first.astype(jnp.int32)) - 1
-            Gc = jnp.zeros((cap, W), jnp.float32).at[rank].add(
+            Gc = jnp.zeros((urows.shape[0], W), jnp.float32).at[rank].add(
                 g[perm], mode="drop", indices_are_sorted=True)
-            urows = jnp.sort(jnp.where(first, srows, R + slot))[:cap]
         with jax.named_scope("hm.update"):
             def update(blocks, g, t):
                 w, s = optimizer.update(blocks[0].astype(jnp.float32), g,
@@ -763,13 +900,13 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t, cap=None):
                                   urows, n_distinct, Gc, t, update)
             return Tn, tree.unflatten(sn)
 
-    fits = n_distinct <= cap
+    fits = n_distinct <= urows.shape[0]
     Tn, sn = jax.lax.cond(
         fits, distinct,
         lambda T, s: dense(T, s, srows, g[perm], indices_are_sorted=True),
         T, state)
     took = fits.astype(jnp.int32)
-    return Tn, sn, dict(zip(TAIL_STATS, (took, 1 - took, n_distinct)))
+    return Tn, sn, dict(zip(TAIL_STATS[:3], (took, 1 - took, n_distinct)))
 
 
 def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
@@ -795,10 +932,14 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     probe_preagg.py, which priced another design (an explicit permuting
     copy of the whole gradient slab, uniform ids, each phase alone).
     ``distinct_tail=False`` keeps the dense tail whatever the shapes: the
-    trainer's choice under -mesh, not a user's.
+    trainer's choice under -mesh, not a user's. Where the tail ranks the
+    batch's rows it does so in FRONT of the step, and the forward gather
+    reads through the distinct rows too (gather_rows: each read out of the
+    table once, the slots out of a compact copy of them).
 
     Returns (params, opt_state, loss_sum, stats): stats counts which tail
-    ran and the batch's distinct rows (TAIL_STATS).
+    ran, the batch's distinct rows and whether the gather read through
+    them (TAIL_STATS).
 
     Semantics delta (documented, same as the FFM fused/parts paths):
     adaptive accumulators see the square of the SUMMED minibatch
@@ -814,12 +955,15 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     def body(params, opt_state, t, idx, val, label, row_mask, lams):
         lams = (lams[0], lams[1], lams[2]) if dyn else lambdas
         T, w0 = params["T"], params["w0"]
-        rows, loss_sum, g0, g128 = _fm_packed_grad(
+        rows, ranks, compact, loss_sum, g0, g128 = _fm_packed_grad(
             loss, params, idx, val, label, row_mask, lams,
-            dyn or bool(lams[1] or lams[2]), K, Wf, P)
+            dyn or bool(lams[1] or lams[2]), K, Wf, P,
+            lambda rows: rank_rows(rows, T, opt_state["T"], optimizer,
+                                   None if distinct_tail else 0))
 
         Tn, sT, stats = rows_update(T, opt_state["T"], rows, g128, optimizer,
-                                    t, None if distinct_tail else 0)
+                                    t, ranks)
+        stats["gather_compact_steps"] = compact
         with jax.named_scope("hm.update"):
             w0n, s0 = optimizer.update(w0.astype(jnp.float32), g0,
                                        opt_state["w0"], t)

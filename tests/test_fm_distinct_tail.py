@@ -83,7 +83,8 @@ def _assert_same(new, ref, idx):
                  (new[1]["w0"]["gg"], ref[1]["w0"]["gg"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert {k: int(v) for k, v in ref[3].items()} == {
-        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0,
+        "gather_compact_steps": 0}
 
 
 CAP = fm.tail_cap(N, R)
@@ -107,7 +108,8 @@ def test_distinct_tail_matches_dense_tail(case, n_distinct, distinct):
     _assert_same(new, ref, idx)
     assert {k: int(v) for k, v in new[3].items()} == {
         "tail_distinct_steps": int(distinct),
-        "tail_dense_steps": int(not distinct), "distinct_rows": n_distinct}
+        "tail_dense_steps": int(not distinct), "distinct_rows": n_distinct,
+        "gather_compact_steps": int(distinct)}
 
 
 def test_every_slot_distinct_within_the_capacity(monkeypatch):
@@ -156,7 +158,8 @@ def test_small_table_picks_the_dense_tail_statically():
     new, ref = _pair(idx)
     _assert_same(new, ref, idx)
     assert {k: int(v) for k, v in new[3].items()} == {
-        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0,
+        "gather_compact_steps": 0}
     step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
     params, state = _state()
     text = step.lower(params, state, 3.0, jnp.asarray(idx), None, _label(b),
@@ -246,7 +249,8 @@ def test_bfloat16_table_takes_the_distinct_tail(width):
     cap = fm.tail_cap(n, rows_n, width, 2)
     assert cap > 150
     out = [jax.jit(lambda T, s, c=c: fm.rows_update(
-        T, s, rows, g, _opt(), 3.0, c))(T, {"gg": gg}) for c in (None, 0)]
+        T, s, rows, g, _opt(), 3.0, fm.rank_rows(rows, T, s, _opt(), c)))(
+            T, {"gg": gg}) for c in (None, 0)]
     (Tn, sn, stats), (Td, sd, dense_stats) = out
     assert Tn.dtype == jnp.bfloat16 and sn["gg"].dtype == jnp.float32
     assert {k: int(v) for k, v in stats.items()} == {
@@ -476,9 +480,10 @@ def test_ffm_joint_step_distinct_tail_matches_its_dense_tail(kind, dtype):
     rows = np.unique(np.asarray(fm.ffm_row_hash(args[0], FR)))
     assert {k: int(v) for k, v in new[3].items()} == {
         "tail_distinct_steps": 1, "tail_dense_steps": 0,
-        "distinct_rows": len(rows)}
+        "distinct_rows": len(rows), "gather_compact_steps": 1}
     assert {k: int(v) for k, v in ref[3].items()} == {
-        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0,
+        "gather_compact_steps": 0}
     touched = np.zeros(FR, bool)
     touched[rows] = True
     assert new[0]["T"].dtype == dtype
@@ -518,7 +523,8 @@ def test_an_optimizer_that_moves_a_zero_gradient_row_keeps_the_dense_tail(
     before = np.asarray(params["T"]).copy()
     new = step(params, state, 3.0, *args)
     assert {k: int(v) for k, v in new[3].items()} == {
-        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0}
+        "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0,
+        "gather_compact_steps": 0}
     assert np.isfinite(np.asarray(new[0]["T"])).all()
     assert not np.array_equal(np.asarray(new[0]["T"]), before)
 
@@ -555,6 +561,252 @@ def test_ffm_megastep_of_four_equals_four_single_steps():
     for name in fm.TAIL_STATS:
         assert [int(v) for v in st2[name]] == [s[name] for s in stats]
     assert [s["tail_distinct_steps"] for s in stats] == [1, 1, 0, 1]
+
+
+# -- the gather through the distinct rows --------------------------------------
+
+def _direct_gather(monkeypatch):
+    """The steps built under it gather `T[rows]` whatever was ranked: no
+    room for a compact table."""
+    monkeypatch.setattr(fm, "COMPACT_TABLE_BYTES", 0)
+
+
+def _assert_bit_equal(new, ref):
+    """Two steps' (params, state, loss, stats), leaf by leaf, bit for bit:
+    a gather is exact, so nothing after it may differ but the count of
+    how it read."""
+    new, ref = ([*out[:3], {k: v for k, v in out[3].items()
+                            if k != "gather_compact_steps"}]
+                for out in (new, ref))
+    a, b = jax.tree_util.tree_leaves(new), jax.tree_util.tree_leaves(ref)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
+
+
+def _fm_both_gathers(monkeypatch, idx, mask=None, block=None):
+    """One packed FM step from the same state, reading through the
+    distinct rows and reading the table directly; (compact, direct)."""
+    def run():
+        step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K)
+        b = idx.shape[0]
+        return step(*_state(), 3.0, jnp.asarray(idx), None, _label(b),
+                    jnp.ones(b) if mask is None else mask)
+    if block:
+        monkeypatch.setattr(fm, "FILL_BLOCK_ROWS", block)
+    new = run()
+    _direct_gather(monkeypatch)
+    return new, run()
+
+
+@pytest.mark.parametrize("case,n_distinct,block", [
+    ("heavy_duplication", 5, None),
+    ("three_blocks_and_a_part", 3 * 128 + 17, 128),
+    ("whole_blocks", 4 * 128, 128),
+    ("last_block_overlaps", CAP - 1, CAP // 2 + 128),
+    ("at_the_capacity", CAP, None),
+    ("one_over_reads_the_table", CAP + 1, None),
+    ("far_over_reads_the_table", 3 * CAP, None),
+])
+def test_fm_step_through_the_distinct_rows_is_the_direct_gather(
+        case, n_distinct, block, monkeypatch):
+    """The compact gather re-addresses reads: table, state, loss and the
+    counters are those of `T[rows]` to the bit, in whole blocks of the fill
+    and in parts, and over the capacity, where the step reads the table
+    directly and takes the dense tail."""
+    idx = _ids(n_distinct)
+    new, ref = _fm_both_gathers(monkeypatch, idx, block=block)
+    _assert_bit_equal(new, ref)
+    fits = int(n_distinct <= CAP)
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": fits, "tail_dense_steps": 1 - fits,
+        "distinct_rows": n_distinct, "gather_compact_steps": fits}
+    assert int(ref[3]["gather_compact_steps"]) == 0
+
+
+def test_gather_capacity_follows_the_compact_tables_bytes(monkeypatch):
+    """The compact table is sized to stay in the chip's fast memory: the
+    benchmark's cells get 196,608 rows of it, the packed FM table fewer
+    than its ranking lists and the flagship's all of them; a ranking
+    never lists fewer than the gather reads through."""
+    n = 32768 * 39
+    fm_cap, ffm_cap = fm.tail_cap(n, 1 << 22), fm.tail_cap(n, 1 << 22, 164, 2)
+    assert fm.gather_cap(fm_cap, 128, 4) == 196_608 < fm_cap
+    assert fm.gather_cap(ffm_cap, 164, 2) == ffm_cap < 196_608
+    assert 161_600 < fm.gather_cap(fm_cap, 128, 4)   # Zipf 1.05's batch
+    assert fm.gather_cap(4096, 128, 4) == 4096
+    assert fm.gather_cap(fm_cap, 640, 4) % 128 == 0
+    monkeypatch.setattr(fm, "COMPACT_TABLE_BYTES", 0)
+    assert fm.gather_cap(fm_cap, 128, 4) == 0
+
+
+@pytest.mark.parametrize("case,n_distinct,compact", [
+    ("within_both", 120, 1), ("at_the_gathers", 128, 1),
+    ("over_the_gathers_alone", 129, 0), ("at_the_tails", CAP, 0)])
+def test_a_batch_between_the_capacities_keeps_the_distinct_tail(
+        case, n_distinct, compact, monkeypatch):
+    """A compact table smaller than the ranking (the packed FM table's in
+    the benchmark): a batch it cannot hold reads the table directly and
+    still updates its distinct rows alone, and is counted as both."""
+    monkeypatch.setattr(fm, "COMPACT_TABLE_BYTES", 128 * 128 * 4)
+    assert 128 < CAP
+    new, ref = _fm_both_gathers(monkeypatch, _ids(n_distinct))
+    _assert_bit_equal(new, ref)
+    assert {k: int(v) for k, v in new[3].items()} == {
+        "tail_distinct_steps": 1, "tail_dense_steps": 0,
+        "distinct_rows": n_distinct, "gather_compact_steps": compact}
+
+
+def test_fm_every_slot_distinct_reads_through_the_distinct_rows(monkeypatch):
+    monkeypatch.setattr(fm, "tail_cap", lambda n, *shape: n)
+    new, ref = _fm_both_gathers(monkeypatch, _ids(N), block=512)
+    _assert_bit_equal(new, ref)
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    assert int(new[3]["distinct_rows"]) == N
+
+
+def test_fm_padded_slots_read_row_zero_through_the_distinct_rows(monkeypatch):
+    """Padded rows hold id 0: packed row 0 is the batch's first distinct
+    row, rank 0, and every padded slot reads it from there."""
+    idx = _ids(9)
+    idx[B // 2:] = 0
+    idx[:, L - 3:] = 0                               # short rows too
+    mask = (jnp.arange(B) < B // 2).astype(jnp.float32)
+    new, ref = _fm_both_gathers(monkeypatch, idx, mask=mask)
+    _assert_bit_equal(new, ref)
+    np.testing.assert_array_equal(np.asarray(new[0]["T"])[0],
+                                  np.asarray(_state()[0]["T"])[0])
+
+
+def test_the_gathers_the_program_holds(monkeypatch):
+    """A step with the ranking has three sorts (ranking, distinct ids, the
+    ranks carried back to slot order) and three `cond`s (the list of
+    distinct rows, the gather, the tail); with no room for a compact table
+    two and two; without a ranking (`-mesh`), none."""
+    def text(**kw):
+        step = fm.make_fm_step_minibatch(get_loss("logloss"), _opt(), LAMS, K,
+                                         **kw)
+        return step.lower(*_state(), 3.0, jnp.asarray(_ids(9)), None,
+                          _label(), jnp.ones(B)).as_text()
+    compact, dense = text(), text(distinct_tail=False)
+    _direct_gather(monkeypatch)
+    direct = text()
+    assert [t.count("stablehlo.sort") for t in (compact, direct, dense)] \
+        == [3, 2, 0]
+    assert [t.count("stablehlo.case") for t in (compact, direct, dense)] \
+        == [3, 2, 0]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["halffloat", "float32"])
+@pytest.mark.parametrize("kind", ["unit", "valued", "pairs", "padded"])
+def test_ffm_joint_step_through_the_distinct_rows_is_the_direct_gather(
+        kind, dtype, monkeypatch):
+    kw, args = _ffm_batch(kind)
+    monkeypatch.setattr(fm, "FILL_BLOCK_ROWS", 128)  # two blocks of C
+    new = _ffm_step(**kw)(*_ffm_state(dtype), 3.0, *args)
+    _direct_gather(monkeypatch)
+    ref = _ffm_step(**kw)(*_ffm_state(dtype), 3.0, *args)
+    _assert_bit_equal(new, ref)
+    assert int(new[3]["tail_distinct_steps"]) == 1
+    assert new[0]["T"].dtype == dtype
+
+
+def test_ffm_batch_over_the_capacity_reads_the_table(monkeypatch):
+    kw, args = _ffm_batch("unit", n_ids=600)
+    cap = fm.tail_cap(FB * FL, FR, FF * FK_ + 8, 2)
+    new = _ffm_step(**kw)(*_ffm_state(), 3.0, *args)
+    assert int(new[3]["distinct_rows"]) > cap
+    assert int(new[3]["tail_dense_steps"]) == 1
+    _direct_gather(monkeypatch)
+    _assert_bit_equal(new, _ffm_step(**kw)(*_ffm_state(), 3.0, *args))
+
+
+@pytest.mark.parametrize("family", ["fm", "ffm"])
+def test_megastep_through_the_distinct_rows_is_the_direct_gather(
+        family, monkeypatch):
+    """Four steps in one scan, the third over the capacity: the carry a
+    step hands the next is the direct gather's, bit for bit."""
+    ks = 4
+    if family == "fm":
+        make = lambda: fm.make_fm_step_minibatch(      # noqa: E731
+            get_loss("logloss"), _opt(), LAMS, K)
+        nds, state, none_val = (6, CAP - 3, CAP + 5, CAP), _state, True
+        idx = np.stack([_ids(nd, seed=10 + i) for i, nd in enumerate(nds)])
+        label = jnp.stack([_label(seed=20 + i) for i in range(ks)])
+        nv = np.asarray([B, B, B - 5, B], np.int32)
+    else:
+        make = lambda: _ffm_step(fieldmajor=True, unit_val=True)  # noqa: E731
+        cap = fm.tail_cap(FB * FL, FR, FF * FK_ + 8, 2)
+        nds, state, none_val = (30, cap - 40, cap + 200, 90), _ffm_state, \
+            False
+        batches = [_ffm_batch("unit", nd, seed=30 + i)[1]
+                   for i, nd in enumerate(nds)]
+        idx = np.stack([np.asarray(b[0]) for b in batches])
+        label = jnp.stack([b[1] for b in batches])
+        nv = np.asarray([FB, FB, FB - 5, FB], np.int32)
+
+    def run():
+        return make_megastep(make().core, none_val=none_val)(
+            *state(), 7.0, jnp.asarray(nv), jnp.asarray(idx), None, label,
+            None, None)
+    new = run()
+    _direct_gather(monkeypatch)
+    _assert_bit_equal(new, run())
+    assert [int(v) for v in new[3]["tail_distinct_steps"]] == [1, 1, 0, 1]
+
+
+@pytest.mark.parametrize("case,n,rows_n,cap,n_distinct", [
+    ("duplicates", 2048, 4096, 256, 150),
+    ("one_row", 512, 4096, 128, 1),
+    ("at_the_capacity", 1024, 4096, 384, 384),
+    ("over_the_capacity", 1024, 4096, 128, 300),
+    ("every_slot_distinct", 1024, 4096, 1024, 1024),
+])
+def test_rank_rows_against_numpy(case, n, rows_n, cap, n_distinct):
+    """The ranking alone: `urows[:n]` are numpy's sorted unique rows and
+    the rest out of range, a slot's rank (the sorted slots' rank carried
+    back through `perm`) is numpy's inverse, `fits` compares the count
+    with the capacity."""
+    rng = np.random.default_rng(n_distinct)
+    pool = rng.choice(rows_n, n_distinct, replace=False)
+    rows = rng.choice(pool, n)
+    rows[rng.choice(n, n_distinct, replace=False)] = pool
+    T = jnp.zeros((rows_n, 128), jnp.float32)
+    ranks = jax.jit(lambda r: fm.rank_rows(r, T, {"gg": T}, _opt(), cap))(
+        jnp.asarray(rows.astype(np.int32)))
+    uniq, inverse = np.unique(rows, return_inverse=True)
+    assert int(ranks.n_distinct) == len(uniq) == n_distinct
+    urows = np.asarray(ranks.urows)
+    assert urows.shape == (cap,)
+    np.testing.assert_array_equal(np.asarray(ranks.srows), np.sort(rows))
+    np.testing.assert_array_equal(rows[np.asarray(ranks.perm)],
+                                  np.sort(rows))
+    if n_distinct <= cap:              # over it nothing reads the list
+        np.testing.assert_array_equal(urows[:n_distinct], uniq)
+        assert (urows[n_distinct:] >= rows_n).all()
+        rank_of_slot = np.empty(n, np.int64)
+        rank_of_slot[np.asarray(ranks.perm)] = np.asarray(ranks.rank)
+        np.testing.assert_array_equal(rank_of_slot, inverse)
+    got, compact = fm.gather_rows(
+        jnp.arange(rows_n * 128, dtype=jnp.float32).reshape(rows_n, 128),
+        jnp.asarray(rows), ranks)
+    np.testing.assert_array_equal(np.asarray(got)[:, 0], rows * 128.0)
+    assert int(compact) == (n_distinct <= cap)
+
+
+def test_no_ranking_where_the_dense_tail_is_static():
+    T = jnp.zeros((4096, 128), jnp.float32)
+    rows = jnp.zeros((9984,), jnp.int32)
+    assert fm.rank_rows(rows, T, {"gg": T}, _opt()) is None   # small table
+    assert fm.rank_rows(rows[:2048], T, {"gg": T}, _opt(), 0) is None
+    adam = make_optimizer("adam", eta_scheme="inverse", eta0=0.1, reg="no")
+    assert fm.rank_rows(rows[:2048], T, adam.init(T.shape), adam) is None
+    got, compact = fm.gather_rows(T + 1.0, rows[:8].reshape(2, 4), None)
+    assert got.shape == (2, 4, 128) and float(got.min()) == 1.0
+    assert int(compact) == 0
 
 
 # -- the counters -------------------------------------------------------------
@@ -608,6 +860,7 @@ def test_tail_counters_fold_with_the_loss_and_nothing_fetches_between(
     counts = dict(t._step_counts)
     assert counts["tail_distinct_steps"] + counts["tail_dense_steps"] == steps
     assert counts["tail_distinct_steps"] == steps  # 12 rows fit the rung
+    assert counts["gather_compact_steps"] == steps
     assert 0 < counts["distinct_rows"] <= 12 * steps
     snap = registry.snapshot()
     assert {n: snap["train"][n] for n in fm.TAIL_STATS} == counts
@@ -617,8 +870,9 @@ def test_tail_counters_fold_with_the_loss_and_nothing_fetches_between(
     from hivemall_tpu.obs.report import summarize
     assert f"hivemall_tpu_train_tail_distinct_steps {steps}" in \
         to_prometheus(snap)
-    assert f"tail:   distinct-row x{steps}  dense x0" in summarize(
-        [{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+    report = summarize([{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+    assert f"tail:   distinct-row x{steps}  dense x0" in report
+    assert f"gather on the distinct rows x{steps}" in report
 
 
 def test_mesh_trainer_keeps_the_dense_tail():
@@ -631,6 +885,7 @@ def test_mesh_trainer_keeps_the_dense_tail():
     t.fit(ds)
     assert t.cumulative_loss == t.cumulative_loss
     assert t._step_counts["tail_distinct_steps"] == 0
+    assert t._step_counts["gather_compact_steps"] == 0
     assert t._step_counts["tail_dense_steps"] == t._t
 
 
@@ -670,13 +925,15 @@ def test_tail_counters_fold_for_train_ffm(k, pack):
     assert t._t == steps and np.isfinite(t.cumulative_loss)
     counts = dict(t._step_counts)
     assert counts["tail_distinct_steps"] == steps
+    assert counts["gather_compact_steps"] == steps
     assert counts["tail_dense_steps"] == 0
     assert steps < counts["distinct_rows"] <= 20 * steps
     snap = registry.snapshot()
     assert {n: snap["train"][n] for n in fm.TAIL_STATS} == counts
     from hivemall_tpu.obs.report import summarize
-    assert f"tail:   distinct-row x{steps}  dense x0" in summarize(
-        [{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+    report = summarize([{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+    assert f"tail:   distinct-row x{steps}  dense x0" in report
+    assert f"gather on the distinct rows x{steps}" in report
 
 
 def test_ffm_mesh_trainer_keeps_the_dense_tail():
@@ -687,4 +944,5 @@ def test_ffm_mesh_trainer_keeps_the_dense_tail():
     t.fit(_ffm_trainer_stream(4, 32, 20, 1 << 20))
     assert t.cumulative_loss == t.cumulative_loss
     assert t._step_counts["tail_distinct_steps"] == 0
+    assert t._step_counts["gather_compact_steps"] == 0
     assert t._step_counts["tail_dense_steps"] == t._t == 4

@@ -42,12 +42,15 @@ def test_parts_and_histogram_kernels_compile_for_v5e():
 def test_fm_minibatch_step_compiles_without_a_loop_for_v5e():
     """One un-scanned packed FM minibatch step at the benchmark cell's
     geometry (-dims 2^26 -factors 5, B=32768, L=39, float32): the worker
-    asserts the compiled text holds no `while(` (PR 24's parent had two,
-    one per direction of a reshape nobody saw; ~25 s of XLA compile) and,
-    since PR 28, one `conditional(` between the distinct-row tail and the
-    dense one, the ONE row kernel of ops/rows_pallas.py (PR 30; four
-    before) compiled by Mosaic, no copy of a whole table, and temporaries
-    under 2.85 GB."""
+    asserts the compiled text holds no `while(` of the compiler's making
+    (PR 24's parent had two, one per direction of a reshape nobody saw;
+    ~40 s of XLA compile) and, since PR 28, one `conditional(` between the
+    distinct-row tail and the dense one, the ONE row kernel of
+    ops/rows_pallas.py (PR 30; four before) compiled by Mosaic, no copy of
+    a whole table, and temporaries under 2.85 GB; since PR 34 a second
+    `conditional(` in front, whose distinct branch gathers the slots out
+    of the compact [cap, 128] table (filled by the program's one `while`)
+    and never out of the 2 GiB one, the slab leaving it without a copy."""
     _compile("fm_minibatch_step", timeout=600)
 
 
@@ -58,7 +61,8 @@ def test_ffm_joint_megastep_compiles_for_v5e_with_the_distinct_tail():
     tail, one `conditional`, the scan and the blocks' loop, NO Mosaic
     kernel (the compiler's verdict on 164-lane and half-word row copies,
     learned here), no whole-table copy inside the scan, temporaries no
-    more than the dense tail's (~30 s of XLA compile)."""
+    more than the dense tail's (~45 s of XLA compile); since PR 34 the
+    gather's `conditional` and the compact table's fill, a third loop."""
     _compile("ffm_joint_megastep", timeout=600)
 
 

@@ -97,14 +97,52 @@ def parts_step_sharded():
                _sds((B,), jnp.float32, ns("dp"))).compile()
 
 
+def _slab_gathers(text, slab):
+    """{branch of the gather's `cond`: shape of the operand read} for the
+    step's forward gathers (`hm.gather`, a result of shape `slab`)."""
+    out = {}
+    for m in re.finditer(r"= %s\S* gather\(%%([\w.]+),.*?op_name=\"([^\"]*)\""
+                         % re.escape(slab), text):
+        operand, path = m.groups()
+        if "/hm.gather/" not in path:
+            continue
+        branch = re.search(r"branch_\d", path)
+        out[branch.group(0) if branch else "no_cond"] = re.search(
+            r"%%%s = (\w+\[[\d,]*\])" % re.escape(operand), text).group(1)
+    return out
+
+
+def _compact_table_spaces(text, shape):
+    """The memory spaces the compiled text gives the compact table in the
+    fill's loop (`S(1)`: the chip's fast memory; "" is HBM)."""
+    loop = re.search(r" while\((?:(?!\n).)*?/hm\.gather/while", text)
+    line = text[text.rindex("\n", 0, loop.start()):loop.end()]
+    return set(re.findall(r"%s\{[^}]*?(S\(\d\))?\}" % re.escape(shape), line))
+
+
+def _assert_compact_gather(text, slab, compact, table):
+    """`gather_rows` as compiled (PR 34): the distinct branch of its cond
+    reads the slots (a result of shape `slab`) out of the `compact` table
+    and never out of the whole `table`, which the other branch reads; and
+    the compiler keeps the compact table in fast memory, which is what
+    the gather's 1.9 ns a row against 10.2 rests on (at the ranking's
+    282,624 rows FM's stays in HBM and the step LOSES 2.3 ms)."""
+    assert _slab_gathers(text, slab) == {
+        "branch_1": compact, "branch_0": table}, _slab_gathers(text, slab)
+    spaces = _compact_table_spaces(text, compact)
+    assert spaces == {"S(1)"}, f"the compact table lives in {spaces}"
+
+
 def fm_minibatch_step():
     """ONE un-scanned make_fm_step_minibatch step at the geometry of the
     benchmark's cell fm_criteo.stream (-dims 2^26 -factors 5, B=32768,
     L=39, float32, unit values elided). The compiled program must hold no
-    loop: the only `while` of the megastep is its own scan. The compiler
-    wrote the packed unpack's [B, L, P, Wf] reshape as two 128-trip loops
-    over lanes, 43% of the step's device time (PERF.md, PR 24 / PR 25),
-    and on the CPU that tier-1 runs on such a reshape costs nothing."""
+    loop the compiler made: the compiler wrote the packed unpack's
+    [B, L, P, Wf] reshape as two 128-trip loops over lanes, 43% of the
+    step's device time (PERF.md, PR 24 / PR 25), and on the CPU that
+    tier-1 runs on such a reshape costs nothing. The one `while` it holds
+    since PR 34 is `gather_rows`' own: the fill of the compact table, a
+    block a trip up to the batch's count of distinct rows."""
     K, B, L = 5, 32768, 39
     Wf, Pk = fm.fm_pack_geometry(K)
     Np = (1 << 26) // Pk
@@ -119,17 +157,29 @@ def fm_minibatch_step():
         _sds((B,), jnp.float32), _sds((B,), jnp.float32)
     ).compile()
     text = compiled.as_text()
-    loops = text.count(" while(")
-    assert loops == 0, f"{loops} while op(s) in the one-step FM program"
+    loops = re.findall(r" while\(.*?op_name=\"([^\"]*)\"", text)
+    assert len(loops) == 1 and "/hm.gather/while" in loops[0], \
+        f"while op(s) {loops}, expected the compact table's fill alone"
     # the distinct-row tail (PR 28): one cond between it and the dense
     # tail, the donated tables passing through both in place (the row
     # kernel aliases them: ONE Mosaic kernel since PR 30), and no more
     # temporaries than the dense tail's G beside the gradient slab (2.80 GB
-    # read here in PR 30; the distinct branch holds no [cap, 128] copy of
-    # the rows any more, only the compact gradient)
-    assert fm.tail_cap(B * L, Np), "the cell's shape must offer the tail"
+    # read here in PRs 30 and 34: the gather's compact table, 101 MB, and
+    # the compact gradient live where the dense branch's G would). In
+    # front (PR 34): a cond that lists the distinct rows only for a batch
+    # within the capacity, and the gather's own, the slab leaving either
+    # of its branches without a copy
+    cap = fm.tail_cap(B * L, Np)
+    assert cap, "the cell's shape must offer the tail"
+    gcap, W = fm.gather_cap(cap, Pk * Wf, 4), Pk * Wf
     conds = text.count(" conditional(")
-    assert conds == 1, f"{conds} conditional op(s), expected one"
+    assert conds == 3, \
+        f"{conds} conditional op(s), expected the list, gather and tail"
+    _assert_compact_gather(text, f"f32[{L},{B},{W}]", f"f32[{gcap},{W}]",
+                           f"f32[{Np},{W}]")
+    slab_copies = re.findall(r"= f32\[(?:%d,%d|%d),%d\]\S* copy\("
+                             % (L, B, L * B, Pk * Wf), text)
+    assert not slab_copies, f"{len(slab_copies)} copies of the slab"
     kernels = text.count("tpu_custom_call")
     assert kernels == 1, f"{kernels} Mosaic kernels, expected update_rows"
     copies = re.findall(r"= f32\[%d,%d\]\S* copy\(" % (Np, Pk * Wf), text)
@@ -151,13 +201,17 @@ def ffm_joint_megastep():
     Mosaic's verdict on this shape was learned here: it compiles no row
     copy of a 164-lane array and no bfloat16 pair (ops/rows_pallas.py has
     its words), so the distinct rows go through XLA's gather and scatter
-    in blocks: one `conditional` between the tails, two `while` (the scan
-    and the blocks' loop, whose trips follow the batch's count), no Mosaic
-    kernel, the four relayouts of the tables at the megastep's two ends
-    (the dense tail's program has the same four) and none inside the scan,
-    and no more temporaries than the dense tail's program: 15.03 GB read
-    against its 15.05 (both hold the dense branch's G; the figure counts
-    buffers the chip overlays, the cell peaks at 4.95 GB)."""
+    in blocks: one `conditional` between the tails and, since PR 34, one
+    around the list of distinct rows and one between the gathers (the
+    distinct branch reads the slots out of the compact [cap, 164] table,
+    kept in fast memory, the other out of the whole one), three
+    `while` (the scan, the blocks' loop and the compact table's fill,
+    whose trips follow the batch's count), no Mosaic kernel, the four
+    relayouts of the tables at the megastep's two ends (the dense tail's
+    program has the same four) and none inside the scan, and no more
+    temporaries than the dense tail's program: 15.04 GB read against its
+    15.05 (both hold the dense branch's G; the figure counts buffers the
+    chip overlays, the cell peaks at 4.95 GB)."""
     from hivemall_tpu.ops.scan import make_megastep
     Fj, B, L, ks = 39, 32768, 39, 2
     Mr, W = 1 << 22, Fj * K + 8
@@ -166,7 +220,8 @@ def ffm_joint_megastep():
     step = fm.make_ffm_step_fused(get_loss("logloss"), opt, LAMS, Fj, K,
                                   fieldmajor=True, unit_val=True)
     T, gg = _sds((Mr, W), jnp.bfloat16), _sds((Mr, W), jnp.float32)
-    assert fm.tail_cap(B * L, Mr, W, 2), "the cell's shape must offer the tail"
+    cap = fm.tail_cap(B * L, Mr, W, 2)
+    assert cap, "the cell's shape must offer the tail"
     assert not rows_pallas.kernel_takes((T, gg))
     compiled = make_megastep(step.core).lower(
         {"T": T, "w0": _sds((), jnp.float32)},
@@ -176,8 +231,13 @@ def ffm_joint_megastep():
         None).compile()
     text = compiled.as_text()
     conds, loops = text.count(" conditional("), text.count(" while(")
-    assert conds == 1, f"{conds} conditional op(s), expected one"
-    assert loops == 2, f"{loops} while op(s), expected the scan and the blocks"
+    assert conds == 3, \
+        f"{conds} conditional op(s), expected the list, gather and tail"
+    assert loops == 3, \
+        f"{loops} while op(s), expected the scan, the blocks and the fill"
+    _assert_compact_gather(
+        text, f"bf16[{B},{L},{W}]",
+        f"bf16[{fm.gather_cap(cap, W, 2)},{W}]", f"bf16[{Mr},{W}]")
     kernels = text.count("tpu_custom_call")
     assert kernels == 0, f"{kernels} Mosaic kernels in the XLA-rows tail"
     copy = r"= (?:bf16|f32)\[%d,%d\]\S* copy\(" % (Mr, W)
