@@ -297,6 +297,7 @@ class ParquetStream:
                 yield ds
             return
         import concurrent.futures as cf
+        tracer = get_tracer()
         ex = cf.ThreadPoolExecutor(max_workers=1,
                                    thread_name_prefix="pq-decode")
         try:
@@ -306,10 +307,14 @@ class ParquetStream:
             it = iter(files)
 
             def timed_shard(f):
-                t0 = _time.perf_counter()
-                ds = self._shard(f)
-                self.stats.add(prep_seconds=_time.perf_counter() - t0,
-                               batches_prepared=1)
+                # source.decode and stats.prep_seconds: the same seconds
+                with tracer.span("source.decode") as sp:
+                    t0 = _time.perf_counter()
+                    ds = self._shard(f)
+                    dt = _time.perf_counter() - t0
+                    if sp is not None:
+                        sp.args = {"rows": len(ds)}
+                self.stats.add(prep_seconds=dt, batches_prepared=1)
                 return ds
 
             # prime exactly decode_ahead futures: with the shard the

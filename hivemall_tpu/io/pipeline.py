@@ -218,6 +218,7 @@ class IngestPipeline:
         self.stats = stats if stats is not None else PipelineStats()
         self.stats.workers = self._workers
         self._fn = fn
+        self._n = 0                     # items taken (`batch` of the spans)
         self._closed = threading.Event()
         if self._workers <= 1:
             # strict sequential fallback: no threads, no queue — bit-exact
@@ -226,7 +227,6 @@ class IngestPipeline:
             self.stats.pool = "none"
             self._src: Optional[Iterator[Any]] = iter(src)
             self._exec = None
-            self._n = 0                 # items taken (`batch` of the span)
             return
         import concurrent.futures as cf
         import multiprocessing as mp
@@ -248,15 +248,18 @@ class IngestPipeline:
         # is a GC root: a closure over self would keep an abandoned
         # pipeline reachable forever and __del__ could never run close())
         q, closed, ex, stats = self._q, self._closed, self._exec, self.stats
+        tracer = get_tracer()
 
         def submit_loop(it: Iterator[Any]) -> None:
             try:
                 for n, item in enumerate(it):
                     f = ex.submit(_timed_call, fn, item, n)
-                    t0 = time.perf_counter()
-                    q.put(f)            # blocking; close() drains to wake
-                    stats.add(
-                        prep_backpressure_seconds=time.perf_counter() - t0)
+                    # the span and the counter: the same seconds
+                    with tracer.span("ingest.wait_slot", None, n):
+                        t0 = time.perf_counter()
+                        q.put(f)        # blocking; close() drains to wake
+                        dt = time.perf_counter() - t0
+                    stats.add(prep_backpressure_seconds=dt)
                     if closed.is_set():
                         f.cancel()
                         return          # consumer abandoned the stream
@@ -294,8 +297,23 @@ class IngestPipeline:
             self.stats.add(prep_seconds=time.perf_counter() - t0,
                            batches_prepared=1)
             return out
-        t0 = time.perf_counter()
-        fut = self._q.get()             # blocking; sentinel always arrives
+        # ingest.wait_prep and prep_wait_seconds: the same seconds, this
+        # thread blocked on the pool (the wait for the stream's end too)
+        with get_tracer().span("ingest.wait_prep", None, self._n):
+            t0 = time.perf_counter()
+            fut = self._q.get()         # blocking; sentinel always arrives
+            if fut is not _STOP and not isinstance(fut, _SourceError):
+                self.stats.sample_queue(self._q.qsize())
+                try:
+                    # a worker's exception re-raises HERE, within one
+                    # batch of where it fired
+                    out, dt = fut.result()
+                except BaseException:
+                    self.stats.add(worker_errors=1)
+                    self.close()
+                    raise
+            waited = time.perf_counter() - t0
+        self.stats.add(prep_wait_seconds=waited)
         if fut is _STOP:
             self._closed.set()
             self._submitter.join()
@@ -305,15 +323,8 @@ class IngestPipeline:
             self.stats.add(source_errors=1)
             self.close()
             raise fut.e
-        self.stats.sample_queue(self._q.qsize())
-        try:
-            out, dt = fut.result()      # worker exception re-raises HERE —
-        except BaseException:           # within one batch of where it fired
-            self.stats.add(worker_errors=1)
-            self.close()
-            raise
-        self.stats.add(prep_wait_seconds=time.perf_counter() - t0,
-                       prep_seconds=dt, batches_prepared=1)
+        self._n += 1
+        self.stats.add(prep_seconds=dt, batches_prepared=1)
         return out
 
     def close(self) -> None:

@@ -125,6 +125,7 @@ class DevicePrefetcher:
         # (the thread is a GC root), so __del__ could never fire to
         # release a worker blocked on a full queue
         q, closed, errbox = self._q, self._closed, self._errbox
+        tracer = get_tracer()
 
         def work():
             try:
@@ -137,8 +138,11 @@ class DevicePrefetcher:
                     # blocking put: no poll loop. If the consumer abandons
                     # the stream, close() drains the queue until this
                     # thread exits, so a put blocked on a full queue
-                    # always wakes.
-                    q.put(staged)
+                    # always wakes. feed.wait_slot: the consumer has
+                    # not taken the previous input yet.
+                    with tracer.span("feed.wait_slot",
+                                     getattr(staged, "seq", None)):
+                        q.put(staged)
                     if closed.is_set():
                         return          # consumer abandoned the stream
             except BaseException as e:          # surfaced on next()

@@ -12,7 +12,7 @@ without reading bench output. This package is the layer that unifies them:
   ring buffer, one attribute check when disabled) wired into the hot path
   at its real seams: ingest prep, megabatch stacking, h2d staging, the
   jitted (mega)step dispatch, MIX exchanges, checkpoint saves. Per-stage
-  ``{count, total_s, p50, p99}`` rollups land in the jsonl metrics stream
+  ``{count, total_s, cpu_s, p50, p99}`` rollups land in the jsonl metrics stream
   at the loss-fold cadence; the raw spans export as Chrome-trace JSON
   (chrome://tracing / Perfetto) alongside ``jax.profiler``.
 - :mod:`registry` — the central counter registry every subsystem registers

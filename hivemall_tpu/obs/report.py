@@ -183,6 +183,7 @@ def _render(state: _TailState, path: str = "",
             out.append(
                 f"  {name:<{width}}  count {s.get('count', 0):>7}  "
                 f"total {_fmt_s(s.get('total_s', 0.0)):>9}  "
+                f"cpu {_fmt_s(s.get('cpu_s', 0.0)):>9}  "
                 f"p50 {_fmt_s(s.get('p50', 0.0)):>9}  "
                 f"p99 {_fmt_s(s.get('p99', 0.0)):>9}  "
                 f"({100.0 * s.get('total_s', 0.0) / total:4.1f}%)")

@@ -9,14 +9,29 @@ Span sites (the training pipeline's real seams — docs/OBSERVABILITY.md):
 
 ========================  ===================================================
 ``source.wait_shard``     ParquetStream blocked on the next decoded shard
+``source.decode``         one shard read and decoded on the ``pq-decode``
+                          thread, ``args.rows`` its rows (work, not a wait)
 ``source.assemble``       one source batch: row gather + padding (the
                           shard's concat/shuffle/take rides on its first
                           batch); never the time suspended at ``yield``
 ``source.note_batch``     the trainer's per-batch stream-order hook
+``pairs.track``           FFM: one batch's observed pairs merged, on the
+                          ``pairs-track`` thread
 ``ingest.prep``           host batch prep (IngestPipeline worker fn, both
                           the pool workers and the sequential fallback)
+``ingest.cache``          one batch assembled from the packed shard cache
+``ingest.wait_prep``      the pipeline's consumer blocked on the pool's next
+                          prepared batch (none in the sequential fallback)
+``ingest.wait_slot``      ``ingest-source`` blocked on the full queue of
+                          prepared batches
 ``stager.stack``          K-step megabatch stacking (MegabatchStager)
+``init.state``            the trainer's state out of its one jitted
+                          initialiser, ``args.bytes`` what it made
 ``h2d.stage``             host->device transfer (prefetch.stage_batch)
+``h2d.shard``             the same under ``-mesh`` from the thread that
+                          dispatches (no prefetcher staged the input)
+``feed.wait_slot``        ``h2d-prefetch`` blocked handing a staged input
+                          over: the consumer has not taken the previous one
 ``loop.wait_input``       the train loop blocked on its next staged input
 ``dispatch.step``         one jitted step dispatch (host-side boundary)
 ``dispatch.megastep``     one fused K-step lax.scan dispatch
@@ -29,13 +44,16 @@ Span sites (the training pipeline's real seams — docs/OBSERVABILITY.md):
 
 Every span records its thread (id and name), a process-unique ``id`` and
 the ``parent`` id of the innermost span open on the same thread when it
-began. Two ordinals tie the spans of one unit of work together across
-threads: ``batch``, a source batch's position in its stream (each stage
-counts what passes it, in order, from 0), and ``seq``, a dispatch's
-position, given where the stager emits it and carried on the megabatch
-object through the prefetcher to the dispatch. Both ride positionally —
-``span(name, seq, batch)`` — so a disabled tracer builds nothing at the
-call site.
+began. It has two clocks: ``dur`` is wall time, ``cpu`` the seconds its
+thread was on a CPU meanwhile (``time.thread_time()`` at both ends), so
+``dur - cpu`` is what the thread spent waiting inside the span: for the
+GIL, for a page fault, on a blocking call. Two ordinals tie the spans of
+one unit of work together across threads: ``batch``, a source batch's
+position in its stream (each stage counts what passes it, in order, from
+0), and ``seq``, a dispatch's position, given where the stager emits it
+and carried on the megabatch object through the prefetcher to the
+dispatch. Both ride positionally — ``span(name, seq, batch)`` — so a
+disabled tracer builds nothing at the call site.
 
 One clock: while a ``jax.profiler`` session is live (the harness's, or
 ``HIVEMALL_TPU_PROF``), every ``span()`` of an enabled tracer is also
@@ -90,12 +108,15 @@ _RESERVOIR = 512      # per-stage duration reservoir for p50/p99
 
 
 class _NullSpan:
-    """Shared no-op context manager — the disabled-tracer fast path."""
+    """Shared no-op context manager — the disabled-tracer fast path.
+    ``with tracer.span(...) as sp`` binds None here and the live span
+    when enabled: a site that learns its ``args`` only inside the block
+    sets ``sp.args`` under ``if sp is not None``."""
 
     __slots__ = ()
 
     def __enter__(self):
-        return self
+        return None
 
     def __exit__(self, *exc):
         return False
@@ -149,7 +170,7 @@ def _annotation_cls():
 
 class _Span:
     __slots__ = ("_tracer", "name", "seq", "batch", "args", "id", "parent",
-                 "t0", "_ann")
+                 "t0", "cpu", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, seq, batch, args):
         self._tracer = tracer
@@ -178,9 +199,11 @@ class _Span:
             self._ann = cls(self.name, **kw)
             self._ann.__enter__()
         self.t0 = time.perf_counter()
-        return self
+        self.cpu = time.thread_time()   # read last, and first at the end:
+        return self                     # the CPU clock lies inside `dur`
 
     def __exit__(self, *exc):
+        self.cpu = time.thread_time() - self.cpu
         dur = time.perf_counter() - self.t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
@@ -190,11 +213,12 @@ class _Span:
 
 
 class _Stage:
-    __slots__ = ("count", "total_s", "durs")
+    __slots__ = ("count", "total_s", "cpu_s", "durs")
 
     def __init__(self):
         self.count = 0
         self.total_s = 0.0
+        self.cpu_s = 0.0
         self.durs: deque = deque(maxlen=_RESERVOIR)
 
 
@@ -274,7 +298,8 @@ class Tracer:
         """Record an already-measured span ending ~now (the router's
         forward loop measures across retries and can't wrap a single
         ``with``). Its parent is the innermost span open on this thread
-        now. No-op when disabled."""
+        now; it has no CPU clock (the interval is over). No-op when
+        disabled."""
         if not self.enabled:
             return
         self._record(name, time.perf_counter() - dur_s, dur_s, trace=trace)
@@ -287,18 +312,22 @@ class Tracer:
         if trace == "\0tls":             # default: the thread's context tag
             trace = getattr(self._tls, "trace", None)
         if span is not None:
+            cpu = span.cpu
             more = (thread.name, span.id, span.parent, span.seq, span.batch,
-                    span.args)
+                    span.args, cpu)
         else:
+            cpu = None
             stack = getattr(self._tls, "stack", None)
             more = (thread.name, next(_span_ids),
-                    stack[-1].id if stack else None, None, None, None)
+                    stack[-1].id if stack else None, None, None, None, cpu)
         with self._lock:
             st = self._stages.get(name)
             if st is None:
                 st = self._stages[name] = _Stage()
             st.count += 1
             st.total_s += dur
+            if cpu is not None:
+                st.cpu_s += cpu
             st.durs.append(dur)
             if len(self._events) == self._events.maxlen:
                 self.dropped += 1        # ring full: the append below
@@ -306,18 +335,21 @@ class Tracer:
 
     # -- reading -------------------------------------------------------------
     def rollup(self) -> Dict[str, dict]:
-        """Per-stage ``{count, total_s, p50, p99}`` (percentiles over the
-        last ``_RESERVOIR`` spans of each stage). JSON-ready; safe to call
+        """Per-stage ``{count, total_s, cpu_s, p50, p99}``: ``cpu_s`` is
+        the part of ``total_s`` the spans' threads were on a CPU
+        (``add_span`` intervals add none); percentiles over the last
+        ``_RESERVOIR`` spans of each stage. JSON-ready; safe to call
         from any thread while spans are being recorded."""
         with self._lock:
-            items = [(name, st.count, st.total_s, list(st.durs))
+            items = [(name, st.count, st.total_s, st.cpu_s, list(st.durs))
                      for name, st in self._stages.items()]
         out: Dict[str, dict] = {}
-        for name, count, total, durs in sorted(items):
+        for name, count, total, cpu, durs in sorted(items):
             durs.sort()
             out[name] = {
                 "count": count,
                 "total_s": round(total, 6),
+                "cpu_s": round(cpu, 6),
                 "p50": round(_pctl(durs, 0.50), 6) if durs else 0.0,
                 "p99": round(_pctl(durs, 0.99), 6) if durs else 0.0,
             }
@@ -331,20 +363,23 @@ class Tracer:
         fleet router concatenates replicas' ``traceEvents`` under their
         own pids to render one request as one cross-process flame.
         ``args`` carries ``id``, ``thread`` and, where set, ``parent``,
-        ``seq``, ``batch``, ``trace`` and the span's own ``args``."""
+        ``seq``, ``batch``, ``trace``, ``cpu`` (thread-CPU microseconds,
+        like ``dur``) and the span's own ``args``."""
         with self._lock:
             events = list(self._events)
         pid = os.getpid()
         wall0 = self._origin_wall - self._origin
         out = []
         for (name, t0, dur, tid, trace, tname, sid, parent, seq, batch,
-             extra) in events:
+             extra, cpu) in events:
             args = dict(extra) if extra else {}
             args.update(id=sid, thread=tname)
             for key, v in (("parent", parent), ("seq", seq),
                            ("batch", batch), ("trace", trace)):
                 if v is not None:
                     args[key] = v
+            if cpu is not None:
+                args["cpu"] = round(cpu * 1e6, 3)
             out.append({"name": name, "ph": "X", "cat": "hivemall_tpu",
                         "ts": round((wall0 + t0) * 1e6, 3),
                         "dur": round(dur * 1e6, 3), "pid": pid, "tid": tid,

@@ -10,6 +10,7 @@ import glob
 import inspect
 import itertools
 import os
+import queue
 import threading
 import time
 import tracemalloc
@@ -21,8 +22,9 @@ from conftest import tracer_spans as _spans
 
 from hivemall_tpu.io.arrow import (ParquetStream, _concat_datasets,
                                    _take_rows, write_parquet_shards)
+from hivemall_tpu.io.pipeline import IngestPipeline, PipelineStats
 from hivemall_tpu.io.prefetch import DevicePrefetcher
-from hivemall_tpu.io.sparse import SparseDataset
+from hivemall_tpu.io.sparse import SparseBatch, SparseDataset
 from hivemall_tpu.models.fm import FFMTrainer, FMTrainer
 from hivemall_tpu.models.linear import GeneralClassifier
 from hivemall_tpu.obs.trace import _NULL_SPAN, Tracer, get_tracer
@@ -101,14 +103,22 @@ def test_span_args_dict_reaches_the_export():
     assert (args["seq"], args["batch"], args["rows"]) == (1, 2, 64)
 
 
-def test_disabled_span_builds_nothing_at_the_new_sites():
+@pytest.mark.parametrize("site", [
+    ("dispatch.megastep", 5, 9), ("h2d.stage", 5, None),
+    ("source.assemble", None, 9), ("feed.wait_slot", 5, None),
+    ("ingest.wait_prep", None, 9), ("ingest.wait_slot", None, 9),
+    ("source.decode", None, None)], ids=lambda site: site[0])
+def test_disabled_span_builds_nothing_at_the_new_sites(site):
     """`span(name, seq, batch)` of a disabled tracer: the shared no-op, no
     `**kwargs` in the signature (that alone would build a dict a call),
     and not a byte allocated over a thousand calls (the `with` protocol
-    binds `__enter__`/`__exit__` itself, whatever the object: left out)."""
+    binds `__enter__`/`__exit__` itself, whatever the object: left out).
+    `as sp` binds None, which is how a site that sets `sp.args` inside
+    the block knows to build nothing."""
     t = Tracer(enabled=False)
-    assert t.span("h2d.stage", 5) is _NULL_SPAN
-    assert t.span("source.assemble", None, 9) is _NULL_SPAN
+    assert t.span(*site) is _NULL_SPAN
+    with t.span(*site) as sp:
+        assert sp is None
     kinds = {p.kind for p in inspect.signature(t.span).parameters.values()}
     assert inspect.Parameter.VAR_KEYWORD not in kinds
     span = t.span
@@ -119,12 +129,245 @@ def test_disabled_span_builds_nothing_at_the_new_sites():
         tracemalloc.reset_peak()
         before = tracemalloc.get_traced_memory()[0]
         for _ in calls:
-            span("dispatch.megastep", 5, 9)
+            span(*site)
         now, peak = tracemalloc.get_traced_memory()
     finally:
         tracemalloc.stop()
     assert now == before and peak == before
     assert t.rollup() == {}
+
+
+# --- two clocks ----------------------------------------------------------------
+
+def _sleeps(seconds):
+    time.sleep(seconds)
+
+
+def _spins(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("body,on_cpu", [(_sleeps, False), (_spins, True)])
+def test_span_records_the_threads_cpu_seconds_beside_its_wall(body, on_cpu):
+    """`args.cpu` (microseconds, like `dur`) is what the thread ran inside
+    the span: far under `dur` where it slept, within 20% of it where it
+    spun. Read in sums over a few spans: a host may tick its thread
+    clock in steps of 10 ms (the chip machine's does), and a spin can
+    lose its core to a neighbour on a shared host, so the best of three
+    attempts counts."""
+    shares = []
+    for _ in range(3):
+        t = Tracer(enabled=True)
+        for _ in range(5):
+            with t.span("s"):
+                body(0.03)
+        evs = _spans(t, "s")
+        assert all(e["dur"] >= 30_000 and e["args"]["cpu"] >= 0
+                   for e in evs)
+        shares.append(sum(e["args"]["cpu"] for e in evs)
+                      / sum(e["dur"] for e in evs))
+        if not on_cpu or abs(shares[-1] - 1.0) <= 0.2:
+            break
+    if on_cpu:
+        assert min(abs(s - 1.0) for s in shares) <= 0.2, shares
+    else:
+        assert shares[0] < 0.2
+
+
+@pytest.mark.parametrize("measured", [False, True],
+                         ids=["span", "add_span"])
+def test_rollup_carries_cpu_seconds(measured):
+    """`rollup()` has `cpu_s` beside `total_s` for every stage; an
+    interval handed over ready-measured (`add_span`) has no CPU clock:
+    no `args.cpu`, and nothing added to its stage's `cpu_s`."""
+    t = Tracer(enabled=True)
+    if measured:
+        t.add_span("s", 0.02)
+    else:
+        with t.span("s"):
+            _spins(0.05)
+    st = t.rollup()["s"]
+    assert set(st) == {"count", "total_s", "cpu_s", "p50", "p99"}
+    (ev,) = _spans(t, "s")
+    if measured:
+        assert st["cpu_s"] == 0.0 and "cpu" not in ev["args"]
+    else:
+        assert 0.0 < st["cpu_s"] <= st["total_s"] + 0.011   # a tick's room
+        assert st["cpu_s"] == pytest.approx(ev["args"]["cpu"] * 1e-6,
+                                            abs=2e-6)
+
+
+# --- the feed's waits ----------------------------------------------------------
+
+WAITS = ("source.wait_shard", "feed.wait_slot", "ingest.wait_prep",
+         "ingest.wait_slot")
+
+
+def _seconds(events):
+    return sum(e["dur"] for e in events) * 1e-6
+
+
+class _TimedQueue(queue.Queue):
+    """A queue that keeps what each thread spent inside `put`."""
+
+    puts = None                       # [(thread name, seconds)], set per test
+
+    def put(self, item, *a, **kw):
+        t0 = time.perf_counter()
+        super().put(item, *a, **kw)
+        self.puts.append((threading.current_thread().name,
+                          time.perf_counter() - t0))
+
+
+def test_prefetcher_blocked_on_its_queue_is_a_span(tracer, monkeypatch):
+    """`feed.wait_slot`: `h2d-prefetch` blocked on `q.put(staged)` because
+    the consumer has not taken the previous input; it carries the staged
+    input's `seq`, and its seconds are what the thread spent in `put`."""
+    monkeypatch.setattr(_TimedQueue, "puts", [])
+    monkeypatch.setattr(queue, "Queue", _TimedQueue)
+    n, nap = 6, 0.03
+    src = [SparseBatch(np.ones((4, 2), np.int32), None,
+                       np.ones(4, np.float32), seq=i) for i in range(n)]
+    got = []
+    for b in DevicePrefetcher(iter(src), depth=1):
+        time.sleep(nap)               # the slow consumer
+        got.append(b.seq)
+    assert got == list(range(n))
+    waits = _spans(tracer, "feed.wait_slot")
+    assert [e["args"]["seq"] for e in waits] == list(range(n))
+    assert {e["args"]["thread"] for e in waits} == {"h2d-prefetch"}
+    assert all("parent" not in e["args"] for e in waits)
+    # one staged input sits in the queue, so all but the first two puts
+    # wait out a nap of the consumer's; the pill's put is no span
+    puts = [s for name, s in _TimedQueue.puts if name == "h2d-prefetch"]
+    assert len(puts) == n + 1
+    assert _seconds(waits) == pytest.approx(sum(puts[:n]), abs=1e-3 * n)
+    assert _seconds(waits) > (n - 2) * nap * 0.8
+    # a wait burns no CPU
+    assert sum(e["args"]["cpu"] for e in waits) < 0.2 * sum(
+        e["dur"] for e in waits)
+
+
+@pytest.mark.parametrize("slow", ["fn", "consumer"])
+def test_pipeline_waits_are_spans_equal_to_their_counters(tracer, slow):
+    """`ingest.wait_prep` (the consumer blocked on the pool, beside
+    `prep_wait_seconds`) and `ingest.wait_slot` (`ingest-source` blocked
+    on the full queue, beside `prep_backpressure_seconds`): each span
+    total equals its counter within a millisecond a span."""
+    n, nap = 12, 0.02
+    stats = PipelineStats()
+
+    def fn(x):
+        if slow == "fn":
+            time.sleep(nap)
+        return x
+
+    out = []
+    for x in IngestPipeline(iter(range(n)), fn, workers=2, depth=2,
+                            stats=stats):
+        if slow == "consumer":
+            time.sleep(nap)
+        out.append(x)
+    assert out == list(range(n))
+    prep = _spans(tracer, "ingest.wait_prep")
+    slot = _spans(tracer, "ingest.wait_slot")
+    me = threading.current_thread().name
+    # one wait a batch and one for the stream's end, on the consumer's
+    # thread; one put a batch on the submitter's
+    assert [e["args"]["batch"] for e in prep] == list(range(n)) + [n]
+    assert {e["args"]["thread"] for e in prep} == {me}
+    assert [e["args"]["batch"] for e in slot] == list(range(n))
+    assert {e["args"]["thread"] for e in slot} == {"ingest-source"}
+    assert _seconds(prep) == pytest.approx(stats.prep_wait_seconds,
+                                           abs=1e-3 * len(prep))
+    assert _seconds(slot) == pytest.approx(stats.prep_backpressure_seconds,
+                                           abs=1e-3 * len(slot))
+    # a slow pool keeps the consumer waiting (and, the queue full, the
+    # submitter too); a slow consumer keeps the submitter waiting alone
+    assert _seconds(prep if slow == "fn" else slot) > 0.25 * n * nap
+    if slow == "consumer":
+        assert _seconds(prep) < 0.25 * n * nap
+
+
+def test_sequential_pipeline_has_no_wait_spans(tracer):
+    assert list(IngestPipeline(iter(range(5)), lambda x: x, workers=1)) \
+        == list(range(5))
+    assert len(_spans(tracer, "ingest.prep")) == 5
+    assert not _spans(tracer, "ingest.wait_prep")
+    assert not _spans(tracer, "ingest.wait_slot")
+
+
+@pytest.mark.parametrize("decode_ahead", [1, 0])
+def test_shard_decode_is_a_span_on_the_decode_thread(tracer, tmp_path,
+                                                     decode_ahead):
+    """`source.decode`: one span a shard through `_shard()` on the
+    `pq-decode` thread, with the shard's rows, the same seconds as
+    `stream.stats.prep_seconds`. With `decode_ahead=0` nothing new:
+    `source.wait_shard` is the decode there."""
+    d = _shards(tmp_path, n=1000, rows_per_shard=300)      # 4 shards
+    stream = ParquetStream(d, decode_ahead=decode_ahead)
+    assert sum(1 for _ in stream.batches(64, epochs=1, seed=1)) == 16
+    decodes = _spans(tracer, "source.decode")
+    assert len(_spans(tracer, "source.wait_shard")) == 4
+    if not decode_ahead:
+        assert not decodes
+        return
+    assert sorted(e["args"]["rows"] for e in decodes) == [100, 300, 300, 300]
+    assert all(e["args"]["thread"].startswith("pq-decode") for e in decodes)
+    assert all("parent" not in e["args"] for e in decodes)
+    assert _seconds(decodes) == pytest.approx(stream.stats.prep_seconds,
+                                              abs=1e-3 * len(decodes))
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_feed_loop_threads_are_tiled_by_waits_and_work(tracer, tmp_path,
+                                                       workers):
+    """A loop thread of the feed (`h2d-prefetch`; with a prep pool also
+    `ingest-source`): its window = its wait spans + its top-level work
+    spans + a remainder (the program's own Python between spans), with
+    remainder >= 0. That needs every wait to be a top-level span and no
+    span enveloping the loop."""
+    d = _shards(tmp_path)
+    t = GeneralClassifier(f"{LINEAR} -steps_per_dispatch 4 "
+                          f"-ingest_workers {workers}")
+    _prefetching(t)
+    t.fit_stream(ParquetStream(d).batches(64, epochs=1, seed=3))
+    loops = {"h2d-prefetch"} | ({"ingest-source"} if workers > 1 else set())
+    by_thread = {}
+    for e in _spans(tracer):
+        by_thread.setdefault(e["args"]["thread"], []).append(e)
+    assert loops <= set(by_thread)
+    for name in loops:
+        evs = by_thread[name]
+        top = [e for e in evs if "parent" not in e["args"]]
+        waits = [e for e in evs if e["name"] in WAITS]
+        assert waits and all("parent" not in e["args"] for e in waits)
+        work = [e for e in top if e["name"] not in WAITS]
+        assert work
+        window = max(e["ts"] + e["dur"] for e in evs) \
+            - min(e["ts"] for e in evs)
+        covered = sum(e["dur"] for e in top)
+        remainder = window - covered
+        # top-level spans of one thread never overlap, so they fit the
+        # window (3 us of rounding a span in the export)
+        assert remainder >= -3.0 * len(top), (name, remainder)
+        assert sum(e["dur"] for e in waits) + sum(e["dur"] for e in work) \
+            + remainder == pytest.approx(window)
+        top.sort(key=lambda e: e["ts"])
+        for a, b in zip(top, top[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 3.0, (name, a, b)
+    # what each loop thread waits for
+    names = {n: {e["name"] for e in by_thread[n]} for n in loops}
+    assert "feed.wait_slot" in names["h2d-prefetch"]
+    if workers > 1:
+        assert "ingest.wait_prep" in names["h2d-prefetch"]
+        assert {"source.wait_shard", "ingest.wait_slot"} \
+            <= names["ingest-source"]
+    else:
+        assert "source.wait_shard" in names["h2d-prefetch"]
+    assert tracer.dropped == 0
 
 
 # --- ids through the pipeline -------------------------------------------------
