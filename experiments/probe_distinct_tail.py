@@ -102,6 +102,7 @@ class FFM:
     distinct rows is recorded beside the asked one)."""
     name, steps, W, itemsize = "ffm", 4, 164, 2
     F, K = (8, 4) if TINY else (39, 4)
+    step_kw: dict = {}                  # more of make_ffm_step_fused's
 
     def __init__(self):
         self.W = self.F * self.K + 8
@@ -115,7 +116,8 @@ class FFM:
 
     def program(self):
         step = fm.make_ffm_step_fused(LOSS, OPT, (0.0, 0.0, 0.0), self.F,
-                                      self.K, fieldmajor=True, unit_val=True)
+                                      self.K, fieldmajor=True, unit_val=True,
+                                      **self.step_kw)
         mega = make_megastep(step.core)
         nv = jnp.full((self.steps,), B, jnp.int32)
 

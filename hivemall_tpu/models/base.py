@@ -865,7 +865,14 @@ class LearnerBase:
         batch arrays sharded over 'dp' (XLA inserts the gradient psum that
         replaces MixServer averaging), every dims-sized state axis sharded
         over 'tp' (feature-dim sharding, the context-parallel analog), the
-        rest replicated. fit()/process() are unchanged.
+        rest replicated. fit()/process() are unchanged. A trainer may hand
+        the mesh to its step's factory instead where the mesh it sees lets
+        every chip run the one-chip step on its own rows: `train_ffm`'s
+        joint step under dp == 1, tp > 1 (`ops/fm.py`
+        `make_ffm_step_fused(mesh=)`, `shard_map` over 'tp': the
+        distinct-row tail and the gather through the distinct rows, which
+        GSPMD's cut of the dense step has not); state and inputs are
+        placed the same either way.
 
         State that ``_make_state`` built is on the mesh already; whatever
         was built whole on one device, or loaded over it (-loadmodel), is

@@ -90,13 +90,13 @@ def _fm_step_cached(loss_name, opt, eta_scheme, eta0, total_steps,
 @_lru_cache(maxsize=64)
 def _ffm_step_fused_cached(loss_name, opt, eta_scheme, eta0, total_steps,
                            power_t, lambdas, F, k, fieldmajor, unit_val,
-                           distinct_tail=True):
+                           distinct_tail=True, mesh=None):
     return make_ffm_step_fused(
         get_loss(loss_name),
         make_optimizer_cached(opt, eta_scheme, eta0, total_steps,
                               power_t),
         lambdas, F, k, fieldmajor=fieldmajor, unit_val=unit_val,
-        distinct_tail=distinct_tail)
+        distinct_tail=distinct_tail, mesh=mesh)
 
 
 @_instrument("ffm", "step")
@@ -847,15 +847,23 @@ class FFMTrainer(FMTrainer):
             self.params, self.opt_state = self._make_state(
                 _fused_state_init(self.optimizer, self.Mr, 1, FK, self.W,
                                   dtype), key, float(o.sigma))
-            # under -mesh the dense tail stays, as for train_fm
             head = (self._loss_name, *self._opt_key,
                     (o.lambda0, o.lambda_w, o.lambda_v), self.F, self.k)
-            tail = not o.get("mesh")
-            self._step = _ffm_step_fused_cached(*head, False, False, tail)
+            # what the mesh shows decides which step a chip runs. Rows
+            # alone dealt out (dp == 1): the one-chip step on each chip's
+            # block (shard_map over tp), whose tail rank_rows picks from
+            # the block and the optimizer as it does on one chip. A dp
+            # axis (a gradient summed over replicas whose distinct rows
+            # differ): GSPMD's cut of the dense step
+            blocks = self.mesh if (
+                self.mesh is not None and self.mesh.shape["dp"] == 1
+                and self.mesh.shape["tp"] > 1) else None
+            tail = (self.mesh is None or blocks is not None, blocks)
+            self._step = _ffm_step_fused_cached(*head, False, False, *tail)
             self._step_fm = None if self.interaction == "pairs" else \
-                _ffm_step_fused_cached(*head, True, False, tail)
+                _ffm_step_fused_cached(*head, True, False, *tail)
             self._step_fm_unit = None if self.interaction == "pairs" else \
-                _ffm_step_fused_cached(*head, True, True, tail)
+                _ffm_step_fused_cached(*head, True, True, *tail)
             self._fused_score = _ffm_score_fused_cached(self.F, self.k)
             self._fused_score_fm = _ffm_score_fieldmajor_cached(self.F,
                                                                 self.k)

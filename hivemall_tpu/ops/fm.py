@@ -351,7 +351,8 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                         F: int, K: int,
                         fieldmajor: bool = False,
                         unit_val: bool = False,
-                        distinct_tail: bool = True) -> Callable:
+                        distinct_tail: bool = True,
+                        mesh=None) -> Callable:
     """The flagship train_ffm step — fused feature-row joint layout.
 
     Design (measured on v5e, B=32k L=40: 9.85 s/step -> 103 ms/step):
@@ -377,7 +378,29 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     the sum goes into a compact gradient and the update to the batch's
     distinct rows; else into a dense G with an update over [Mr, W] (any
     -opt works there). ``distinct_tail=False`` keeps the dense tail
-    whatever the shapes: the trainer's choice under -mesh, not a user's.
+    whatever the shapes: the trainer's choice for a mesh this step is
+    not handed, not a user's.
+
+    ``mesh`` (a (dp, tp) mesh with dp == 1; None: one chip, or GSPMD's
+    cut of this same program) runs the step under `jax.shard_map` over
+    'tp', T and its state in their row sharding P('tp', None), w0 and
+    the batch replicated: every chip makes the calls the one chip makes,
+    rank_rows -> gather_rows -> fwd/bwd -> rows_update, on its own
+    [Mr/tp, W] block. The hash folds to the GLOBAL Mr and a chip's slots
+    are those whose row falls in its block. TWO shard_maps with no
+    collective in either: the first ranks and gathers and hands out the
+    blocks' slabs, zero at the others' slots, stacked over 'tp'; their
+    sum (each slot has one owner, so it is T[rows] bit for bit) is a
+    plain reduction between the two, which the partitioner makes the
+    all-reduce its cut of the dense step has: the step's one collective
+    of any size, and named in a trace as GSPMD names its own. In the
+    second, forward and backward run replicated on the whole slab and
+    each chip applies the gradient of its own slots, taking its own
+    branch of each cond by its own count of distinct rows (an optimizer
+    rank_rows does not rank for: the dense tail on each block). The
+    stats come out replicated: distinct_rows the sum over the chips,
+    tail_distinct_steps and gather_compact_steps 1 only where EVERY chip
+    took that branch (else the step counts as dense).
 
     The fieldmajor step takes no field array (the layout IS the field
     assignment: slot s -> field s % F).
@@ -392,16 +415,27 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     squares; L2 (-lambda*) is still applied per-occurrence at slab level.
     """
     lam0, lam_w, lam_v = lambdas
+    block = mesh is not None            # T in the step: a chip's block
+    tp = mesh.shape["tp"] if block else 1
 
-    def body(params, opt_state, t, idx, val, label, row_mask, field):
+    def front(params, opt_state, idx):
+        T = params["T"]
+        R = T.shape[0]                  # under a mesh: this chip's block
+        with jax.named_scope("hm.gather"):
+            rows = ffm_row_hash(idx, R * tp)
+            if block:                   # another block's slot: the id R
+                rows = rows - jax.lax.axis_index("tp") * R
+                rows = jnp.where((rows >= 0) & (rows < R), rows, R)
+        ranks = rank_rows(rows.reshape(-1), T, opt_state["T"], optimizer,
+                          None if distinct_tail else 0, block)
+        slab, compact = gather_rows(T, rows, ranks, block)  # own dtype
+        return rows, ranks, compact, slab
+
+    def back(params, opt_state, t, rows, ranks, compact, slab, val, label,
+             row_mask, field):
         T, w0 = params["T"], params["w0"]
         FK = F * K
         W = T.shape[1]
-        with jax.named_scope("hm.gather"):
-            rows = ffm_row_hash(idx, T.shape[0])
-        ranks = rank_rows(rows.reshape(-1), T, opt_state["T"], optimizer,
-                          None if distinct_tail else 0)
-        slab, compact = gather_rows(T, rows, ranks)  # own dtype
 
         def batch_loss(w0f, slabf):
             if fieldmajor:
@@ -435,6 +469,53 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                                        opt_state["w0"], t)
         return ({"T": Tn, "w0": w0n.astype(w0.dtype)},
                 {"T": sT, "w0": s0}, loss_sum, stats)
+
+    def body(params, opt_state, t, idx, *batch):
+        return back(params, opt_state, t, *front(params, opt_state, idx),
+                    *batch)
+
+    if block:
+        from jax.sharding import PartitionSpec as P
+        if mesh.shape["dp"] != 1:
+            raise ValueError("make_ffm_step_fused(mesh=) deals rows over "
+                             "'tp' alone; a dp axis sums a gradient over "
+                             "replicas: leave that mesh to GSPMD")
+        tables, chips = {"T": P("tp", None), "w0": P()}, P("tp")
+
+        def lead(tree):                 # a chip's own, stacked over 'tp'
+            return jax.tree_util.tree_map(lambda a: a[None], tree)
+
+        def own(tree):
+            return jax.tree_util.tree_map(lambda a: a[0], tree)
+
+        def body(params, opt_state, t, idx, *batch):
+            # a None (no val, no field, no ranking) is an empty pytree to
+            # a spec. No replication check: a branch of a cond or a trip
+            # of a loop may leave a constant where the other leaves a
+            # chip's own
+            if params["T"].shape[0] % tp:
+                raise ValueError(f"T's {params['T'].shape[0]} rows do not "
+                                 f"deal into {tp} equal blocks over 'tp'")
+            *mine, slabs = jax.shard_map(
+                lambda *a: lead(front(*a)), mesh=mesh,
+                in_specs=(tables, tables, P()), out_specs=chips,
+                check_vma=False)(params, opt_state, idx)
+            with jax.named_scope("hm.gather"):
+                # one owner a slot, so exact in the table's own dtype
+                slab = jax.lax.reduce(slabs, jnp.zeros((), slabs.dtype),
+                                      jax.lax.add, (0,))
+
+            def local(params, opt_state, t, mine, slab, *batch):
+                *out, stats = back(params, opt_state, t, *own(mine), slab,
+                                   *batch)
+                return (*out, lead(stats))
+            *out, stats = jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(tables, tables, P(), chips) + (P(),) * (
+                    1 + len(batch)),
+                out_specs=(tables, tables, P(), chips),
+                check_vma=False)(params, opt_state, t, mine, slab, *batch)
+            return (*out, _stats_over_blocks(stats))
 
     if unit_val:
         assert fieldmajor, "unit_val implies the canonical fieldmajor batch"
@@ -674,6 +755,17 @@ TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows",
               "gather_compact_steps")
 
 
+def _stats_over_blocks(stats: dict) -> dict:
+    """TAIL_STATS of one step whose table is dealt in blocks over a mesh's
+    chips, from each block's own, stacked [blocks]: the distinct rows
+    summed (the blocks share none), a branch counted as taken where every
+    block took it, and the step's tail dense wherever one block's was."""
+    every = {k: stats[k].min(0)
+             for k in ("tail_distinct_steps", "gather_compact_steps")}
+    return {**every, "tail_dense_steps": 1 - every["tail_distinct_steps"],
+            "distinct_rows": stats["distinct_rows"].sum(0)}
+
+
 def _tail_cost(W: int, itemsize: int) -> _TailCost:
     """The readings at (W, itemsize). A shape nobody read moves its rows
     as the flagship's do (only (128, 4) has the kernel): the flagship's,
@@ -750,10 +842,19 @@ class RowRanks(NamedTuple):
     n_distinct: jax.Array   #: int32 scalar
 
 
-def rank_rows(rows, T, state, optimizer: Optimizer, cap=None):
+def rank_rows(rows, T, state, optimizer: Optimizer, cap=None,
+              block: bool = False):
     """Rank the slots ``rows`` [n] of a batch by table row, once, for
     `gather_rows` and `rows_update`; None where the step takes the dense
     tail whatever the batch holds, and then has no ranking in its program.
+
+    ``block`` says that T is one block of a table whose rows are dealt
+    over the chips of a mesh, and ``rows`` the block's own numbering, in
+    which a slot of another block carries the id R. Such slots sort behind
+    every row of the block, are not counted in ``n_distinct``, never enter
+    ``urows`` and rank at the capacity, so that the gather reads nothing
+    for them and the tail's `mode="drop"` adds nothing. Capacities follow
+    the shapes handed in: the block's.
 
     ``cap`` (None: tail_cap of T [R, W] and the ``state`` leaves' shapes, 0
     for an optimizer that moves a zero-gradient row) is static: the most
@@ -776,12 +877,16 @@ def rank_rows(rows, T, state, optimizer: Optimizer, cap=None):
         srows, perm = jax.lax.sort_key_val(rows, slot)
         first = jnp.concatenate(
             [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
+        if block:
+            first &= srows < R
         n_distinct = first.sum(dtype=jnp.int32)
 
     def listed():
         with jax.named_scope("hm.scatter"):
-            return (jnp.cumsum(first.astype(jnp.int32)) - 1,
-                    jnp.sort(jnp.where(first, srows, R + slot))[:cap])
+            rank = jnp.cumsum(first.astype(jnp.int32)) - 1
+            if block:
+                rank = jnp.where(srows < R, rank, cap)
+            return rank, jnp.sort(jnp.where(first, srows, R + slot))[:cap]
     # a batch over the capacity reads neither: its step stays the dense
     # tail's, which is fed the sorted rows alone (1.9 ms less)
     rank, urows = jax.lax.cond(
@@ -790,13 +895,15 @@ def rank_rows(rows, T, state, optimizer: Optimizer, cap=None):
     return RowRanks(srows, perm, rank, urows, n_distinct)
 
 
-def gather_rows(T, rows, ranks: Optional[RowRanks]):
+def gather_rows(T, rows, ranks: Optional[RowRanks], block: bool = False):
     """``T[rows]`` (rows of any shape), bit for bit, and whether it was
     read through the batch's distinct rows (an int32 scalar, the step's
     ``gather_compact_steps``): where `rank_rows` ranked them and the batch
     holds at most `gather_cap` of them, the distinct rows are read out of
     the table ONCE into a compact C [gather_cap, W], and every slot reads
-    C at its row's rank.
+    C at its row's rank. Of a ``block`` of a table (`rank_rows`) a slot
+    of another block, the id R, reads zeros, so that the blocks' slabs sum
+    to the whole table's.
 
     The rank of a SLOT is the sorted slots' rank carried back by one more
     key-value sort, of (perm, rank): an int32 scatter of as many scalars
@@ -811,10 +918,18 @@ def gather_rows(T, rows, ranks: Optional[RowRanks]):
         with jax.named_scope("hm.gather"):
             return T[rows]
 
+    def of_the_block(slab):
+        # behind the cond, not in its branches: the compiler hoists a
+        # select both branches end in, and materialises its mask for it
+        if not block:
+            return slab
+        with jax.named_scope("hm.gather"):
+            return jnp.where((rows < T.shape[0])[..., None], slab, 0)
+
     cap = gather_cap(ranks.urows.shape[0], T.shape[1], T.dtype.itemsize) \
         if ranks is not None else 0
     if not cap:
-        return direct(), jnp.zeros((), jnp.int32)
+        return of_the_block(direct()), jnp.zeros((), jnp.int32)
     tb = min(cap, FILL_BLOCK_ROWS)
 
     def compact():
@@ -837,7 +952,8 @@ def gather_rows(T, rows, ranks: Optional[RowRanks]):
                 jnp.zeros((cap, T.shape[1]), T.dtype))
             return C[rank_of_slot.reshape(rows.shape)]
     fits = ranks.n_distinct <= cap
-    return jax.lax.cond(fits, compact, direct), fits.astype(jnp.int32)
+    return (of_the_block(jax.lax.cond(fits, compact, direct)),
+            fits.astype(jnp.int32))
 
 
 def rows_update(T, state, rows, g, optimizer: Optimizer, t,
@@ -871,7 +987,10 @@ def rows_update(T, state, rows, g, optimizer: Optimizer, t,
 
     ``ranks`` None is the dense tail alone. A batch with more distinct
     rows than the ranking's capacity takes the dense tail too (lax.cond),
-    fed the same sorted rows."""
+    fed the same sorted rows. Where T is a block of a table (`rank_rows`'
+    ``block``), a slot of another block carries the id R, or the rank
+    ``cap``, and either scatter-add drops it: its gradient row is added
+    by the chip that holds its row."""
     R, W = T.shape
     tree = jax.tree_util.tree_structure(state)
 

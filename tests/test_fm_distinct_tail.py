@@ -936,13 +936,57 @@ def test_tail_counters_fold_for_train_ffm(k, pack):
     assert f"gather on the distinct rows x{steps}" in report
 
 
-def test_ffm_mesh_trainer_keeps_the_dense_tail():
+@pytest.mark.parametrize("tp,k", [(2, 1), (4, 1), (4, 4)],
+                         ids=["tp2", "tp4", "tp4_megastep"])
+def test_ffm_mesh_trainer_takes_the_distinct_tail(tp, k):
+    """`train_ffm -mesh dp=1,tp>1` (AdaGrad): the trainer hands its mesh
+    to the step's factory, every chip runs the one-chip step on its own
+    block of rows, and the counters say so: every step the distinct-row
+    tail and the gather through the distinct rows on EVERY chip,
+    `distinct_rows` the one-device trainer's count (the chips' summed)."""
     from hivemall_tpu.models.fm import FFMTrainer
-    if len(jax.devices()) < 2:
-        pytest.skip("needs two devices")
-    t = FFMTrainer(f"{_FFM_OPTS} -mesh dp=1,tp=2")
-    t.fit(_ffm_trainer_stream(4, 32, 20, 1 << 20))
-    assert t.cumulative_loss == t.cumulative_loss
+    if len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} devices")
+    opts = f"{_FFM_OPTS} -steps_per_dispatch {k}"
+    t = FFMTrainer(f"{opts} -mesh dp=1,tp={tp}")
+    assert fm.tail_cap(32 * 8, t.Mr // tp, t.W, 2) == 256
+    one = FFMTrainer(opts)
+    for trainer in (t, one):
+        trainer.fit_stream(_ffm_trainer_stream(8, 32, 20, 1 << 20).batches(
+            32, shuffle=False))
+    assert t._t == one._t == 8 and np.isfinite(t.cumulative_loss)
+    assert t.cumulative_loss == pytest.approx(one.cumulative_loss, rel=1e-5)
+    assert dict(t._step_counts) == dict(one._step_counts)   # folded above
+    assert t._step_counts["tail_distinct_steps"] == t._t
+    assert t._step_counts["gather_compact_steps"] == t._t
+    assert t._step_counts["tail_dense_steps"] == 0
+    assert 8 < t._step_counts["distinct_rows"] <= 8 * 20
+    assert t.params["T"].sharding.spec == jax.sharding.PartitionSpec(
+        "tp", None)
+    np.testing.assert_allclose(np.asarray(t.params["T"], np.float32),
+                               np.asarray(one.params["T"], np.float32),
+                               rtol=2.0 ** -6, atol=1e-6)
+
+
+@pytest.mark.parametrize("opt,mesh", [("", "dp=2,tp=2"),
+                                      ("-opt ftrl", "dp=1,tp=2"),
+                                      ("", "dp=2,tp=1")],
+                         ids=["dp2_tp2", "ftrl", "dp2"])
+def test_ffm_mesh_trainer_keeps_the_dense_tail(opt, mesh):
+    """A dp axis sums a gradient over replicas whose distinct rows differ:
+    GSPMD's cut of the dense step, as before PR 36. FTRL rebuilds every
+    row at every t: under dp=1,tp=2 it runs the step every such mesh runs,
+    each chip on its block, and `rank_rows` keeps the dense tail there as
+    it does on one chip. Either way the one-device trainer's loss."""
+    from hivemall_tpu.models.fm import FFMTrainer
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    t = FFMTrainer(f"{_FFM_OPTS} {opt} -mesh {mesh}")
+    one = FFMTrainer(f"{_FFM_OPTS} {opt}")
+    for trainer in (t, one):
+        trainer.fit(_ffm_trainer_stream(4, 32, 20, 1 << 20))
+    assert t.cumulative_loss == pytest.approx(one.cumulative_loss, rel=1e-5)
     assert t._step_counts["tail_distinct_steps"] == 0
     assert t._step_counts["gather_compact_steps"] == 0
+    assert t._step_counts["distinct_rows"] == 0
     assert t._step_counts["tail_dense_steps"] == t._t == 4

@@ -66,6 +66,20 @@ def test_ffm_joint_megastep_compiles_for_v5e_with_the_distinct_tail():
     _compile("ffm_joint_megastep", timeout=600)
 
 
+def test_ffm_joint_megastep_compiles_for_four_v5e_chips_as_the_one_chips():
+    """The same megastep under `-mesh dp=1,tp=4` at the four-chip cell's
+    geometry (`-dims 2^30`: [4194304, 164] a chip; PR 36), two
+    `shard_map`s over tp: the worker asserts that a chip's program is the one chip's
+    (three `conditional`, three `while`, the compact table of the chip's
+    own distinct rows in fast memory, no Mosaic kernel), that a batch
+    within the capacities runs no zero fill of a table-sized gradient and
+    no table-sized AdaGrad pass (the parent's GSPMD program ran both every
+    step), and that the one collective of any size is the slab's
+    all-reduce, bf16[32768,39,164], made and NAMED by the partitioner
+    (the benchmark reads collectives by name) (~40 s of XLA compile)."""
+    _compile("ffm_joint_megastep_tp4", timeout=600)
+
+
 def test_state_initialiser_compiles_for_v5e_within_a_chip():
     """The fused tables' jitted initialiser at the size no one chip holds
     (PR 31): `train_ffm -dims 2^30 -halffloat` over tp=4, every output

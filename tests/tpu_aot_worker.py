@@ -65,6 +65,10 @@ def _mesh_2x2():
     return Mesh(np.asarray(TOPO.devices).reshape(2, 2), ("dp", "tp"))
 
 
+def _mesh_1x4():
+    return Mesh(np.asarray(TOPO.devices).reshape(1, 4), ("dp", "tp"))
+
+
 def parts_accum_kernel_2x2():
     """The sharded step's accumulate kernel at its per-rank flagship
     shapes (B=32768 over dp=2, F=40 over tp=2), one instance per device."""
@@ -118,6 +122,39 @@ def _compact_table_spaces(text, shape):
     loop = re.search(r" while\((?:(?!\n).)*?/hm\.gather/while", text)
     line = text[text.rindex("\n", 0, loop.start()):loop.end()]
     return set(re.findall(r"%s\{[^}]*?(S\(\d\))?\}" % re.escape(shape), line))
+
+
+def _fitting_path(text):
+    """The instructions of a compiled module that run when every
+    `conditional` takes its LAST branch (`lax.cond`'s true one: a batch
+    within the capacities), from ENTRY down through fusions, calls and
+    loops."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    entry = re.search(r"\nENTRY %([\w.-]+) ", text).group(1)
+    seen, todo, lines = set(), [entry], []
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            lines.append(line)
+            branches = re.search(r"branch_computations=\{([^}]*)\}", line)
+            if branches:
+                todo.append(branches.group(1).split(",")[-1].strip(" %"))
+            todo += re.findall(
+                r"(?:calls|body|condition|to_apply|true_computation)=%"
+                r"([\w.-]+)", line)
+    return lines
 
 
 def _assert_compact_gather(text, slab, compact, table):
@@ -249,6 +286,79 @@ def ffm_joint_megastep():
     assert temp <= 15.1e9, f"{temp / 1e9:.2f} GB of temporaries"
 
 
+def ffm_joint_megastep_tp4():
+    """The same megastep as a chip of four runs it (PR 36): the cell
+    ffm_criteo_joint_tp4.stream_mesh's geometry (-dims 2^30: 16,777,216
+    rows over `-mesh dp=1,tp=4`, so [4194304, 164] a chip, the one-chip
+    flagship's block), `make_ffm_step_fused(mesh=...)` under `shard_map`
+    over tp (two of them, the slabs' sum between). A chip's program is
+    the one chip's: no zero fill of a float32 [4194304, 164] gradient and no table-sized AdaGrad pass
+    outside the dense branch of the tail's `conditional`, the compact
+    table of the chip's own distinct rows in fast memory, ONE all-reduce,
+    of the slab (bf16[32768,39,164], 419 MB) and of nothing else but
+    scalars (the step's stats), a chip's outputs its block of the state
+    and its temporaries the one-chip program's."""
+    from hivemall_tpu.ops.scan import make_megastep
+    Fj, B, L, ks, tp = 39, 32768, 39, 2, 4
+    Mr, W = 1 << 24, Fj * K + 8
+    mesh = _mesh_1x4()
+    rows, everywhere = NamedSharding(mesh, P("tp", None)), \
+        NamedSharding(mesh, P())
+    opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
+                         reg="no")
+    step = fm.make_ffm_step_fused(get_loss("logloss"), opt, LAMS, Fj, K,
+                                  fieldmajor=True, unit_val=True, mesh=mesh)
+    cap = fm.tail_cap(B * L, Mr // tp, W, 2)
+    assert cap == 174080, cap           # the one-chip flagship's
+    w0 = _sds((), jnp.float32, everywhere)
+    compiled = make_megastep(step.core).lower(
+        {"T": _sds((Mr, W), jnp.bfloat16, rows), "w0": w0},
+        {"T": {"gg": _sds((Mr, W), jnp.float32, rows)}, "w0": {"gg": w0}},
+        w0, _sds((ks,), jnp.int32, everywhere),
+        _sds((ks, B, L), jnp.int32, everywhere), None,
+        _sds((ks, B), jnp.float32, everywhere), None, None).compile()
+    text = compiled.as_text()
+    conds, loops = text.count(" conditional("), text.count(" while(")
+    assert conds == 3, \
+        f"{conds} conditional op(s), expected the list, gather and tail"
+    assert loops == 3, \
+        f"{loops} while op(s), expected the scan, the blocks and the fill"
+    block = f"[{Mr // tp},{W}]"
+    _assert_compact_gather(
+        text, f"bf16[{B},{L},{W}]",
+        f"bf16[{fm.gather_cap(cap, W, 2)},{W}]", f"bf16{block}")
+    assert "tpu_custom_call" not in text
+    # what the parent's GSPMD program ran every step, the zero fill of a
+    # table-sized G and the elementwise pass over table and state, is in
+    # the dense branch of the tail's cond and nowhere a fitting batch goes
+    # (the relayouts at the megastep's two ends are copies, the blocks'
+    # row scatters update in place)
+    dense = [line.split(" = ")[0].strip() for line in _fitting_path(text)
+             if re.search(r"= \(?[^=]*%s[^=]* (broadcast\(|fusion\(.*"
+                          r"kind=kLoop)" % re.escape(block), line)]
+    assert not dense, f"table-sized passes a fitting batch runs: {dense}"
+    # named by the partitioner as its own are (`mesh.collective_share`
+    # reads a trace's ops by name; a `lax.psum` would be `psum.N`)
+    reduces = re.findall(r"%all-reduce\S* = (\w+\[[\d,]*\])\S* "
+                         r"all-reduce(?:-start)?\(", text)
+    assert len(reduces) == len(re.findall(r" all-reduce(?:-start)?\(",
+                                          text)), "an all-reduce unnamed"
+    big = [r for r in reduces if r != f"bf16[{B},{L},{W}]"
+           and np.prod([int(d) for d in re.findall(r"\d+", r)[1:]]) > 64]
+    assert reduces.count(f"bf16[{B},{L},{W}]") == 1 and not big, reduces
+    for kind in ("all-gather", "collective-permute", "all-to-all",
+                 "reduce-scatter"):
+        assert f" {kind}(" not in text and f" {kind}-start(" not in text, \
+            f"{kind} in the sharded megastep"
+    # a chip's outputs are its block of the state, its temporaries no
+    # more than the one chip's program reads (15.04 GB, the dense
+    # branch's buffers, which the chip overlays: no bound, see there)
+    mem = compiled.memory_analysis()
+    state = Mr // tp * W * (2 + 4)
+    assert abs(mem.output_size_in_bytes - state) < 0.05 * state, mem
+    assert mem.temp_size_in_bytes <= 15.1e9, mem
+
+
 def state_init():
     """The fused tables' initialiser (models/fm.py `_fused_state_init`,
     the function `LearnerBase._make_state` jits) at the size no one chip
@@ -261,7 +371,7 @@ def state_init():
     opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
                          reg="no")
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    mesh = Mesh(np.asarray(TOPO.devices).reshape(1, 4), ("dp", "tp"))
+    mesh = _mesh_1x4()
     rows, everywhere = NamedSharding(mesh, P("tp", None)), \
         NamedSharding(mesh, P())
     Mr, FK = 1 << 24, 39 * 4
@@ -355,7 +465,8 @@ def hist_sorted():
 
 CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
                                  parts_step_sharded, fm_minibatch_step,
-                                 ffm_joint_megastep, hist_flat, hist_dense,
+                                 ffm_joint_megastep, ffm_joint_megastep_tp4,
+                                 hist_flat, hist_dense,
                                  hist_sorted, state_init, linear_megastep)}
 
 if __name__ == "__main__":
