@@ -86,22 +86,24 @@ def table_to_dataset(table, *, feature_col: str = "features",
     """One pyarrow Table -> SparseDataset (schemas per module docstring)."""
     import pyarrow as pa
     names = set(table.column_names)
+    # copy=False: a column already of the dtype is used where Arrow holds
+    # it (read-only), not copied on the decode thread
     labels = table.column(label_col).to_numpy(
-        zero_copy_only=False).astype(np.float32)
+        zero_copy_only=False).astype(np.float32, copy=False)
 
     if "indices" in names:              # pre-parsed CSR fast path
         col = table.column("indices").combine_chunks()
-        indices = col.flatten().to_numpy().astype(np.int32)
+        indices = col.flatten().to_numpy().astype(np.int32, copy=False)
         indptr = col.offsets.to_numpy().astype(np.int64)
         if "values" in names:
             values = table.column("values").combine_chunks().flatten() \
-                .to_numpy().astype(np.float32)
+                .to_numpy().astype(np.float32, copy=False)
         else:
             values = np.ones(len(indices), np.float32)
         fields = None
         if "fields" in names:
             fields = table.column("fields").combine_chunks().flatten() \
-                .to_numpy().astype(np.int32)
+                .to_numpy().astype(np.int32, copy=False)
         return SparseDataset(indices, indptr, values, labels, fields)
 
     col = table.column(feature_col).combine_chunks()
@@ -376,16 +378,17 @@ class ParquetStream:
         L = max_len or self.max_row_len
         rng = np.random.default_rng(seed)
         # source.assemble: one span per batch yielded, carrying the batch's
-        # ordinal in this traversal. It covers the work (the batch's row
-        # gather and padding in SparseDataset.batches_in_order; for a
-        # shard's FIRST batch also the carry's concat, the shard's
-        # permutation and the copy of its remainder, under a batch of rows
-        # each), never the time this generator sits suspended at `yield`
-        # nor the wait for a decoded shard (source.wait_shard, in
+        # ordinal in this traversal. It covers the work (the batch's gather
+        # in SparseDataset.batches_in_order: whole rows out of [n, r] views
+        # of the shard's arrays where its rows are one length, else slot by
+        # slot; for a shard's FIRST batch also the carry's concat, the
+        # shard's permutation and the copy of its remainder, under a batch
+        # of rows each), never the time this generator sits suspended at
+        # `yield` nor the wait for a decoded shard (source.wait_shard, in
         # _iter_shards). A shard is gathered ONCE: its batches come
-        # straight from the decoded CSR arrays in shuffled row order, with
-        # no reordered copy of the shard in between. The rng calls keep
-        # their number and order, so a seed's batches never change.
+        # straight from the decoded arrays in shuffled row order, with no
+        # reordered copy of the shard in between. The rng calls keep their
+        # number and order, so a seed's batches never change.
         tracer = get_tracer()
         n_out = 0
         for ep in range(epochs):

@@ -489,21 +489,75 @@ class SparseDataset:
                 order, batch_size, L, truncate=truncate,
                 drop_remainder=drop_remainder)
 
+    def rows2d(self, L: int, lens: Optional[np.ndarray] = None
+               ) -> Optional[Tuple[np.ndarray, np.ndarray,
+                                   Optional[np.ndarray]]]:
+        """Where every row is ``r`` long: ``(indices, values, fields)`` as
+        row-major ``[n, min(r, L)]`` reshape VIEWS of the CSR arrays
+        (fields None where the dataset has none). No copy is made and
+        nothing is kept, so the dataset holds no more than its CSR arrays.
+        None where the rows differ in length (or there are none): their
+        batches gather slot by slot. ``lens``: the row lengths, where the
+        caller has them."""
+        n, ip = len(self), self.indptr
+        if lens is None:
+            lens = np.diff(ip)
+        if not n or not np.all(lens == lens[0]):
+            return None
+        s, r = int(ip[0]), int(lens[0])
+        W = min(r, L)
+
+        def cut(a):
+            return a[s:s + n * r].reshape(n, r)[:, :W]
+        return (cut(self.indices), cut(self.values),
+                None if self.fields is None else cut(self.fields))
+
     def batches_in_order(self, order: np.ndarray, batch_size: int, L: int,
                          *, truncate: bool = False,
                          drop_remainder: bool = False
                          ) -> Iterator[SparseBatch]:
         """Padded ``[batch_size, L]`` batches of rows ``order``, in that
-        order, gathered straight from this dataset's CSR arrays: THE batch
-        assembly, shared by :meth:`batches` and ``ParquetStream.batches``
-        (which passes a shard's shuffled row order, so no reordered copy
-        of the shard is ever made). The last batch is short
+        order: THE batch assembly, shared by :meth:`batches` and
+        ``ParquetStream.batches`` (which passes a shard's shuffled row
+        order, so no reordered copy of the shard is ever made). Where
+        every row is one length (Criteo's 39), a batch is a gather of
+        whole rows out of :meth:`rows2d`'s views; other datasets gather
+        slot by slot out of the CSR arrays. The last batch is short
         (``n_valid``) when ``order`` is no multiple of ``batch_size``."""
         lens = np.diff(self.indptr).astype(np.int64)
         if not truncate and len(lens) and int(lens.max()) > L:
             raise ValueError(
                 f"max_len={L} would drop features from rows up to "
                 f"{int(lens.max())} long; pass truncate=True to allow")
+        grid = self.rows2d(L, lens)
+        if grid is None:
+            yield from self._gather_slots(order, batch_size, L, lens,
+                                          drop_remainder)
+            return
+        W = grid[0].shape[1]
+        for s in range(0, len(order), batch_size):
+            take = order[s: s + batch_size]
+            nv = len(take)
+            if nv < batch_size and drop_remainder:
+                break
+            if nv == batch_size and W == L:
+                # np.take copies whole rows faster than fancy indexing
+                idx, val, fld = (None if a is None else
+                                 np.take(a, take, axis=0) for a in grid)
+                yield SparseBatch(idx, val, self.labels[take], fld)
+                continue
+            idx, val, fld = (None if a is None else
+                             np.zeros((batch_size, L), a.dtype)
+                             for a in grid)
+            for out, a in zip((idx, val, fld), grid):
+                if out is not None:
+                    out[:nv, :W] = a[take]
+            lab = np.zeros(batch_size, np.float32)
+            lab[:nv] = self.labels[take]
+            yield SparseBatch(idx, val, lab, fld,
+                              n_valid=nv if nv < batch_size else None)
+
+    def _gather_slots(self, order, batch_size, L, lens, drop_remainder):
         pos = np.arange(L, dtype=np.int64)[None, :]           # [1, L]
         for s in range(0, len(order), batch_size):
             take = order[s: s + batch_size]

@@ -11,7 +11,9 @@ Span sites (the training pipeline's real seams — docs/OBSERVABILITY.md):
 ``source.wait_shard``     ParquetStream blocked on the next decoded shard
 ``source.decode``         one shard read and decoded on the ``pq-decode``
                           thread, ``args.rows`` its rows (work, not a wait)
-``source.assemble``       one source batch: row gather + padding (the
+``source.assemble``       one source batch: whole rows gathered out of
+                          [n, r] views where the shard's rows are one
+                          length, else slot by slot, and padding (the
                           shard's concat/shuffle/take rides on its first
                           batch); never the time suspended at ``yield``
 ``source.note_batch``     the trainer's per-batch stream-order hook

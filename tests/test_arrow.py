@@ -299,6 +299,98 @@ def test_stream_batches_bit_identical_to_the_double_copy_loop(
         assert got[-1].n_valid == (2 * 500 // 2) % batch
 
 
+def _uniform_ds(n=500, r=6, with_fields=False, seed=5):
+    """Every row exactly r long: the Criteo shape the benchmark writes."""
+    rng = np.random.default_rng(seed)
+    return SparseDataset(
+        rng.integers(1, 1 << 20, n * r).astype(np.int32),
+        np.arange(n + 1, dtype=np.int64) * r,
+        rng.normal(0, 1, n * r).astype(np.float32),
+        rng.normal(0, 1, n).astype(np.float32),
+        rng.integers(0, 8, n * r).astype(np.int32) if with_fields else None)
+
+
+# case: (dataset, rows a shard, batch, max_len, truncate, shard cache, and
+# how a batch is gathered: whole rows out of [n, r] views of the shard's
+# arrays where its rows are one length, else slot by slot)
+_LAYOUT_CASES = {
+    "uniform": (_uniform_ds, 128, 64, None, False, False, "view"),
+    "uniform_truncate": (_uniform_ds, 128, 64, 4, True, False, "view"),
+    "uniform_carry": (_uniform_ds, 150, 64, None, False, False, "view"),
+    "uniform_fields": (lambda: _uniform_ds(with_fields=True), 150, 64, None,
+                       False, False, "view"),
+    "variable": (_ragged_ds, 128, 64, None, False, False, "slots"),
+    "truncate": (_ragged_ds, 128, 64, 5, True, False, "slots"),
+    "carry": (_ragged_ds, 150, 64, None, False, False, "slots"),
+    "fields": (lambda: _ragged_ds(with_fields=True), 150, 64, None, False,
+               False, "slots"),
+    "cache": (_uniform_ds, 150, 64, 8, False, True, "view"),
+    "cache_variable": (_ragged_ds, 150, 64, None, False, True, "slots"),
+}
+
+_CSR = {"indices", "indptr", "values", "labels", "fields"}
+
+
+@pytest.mark.parametrize("case", list(_LAYOUT_CASES))
+def test_batches_from_the_row_layout_match_a_per_slot_gather(tmp_path,
+                                                            case):
+    """Where a shard's rows are one length, a batch is whole rows out of
+    [n, r] views of its decoded arrays; it is bit for bit what the per-slot
+    gather over the CSR arrays gives (_frozen_padded above), for the stream
+    and for SparseDataset.batches_in_order alike, and no shard keeps any
+    array beside its CSR ones (a warm shard cache holds its shards for the
+    stream's life)."""
+    make, rows, batch, max_len, truncate, cache, layout = \
+        _LAYOUT_CASES[case]
+    ds = make()
+    d = str(tmp_path / "s")
+    write_parquet_shards(ds, d, rows_per_shard=rows)
+    cache_dir = str(tmp_path / "cache") if cache else None
+    if cache_dir:                   # fill it: the run below reads it warm
+        for _ in ParquetStream(d, cache_dir=cache_dir).batches(
+                batch, shuffle=False):
+            pass
+    stream = ParquetStream(d, cache_dir=cache_dir)
+    L = max_len or stream.max_row_len
+    got = list(stream.batches(batch, epochs=2, shuffle=True, seed=4321,
+                              max_len=max_len, truncate=truncate))
+    want = list(_frozen_stream_batches(ParquetStream(d), batch, epochs=2,
+                                       shuffle=True, seed=4321, L=L))
+    _assert_same_batches(got, want)
+    if rows % batch:                # the carry, and a short last batch
+        assert got[-1].n_valid == len(ds) % batch
+
+    (shard,) = stream._iter_shards(stream.files[:1])
+    grid = shard.rows2d(L)
+    if layout == "slots":
+        assert grid is None
+    else:                           # views: no copy of the decoded arrays
+        idx, val, fld = grid
+        assert idx.shape == val.shape == (len(shard), min(L, 6))
+        assert (fld is None) == (shard.fields is None)
+        assert np.shares_memory(idx, shard.indices)
+        assert np.shares_memory(val, shard.values)
+        assert fld is None or np.shares_memory(fld, shard.fields)
+        if cache:                   # a view of the mmapped cache file
+            assert isinstance(shard.indices.base, np.memmap)
+    if cache:                       # two epochs later, the memo holds
+        memo = [m for _, m in stream._cache._memo.values()]  # CSR alone
+        assert len(memo) == len(stream.files)
+        for m in memo:
+            extra = {k: v for k, v in vars(m).items() if k not in _CSR}
+            assert not any(isinstance(v, (np.ndarray, tuple, list))
+                           for v in extra.values()), sorted(extra)
+
+    # in memory, in a shuffled order, through the same routine
+    order = np.random.default_rng(8).permutation(len(ds))
+    L = max_len or ds.max_row_len
+    got = list(ds.batches_in_order(order, batch, L, truncate=truncate))
+    _assert_same_batches(
+        got, list(_frozen_padded(_frozen_take_rows(ds, order), batch, L)))
+    assert (ds.rows2d(L) is None) == (layout == "slots")
+    assert set(vars(ds)) == _CSR
+
+
 def test_stream_rng_draws_unchanged_in_number_and_order(tmp_path,
                                                         monkeypatch):
     """One permutation of the files an epoch, one of the rows a shard (the
