@@ -85,9 +85,12 @@ def main():
     rda = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
                          power_t=0.1, reg="rda", lam=1e-6)
     w = S((1 << 28,), jnp.float32)
-    show("linear_mega", make_megastep(
-        make_linear_step(loss, rda).core, none_val=True).lower(
-            w, {"u": w, "gg": w}, f0, ts, idxs, None, rows, None, None))
+    step = make_linear_step(loss, rda)
+    # the distinct-slot step rebuilds `w` after the scan (`finish`)
+    after = {"finish": step.finish} if hasattr(step, "finish") else {}
+    show("linear_mega", make_megastep(step.core, none_val=True, **after
+                                      ).lower(
+        w, {"u": w, "gg": w}, f0, ts, idxs, None, rows, None, None))
 
     V, Bp, lanes = 2_519_370, 16384, w2v.row_lanes(300)
     table = S((V, lanes), jnp.float32)

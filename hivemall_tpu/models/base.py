@@ -1289,6 +1289,11 @@ class LearnerBase:
         # (the K=1 condition `t % 256 == 0` is the K=1 case of this)
         self._emit_cadence_events(K)
 
+    def _scan_step(self):
+        """The scannable step this dispatch runs: ``_step``, unless a
+        family keeps a second one for some dispatches (models/linear.py)."""
+        return self._step
+
     def _megastep_state(self) -> Tuple[Any, Any]:
         """(model-state, optimizer-state) pair threaded through the scan
         carry: ``params`` and ``opt_state``, the names every scannable
@@ -1316,7 +1321,7 @@ class LearnerBase:
         the [K] per-step loss sums as a device array."""
         import jax.numpy as jnp
         from ..ops.scan import megastep_for
-        mega = megastep_for(self._step, none_val=True)
+        mega = megastep_for(self._scan_step(), none_val=True)
         nv = mb.nv_dev if mb.nv_dev is not None else jnp.asarray(mb.nv)
         s1, s2 = self._megastep_state()
         s1, s2, losses, *stats = mega(s1, s2, float(self._t), nv, mb.idx,

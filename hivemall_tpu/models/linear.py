@@ -46,11 +46,12 @@ from ..obs.devprof import instrument_factory as _instrument
 @_instrument("linear", "step")
 @_lru_cache(maxsize=128)
 def _linear_step_cached(loss_name, opt_name, eta_scheme, eta0, total_steps,
-                        power_t, reg, lam, l1_ratio):
+                        power_t, reg, lam, l1_ratio, distinct=True):
     return make_linear_step(
         get_loss(loss_name),
         make_optimizer_cached(opt_name, eta_scheme, eta0,
-                              total_steps, power_t, reg, lam, l1_ratio))
+                              total_steps, power_t, reg, lam, l1_ratio),
+        distinct)
 
 
 @_lru_cache(maxsize=128)
@@ -73,7 +74,9 @@ class _LinearLearner(LearnerBase):
     """Shared machinery for dense-table linear trainers. The [dims] weight
     table is ``params``, the optimizer's co-shaped arrays ``opt_state``."""
     UNIT_VAL_ELISION = True      # ops.linear.make_linear_step takes val=None
-    w = weight_table_view
+    # the table under its older name; a write is one from outside
+    w = property(weight_table_view.fget,
+                 lambda self, table: self._store_weight_table(table))
 
     FIXED_LOSS: Optional[str] = None       # set by historical subclasses
     FIXED_OPT: Optional[str] = None
@@ -92,8 +95,31 @@ class _LinearLearner(LearnerBase):
         dtype = jnp.bfloat16 if o.halffloat else jnp.float32
         self.params, self.opt_state = self._make_state(
             _linear_state_init(self.optimizer, self.dims, dtype))
-        self._step = _linear_step_cached(loss_name, *opt_key)
+        # the distinct-slot step where the optimizer builds w from its
+        # state (ops/linear.py), on one chip: a -mesh keeps GSPMD's cut of
+        # the dense step. The dense step also takes the one dispatch after
+        # w was written from outside (_note_w_written): the distinct one
+        # holds w to be the optimizer's weight of the state, or the zeros
+        # the table is born with before the first step
+        self._step = _linear_step_cached(
+            loss_name, *opt_key,
+            self.mesh is None and self.optimizer.weight_of is not None)
+        self._dense_step = _linear_step_cached(loss_name, *opt_key, False)
+        self._w_written = False
         self._predict = _linear_predict_cached()
+
+    def _note_w_written(self) -> None:
+        """w was written from outside (a warm start, MIX, a restore): the
+        next dispatch's margin reads it as stored, through the dense step,
+        after which w is the optimizer's weight of the state again."""
+        self._w_written = self._step is not self._dense_step
+
+    def _restore_arrays(self, tree) -> None:
+        super()._restore_arrays(tree)
+        self._note_w_written()
+
+    def _scan_step(self):
+        return self._dense_step if self._w_written else self._step
 
     def _convert_label(self, label: float) -> float:
         if self.ZERO_ONE_LABELS:
@@ -102,10 +128,18 @@ class _LinearLearner(LearnerBase):
         return super()._convert_label(label)
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        self.params, self.opt_state, loss_sum = self._step(
+        # the distinct-slot step returns its counts beside the loss
+        self.params, self.opt_state, loss_sum, *stats = self._scan_step()(
             self.params, self.opt_state, float(self._t),
             batch.idx, batch.val, batch.label, batch.row_mask)
+        self._stats_pending += stats
+        self._w_written = False
         return loss_sum
+
+    def _train_megabatch(self, mb):
+        losses = super()._train_megabatch(mb)
+        self._w_written = False
+        return losses
 
     def _finalize_device(self):
         """Optimizer-finalized weights as a DEVICE array — the one
@@ -120,6 +154,11 @@ class _LinearLearner(LearnerBase):
 
     def _load_weights(self, w: np.ndarray) -> None:
         self.params = jnp.asarray(w, self.params.dtype)
+        self._note_w_written()
+
+    def _store_weight_table(self, t) -> None:
+        self.params = t
+        self._note_w_written()
 
     # -- scoring (the predict-is-a-join path, SURVEY.md §4.2) ---------------
     def _make_margin_fn(self):

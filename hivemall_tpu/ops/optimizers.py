@@ -10,7 +10,10 @@ the model is a dense ``[N]`` (or ``[N, K]``) table in HBM and one jitted call
 updates the whole table elementwise after a scatter-add of the minibatch
 gradient — O(N) HBM traffic per step, fully fused by XLA, no per-row scalar
 loops. Per-coordinate adaptive state (gg, m/v, z/n) lives in co-shaped arrays,
-the analog of WeightValueParamsF1/F2 cells.
+the analog of WeightValueParamsF1/F2 cells. Where the weight an update leaves
+is a function of the state and t alone (``weight_of``: AdaGrad-RDA), the
+linear step (ops/linear.py) updates the state at a batch's distinct slots
+only and builds w where it reads it, and over the table once a dispatch.
 
 API: ``opt.init(shape) -> state``; ``opt.update(w, g, state, t) -> (w, state)``
 with t the 0-based global step; ``opt.finalize(w, state) -> w`` materializes
@@ -58,6 +61,12 @@ class Optimizer:
     # AdaGrad without a regularizer; RDA and FTRL rebuild w from their
     # sums at every t, the others decay their state.
     zero_grad_noop: bool = False
+    # weight_of(state, t) -> w: the weight an update at step t leaves, a
+    # function of the state it leaves and t alone (update ignores its w),
+    # where an entry whose gradient is zero keeps its state. A step may
+    # then update the state at the entries its batch touched, and build w
+    # where it reads it (ops/linear.py). AdaGrad-RDA; None for the others.
+    weight_of: Callable[..., Any] = None  # type: ignore[assignment]
 
     def __post_init__(self):
         if self.finalize is None:
@@ -176,30 +185,32 @@ def make_optimizer(name: str = "adagrad", *, eta_scheme: str = "fixed",
     if key in ("adagradrda", "rda"):
         # Xiao's l1-RDA with AdaGrad scaling (reference: AdaGradRDAUDTF /
         # Optimizer.RDA): weights are re-materialized from the running
-        # gradient sum each step; lam is the l1 truncation threshold.
+        # gradient sum each step; lam is the l1 truncation threshold. The
+        # weight is rda_weight of the sums and t alone, so the linear step
+        # keeps u and gg and builds w where its margin reads it
+        # (ops/linear.py, weight_of); the dense update writes it everywhere.
         def rda_init(shape, dtype=jnp.float32):
             return {"u": jnp.zeros(shape, jnp.float32),
                     "gg": jnp.zeros(shape, jnp.float32)}
 
-        def rda_update(w, g, s, t):
-            u = s["u"] + g
-            gg = s["gg"] + g * g
+        def rda_weight(s, t):
+            u, gg = s["u"], s["gg"]
             tt = t + 1.0
             thresh = jnp.maximum(0.0, jnp.abs(u) / tt - lam)
-            w_new = -jnp.sign(u) * eta(t) * tt * thresh / (jnp.sqrt(gg) + EPS)
-            return w_new, {"u": u, "gg": gg}
+            return -jnp.sign(u) * eta(t) * tt * thresh / (jnp.sqrt(gg) + EPS)
+
+        def rda_update(w, g, s, t):
+            s = {"u": s["u"] + g, "gg": s["gg"] + g * g}
+            return rda_weight(s, t), s
 
         def rda_sparse(w, g, s, ix, t):
             u = s["u"].at[ix].add(g)
             gg = s["gg"].at[ix].add(g * g)
-            ug, gf = u[ix], gg[ix]
-            tt = t + 1.0
-            thresh = jnp.maximum(0.0, jnp.abs(ug) / tt - lam)
-            w_new = -jnp.sign(ug) * eta(t) * tt * thresh / (jnp.sqrt(gf) + EPS)
+            w_new = rda_weight({"u": u[ix], "gg": gg[ix]}, t)
             return w.at[ix].set(w_new.astype(w.dtype)), {"u": u, "gg": gg}
 
         return Optimizer("adagrad_rda", rda_init, rda_update,
-                         sparse_update=rda_sparse)
+                         sparse_update=rda_sparse, weight_of=rda_weight)
 
     if key == "ftrl":
         # FTRL-Proximal (McMahan et al.) — the update family BASELINE names

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["update_rows", "scatter_rows", "scatter_rows_takes",
+__all__ = ["update_rows", "scatter_rows", "scatter_rows_takes", "fold_runs",
            "scatter_rows_cap", "use_kernels_default", "kernel_takes",
            "LIST_MULTIPLE", "BLOCK_ROWS", "XLA_BLOCK_ROWS",
            "SCATTER_VMEM_BYTES"]
@@ -377,3 +377,82 @@ def scatter_rows(g, rank, cap: int, *, interpret=None):
         interpret=bool(interpret), name="scatter_rows",
     )(rank.reshape(M // bm, bm * S // 128, 128), g.reshape(M, S, W))
     return out.reshape(cap, W)
+
+
+# --- runs summed one addend after another ------------------------------------
+# A sum of a run of equal keys, in order, as XLA's scatter-add and a float32
+# reference make it (fl(fl(a + b) + c) ...), costs XLA 8.7 ns an addend on a
+# v5e (its scatter-add serialises duplicates), 11 ms for a batch of the
+# linear cell. The order matters: thousands of near-equal addends round the
+# same way at each add, and a tree's sum of a hot slot differs from the
+# ordered one by 1e-4 of it. This kernel keeps the order and runs 1024
+# stretches of the sequence side by side, one per word of a vector register.
+
+
+#: stretches of the sequence a pass folds side by side: one [8, 128] tile
+FOLD_LANES = 8 * 128
+
+
+def fold_runs(vals, first, longest, *, interpret=None):
+    """[n] float32: at each j, the sum of ``vals`` [n] from the start of
+    j's run (the last i <= j with ``first[i]``; first[0] must be set) to
+    j, added in order: fl(fl(v_s + v_s+1) + ...). The sequence is cut into
+    FOLD_LANES stretches, folded side by side a step of each per vector
+    op; a run that crosses into a stretch is right there once the pass
+    before has folded the stretch it comes from, so a run over k stretches
+    is right after k passes: ceil((``longest`` - 1) / stretch) + 1 passes,
+    ``longest`` the longest run (a runtime scalar). Pallas on a TPU (or
+    ``interpret=True``); None elsewhere: the caller sums by XLA's
+    scatter-add, which adds in the same order."""
+    if interpret is None and not use_kernels_default():
+        return None
+    n = vals.shape[0]
+    per = -(-n // (FOLD_LANES * 8)) * 8          # a stretch, whole 8s
+    pad = per * FOLD_LANES - n
+    # [per, 8, 128]: step i of stretch (a, b) at [i, a, b]
+    v = jnp.concatenate([vals.astype(jnp.float32),
+                         jnp.zeros((pad,), jnp.float32)]
+                        ).reshape(8, 128, per).transpose(2, 0, 1)
+    f = jnp.concatenate([first, jnp.ones((pad,), bool)]
+                        ).astype(jnp.int32).reshape(8, 128, per
+                                                    ).transpose(2, 0, 1)
+    bi = 8
+    while per % (2 * bi) == 0 and 2 * bi <= 256:
+        bi *= 2
+
+    def kernel(c_ref, v_ref, f_ref, o_ref, acc_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            acc_ref[...] = c_ref[...]
+
+        def step(i, acc):
+            x = v_ref[i]
+            acc = jnp.where(f_ref[i] != 0, x, acc + x)
+            o_ref[i] = acc
+            return acc
+        acc_ref[...] = jax.lax.fori_loop(0, bi, step, acc_ref[...])
+
+    block = pl.BlockSpec((bi, 8, 128), lambda i: (i, 0, 0))
+    one_pass = pl.pallas_call(
+        kernel, grid=(per // bi,),
+        in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0)), block, block],
+        out_specs=block,
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((per, 8, 128), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=bool(interpret), name="fold_runs")
+
+    def again(carry):
+        p, out = carry
+        # each stretch starts from where the one before it ended
+        last = out[per - 1].reshape(-1)
+        c = jnp.concatenate([jnp.zeros((1,), jnp.float32), last[:-1]])
+        return p + 1, one_pass(c.reshape(8, 128), v, f)
+
+    passes = (jnp.maximum(longest, 1) - 1 + per - 1) // per + 1
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < passes, again,
+        again((jnp.zeros((), jnp.int32), jnp.zeros((per, 8, 128),
+                                                    jnp.float32))))
+    return out.transpose(1, 2, 0).reshape(-1)[:n]

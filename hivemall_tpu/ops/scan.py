@@ -66,7 +66,7 @@ def scannable(step, core):
     return step
 
 
-def make_megastep(core, *, none_val: bool = False):
+def make_megastep(core, *, none_val: bool = False, finish=None):
     """Build the jitted K-step megastep around one scannable core.
 
     Signature: ``megastep(s1, s2, t0, nv, idx, val, label, field, lams)``
@@ -84,6 +84,10 @@ def make_megastep(core, *, none_val: bool = False):
     device; the caller folds them at its existing cadence so no step ever
     blocks the host — and the core's ``stats``, stacked [K], where it
     returns any.
+
+    ``finish(s1, s2, t) -> (s1, s2)``, where the step has one (ops/linear.py
+    ``step.finish``), runs once after the scan with the last step's t: what
+    a core leaves stale inside a dispatch is rebuilt there.
     """
 
     @partial(jax.jit, donate_argnums=(0, 1))
@@ -115,6 +119,8 @@ def make_megastep(core, *, none_val: bool = False):
         # the slicing and stacking the scan adds is named too
         with jax.named_scope("hm.scan"):
             (s1, s2, _), out = jax.lax.scan(body, (s1, s2, t0), xs)
+        if finish is not None:
+            s1, s2 = finish(s1, s2, t0 + (label.shape[0] - 1.0))
         return (s1, s2, *out)
 
     return megastep
@@ -159,7 +165,8 @@ def megastep_for(step, *, none_val: bool = False):
         from ..obs.devprof import get_devprof
         t0 = time.perf_counter()
         fn = _profiled_megastep(
-            make_megastep(getattr(step, "core", step), none_val=none_val))
+            make_megastep(getattr(step, "core", step), none_val=none_val,
+                          finish=getattr(step, "finish", None)))
         _MEGASTEP_CACHE[key] = fn
         get_devprof().record_build("scan", "megastep",
                                    time.perf_counter() - t0)

@@ -99,7 +99,11 @@ def test_linear_megastep_compiles_for_v5e_within_a_chip():
     logreg_criteo.stream, PR 33): the state's jitted initialiser (three
     float32 [2^28] arrays, 3.22 GB) and the megastep (B=32768, L=39, unit
     values elided), every phase scope in the compiled text, state and
-    temporaries inside one chip (~20 s of XLA compile)."""
+    temporaries inside one chip; the distinct-slot step: one
+    `conditional`, the `fold_runs` kernel, the state updated in place, no
+    whole array copied, and no pass over a [2^28] array for a batch within
+    the capacity but the rebuild of `w` after the scan (~45 s of XLA
+    compile)."""
     _compile("linear_megastep", timeout=600)
 
 

@@ -440,42 +440,60 @@ def linear_megastep():
     `w`, `u`, `gg` three float32 [2^28] arrays; B=32768, L=39, unit values
     elided): the state's jitted initialiser (models/linear.py
     `_linear_state_init`, what `LearnerBase._make_state` jits) and the
-    megastep (two steps: a scan's body compiles once). The state is 3.22
-    GB and stays inside one chip with the step's temporaries, each of
-    the four phase scopes names ops of the step, and no Mosaic kernel is
-    in it."""
+    megastep (two steps: a scan's body compiles once) of the distinct-slot
+    step, its `finish` after the scan. The state is 3.22 GB and
+    stays inside one chip with the step's temporaries, each of the four
+    phase scopes names ops of the step, the three arrays are updated in
+    place, no whole array is copied, and a batch within the capacity
+    (the `cond`'s distinct branch) runs no pass over a [2^28] array: the
+    one such pass is `finish`'s rebuild of `w`, once a dispatch, after the
+    scan; its state is written by sorted scatters in place, its runs
+    summed by the one Pallas kernel, `fold_runs`."""
     from hivemall_tpu.models.linear import _linear_state_init
-    from hivemall_tpu.ops.linear import make_linear_step
+    from hivemall_tpu.ops.linear import distinct_cap, make_linear_step
     from hivemall_tpu.ops.scan import make_megastep
     dims, B, L, ks = 1 << 28, 32768, 39, 2
     opt = make_optimizer("adagrad", eta_scheme="inverse", eta0=0.1,
                          power_t=0.1, reg="rda", lam=1e-6)
-    assert opt.name == "adagrad_rda"
+    assert opt.name == "adagrad_rda" and opt.weight_of is not None
     init = jax.jit(_linear_state_init(opt, dims, jnp.float32),
                    out_shardings=(DEV0, {"u": DEV0, "gg": DEV0})).lower() \
         .compile().memory_analysis()
     assert 0 <= init.output_size_in_bytes - 3 * 4 * dims < 4096 \
         and init.temp_size_in_bytes == 0, init
+    step = make_linear_step(get_loss("logloss"), opt)
     table = _sds((dims,), jnp.float32)
-    compiled = make_megastep(
-        make_linear_step(get_loss("logloss"), opt).core, none_val=True
-    ).lower(table, {"u": table, "gg": table}, _sds((), jnp.float32),
-            _sds((ks,), jnp.int32), _sds((ks, B, L), jnp.int32), None,
-            _sds((ks, B), jnp.float32), None, None).compile()
+    compiled = make_megastep(step.core, none_val=True, finish=step.finish
+                             ).lower(
+        table, {"u": table, "gg": table}, _sds((), jnp.float32),
+        _sds((ks,), jnp.int32), _sds((ks, B, L), jnp.int32), None,
+        _sds((ks, B), jnp.float32), None, None).compile()
     text = compiled.as_text()
     for scope in ("hm.gather", "hm.grad", "hm.scatter", "hm.update"):
         assert f"/{scope}/" in text, f"no op under {scope}"
-    assert "tpu_custom_call" not in text
+    cap = distinct_cap(B * L, dims)
+    assert 74_000 < cap < B * L and f"f32[{cap}]" in text, cap
+    assert text.count(" conditional(") == 1, "one cond: distinct or dense"
+    kernels = text.count("tpu_custom_call")
+    assert kernels == 2 and "fold_runs" in text, \
+        f"{kernels} Mosaic kernels, expected fold_runs' pass before and " \
+        "in its loop"
+    big = r"f32\[%d\]" % dims
+    assert not re.findall(r"= %s\S* copy\(" % big, text), "a whole copy"
+    passes = [line for line in _fitting_path(text)
+              if re.search(r"= %s\S* fusion\(.*kind=kLoop" % big, line)]
+    assert len(passes) == 1 and "/hm.update/" in passes[0] \
+        and "/hm.scan/" not in passes[0], \
+        f"table-sized passes a fitting batch runs: {passes}"
     mem = compiled.memory_analysis()
-    # donated: the three arrays are updated in place. The dense gradient
-    # needs no array of its own: RDA rebuilds `w` from `u` and `gg`, so
-    # after the margin's gather the compiler scatters into `w`'s buffer
-    # (5.9 MB of temporaries read here, not 1.07 GB)
+    # donated: the three arrays are updated in place. The temporaries
+    # are the dense branch's (a batch past the capacity): one table-sized
+    # array, 1.11 GB read here (the cell's traced runs peak at 3.60-3.68
+    # GB of HBM, under the dense step's 3.83)
     assert mem.alias_size_in_bytes >= 3 * 4 * dims
-    assert mem.temp_size_in_bytes < 4 * dims, mem
+    assert mem.temp_size_in_bytes < 1.2e9, mem
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 15.75 * 2 ** 30
-
 
 
 def skipgram_megastep():
