@@ -159,17 +159,22 @@ def _render(state: _TailState, path: str = "",
     if tail.get("tail_distinct_steps") or tail.get("tail_dense_steps"):
         # which tail the minibatch step took (ops/fm.py rows_update), on
         # how many steps the gather in front read through the batch's
-        # distinct rows too (gather_rows), and on how many the tail's
-        # scatter-add summed them in fast memory in one pass
+        # distinct rows too (gather_rows), on how many the tail's
+        # scatter-add summed them in fast memory in one pass, and, of a
+        # step over a mesh's blocks, on how many the slab crossed the mesh
+        # as the blocks' compact tables (ffm_slab_over_blocks)
         steps = tail["tail_distinct_steps"] + tail["tail_dense_steps"]
-        out.append(
-            f"tail:   distinct-row x{tail['tail_distinct_steps']}  "
-            f"dense x{tail['tail_dense_steps']}  distinct rows/step "
-            f"{tail.get('distinct_rows', 0) / max(1, steps):.0f}  "
-            f"gather on the distinct rows x"
-            f"{tail.get('gather_compact_steps', 0)}  "
-            f"scatter in fast memory x"
-            f"{tail.get('scatter_compact_steps', 0)}")
+        line = (f"tail:   distinct-row x{tail['tail_distinct_steps']}  "
+                f"dense x{tail['tail_dense_steps']}  distinct rows/step "
+                f"{tail.get('distinct_rows', 0) / max(1, steps):.0f}  "
+                f"gather on the distinct rows x"
+                f"{tail.get('gather_compact_steps', 0)}  "
+                f"scatter in fast memory x"
+                f"{tail.get('scatter_compact_steps', 0)}")
+        if "exchange_compact_steps" in tail:
+            line += (f"  exchange of compact tables x"
+                     f"{tail['exchange_compact_steps']}")
+        out.append(line)
     stages = (roll or {}).get("stages") \
         or ((snap or {}).get("spans") if snap else None)
     if stages:

@@ -347,6 +347,133 @@ def make_ffm_score_fieldmajor(F: int, K: int):
     return score
 
 
+def _ffm_ranked(T, state, idx, optimizer: Optimizer, distinct_tail: bool,
+                tp: int = 1, block: bool = False):
+    """The joint step's table rows of the batch ``idx`` and their ranking
+    (`rank_rows`). Of a ``block`` of a table dealt over 'tp' (T is this
+    chip's), the hash folds to the whole table's rows and a slot of
+    another block carries the id R."""
+    R = T.shape[0]
+    with jax.named_scope("hm.gather"):
+        rows = ffm_row_hash(idx, R * tp)
+        if block:                       # another block's slot: the id R
+            rows = rows - jax.lax.axis_index("tp") * R
+            rows = jnp.where((rows >= 0) & (rows < R), rows, R)
+    return rows, rank_rows(rows.reshape(-1), T, state, optimizer,
+                           None if distinct_tail else 0, block)
+
+
+def _lead(tree):                        # a chip's own, stacked over 'tp'
+    return jax.tree_util.tree_map(lambda a: a[None], tree)
+
+
+def _own(tree):
+    return jax.tree_util.tree_map(lambda a: a[0], tree)
+
+
+def ffm_slab_over_blocks(mesh, T, state, idx, optimizer: Optimizer,
+                         distinct_tail: bool = True):
+    """The front of `make_ffm_step_fused(mesh=)`: the slab ``T[rows]`` of
+    the batch ``idx`` over the WHOLE table T [Mr, W], whose rows and
+    ``state`` are dealt over the mesh's 'tp' (P('tp', None)), bit for bit
+    and replicated; each chip's (rows, ranks, compact) in its block's
+    numbering, stacked over 'tp'; and whether the slab crossed the mesh as
+    the blocks' compact tables (an int32 scalar, the step's
+    ``exchange_compact_steps``).
+
+    Every chip ranks the slots by its own block's rows (`rank_rows`). No
+    collective is written here: what leaves a `shard_map` stacked over
+    'tp' and is summed or laid out whole between them is the
+    partitioner's to move, and named as it names its own.
+
+      * Where every block holds at most ``gather_cap(cap, W, itemsize,
+        tp)`` distinct rows (the flagship's block over tp=4: 49,152, so
+        that the tables of all chips fit COMPACT_TABLE_BYTES together),
+        each chip fills its compact table C [cap, W] of its distinct rows
+        (`_compact_table`) and gives each of its slots the row of C at
+        ``chip * cap + slot_rank`` (0 at another block's slot). The
+        tables laid end to end reach every chip (an all-gather of [tp *
+        cap, W]), the slots' rows summed (an int32 all-reduce of one
+        scalar a slot; one owner a slot, so exact), and every chip
+        expands the slab out of them as `gather_rows` does out of its
+        own.
+      * Else (one block over, a `lax.cond` on the blocks' largest count),
+        each chip's slab is its `gather_rows` of its block, zero at the
+        others' slots, and the slabs are summed (an all-reduce of the
+        whole [B, L, W] slab, exact in T's dtype), as a step whose blocks
+        rank nothing (`rank_rows` None) always does."""
+    from jax.sharding import PartitionSpec as P
+    tp, chips = mesh.shape["tp"], P("tp")
+
+    def shares(T, state, idx):
+        """A chip's (rows, ranks, compact) and its part of the slab: C
+        (filled only where its count is within cap) and its slots' rows of
+        the tables laid end to end; or, where nothing ranks the rows or
+        no row fits, its slab, zero at the others' slots."""
+        rows, ranks = _ffm_ranked(T, state, idx, optimizer, distinct_tail,
+                                  tp, True)
+        R, W = T.shape
+        cap = 0 if ranks is None else gather_cap(
+            ranks.urows.shape[0], W, T.dtype.itemsize, tp)
+        if not cap:  # graftcheck: disable=GC09 (static)
+            slab, compact = gather_rows(T, rows, ranks, True)
+            return _lead(((rows, ranks, compact), slab))
+        n = ranks.n_distinct
+        with jax.named_scope("hm.gather"):
+            tb = min(cap, FILL_BLOCK_ROWS)
+            C = _compact_table(T, ranks, cap,
+                               jnp.where(n <= cap, (n + tb - 1) // tb, 0))
+            at = jnp.where(rows.reshape(-1) < R, jax.lax.axis_index("tp")
+                           * cap + ranks.slot_rank, 0)
+        # what gather_rows reports for the same ranking
+        compact = (n <= gather_cap(ranks.urows.shape[0], W,
+                                   T.dtype.itemsize)).astype(jnp.int32)
+        # C leaves as a block of the tables laid end to end, [tp * cap,
+        # W], not stacked: the compiler gathers a stacked [tp, cap, W]
+        # transposed and relayouts it twice around the all-gather
+        return _lead((rows, ranks, compact)), (C, *_lead((at, n)))
+
+    def summed(slabs):
+        with jax.named_scope("hm.gather"):
+            # one owner a slot, so exact in the table's own dtype
+            return jax.lax.reduce(slabs, jnp.zeros((), slabs.dtype),
+                                  jax.lax.add, (0,))
+
+    if T.shape[0] % tp:
+        raise ValueError(f"T's {T.shape[0]} rows do not deal into {tp} "
+                         "equal blocks over 'tp'")
+    # no replication check: a branch of a cond may leave a constant where
+    # the other leaves a chip's own
+    mine, part = jax.shard_map(
+        shares, mesh=mesh, in_specs=(P("tp", None), P("tp", None), P()),
+        out_specs=chips, check_vma=False)(T, state, idx)
+    if not isinstance(part, tuple):
+        return mine, summed(part), jnp.zeros((), jnp.int32)
+    (rows, ranks, _), (C, at, n) = mine, part
+    cap = C.shape[0] // tp
+
+    def of_tables():
+        with jax.named_scope("hm.gather"):
+            slot = jax.lax.reduce(at, jnp.zeros((), at.dtype), jax.lax.add,
+                                  (0,))
+
+        def expand(C, slot):
+            with jax.named_scope("hm.gather"):
+                return C[slot.reshape(rows.shape[1:])]
+        return jax.shard_map(expand, mesh=mesh, in_specs=(P(), P()),
+                             out_specs=P(), check_vma=False)(C, slot)
+
+    def of_slabs():
+        return summed(jax.shard_map(
+            lambda T, rows, ranks: _lead(gather_rows(
+                T, rows[0], _own(ranks), True)[0]),
+            mesh=mesh, in_specs=(P("tp", None), chips, chips),
+            out_specs=chips, check_vma=False)(T, rows, ranks))
+    fits = n.max() <= cap
+    return mine, jax.lax.cond(fits, of_tables, of_slabs), \
+        fits.astype(jnp.int32)
+
+
 def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                         lambdas: Tuple[float, float, float],
                         F: int, K: int,
@@ -388,20 +515,22 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     the batch replicated: every chip makes the calls the one chip makes,
     rank_rows -> gather_rows -> fwd/bwd -> rows_update, on its own
     [Mr/tp, W] block. The hash folds to the GLOBAL Mr and a chip's slots
-    are those whose row falls in its block. TWO shard_maps with no
-    collective in either: the first ranks and gathers and hands out the
-    blocks' slabs, zero at the others' slots, stacked over 'tp'; their
-    sum (each slot has one owner, so it is T[rows] bit for bit) is a
-    plain reduction between the two, which the partitioner makes the
-    all-reduce its cut of the dense step has: the step's one collective
-    of any size, and named in a trace as GSPMD names its own. In the
-    second, forward and backward run replicated on the whole slab and
-    each chip applies the gradient of its own slots, taking its own
-    branch of each cond by its own count of distinct rows (an optimizer
-    rank_rows does not rank for: the dense tail on each block). The
-    stats come out replicated: distinct_rows the sum over the chips,
-    tail_distinct_steps and gather_compact_steps 1 only where EVERY chip
-    took that branch (else the step counts as dense).
+    are those whose row falls in its block. No collective is written in
+    the step: the front, `ffm_slab_over_blocks`, ranks each block's rows
+    and hands the whole slab T[rows] to every chip, bit for bit, by what
+    the partitioner moves between `shard_map`s: where every block holds
+    few enough distinct rows, the blocks' compact tables (an all-gather)
+    and each slot's row in them (an int32 all-reduce); else the blocks'
+    slabs summed (an all-reduce of the slab, the cut of the dense step
+    GSPMD makes). In the last `shard_map`, forward and backward run
+    replicated on the whole slab and each chip applies the gradient of
+    its own slots, taking its own branch of each cond by its own count
+    of distinct rows (an optimizer rank_rows does not rank for: the
+    dense tail on each block). The stats come out replicated:
+    distinct_rows the sum over the chips, tail_distinct_steps and
+    gather_compact_steps 1 only where EVERY chip took that branch (else
+    the step counts as dense), and, beside TAIL_STATS,
+    exchange_compact_steps 1 where the slab crossed as compact tables.
 
     The fieldmajor step takes no field array (the layout IS the field
     assignment: slot s -> field s % F).
@@ -416,21 +545,12 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
     squares; L2 (-lambda*) is still applied per-occurrence at slab level.
     """
     lam0, lam_w, lam_v = lambdas
-    block = mesh is not None            # T in the step: a chip's block
-    tp = mesh.shape["tp"] if block else 1
 
     def front(params, opt_state, idx):
-        T = params["T"]
-        R = T.shape[0]                  # under a mesh: this chip's block
-        with jax.named_scope("hm.gather"):
-            rows = ffm_row_hash(idx, R * tp)
-            if block:                   # another block's slot: the id R
-                rows = rows - jax.lax.axis_index("tp") * R
-                rows = jnp.where((rows >= 0) & (rows < R), rows, R)
-        ranks = rank_rows(rows.reshape(-1), T, opt_state["T"], optimizer,
-                          None if distinct_tail else 0, block)
-        slab, compact = gather_rows(T, rows, ranks, block)  # own dtype
-        return rows, ranks, compact, slab
+        rows, ranks = _ffm_ranked(params["T"], opt_state["T"], idx,
+                                  optimizer, distinct_tail)
+        slab, compact = gather_rows(params["T"], rows, ranks)
+        return rows, ranks, compact, slab    # the slab in T's own dtype
 
     def back(params, opt_state, t, rows, ranks, compact, slab, val, label,
              row_mask, field):
@@ -473,7 +593,7 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
         return back(params, opt_state, t, *front(params, opt_state, idx),
                     *batch)
 
-    if block:
+    if mesh is not None:
         from jax.sharding import PartitionSpec as P
         if mesh.shape["dp"] != 1:
             raise ValueError("make_ffm_step_fused(mesh=) deals rows over "
@@ -481,40 +601,26 @@ def make_ffm_step_fused(loss: Loss, optimizer: Optimizer,
                              "replicas: leave that mesh to GSPMD")
         tables, chips = {"T": P("tp", None), "w0": P()}, P("tp")
 
-        def lead(tree):                 # a chip's own, stacked over 'tp'
-            return jax.tree_util.tree_map(lambda a: a[None], tree)
-
-        def own(tree):
-            return jax.tree_util.tree_map(lambda a: a[0], tree)
-
         def body(params, opt_state, t, idx, *batch):
             # a None (no val, no field, no ranking) is an empty pytree to
             # a spec. No replication check: a branch of a cond or a trip
             # of a loop may leave a constant where the other leaves a
             # chip's own
-            if params["T"].shape[0] % tp:
-                raise ValueError(f"T's {params['T'].shape[0]} rows do not "
-                                 f"deal into {tp} equal blocks over 'tp'")
-            *mine, slabs = jax.shard_map(
-                lambda *a: lead(front(*a)), mesh=mesh,
-                in_specs=(tables, tables, P()), out_specs=chips,
-                check_vma=False)(params, opt_state, idx)
-            with jax.named_scope("hm.gather"):
-                # one owner a slot, so exact in the table's own dtype
-                slab = jax.lax.reduce(slabs, jnp.zeros((), slabs.dtype),
-                                      jax.lax.add, (0,))
+            mine, slab, exchanged = ffm_slab_over_blocks(
+                mesh, params["T"], opt_state["T"], idx, optimizer,
+                distinct_tail)
 
             def local(params, opt_state, t, mine, slab, *batch):
-                *out, stats = back(params, opt_state, t, *own(mine), slab,
+                *out, stats = back(params, opt_state, t, *_own(mine), slab,
                                    *batch)
-                return (*out, lead(stats))
+                return (*out, _lead(stats))
             *out, stats = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(tables, tables, P(), chips) + (P(),) * (
                     1 + len(batch)),
                 out_specs=(tables, tables, P(), chips),
                 check_vma=False)(params, opt_state, t, mine, slab, *batch)
-            return (*out, _stats_over_blocks(stats))
+            return (*out, _stats_over_blocks(stats, exchanged))
 
     if unit_val:
         assert fieldmajor, "unit_val implies the canonical fieldmajor batch"
@@ -762,23 +868,26 @@ TAIL_STATS = ("tail_distinct_steps", "tail_dense_steps", "distinct_rows",
               "gather_compact_steps", "scatter_compact_steps")
 
 
-def _stats_over_blocks(stats: dict) -> dict:
+def _stats_over_blocks(stats: dict, exchanged) -> dict:
     """TAIL_STATS of one step whose table is dealt in blocks over a mesh's
     chips, from each block's own, stacked [blocks]: the distinct rows
     summed (the blocks share none), a branch counted as taken where every
-    block took it, and the step's tail dense wherever one block's was."""
+    block took it, and the step's tail dense wherever one block's was;
+    and ``exchange_compact_steps``, ``exchanged``: 1 where the slab
+    crossed the mesh as the blocks' compact tables."""
     every = {k: stats[k].min(0)
              for k in ("tail_distinct_steps", "gather_compact_steps",
                        "scatter_compact_steps")}
     return {**every, "tail_dense_steps": 1 - every["tail_distinct_steps"],
-            "distinct_rows": stats["distinct_rows"].sum(0)}
+            "distinct_rows": stats["distinct_rows"].sum(0),
+            "exchange_compact_steps": exchanged}
 
 
 def _tail_cost(W: int, itemsize: int) -> _TailCost:
     """The readings at (W, itemsize). A shape nobody read moves its rows
     as the flagship's do (only (128, 4) has the kernel): the flagship's,
     the table-row cost by the bytes of a padded row of T, gg and G."""
-    if (W, itemsize) in _TAIL_COSTS:
+    if (W, itemsize) in _TAIL_COSTS:  # graftcheck: disable=GC09 (static)
         return _TAIL_COSTS[W, itemsize]
 
     def row_bytes(W, itemsize):
@@ -802,6 +911,7 @@ def tail_cap(n: int, R: int, W: int = 128, itemsize: int = 4) -> int:
         return 0
     c = _tail_cost(W, itemsize)
     even = (R * c.dense_table_row - n * c.rank_slot) / c.distinct_row
+    # graftcheck: disable=GC09 (Python numbers of the shapes)
     cap = max(0, min(n, int(even), c.passes * scatter_rows_cap(W)))
     return (cap // BLOCK_ROWS * BLOCK_ROWS
             or cap // LIST_MULTIPLE * LIST_MULTIPLE)
@@ -833,13 +943,37 @@ COMPACT_TABLE_BYTES = 96 << 20
 FILL_BLOCK_ROWS = 2048
 
 
-def gather_cap(cap: int, W: int, itemsize: int) -> int:
+def gather_cap(cap: int, W: int, itemsize: int, chips: int = 1) -> int:
     """Most distinct rows `gather_rows` reads through: the ranking's
     capacity, or the rows of [., W] the compact table's bytes hold, whole
-    id tiles of them."""
+    id tiles of them. Of ``chips`` that exchange their compact tables
+    (`make_ffm_step_fused(mesh=)`), the rows one chip may add so that the
+    tables of all of them fit those bytes together (the flagship's block
+    over tp=4: 49,152)."""
     row_bytes = -(-W // 128) * 128 * itemsize
-    return min(cap, COMPACT_TABLE_BYTES // row_bytes // LIST_MULTIPLE
-               * LIST_MULTIPLE)
+    return min(cap, COMPACT_TABLE_BYTES // row_bytes // chips
+               // LIST_MULTIPLE * LIST_MULTIPLE)
+
+
+def _compact_table(T, ranks: RowRanks, cap: int, trips=None):
+    """C [cap, W]: the distinct rows ``ranks.urows`` read out of T once, a
+    block of FILL_BLOCK_ROWS a trip up to the count (or ``trips``), so
+    that the fill costs by the rows a batch holds and not by the
+    capacity (rows of C past the count are never read: a rank is under
+    the count)."""
+    tb = min(cap, FILL_BLOCK_ROWS)
+
+    def block(i, C):
+        # the last block of a capacity that is not whole blocks ends at
+        # the capacity and reads some rows a second time
+        at = jnp.minimum(i * tb, cap - tb)
+        ids = jax.lax.dynamic_slice_in_dim(ranks.urows, at, tb)
+        return jax.lax.dynamic_update_slice_in_dim(
+            C, T.at[ids].get(mode="clip"), at, 0)
+    if trips is None:
+        trips = (ranks.n_distinct + tb - 1) // tb
+    return jax.lax.fori_loop(0, trips, block,
+                             jnp.zeros((cap, T.shape[1]), T.dtype))
 
 
 def scatter_cap(cap: int, W: int) -> int:
@@ -893,7 +1027,7 @@ def rank_rows(rows, T, state, optimizer: Optimizer, cap=None,
         leaves = jax.tree_util.tree_leaves(state)
         cap = tail_cap(n, R, W, min(a.dtype.itemsize for a in (T, *leaves))
                        ) if optimizer.zero_grad_noop else 0
-    if not cap:
+    if not cap:  # graftcheck: disable=GC09 (static)
         return None
     with jax.named_scope("hm.scatter"):
         slot = jnp.arange(n, dtype=jnp.int32)
@@ -934,14 +1068,11 @@ def gather_rows(T, rows, ranks: Optional[RowRanks], block: bool = False):
     of another block, the id R, reads zeros, so that the blocks' slabs sum
     to the whole table's.
 
-    A slot reads C at `rank_rows`' ``slot_rank``. C is filled a block a
-    trip up to the count, so that the fill costs by the rows a batch holds
-    and not by the capacity (rows of C past the count are never read: a
-    rank is under the count). A batch over the capacity reads the table
-    directly, as a
-    step without ranking does. The ``cond`` is outside ``hm.gather`` and
-    its branches inside, as the tail's: a trace reader that took the
-    ``cond`` for an operation under a scope would count the gather twice."""
+    A slot reads C (`_compact_table`) at `rank_rows`' ``slot_rank``. A
+    batch over the capacity reads the table directly, as a step without
+    ranking does. The ``cond`` is outside ``hm.gather`` and its branches
+    inside, as the tail's: a trace reader that took the ``cond`` for an
+    operation under a scope would count the gather twice."""
     def direct():
         with jax.named_scope("hm.gather"):
             return T[rows]
@@ -956,22 +1087,12 @@ def gather_rows(T, rows, ranks: Optional[RowRanks], block: bool = False):
 
     cap = gather_cap(ranks.urows.shape[0], T.shape[1], T.dtype.itemsize) \
         if ranks is not None else 0
-    if not cap:
+    if not cap:  # graftcheck: disable=GC09 (static)
         return of_the_block(direct()), jnp.zeros((), jnp.int32)
-    tb = min(cap, FILL_BLOCK_ROWS)
 
     def compact():
         with jax.named_scope("hm.gather"):
-            def block(i, C):
-                # the last block of a capacity that is not whole blocks
-                # ends at the capacity and reads some rows a second time
-                at = jnp.minimum(i * tb, cap - tb)
-                ids = jax.lax.dynamic_slice_in_dim(ranks.urows, at, tb)
-                return jax.lax.dynamic_update_slice_in_dim(
-                    C, T.at[ids].get(mode="clip"), at, 0)
-            C = jax.lax.fori_loop(
-                0, (ranks.n_distinct + tb - 1) // tb, block,
-                jnp.zeros((cap, T.shape[1]), T.dtype))
+            C = _compact_table(T, ranks, cap)
             return C[ranks.slot_rank.reshape(rows.shape)]
     fits = ranks.n_distinct <= cap
     return (of_the_block(jax.lax.cond(fits, compact, direct)),

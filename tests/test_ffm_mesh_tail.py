@@ -7,8 +7,12 @@ to float32 rounding where a batch touched a row (a duplicate's addends may
 meet in another order, a row is rounded to the table's dtype once on
 either side) and are BIT-equal everywhere else; the step's stats are the
 one-device step's where every chip took the branch the one device took.
-Shapes whose LOCAL `tail_cap` is not 0: the table of `_FFM_OPTS` in
-tests/test_fm_distinct_tail.py (131,072 rows of 40 lanes), 1,024 slots.
+The slab crosses the mesh as the blocks' compact tables where every block
+holds few enough distinct rows (`exchange_compact_steps`, the one counter
+the one-device step has not), else as the blocks' slabs summed: both are
+T[rows] bit for bit. Shapes whose LOCAL `tail_cap` is not 0: the table of
+`_FFM_OPTS` in tests/test_fm_distinct_tail.py (131,072 rows of 40 lanes),
+1,024 slots.
 """
 
 import re
@@ -99,13 +103,20 @@ def _both(kind, idx, tp, dtype=jnp.bfloat16):
     kw, args = _batch(kind, idx)
     ref = _step(**kw)(*_state(dtype), 3.0, *args)
     new = _step(mesh, **kw)(*_state(dtype, mesh), 3.0, *args)
+    assert "exchange_compact_steps" not in ref[3]
     assert new[0]["T"].sharding.spec == P("tp", None)
     assert new[1]["T"]["gg"].sharding.spec == P("tp", None)
     return new, ref, np.unique(np.asarray(fm.ffm_row_hash(args[0], MR)))
 
 
 def _stats(out):
-    return {k: int(v) for k, v in out[3].items()}
+    """TAIL_STATS, which the one-device step counts too."""
+    return {k: int(v) for k, v in out[3].items()
+            if k != "exchange_compact_steps"}
+
+
+def _exchanged(out):
+    return int(out[3]["exchange_compact_steps"])
 
 
 def _assert_same_to_rounding(new, ref, rows):
@@ -148,6 +159,7 @@ def test_step_over_tp_is_the_one_device_step(tp, kind, dtype):
         "tail_distinct_steps": 1, "tail_dense_steps": 0,
         "distinct_rows": 30 * tp, "gather_compact_steps": 1,
         "scatter_compact_steps": 1}
+    assert _exchanged(new) == 1
     _assert_same_to_rounding(new, ref, rows)
 
 
@@ -156,7 +168,7 @@ def test_padded_batch_over_tp(tp):
     """Padding is feature id 0, row 0: block 0's, with a zero gradient."""
     new, ref, rows = _both("padded", _ids([40] * tp, tp=tp), tp)
     assert 0 in rows and _stats(new) == _stats(ref)
-    assert _stats(new)["tail_distinct_steps"] == 1
+    assert _stats(new)["tail_distinct_steps"] == 1 and _exchanged(new) == 1
     _assert_same_to_rounding(new, ref, rows)
 
 
@@ -166,6 +178,7 @@ def test_chips_with_no_slot_of_their_own():
     new, ref, rows = _both("unit", _ids([0, 90, 0, 0]), 4)
     assert (rows // (MR // 4) == 1).all()
     assert _stats(new) == _stats(ref) and _stats(new)["distinct_rows"] == 90
+    assert _exchanged(new) == 1
     _assert_same_to_rounding(new, ref, rows)
     before = np.asarray(_state()[0]["T"], np.float32)
     after = np.asarray(new[0]["T"], np.float32)
@@ -179,7 +192,9 @@ def test_one_chip_over_its_capacity_takes_its_own_dense_tail():
     """Block 2 holds 700 distinct rows of a capacity of 640, the others
     20 each: chip 2 reads its table directly and runs the dense tail on
     ITS block, the others the distinct-row tail, and the step counts as
-    dense; the one device, with room for 1,024, took the distinct tail."""
+    dense; the one device, with room for 1,024, took the distinct tail.
+    The slab crosses as the blocks' slabs: block 2 is over the exchange's
+    room too."""
     new, ref, rows = _both("unit", _ids([20, 20, 700, 20]), 4)
     assert _stats(ref) == {
         "tail_distinct_steps": 1, "tail_dense_steps": 0,
@@ -189,6 +204,7 @@ def test_one_chip_over_its_capacity_takes_its_own_dense_tail():
         "tail_distinct_steps": 0, "tail_dense_steps": 1,
         "distinct_rows": 760, "gather_compact_steps": 0,
         "scatter_compact_steps": 0}
+    assert _exchanged(new) == 0
     _assert_same_to_rounding(new, ref, rows)
 
 
@@ -220,9 +236,10 @@ def test_megastep_of_four_over_tp_equals_four_single_steps():
         assert a.sharding == b.sharding
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
-    for name in fm.TAIL_STATS:
+    for name in (*fm.TAIL_STATS, "exchange_compact_steps"):
         assert [int(v) for v in st2[name]] == [s[name] for s in stats]
     assert [s["tail_distinct_steps"] for s in stats] == [1, 1, 0, 1]
+    assert [s["exchange_compact_steps"] for s in stats] == [1, 1, 0, 1]
     assert [s["distinct_rows"] for s in stats] == [
         len(np.unique(np.asarray(fm.ffm_row_hash(b[0], MR))))
         for b in batches]
@@ -251,6 +268,7 @@ def test_small_blocks_keep_the_dense_tail_on_each_chip():
     assert _stats(new) == _stats(ref) == {
         "tail_distinct_steps": 0, "tail_dense_steps": 1, "distinct_rows": 0,
         "gather_compact_steps": 0, "scatter_compact_steps": 0}
+    assert _exchanged(new) == 0
     for a, b in ((new[0]["T"], ref[0]["T"]),
                  (new[1]["T"]["gg"], ref[1]["T"]["gg"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6,
@@ -275,20 +293,38 @@ def test_the_factory_refuses_what_it_cannot_deal():
 
 
 def test_the_steps_collectives_are_the_partitioners():
-    """No collective is written inside the two `shard_map`s: the blocks'
-    slabs and stats leave them stacked over tp, and the sums between are
-    plain reductions over that axis, which the partitioner turns into the
-    all-reduce its cut of the dense step has (and names as it names that
-    one: the benchmark's `mesh.collective_share` reads ops by name)."""
+    """No collective is written inside the `shard_map`s (the ranking and
+    the exchange's parts, the expansion out of the blocks' compact
+    tables, the fallback's gather of each block, the step's rest): what
+    leaves them stacked over tp is summed or laid out whole between them
+    by plain operations, which the partitioner turns into collectives
+    and names as it names its own (the benchmark's
+    `mesh.collective_share` reads ops by name): an all-gather of the
+    blocks' tables, an int32 all-reduce of the slots' rows in them, the
+    fallback's all-reduce of the slabs, and scalars."""
     kw, args = _batch("unit", _ids([30] * 4))
     mesh = _mesh(4)
-    text = _step(mesh, **kw).lower(*_state(mesh=mesh), 3.0, *args).as_text()
-    assert text.count("sdy.manual_computation") == 2
+    lowered = _step(mesh, **kw).lower(*_state(mesh=mesh), 3.0, *args)
+    text = lowered.as_text()
+    assert text.count("sdy.manual_computation") == 4
     for op in ("all_reduce", "all_gather", "reduce_scatter",
                "collective_permute", "all_to_all"):
         assert f"stablehlo.{op}" not in text, op
     assert re.search(r"stablehlo\.reduce\(.*\(tensor<4x%dx%dx%dxbf16>"
                      % (B, L, W), text)
+    assert re.search(r"stablehlo\.reduce\(.*\(tensor<4x%dxi32>" % N, text)
+    cap = fm.gather_cap(fm.tail_cap(N, MR // 4, W, 2), W, 2, 4)
+    compiled = lowered.compile().as_text()
+    found = re.findall(r"%(\S+) = (\S+) (all-reduce|all-gather|"
+                       r"reduce-scatter|collective-permute|all-to-all)"
+                       r"(?:-start)?\(", compiled)
+    assert all(name.startswith(kind) for name, _, kind in found), found
+    # the CPU moves the bfloat16 tables and slabs as float32
+    big = sorted((kind, re.sub(r"\{.*", "", shape)) for _, shape, kind in found
+                 if "[]" not in shape and not shape.startswith("("))
+    assert big == [("all-gather", f"f32[{4 * cap},{W}]"),
+                   ("all-reduce", f"f32[{B},{L},{W}]"),
+                   ("all-reduce", f"s32[{N}]")], big
 
 
 def test_rank_rows_sets_another_blocks_slots_aside():
@@ -338,6 +374,7 @@ def test_scatter_compact_steps_counts_where_every_chip_took_one_pass(
         "tail_distinct_steps": 1, "tail_dense_steps": 0,
         "distinct_rows": sum(per_block), "gather_compact_steps": 1,
         "scatter_compact_steps": one_pass}
+    assert _exchanged(new) == 1
     _assert_same_to_rounding(new, ref, rows)
     mesh = _mesh(4)
     step = _step(mesh, fieldmajor=True, unit_val=True)
@@ -348,3 +385,69 @@ def test_scatter_compact_steps_counts_where_every_chip_took_one_pass(
         jnp.stack([b[0] for b in batches]), None,
         jnp.stack([b[1] for b in batches]), None, None)
     assert [int(v) for v in st["scatter_compact_steps"]] == [1, one_pass]
+
+
+#: COMPACT_TABLE_BYTES cut so that the blocks' tables laid end to end hold
+#: 128 rows a chip of four (256 of two) and a chip's own gather 512: a
+#: block of more than 128 distinct rows takes the slabs' sum while every
+#: chip still reads its gather through its distinct rows
+_ROOM = 128 * 256 * 4
+
+
+@pytest.mark.parametrize("tp,per_block,exchanged", [
+    (4, [30, 30, 30, 30], 1),
+    (4, [20, 20, 200, 20], 0),
+    (2, [100, 100], 1),
+    (2, [20, 300], 0),
+], ids=["tp4_every_block_fits", "tp4_one_block_over",
+        "tp2_every_block_fits", "tp2_one_block_over"])
+def test_the_slab_crosses_as_compact_tables_where_every_block_fits(
+        tp, per_block, exchanged, monkeypatch):
+    """`ffm_slab_over_blocks`: every chip sees T[rows] of the whole table,
+    bit for bit (what the blocks' summed slabs are), whether it crossed the
+    mesh as the blocks' compact tables (every block within the room) or,
+    where ONE block holds more distinct rows than its share of the room,
+    as the blocks' slabs on every chip; the step then equals the
+    one-device step either way, and counts which it took."""
+    monkeypatch.setattr(fm, "COMPACT_TABLE_BYTES", _ROOM)
+    cap = fm.tail_cap(N, MR // tp, W, 2)
+    assert fm.gather_cap(cap, W, 2, tp) == 512 // tp
+    assert fm.gather_cap(cap, W, 2) == 512
+    assert (max(per_block) <= 512 // tp) == bool(exchanged)
+    mesh = _mesh(tp)
+    idx = jnp.asarray(_ids(per_block, tp=tp))
+    params, state = _state(mesh=mesh)
+    mine, slab, ex = jax.jit(lambda T, s, i: fm.ffm_slab_over_blocks(
+        mesh, T, s, i, OPT))(params["T"], state["T"], idx)
+    assert int(ex) == exchanged
+    assert [int(c) for c in mine[2]] == [1] * tp   # every chip's gather
+    whole = np.asarray(_state()[0]["T"])[np.asarray(fm.ffm_row_hash(idx, MR))]
+    assert slab.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(slab).view(np.uint16),
+                                  whole.view(np.uint16))
+    new, ref, rows = _both("unit", np.asarray(idx), tp)
+    assert _stats(new) == _stats(ref) == {
+        "tail_distinct_steps": 1, "tail_dense_steps": 0,
+        "distinct_rows": sum(per_block), "gather_compact_steps": 1,
+        "scatter_compact_steps": 1}
+    assert _exchanged(new) == exchanged
+    _assert_same_to_rounding(new, ref, rows)
+
+
+def test_exchange_compact_steps_counts_each_step_of_a_megastep(monkeypatch):
+    """The megastep stacks the counter per step, as the others: a batch
+    whose blocks all fit, one with a block over the exchange's room (the
+    slabs' sum, the distinct-row tail still), one with a block over its
+    ranking's capacity (the slabs' sum and that chip's dense tail)."""
+    monkeypatch.setattr(fm, "COMPACT_TABLE_BYTES", _ROOM)
+    mesh = _mesh(4)
+    step = _step(mesh, fieldmajor=True, unit_val=True)
+    per_block = ([30] * 4, [20, 20, 200, 20], [20, 20, 700, 20])
+    batches = [_batch("unit", _ids(pb, seed=70 + i), seed=80 + i)[1]
+               for i, pb in enumerate(per_block)]
+    _, _, _, st = make_megastep(step.core)(
+        *_state(mesh=mesh), 7.0, jnp.full((3,), B, np.int32),
+        jnp.stack([b[0] for b in batches]), None,
+        jnp.stack([b[1] for b in batches]), None, None)
+    assert [int(v) for v in st["exchange_compact_steps"]] == [1, 0, 0]
+    assert [int(v) for v in st["tail_distinct_steps"]] == [1, 1, 0]

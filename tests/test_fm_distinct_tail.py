@@ -970,16 +970,26 @@ def test_ffm_mesh_trainer_takes_the_distinct_tail(tp, k):
     from hivemall_tpu.models.fm import FFMTrainer
     if len(jax.devices()) < tp:
         pytest.skip(f"needs {tp} devices")
+    from hivemall_tpu.obs.registry import registry
+    from hivemall_tpu.obs.report import summarize
     opts = f"{_FFM_OPTS} -steps_per_dispatch {k}"
-    t = FFMTrainer(f"{opts} -mesh dp=1,tp={tp}")
-    assert fm.tail_cap(32 * 8, t.Mr // tp, t.W, 2) == 256
     one = FFMTrainer(opts)
+    t = FFMTrainer(f"{opts} -mesh dp=1,tp={tp}")   # the registry's, last
+    assert fm.tail_cap(32 * 8, t.Mr // tp, t.W, 2) == 256
     for trainer in (t, one):
         trainer.fit_stream(_ffm_trainer_stream(8, 32, 20, 1 << 20).batches(
             32, shuffle=False))
     assert t._t == one._t == 8 and np.isfinite(t.cumulative_loss)
     assert t.cumulative_loss == pytest.approx(one.cumulative_loss, rel=1e-5)
-    assert dict(t._step_counts) == dict(one._step_counts)   # folded above
+    # folded above; beside them, how the slab crossed the mesh: as the
+    # blocks' compact tables on every step (20 ids, a few rows a block)
+    counts = dict(t._step_counts)
+    assert counts.pop("exchange_compact_steps") == t._t
+    assert counts == dict(one._step_counts)
+    snap = registry.snapshot()
+    assert snap["train"]["exchange_compact_steps"] == t._t
+    report = summarize([{"event": "train_done", "ts": 1.0, "telemetry": snap}])
+    assert f"exchange of compact tables x{t._t}" in report
     assert t._step_counts["tail_distinct_steps"] == t._t
     assert t._step_counts["gather_compact_steps"] == t._t
     assert t._step_counts["scatter_compact_steps"] == t._t

@@ -73,16 +73,19 @@ def test_ffm_joint_megastep_compiles_for_v5e_with_the_distinct_tail():
 
 def test_ffm_joint_megastep_compiles_for_four_v5e_chips_as_the_one_chips():
     """The same megastep under `-mesh dp=1,tp=4` at the four-chip cell's
-    geometry (`-dims 2^30`: [4194304, 164] a chip; PR 36), two
-    `shard_map`s over tp: the worker asserts that a chip's program is the one chip's
-    (three `conditional`, four `while`, the compact table of the chip's
-    own distinct rows and its compact gradient in fast memory, the one
-    `scatter_rows` kernel), that a batch
+    geometry (`-dims 2^30`: [4194304, 164] a chip), `shard_map`s
+    over tp: the worker asserts that a chip's program is the one chip's
+    and the exchange (four `conditional`, five `while`, the compact
+    tables of the chip's own distinct rows and its compact gradient in
+    fast memory, the one `scatter_rows` kernel), that a batch
     within the capacities runs no zero fill of a table-sized gradient and
     no table-sized AdaGrad pass (the parent's GSPMD program ran both every
-    step), and that the one collective of any size is the slab's
-    all-reduce, bf16[32768,39,164], made and NAMED by the partitioner
-    (the benchmark reads collectives by name) (~40 s of XLA compile)."""
+    step), and that the collectives of any size are, on that batch's
+    path, the all-gather of the blocks' compact tables, bf16[196608,164],
+    and the all-reduce of the slots' rows in them, s32[1277952], the
+    slab's all-reduce, bf16[32768,39,164], left to the other branch,
+    all made and NAMED by the partitioner (the benchmark reads
+    collectives by name) (~40 s of XLA compile)."""
     _compile("ffm_joint_megastep_tp4", timeout=600)
 
 

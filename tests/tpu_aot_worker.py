@@ -102,25 +102,27 @@ def parts_step_sharded():
 
 
 def _slab_gathers(text, slab):
-    """{branch of the gather's `cond`: shape of the operand read} for the
-    step's forward gathers (`hm.gather`, a result of shape `slab`)."""
+    """{branches of the `cond`s around the gather, outermost first
+    ("branch_0/branch_1"): shape of the operand read} for the step's
+    forward gathers (`hm.gather`, a result of shape `slab`)."""
     out = {}
     for m in re.finditer(r"= %s\S* gather\(%%([\w.]+),.*?op_name=\"([^\"]*)\""
                          % re.escape(slab), text):
         operand, path = m.groups()
         if "/hm.gather/" not in path:
             continue
-        branch = re.search(r"branch_\d", path)
-        out[branch.group(0) if branch else "no_cond"] = re.search(
+        branches = re.findall(r"branch_\d", path)
+        out["/".join(branches) or "no_cond"] = re.search(
             r"%%%s = (\w+\[[\d,]*\])" % re.escape(operand), text).group(1)
     return out
 
 
 def _compact_table_spaces(text, shape):
-    """The memory spaces the compiled text gives the compact table in the
-    fill's loop (`S(1)`: the chip's fast memory; "" is HBM)."""
-    loop = re.search(r" while\((?:(?!\n).)*?/hm\.gather/while", text)
-    line = text[text.rindex("\n", 0, loop.start()):loop.end()]
+    """The memory spaces the compiled text gives the compact table of
+    shape `shape` in its fill's loop (`S(1)`: the chip's fast memory; ""
+    is HBM)."""
+    line = next(line for line in text.splitlines() if " while(" in line
+                and "/hm.gather/while" in line and shape + "{" in line)
     return set(re.findall(r"%s\{[^}]*?(S\(\d\))?\}" % re.escape(shape), line))
 
 
@@ -328,13 +330,24 @@ def ffm_joint_megastep_tp4():
     ffm_criteo_joint_tp4.stream_mesh's geometry (-dims 2^30: 16,777,216
     rows over `-mesh dp=1,tp=4`, so [4194304, 164] a chip, the one-chip
     flagship's block), `make_ffm_step_fused(mesh=...)` under `shard_map`
-    over tp (two of them, the slabs' sum between). A chip's program is
-    the one chip's: no zero fill of a float32 [4194304, 164] gradient and no table-sized AdaGrad pass
+    over tp. A chip's program is the one chip's: no zero fill of a
+    float32 [4194304, 164] gradient and no table-sized AdaGrad pass
     outside the dense branch of the tail's `conditional`, the compact
-    table of the chip's own distinct rows in fast memory, ONE all-reduce,
-    of the slab (bf16[32768,39,164], 419 MB) and of nothing else but
-    scalars (the step's stats), a chip's outputs its block of the state
-    and its temporaries the one-chip program's."""
+    table of the chip's own distinct rows in fast memory, a chip's
+    outputs its block of the state and its temporaries the one-chip
+    program's.
+
+    The slab crosses the mesh as the blocks' compact tables
+    (`ops/fm.py` `ffm_slab_over_blocks`): one `conditional` more, on the
+    blocks' largest count of distinct rows, and one `while` more (each
+    chip's fill of its own [49152, 164] table, beside the fill of
+    `gather_rows`' in the other branch). A batch within the capacities
+    moves ONE all-gather, of the tables laid end to end (bf16[196608,164]:
+    tp * 49,152 rows, placed in fast memory, which the expansion reads),
+    and ONE int32 all-reduce of a row a slot (s32[1277952]), besides
+    scalars; the slabs' all-reduce (bf16[32768,39,164], 419 MB), the
+    only collective of size before the exchange, is left in the other branch
+    alone. Every collective made and NAMED by the partitioner."""
     from hivemall_tpu.ops.scan import make_megastep
     Fj, B, L, ks, tp = 39, 32768, 39, 2, 4
     Mr, W = 1 << 24, Fj * K + 8
@@ -347,6 +360,8 @@ def ffm_joint_megastep_tp4():
                                   fieldmajor=True, unit_val=True, mesh=mesh)
     cap = fm.tail_cap(B * L, Mr // tp, W, 2)
     assert cap == 174080, cap           # the one-chip flagship's
+    ecap = fm.gather_cap(cap, W, 2, tp)
+    assert ecap == 49152, ecap
     w0 = _sds((), jnp.float32, everywhere)
     compiled = make_megastep(step.core).lower(
         {"T": _sds((Mr, W), jnp.bfloat16, rows), "w0": w0},
@@ -356,14 +371,23 @@ def ffm_joint_megastep_tp4():
         _sds((ks, B), jnp.float32, everywhere), None, None).compile()
     text = compiled.as_text()
     conds, loops = text.count(" conditional("), text.count(" while(")
-    assert conds == 3, \
-        f"{conds} conditional op(s), expected the list, gather and tail"
-    assert loops == 4, f"{loops} while op(s), expected the scan, the " \
-        "tail's passes, the blocks and the fill"
-    block = f"[{Mr // tp},{W}]"
-    _assert_compact_gather(
-        text, f"bf16[{B},{L},{W}]",
-        f"bf16[{fm.gather_cap(cap, W, 2)},{W}]", f"bf16{block}")
+    assert conds == 4, f"{conds} conditional op(s), expected the list, " \
+        "the exchange, the gather of its fallback and the tail"
+    assert loops == 5, f"{loops} while op(s), expected the scan, the " \
+        "tail's passes, the blocks and two fills"
+    block, slab = f"[{Mr // tp},{W}]", f"bf16[{B},{L},{W}]"
+    every = f"bf16[{tp * ecap},{W}]"
+    # the exchange's true branch expands the slab out of the tables laid
+    # end to end; its other branch gathers each block for its slab
+    gathers = _slab_gathers(text, slab)
+    assert gathers == {
+        "branch_1": every,
+        "branch_0/branch_1": f"bf16[{fm.gather_cap(cap, W, 2)},{W}]",
+        "branch_0/branch_0": f"bf16{block}"}, gathers
+    for table in (f"bf16[{ecap},{W}]", f"bf16[{fm.gather_cap(cap, W, 2)},"
+                  f"{W}]"):
+        spaces = _compact_table_spaces(text, table)
+        assert spaces == {"S(1)"}, f"{table} lives in {spaces}"
     # a chip walks all the slab's slots, and drops those of other blocks
     _assert_compact_scatter(text, B * L, W, fm.scatter_cap(cap, W))
     assert text.count("tpu_custom_call") == 1
@@ -372,7 +396,8 @@ def ffm_joint_megastep_tp4():
     # the dense branch of the tail's cond and nowhere a fitting batch goes
     # (the relayouts at the megastep's two ends are copies, the blocks'
     # row scatters update in place)
-    dense = [line.split(" = ")[0].strip() for line in _fitting_path(text)
+    fitting = _fitting_path(text)
+    dense = [line.split(" = ")[0].strip() for line in fitting
              if re.search(r"= \(?[^=]*%s[^=]* (broadcast\(|fusion\(.*"
                           r"kind=kLoop)" % re.escape(block), line)]
     assert not dense, f"table-sized passes a fitting batch runs: {dense}"
@@ -380,16 +405,28 @@ def ffm_joint_megastep_tp4():
     # reads a trace's ops by name; a `lax.psum` would be `psum.N`)
     # (a result may be a tuple: the partitioner combines the stats' small
     # reductions, two counters in one since PR 38)
-    named = re.findall(r"%(\S+) = ([^=]*?) all-reduce(?:-start)?\(", text)
-    assert all(n.startswith("all-reduce") for n, _ in named) and len(
-        named) == len(re.findall(r" all-reduce(?:-start)?\(", text)), \
-        f"an all-reduce unnamed: {[n for n, _ in named]}"
-    reduces = [a for _, r in named for a in re.findall(r"\w+\[[\d,]*\]", r)]
-    big = [r for r in reduces if r != f"bf16[{B},{L},{W}]"
-           and np.prod([int(d) for d in re.findall(r"\d+", r)[1:]]) > 64]
-    assert reduces.count(f"bf16[{B},{L},{W}]") == 1 and not big, reduces
-    for kind in ("all-gather", "collective-permute", "all-to-all",
-                 "reduce-scatter"):
+    kinds = ("all-reduce", "all-gather")
+
+    def named(lines):
+        return [(n, k, a) for line in lines for n, r, k in re.findall(
+            r"%(\S+) = ([^=]*?) (all-reduce|all-gather)(?:-start)?\(", line)
+            for a in re.findall(r"\w+\[[\d,]*\]", r)]
+    every_one = named(text.splitlines())
+    assert all(n.startswith(k) for n, k, _ in every_one) and len(
+        {n for n, _, _ in every_one}) == sum(
+            len(re.findall(r" %s(?:-start)?\(" % k, text)) for k in kinds), \
+        f"a collective unnamed: {[n for n, _, _ in every_one]}"
+
+    def big(found):
+        return sorted((k, a) for _, k, a in found if np.prod(
+            [int(d) for d in re.findall(r"\d+", a)[1:]]) > 64)
+    assert big(every_one) == sorted([
+        ("all-gather", every), ("all-reduce", f"s32[{B * L}]"),
+        ("all-reduce", slab)]), big(every_one)
+    assert big(named(fitting)) == sorted([
+        ("all-gather", every), ("all-reduce", f"s32[{B * L}]")]), \
+        big(named(fitting))
+    for kind in ("collective-permute", "all-to-all", "reduce-scatter"):
         assert f" {kind}(" not in text and f" {kind}-start(" not in text, \
             f"{kind} in the sharded megastep"
     # a chip's outputs are its block of the state, its temporaries no
