@@ -3,7 +3,7 @@
 
 Drives the main path once, in ONE process, through the entry points a user
 calls, at the full width of the flagship (train_ffm at libffm's Criteo
-setting: 40 fields, 4 factors, 32768-row batches, a 2^24-row hashed table;
+setting: 40 fields, 4 factors, 32768-row batches, 2^24 hashed slots;
 depth cut to a few windows, weights random from a seed):
 
   train   lookup("train_ffm") -> fit on a planted-signal SparseDataset (two
@@ -16,9 +16,11 @@ depth cut to a few windows, weights random from a seed):
   serve   save_bundle -> PredictEngine (jitted scorer) -> PredictServer in
           this process -> /predict with 1, 7 and 256 rows, scores held to
           trainer.predict on those rows
-  mesh    with >= 4 chips: the train leg under -mesh dp=2,tp=2, joint
-          (GSPMD) and -ffm_table parts (shard_map + Pallas), shards on four
-          distinct devices, first-window losses held to the one-chip leg
+  mesh    with >= 4 chips: the train leg under -mesh dp=2,tp=2 (GSPMD),
+          the table's shards on four distinct devices, first-window losses
+          held to the one-chip train leg's
+
+The table is -ffm_table auto, the joint layout every cell and user runs.
 
 Any leg that raises fails the script. It needs a TPU: there is no option
 that lets it pass without one (tests/test_chip_smoke.py calls the legs at
@@ -45,13 +47,10 @@ FULL = dict(dims=1 << 24, fields=40, factors=4, batch=32768, vocab=1000,
 WINDOW = 8                     # -steps_per_dispatch auto on accelerators
 
 
-def ffm_options(size: dict, *, table: str = "parts", mesh: str = "",
-                extra: str = "") -> str:
+def ffm_options(size: dict, *, mesh: str = "", extra: str = "") -> str:
     opts = (f"-dims {size['dims']} -factors {size['factors']} "
             f"-fields {size['fields']} -mini_batch {size['batch']} "
             "-opt adagrad -classification -halffloat")
-    if table != "auto":
-        opts += f" -ffm_table {table}"
     if mesh:
         opts += f" -mesh {mesh}"
     return f"{opts} {extra}".strip()
@@ -93,8 +92,8 @@ def _shard_devices(arr) -> int:
     return len({s.device for s in arr.addressable_shards})
 
 
-def train_leg(size: dict, *, table: str = "parts", mesh: str = "",
-              extra_opts: str = "", stream: bool = True) -> tuple:
+def train_leg(size: dict, *, mesh: str = "", extra_opts: str = "",
+              stream: bool = True) -> tuple:
     """fit (+ second fit for the no-retrace check) (+ fit_stream). Returns
     (trainer, options, report); raises on any failed check."""
     import jax
@@ -103,7 +102,7 @@ def train_leg(size: dict, *, table: str = "parts", mesh: str = "",
     from hivemall_tpu.utils.device import pallas_interpret
 
     B = size["batch"]
-    opts = ffm_options(size, table=table, mesh=mesh, extra=extra_opts)
+    opts = ffm_options(size, mesh=mesh, extra=extra_opts)
     dp = get_devprof()
     c0, s0 = dp.compiles, dp.compile_s
     t0 = time.perf_counter()
@@ -132,7 +131,8 @@ def train_leg(size: dict, *, table: str = "parts", mesh: str = "",
     packed = trainer._pack_input_on()
     if packed and K > 1:
         assert "ffm.packed_megastep" in dp.builds, sorted(dp.builds)
-    if trainer.layout == "parts":
+    assert trainer.layout == "joint", trainer.layout
+    if not mesh:
         # the step that ran is compiled Pallas exactly when the device
         # policy says so (never on a CPU nobody named, always on a TPU)
         assert _uses_mosaic(trainer, size) == (not pallas_interpret())
@@ -286,34 +286,26 @@ def serve_leg(trainer, opts: str, size: dict) -> dict:
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def mesh_leg(size: dict, one_chip_parts_losses, *, mesh: str = "dp=2,tp=2",
+def mesh_leg(size: dict, one_chip_losses, *, mesh: str = "dp=2,tp=2",
              extra_opts: str = "", rtol: float = 0.06) -> dict:
-    """The train leg under a (dp, tp) mesh, joint (GSPMD) then parts
-    (shard_map + Pallas). The big table must live on every device of the
-    mesh, and the first window must track the same layout on one chip
-    (same init, data, order and minibatch-AdaGrad semantics) within bf16
-    noise — the joint layout's one-chip reference is run here."""
-    out = {}
-    for table, leaf in (("auto", "T"), ("parts", "T2")):
-        ref = one_chip_parts_losses if table == "parts" else train_leg(
-            size, table=table, extra_opts=extra_opts,
-            stream=False)[2]["first_window_losses"]
-        trainer, _, rep = train_leg(size, table=table, mesh=mesh,
-                                    extra_opts=extra_opts, stream=False)
-        n_dev = trainer.mesh.devices.size
-        on = _shard_devices(trainer.params[leaf])
-        assert on == n_dev, f"{leaf} on {on} of {n_dev} devices"
-        rel = np.abs(np.asarray(rep["first_window_losses"])
-                     - np.asarray(ref)) / np.asarray(ref)
-        print(f"chip_smoke: mesh {table} on {on} devices, first-window "
-              f"rel diff vs one chip {rel.round(4).tolist()}", flush=True)
-        assert rel[0] <= 5e-3, f"{table}: step-1 loss off by {rel[0]:.4f}"
-        assert rel.max() <= rtol, f"{table}: first window off by {rel.max()}"
-        rep.update(layout=trainer.layout, shard_devices=on,
-                   max_rel_diff_vs_one_chip=round(float(rel.max()), 4))
-        out["joint" if table == "auto" else "parts"] = rep
-        del trainer
-    return out
+    """The train leg under a (dp, tp) mesh (GSPMD). The table must live on
+    every device of the mesh, and the first window must track the one-chip
+    train leg's (same init, data, order and minibatch-AdaGrad semantics)
+    within bf16 noise."""
+    trainer, _, rep = train_leg(size, mesh=mesh, extra_opts=extra_opts,
+                                stream=False)
+    n_dev = trainer.mesh.devices.size
+    on = _shard_devices(trainer.params["T"])
+    assert on == n_dev, f"T on {on} of {n_dev} devices"
+    rel = np.abs(np.asarray(rep["first_window_losses"])
+                 - np.asarray(one_chip_losses)) / np.asarray(one_chip_losses)
+    print(f"chip_smoke: mesh on {on} devices, first-window rel diff vs "
+          f"one chip {rel.round(4).tolist()}", flush=True)
+    assert rel[0] <= 5e-3, f"step-1 loss off by {rel[0]:.4f}"
+    assert rel.max() <= rtol, f"first window off by {rel.max()}"
+    rep.update(layout=trainer.layout, shard_devices=on,
+               max_rel_diff_vs_one_chip=round(float(rel.max()), 4))
+    return rep
 
 
 def main() -> int:

@@ -1,12 +1,14 @@
-"""Which one-chip programs a change left alone: sha256 (16 hex digits) and
-length of the LOWERED text (StableHLO, before any compiler) of the steps
-and megasteps the benchmark's one-chip cells run, at the cells' shapes:
+"""Which programs a change left alone: sha256 (16 hex digits) and length
+of the LOWERED text (StableHLO, before any compiler) of the steps and
+megasteps the benchmark's cells run, at the cells' shapes:
 `fm_criteo.stream` (packed FM, -dims 2^26 -factors 5), `ffm_criteo_joint
 .stream` (the joint fused step's three variants, each with and without the
 distinct-row tail, -dims 2^28 -halffloat), `logreg_criteo.stream`
-(AdaGrad-RDA, -dims 2^28) and, since PR 38, `skipgram_enwiki.stream_text`
-(-dim 300 on 2,519,370 words). Thirteen lines; nothing is compiled or
-run, so it says nothing about time.
+(AdaGrad-RDA, -dims 2^28), `skipgram_enwiki.stream_text` (-dim 300 on
+2,519,370 words) and the four-chip cell `ffm_criteo_joint_tp4.stream_mesh`'s
+megastep (-dims 2^30 -halffloat -mesh dp=1,tp=4, lowered over four host CPU
+devices; the text does not depend on which devices). Fourteen lines;
+nothing is compiled or run, so it says nothing about time.
 
 A perf PR that must not move a cell compares two trees (PRs 34, 36, 38):
 
@@ -24,11 +26,16 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=4")
 sys.path.insert(0, os.path.abspath(sys.argv[1]) if len(sys.argv) > 1 else
                 os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from hivemall_tpu.models import word2vec as w2v
 from hivemall_tpu.ops import fm
@@ -36,6 +43,7 @@ from hivemall_tpu.ops.linear import make_linear_step
 from hivemall_tpu.ops.losses import get_loss
 from hivemall_tpu.ops.optimizers import make_optimizer
 from hivemall_tpu.ops.scan import make_megastep
+from hivemall_tpu.parallel.mesh import make_mesh
 
 S = jax.ShapeDtypeStruct
 KS, B, L = 2, 32768, 39
@@ -100,6 +108,22 @@ def main():
             {"in": table, "out": table}, {"words": S((2,), jnp.int32)}, f0,
             ts, S((KS, Bp, 5), jnp.int32), None, S((KS, Bp), jnp.float32),
             None, S((1 << 27,), jnp.int32)))
+
+    # the four-chip cell: -dims 2^30 is 2^24 rows dealt over tp=4
+    mesh = make_mesh(dp=1, tp=4)
+    blocks, everywhere = NamedSharding(mesh, P("tp", None)), \
+        NamedSharding(mesh, P())
+    Mr, W = 1 << 24, F * Kf + 8
+    step = fm.make_ffm_step_fused(loss, opt, (0.01,) * 3, F, Kf,
+                                  fieldmajor=True, unit_val=True, mesh=mesh)
+    w0 = S((), jnp.float32, sharding=everywhere)
+    show("ffm_unit_mega_tp4", make_megastep(step.core).lower(
+        {"T": S((Mr, W), jnp.bfloat16, sharding=blocks), "w0": w0},
+        {"T": {"gg": S((Mr, W), jnp.float32, sharding=blocks)},
+         "w0": {"gg": w0}},
+        w0, S((KS,), jnp.int32, sharding=everywhere),
+        S((KS, B, L), jnp.int32, sharding=everywhere), None,
+        S((KS, B), jnp.float32, sharding=everywhere), None, None))
 
 
 if __name__ == "__main__":

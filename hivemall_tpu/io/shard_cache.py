@@ -338,23 +338,20 @@ class CachedPackedShard:
         self.m_cap = int(header["m_cap"])
         self.n_rows = int(header["n_rows"])
 
-    def batches(self, batch_size: int, order: np.ndarray, *, stats=None,
-                pad_rows=None) -> Iterator[PackedBatch]:
+    def batches(self, batch_size: int, order: np.ndarray, *,
+                stats=None) -> Iterator[PackedBatch]:
         """Yield the epoch's PackedBatches for ``order`` (a permutation or
         arange over the dataset rows). Per batch: gather the records, pick
         the batch's canonical width from the rows' multiplicities (exactly
         how ``canonicalize_fieldmajor`` sizes the streamed batch), and lay
-        the lanes/labels out as ``pack_unit_fieldmajor`` does. ``pad_rows``
-        maps the logical batch size to the allocated row count (the parts
-        layout's kernel-grid row padding); identity otherwise."""
+        the lanes/labels out as ``pack_unit_fieldmajor`` does."""
         F, Lcap3 = self.F, self.m_cap * self.F * 3
-        bs = int(batch_size)
-        B = int(pad_rows(bs)) if pad_rows is not None else bs
+        B = int(batch_size)
         tracer = get_tracer()
-        for s in range(0, len(order), bs):
+        for s in range(0, len(order), B):
             t0 = time.perf_counter()
             with tracer.span("ingest.cache"):
-                take = order[s:s + bs]
+                take = order[s:s + B]
                 nv = len(take)
                 m_b = pow2_len(max(1, int(self.m_row[take].max(initial=0))))
                 Lb = m_b * F

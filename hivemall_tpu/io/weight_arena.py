@@ -75,9 +75,9 @@ from ..ops.fm import _J1 as _ROWHASH_J1, _J3 as _ROWHASH_J3  # noqa: E402
 
 
 class ArenaUnsupported(ValueError):
-    """The trainer's serving state has no arena mapping (e.g. the FFM
-    ``parts`` layout, whose table geometry is kernel-grid-shaped). The
-    engine degrades to the bundle path; quantized serving is refused."""
+    """The trainer's serving state has no arena mapping (a trainer with no
+    ``serving_tables()`` surface, e.g. the tree ensembles). The engine
+    degrades to the bundle path; quantized serving is refused."""
 
 
 def arena_path(bundle_path: str) -> str:

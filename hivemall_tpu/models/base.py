@@ -1148,9 +1148,8 @@ class LearnerBase:
 
     def _note_batch(self, batch: SparseBatch) -> None:
         """Hook for emission-time metadata on the streaming path, called
-        once a batch in stream order on the source thread (FFM's joint and
-        parts layouts hand the batch to their observed-pair tracker
-        here)."""
+        once a batch in stream order on the source thread (FFM's joint
+        layout hands the batch to its observed-pair tracker here)."""
 
     # -- shared plumbing -----------------------------------------------------
     def _parse_row(self, features) -> Tuple[np.ndarray, np.ndarray]:

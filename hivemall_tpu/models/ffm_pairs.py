@@ -1,6 +1,6 @@
 """Observed (feature, field) pairs of an FFM trainer.
 
-Joint- and parts-layout model emission enumerates the pairs training saw
+Joint-layout model emission enumerates the pairs training saw
 (``FFMTrainer._observed_pairs``). They are kept as packed int64 keys
 ``feature_id * F + field`` in one sorted array, no Python tuples.
 

@@ -110,25 +110,6 @@ def _ffm_step_cached(loss_name, opt, eta_scheme, eta0, total_steps,
         lambdas)
 
 
-@_instrument("ffm", "parts_step")
-@_lru_cache(maxsize=64)
-def _parts_step_cached(loss_name, eta_scheme, eta0, total_steps, power_t,
-                       lambdas, F, k, MRF, unit_val, interpret):
-    from ..ops.fm_pallas import make_parts_step
-    from ..ops.schedules import make_eta
-    return make_parts_step(get_loss(loss_name),
-                           make_eta(eta_scheme, eta0, total_steps, power_t),
-                           lambdas, F, k, MRF, unit_val=unit_val,
-                           interpret=interpret)
-
-
-@_instrument("ffm", "parts_score")
-@_lru_cache(maxsize=64)
-def _parts_score_cached(F, k, MRF):
-    from ..ops.fm_pallas import make_parts_score
-    return make_parts_score(F, k, MRF)
-
-
 @_instrument("fm", "score_fused")
 @_lru_cache(maxsize=64)
 def _fm_score_fused_cached(k):
@@ -343,7 +324,7 @@ class FMTrainer(LearnerBase):
             # minibatch: ONE scatter-add into a dense G + dense optimizer
             # pass (2 table-row index ops/slot) instead of the
             # per-occurrence sparse chain's 5 — the update shape the FFM
-            # fused/parts paths already use. AdaGrad only: SGD's sparse
+            # fused path already uses. AdaGrad only: SGD's sparse
             # form is already 2 index ops, and the dense pass would be
             # pure overhead there.
             if upd == "minibatch" and self.optimizer.name != "adagrad":
@@ -606,7 +587,7 @@ class FMTrainer(LearnerBase):
 
 
 # --- FFM host prep as pure config-parameterized functions -------------------
-# The parallel prep leg (canonicalize -> parts row pad -> pack) must exist
+# The parallel prep leg (canonicalize -> pack) must exist
 # in TWO callable forms with identical semantics: the bound trainer methods
 # (thread pools, sequential fallback) and a PICKLABLE config-built callable
 # for -ingest_pool process — a bound method would drag the whole trainer
@@ -652,53 +633,20 @@ def _ffm_canonicalize(batch: SparseBatch, F: int, canon_on: bool,
                        n_valid=batch.n_valid, fieldmajor=True)
 
 
-def _parts_row_target(B: int, dp: int = 1) -> int:
-    """The parts kernel's allocated row count for ``B`` logical rows:
-    whole 128-row tiles (the SMEM row-id packing) up to 2048, then whole
-    2048-row chunks, scaled by the dp axis. The ONE copy of the grid rule
-    — the streamed pad (_ffm_pad_parts) and the shard cache's batch
-    assembly (_cache_row_pad) must agree or cached batches stop matching
-    the compiled buckets."""
-    mult = 128 * dp if B <= 2048 * dp else 2048 * dp
-    return -(-B // mult) * mult
-
-
-def _ffm_pad_parts(batch: SparseBatch, dp: int = 1) -> SparseBatch:
-    """Pad the batch's row count to the Pallas parts kernel's grid
-    multiple (_parts_row_target); padded rows carry idx 0 and are masked
-    out of the loss by n_valid. Under -mesh each dp rank must receive
-    whole tiles, so the multiple scales by dp on both branches."""
-    B = batch.batch_size
-    target = _parts_row_target(B, dp)
-    if target == B:
-        return batch
-    pad = target - B
-    idx = np.pad(np.asarray(batch.idx), ((0, pad), (0, 0)))
-    val = None if batch.val is None else np.pad(
-        np.asarray(batch.val), ((0, pad), (0, 0)))
-    lab = np.pad(np.asarray(batch.label), (0, pad))
-    nv = batch.n_valid if batch.n_valid is not None else B
-    return SparseBatch(idx, val, lab, None, n_valid=nv, fieldmajor=True)
-
-
 @_dataclass(frozen=True)
 class FFMPrep:
     """Picklable FFM train-prep: a plain dataclass of the option-derived
     booleans the bound prep reads off the trainer, so a process-pool
-    worker rebuilds the exact same function from ~5 scalars instead of a
+    worker rebuilds the exact same function from four scalars instead of a
     pickled trainer. ``__call__`` IS ``_preprocess_train_parallel``."""
 
     F: int
-    canon: bool          # a field-major step exists (joint/parts layouts)
+    canon: bool          # a field-major step exists (joint layout)
     forced: bool         # -ffm_interaction fieldmajor
-    parts: bool          # parts layout: kernel-grid row padding
     pack: bool           # packed uint8 wire format conditions all hold
-    parts_dp: int = 1
 
     def __call__(self, batch: SparseBatch):
         batch = _ffm_canonicalize(batch, self.F, self.canon, self.forced)
-        if self.parts and batch.fieldmajor:
-            batch = _ffm_pad_parts(batch, self.parts_dp)
         if (self.pack and batch.fieldmajor and batch.val is None
                 and isinstance(batch.idx, np.ndarray)):
             return pack_unit_fieldmajor(batch)
@@ -721,9 +669,10 @@ class FFMTrainer(FMTrainer):
     """SQL: train_ffm — reference hivemall.fm.FieldAwareFactorizationMachineUDTF.
 
     Features are "field:index:value" triples (ftvec.trans.ffm_features).
-    Two latent-table layouts (-ffm_table):
+    Two latent-table layouts (-ffm_table; auto picks joint for a
+    power-of-two -dims, else dense):
 
-      joint (default) — the fused feature-row layout: one table
+      joint — the fused feature-row layout: one table
         T[Mr, F*K + 8] where row ffm_row_hash(feature) holds ALL F of that
         feature's per-field latent vectors plus its linear weight, and
         Mr * F_pow2 = -dims total (feature, field) capacity. The TPU analog
@@ -745,12 +694,11 @@ class FFMTrainer(FMTrainer):
         s.add("fields", "num_fields", type=int, default=64,
               help="field-space size F")
         s.add("ffm_table", default="auto",
-              help="latent-table layout: joint (hashed flat [M,K], "
-                   "Criteo-scale) | parts (field-partitioned fused rows "
-                   "with the Pallas VMEM scatter+AdaGrad kernel — fastest "
-                   "on TPU for adagrad/-halffloat/fieldmajor configs) | "
-                   "dense ([N,F,K] field cube) | auto (joint when -dims "
-                   "is a power of two, else dense)")
+              help="latent-table layout: joint (one hashed fused row per "
+                   "feature holding its F latent vectors and its weight, "
+                   "Criteo-scale, power-of-two -dims) | dense ([N,F,K] "
+                   "field cube, exact cells) | auto (joint when -dims is "
+                   "a power of two, else dense)")
         s.add("ffm_interaction", default="auto",
               help="pair-interaction kernel for the joint layout: "
                    "fieldmajor (canonical field-major batches, no L^2 "
@@ -778,8 +726,8 @@ class FFMTrainer(FMTrainer):
         self.k = int(o.factors)
         self.F = int(o.fields)
         self.layout = str(o.ffm_table)
-        if self.layout not in ("joint", "dense", "auto", "parts"):
-            raise ValueError(f"-ffm_table must be joint|parts|dense|auto, "
+        if self.layout not in ("joint", "dense", "auto"):
+            raise ValueError(f"-ffm_table must be joint|dense|auto, "
                              f"got {self.layout!r}")
         self.interaction = str(getattr(o, "ffm_interaction", "auto"))
         if self.interaction not in ("auto", "pairs", "fieldmajor"):
@@ -788,54 +736,13 @@ class FFMTrainer(FMTrainer):
         pow2 = (self.dims & (self.dims - 1)) == 0
         if self.layout == "auto":
             self.layout = "joint" if pow2 else "dense"
-        if self.layout in ("joint", "parts") and not pow2:
-            raise ValueError(f"-ffm_table {self.layout} needs a "
-                             f"power-of-two -dims (got {self.dims})")
+        if self.layout == "joint" and not pow2:
+            raise ValueError("-ffm_table joint needs a power-of-two -dims "
+                             f"(got {self.dims})")
         dtype = jnp.bfloat16 if o.halffloat else jnp.float32
         key = jax.random.PRNGKey(int(o.seed))
         self._pairs = ObservedPairs(self.F)   # seen on the stream path
         self._fit_ds = None            # dataset ref, columnar path
-        if self.layout == "parts":
-            from ..ops.fm_pallas import parts_geometry, parts_supported
-            if not parts_supported(self.F, self.k, self.optimizer.name,
-                                   dtype):
-                raise ValueError(
-                    "-ffm_table parts requires -opt adagrad, -halffloat, "
-                    f"and F*K+8 <= 248 (got opt={self.optimizer.name}, "
-                    f"dtype={dtype.__name__}, F={self.F}, K={self.k}); "
-                    "use -ffm_table joint")
-            self.MRF, self.Wp, self.HP = parts_geometry(self.dims, self.F,
-                                                        self.k)
-            FK = self.F * self.k
-            Tl = jnp.concatenate([
-                jax.random.normal(key, (self.F * self.MRF, FK))
-                * float(o.sigma),
-                jnp.zeros((self.F * self.MRF, self.Wp - FK)),
-            ], axis=1)
-            self.params = {
-                "w0": jnp.zeros((), jnp.float32),
-                "T2": Tl.reshape(self.F * self.MRF * self.HP,
-                                 128).astype(dtype)}
-            self.opt_state = {
-                "w0": self.optimizer.init(()),
-                "T2": {"gg": jnp.zeros((self.F * self.MRF * self.HP, 128),
-                                       jnp.float32)}}
-            from ..utils.device import pallas_interpret
-            interp = pallas_interpret()
-            lamt = (o.lambda0, o.lambda_w, o.lambda_v)
-            eta_key = (str(o.eta), float(o.eta0), o.total_steps, o.power_t)
-            self._step = None
-            self._step_fm = _parts_step_cached(
-                self._loss_name, *eta_key, lamt, self.F, self.k, self.MRF,
-                False, interp)
-            self._step_fm_unit = _parts_step_cached(
-                self._loss_name, *eta_key, lamt, self.F, self.k, self.MRF,
-                True, interp)
-            self._fused_score = None
-            self._fused_score_fm = _parts_score_cached(self.F, self.k,
-                                                       self.MRF)
-            self.interaction = "fieldmajor"   # parts is fieldmajor-only
-            return
         if self.layout == "joint":
             f_pow2 = 1
             while f_pow2 < self.F:
@@ -887,48 +794,6 @@ class FFMTrainer(FMTrainer):
             self._step_fm_unit = None
             self.interaction = "pairs"
 
-    def _apply_mesh(self, spec: str) -> None:
-        if getattr(self, "layout", None) == "parts":
-            self._apply_mesh_parts(spec)
-            return
-        super()._apply_mesh(spec)
-
-    def _apply_mesh_parts(self, spec: str) -> None:
-        """Shard the parts layout over a (dp, tp) mesh: field partitions
-        over 'tp' (the shard boundary is a partition boundary, so slab
-        gathers stay rank-local), batch over 'dp' with a G psum before the
-        optimizer tail (ops.fm_pallas.make_parts_step_sharded). The fused
-        single-chip kernel remains the mesh=None path."""
-        from ..ops.fm_pallas import make_parts_step_sharded
-        from ..ops.schedules import make_eta
-        from ..parallel.mesh import make_mesh, parse_mesh_spec
-        from ..utils.device import pallas_interpret
-        o = self.opts
-        dp, tp = parse_mesh_spec(spec)
-        if self.F % tp:
-            raise ValueError(f"-ffm_table parts: -fields {self.F} must be "
-                             f"divisible by the tp axis ({tp})")
-        B = int(o.mini_batch)
-        Bd = B // dp
-        if B % (dp * 128) or (Bd > 2048 and Bd % 2048):
-            raise ValueError(f"-ffm_table parts: -mini_batch "
-                             f"{o.mini_batch} must be a multiple of "
-                             f"128*dp ({128 * dp}) and, when the per-rank "
-                             f"batch exceeds 2048, of 2048*dp — each dp "
-                             "rank feeds the kernel whole chunk tiles")
-        self.mesh = make_mesh(dp=dp, tp=tp)
-        eta_fn = make_eta(o.eta, o.eta0, o.total_steps, o.power_t)
-        lamt = (o.lambda0, o.lambda_w, o.lambda_v)
-        interp = pallas_interpret()
-        self._step_fm = make_parts_step_sharded(
-            self.loss, eta_fn, lamt, self.F, self.k, self.MRF, self.mesh,
-            interpret=interp)
-        self._step_fm_unit = make_parts_step_sharded(
-            self.loss, eta_fn, lamt, self.F, self.k, self.MRF, self.mesh,
-            unit_val=True, interpret=interp)
-        self._tp_sizes.add(self.F * self.MRF * self.HP)
-        self._reshard_state()
-
     def _batch_args(self, batch: SparseBatch) -> tuple:
         if batch.field is None:
             raise ValueError("train_ffm needs field ids; use "
@@ -936,14 +801,15 @@ class FFMTrainer(FMTrainer):
         return (batch.field,)
 
     def _preprocess_batch(self, batch: SparseBatch) -> SparseBatch:
-        batch = self._canonicalize_batch(batch)
-        if self.layout == "parts" and batch.fieldmajor:
-            batch = self._pad_parts_rows(batch)
-        return batch
+        """Field-major canonicalization (see _ffm_canonicalize — the
+        module function is the single implementation, shared with the
+        picklable process-pool prep)."""
+        return _ffm_canonicalize(batch, self.F, self._step_fm is not None,
+                                 self.interaction == "fieldmajor")
 
     def _preprocess_train_serial(self, batch: SparseBatch):
         # FFM prep has no cross-batch state (no elision latch: unit-ness
-        # is decided per batch inside _canonicalize_batch) — everything
+        # is decided per batch inside _preprocess_batch) — everything
         # runs on the parallel leg, nothing on the serial one
         return batch
 
@@ -967,9 +833,6 @@ class FFMTrainer(FMTrainer):
         return FFMPrep(
             F=self.F, canon=self._step_fm is not None,
             forced=self.interaction == "fieldmajor",
-            parts=self.layout == "parts",
-            parts_dp=(self.mesh.shape["dp"] if self.mesh is not None
-                      else 1),
             pack=(self._pack_input_on() and self._step_fm_unit is not None
                   and self.dims <= (1 << 24)))
 
@@ -1006,12 +869,6 @@ class FFMTrainer(FMTrainer):
         return PackedShardCache(ckdir, self._prep_cache_config(),
                                 F=self.F, name=self.NAME)
 
-    def _cache_row_pad(self, B: int) -> int:
-        """Allocated row count for a cached batch of ``B`` logical rows —
-        the parts layout's kernel-grid padding (single-chip rule; the
-        cache is off under -mesh), identity for joint."""
-        return _parts_row_target(B) if self.layout == "parts" else B
-
     def _streamed_epoch(self, ds, bs, shuffle, seed, prefetch, writer,
                         order) -> None:
         """One base-loop epoch (prep pipeline -> megabatch stacking ->
@@ -1037,8 +894,7 @@ class FFMTrainer(FMTrainer):
         canonicalize and pack never run — record gather + h2d + step is
         the whole host leg."""
         closers: list = []
-        it = shard.batches(bs, order, stats=self.pipeline_stats,
-                           pad_rows=self._cache_row_pad)
+        it = shard.batches(bs, order, stats=self.pipeline_stats)
         it = self._wrap_megabatch(it, prefetch=prefetch)
         if prefetch:
             it = self._wrap_prefetch(it, closers)
@@ -1115,8 +971,7 @@ class FFMTrainer(FMTrainer):
         # staged buffers for the on-device replay of epochs >= 2 ----
         closers: list = []
         if shard is not None:
-            it = shard.batches(bs, order_for(0), stats=self.pipeline_stats,
-                               pad_rows=self._cache_row_pad)
+            it = shard.batches(bs, order_for(0), stats=self.pipeline_stats)
         else:
             it = self._ingest_iter(
                 ds.batches(bs, shuffle=shuffle, seed=seed0), closers)
@@ -1197,7 +1052,7 @@ class FFMTrainer(FMTrainer):
             return None
         mats = []
         n_real = 0
-        pad_rows = []
+        pads = []
         for s in staged:
             nv = s.B if s.n_valid is None else s.n_valid
             ni = s.B * L * 3
@@ -1207,8 +1062,8 @@ class FFMTrainer(FMTrainer):
             mats.append(rows_m[:nv])
             n_real += nv
             if nv < s.B:
-                pad_rows.append(rows_m[nv:])
-        M = jnp.concatenate(mats + pad_rows)              # [N_total, rb]
+                pads.append(rows_m[nv:])
+        M = jnp.concatenate(mats + pads)              # [N_total, rb]
         return (M, n_real, B, L)
 
     def _replay_epochs(self, mat, n_epochs: int, shuffle: bool,
@@ -1343,33 +1198,7 @@ class FFMTrainer(FMTrainer):
         # the cross-instance sharing on the flagship packed path)
         return _packed_wrap_cached(self._step_fm_unit, B, L)
 
-    def _pad_parts_rows(self, batch: SparseBatch) -> SparseBatch:
-        """Parts-layout kernel-grid row padding (see _ffm_pad_parts — the
-        module function is the single implementation, shared with the
-        picklable process-pool prep)."""
-        return _ffm_pad_parts(
-            batch, self.mesh.shape["dp"] if self.mesh is not None else 1)
-
-    def _canonicalize_batch(self, batch: SparseBatch) -> SparseBatch:
-        """Field-major canonicalization (see _ffm_canonicalize — the
-        module function is the single implementation, shared with the
-        picklable process-pool prep)."""
-        return _ffm_canonicalize(batch, self.F, self._step_fm is not None,
-                                 self.interaction == "fieldmajor")
-
     # -- fused multi-step dispatch (-steps_per_dispatch) ---------------------
-    def _supports_megastep(self) -> bool:
-        # the FFM dispatch picks among THREE steps per batch kind (pairs /
-        # fieldmajor / fieldmajor-unit+packed); fusion is on when any of
-        # them is scannable — a window of a non-scannable kind (only
-        # possible under the mesh-sharded parts steps, which also null
-        # self._step) simply never forms. parts layout keeps
-        # self._step = None, so the base check alone would disable the
-        # flagship path.
-        return any(
-            getattr(s, "core", None) is not None
-            for s in (self._step, self._step_fm, self._step_fm_unit))
-
     def _mega_field(self, mb):
         # pairs-path megabatches carry stacked per-step field arrays; the
         # pairs core takes them as its trailing batch argument
@@ -1405,8 +1234,7 @@ class FFMTrainer(FMTrainer):
         return super()._train_megabatch(mb)
 
     def _train_batch(self, batch: SparseBatch) -> float:
-        # a joint step returns its tail's stats beside the loss, a parts
-        # step the loss alone
+        # a joint step returns its tail's stats beside the loss
         if isinstance(batch, PackedBatch):
             nv = batch.B if batch.n_valid is None else batch.n_valid
             out = self._packed_step(batch.B, batch.L)(
@@ -1487,14 +1315,6 @@ class FFMTrainer(FMTrainer):
 
     def _score_batch(self, batch: SparseBatch) -> np.ndarray:
         p = self.params
-        if self.layout == "parts":
-            B0 = batch.batch_size
-            if not batch.fieldmajor:
-                batch = self._preprocess_batch(batch)   # forced; may raise
-            out = np.asarray(self._fused_score_fm(
-                p["w0"], p["T2"], jnp.asarray(batch.idx),
-                None if batch.val is None else jnp.asarray(batch.val)))
-            return out[:B0]            # drop kernel-grid padding rows
         if self.layout == "joint":
             if not batch.fieldmajor and self._step_fm is not None:
                 # scoring fast path; unlike training, a row canonicalization
@@ -1522,11 +1342,7 @@ class FFMTrainer(FMTrainer):
         """Arena extraction (io.weight_arena): joint keeps the fused
         row-hashed table (V block + the linear-weight column, pad lanes
         dropped); dense flattens the field cube to the pair-flat [N*F, K]
-        the general scorer gathers. The ``parts`` layout's kernel-grid
-        geometry has no host-gather mapping — unsupported (the engine
-        keeps the bundle path; docs/PERFORMANCE.md "when NOT to
-        quantize")."""
-        from ..io.weight_arena import ArenaUnsupported
+        the general scorer gathers."""
         p = self.params
         cls = bool(self.classification)
         w0 = float(np.asarray(p["w0"], np.float32))
@@ -1537,26 +1353,22 @@ class FFMTrainer(FMTrainer):
                      "classification": cls},
                     {"T": np.ascontiguousarray(
                         T[:, :self.F * self.k + 1])})
-        if self.layout == "dense":
-            V = np.asarray(p["V"].astype(jnp.float32))
-            return ({"family": "ffm_dense", "F": self.F, "k": self.k,
-                     "w0": w0, "classification": cls},
-                    {"w": np.asarray(p["w"].astype(jnp.float32)),
-                     "V2": np.ascontiguousarray(
-                         V.reshape(-1, self.k))})
-        raise ArenaUnsupported(
-            f"-ffm_table {self.layout} has no weight-arena mapping")
+        V = np.asarray(p["V"].astype(jnp.float32))
+        return ({"family": "ffm_dense", "F": self.F, "k": self.k,
+                 "w0": w0, "classification": cls},
+                {"w": np.asarray(p["w"].astype(jnp.float32)),
+                 "V2": np.ascontiguousarray(V.reshape(-1, self.k))})
 
     def _wants_fit_ds(self) -> bool:
         # emission needs observed pairs
-        return self.layout in ("joint", "parts")
+        return self.layout == "joint"
 
     def _note_batch(self, batch) -> None:
         """Streaming path (fit_stream): record observed (feature, field)
         pairs so joint-layout model emission keeps names/fields. Inside a
         fit_stream this only hands the batch's host arrays to the tracker
         thread (models/ffm_pairs.py); the unique and the merge run there."""
-        if self.layout not in ("joint", "parts") or batch.field is None:
+        if self.layout != "joint" or batch.field is None:
             return
         self._pairs.note(batch.idx, batch.field, batch.val)
 
@@ -1578,15 +1390,8 @@ class FFMTrainer(FMTrainer):
         ii, ff = np.divmod(uniq, self.F)
         return ii.astype(np.int32), ff.astype(np.int32)
 
-    def _rows_for(self, keys: np.ndarray, fields: np.ndarray = None
-                  ) -> np.ndarray:
-        """Host-side fused-table row ids for feature ids (joint layout) or
-        (feature, own-field) pairs (parts layout)."""
-        if self.layout == "parts":
-            from ..ops.fm_pallas import parts_row_hash
-            return np.asarray(parts_row_hash(
-                jnp.asarray(keys, jnp.int32),
-                jnp.asarray(fields, jnp.int32), self.MRF))
+    def _rows_for(self, keys: np.ndarray) -> np.ndarray:
+        """Host-side fused-table row ids for feature ids (joint layout)."""
         return np.asarray(ffm_row_hash(jnp.asarray(keys, jnp.int32),
                                        self.Mr))
 
@@ -1613,11 +1418,7 @@ class FFMTrainer(FMTrainer):
                         yield (name, f, float(w[i]), V[i, f].tolist())
             return
         FK = self.F * self.k
-        if self.layout == "parts":
-            T = np.asarray(self.params["T2"].astype(jnp.float32)).reshape(
-                self.F * self.MRF, self.Wp)
-        else:
-            T = np.asarray(self.params["T"].astype(jnp.float32))
+        T = np.asarray(self.params["T"].astype(jnp.float32))
         pairs = self._observed_pairs()
         if pairs is None:
             live = np.nonzero(np.abs(T[:, :FK]).sum(-1) > 0)[0]
@@ -1629,7 +1430,7 @@ class FFMTrainer(FMTrainer):
                                vec.tolist())
             return
         ii, ff = pairs
-        rr = self._rows_for(ii, ff)
+        rr = self._rows_for(ii)
         for i, f, r in zip(ii.tolist(), ff.tolist(), rr.tolist()):
             if i == 0:
                 continue
@@ -1639,14 +1440,11 @@ class FFMTrainer(FMTrainer):
 
     # -- sparse weight access for the mix client (joint layout) -------------
     def _weight_table(self):
-        if self.layout in ("joint", "parts"):
+        if self.layout == "joint":
             return None                # w lives inside T; use overrides
         return super()._weight_table()
 
     def _get_weights_at(self, keys: np.ndarray) -> np.ndarray:
-        if self.layout == "parts":
-            raise ValueError("MIX weight exchange is not supported with "
-                             "-ffm_table parts; use -ffm_table joint")
         if self.layout != "joint":
             return super()._get_weights_at(keys)
         FK = self.F * self.k
@@ -1654,9 +1452,6 @@ class FFMTrainer(FMTrainer):
         return np.asarray(self.params["T"][rr, FK], np.float32)
 
     def _set_weights_at(self, keys: np.ndarray, vals: np.ndarray) -> None:
-        if self.layout == "parts":
-            raise ValueError("MIX weight exchange is not supported with "
-                             "-ffm_table parts; use -ffm_table joint")
         if self.layout != "joint":
             return super()._set_weights_at(keys, vals)
         FK = self.F * self.k
@@ -1665,23 +1460,12 @@ class FFMTrainer(FMTrainer):
         self.params["T"] = T.at[rr, FK].set(jnp.asarray(vals, T.dtype))
 
     def _finalized_weights(self) -> np.ndarray:
-        if self.layout == "parts":
-            FK = self.F * self.k
-            Tl = self.params["T2"].reshape(self.F * self.MRF, self.Wp)
-            return np.asarray(Tl[:, FK].astype(jnp.float32))
         if self.layout != "joint":
             return super()._finalized_weights()
         FK = self.F * self.k
         return np.asarray(self.params["T"][:, FK].astype(jnp.float32))
 
     def _load_weights(self, w: np.ndarray) -> None:
-        if self.layout == "parts":
-            FK = self.F * self.k
-            T2 = self.params["T2"]
-            Tl = T2.reshape(self.F * self.MRF, self.Wp)
-            Tl = Tl.at[:, FK].set(jnp.asarray(w, T2.dtype))
-            self.params["T2"] = Tl.reshape(T2.shape)
-            return
         if self.layout != "joint":
             return super()._load_weights(w)
         FK = self.F * self.k

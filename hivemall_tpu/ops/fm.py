@@ -1231,11 +1231,12 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     (tail_cap), else summed into a dense G with an O(table) pass (priced
     at ~5 ms from a round-5 probe on a small table; the benchmark's 2 GiB
     float32 table reads 15.9 ms, and 3.2 more to zero G: PERF.md section
-    6, PR 28). Round 5 ruled pre-aggregation out from experiments/
-    probe_preagg.py, which priced another design (an explicit permuting
-    copy of the whole gradient slab, uniform ids, each phase alone).
-    ``distinct_tail=False`` keeps the dense tail whatever the shapes: the
-    trainer's choice under -mesh, not a user's. Where the tail ranks the
+    6). The dense tail stays for what the distinct rows cannot
+    take: a table small against the batch, where one pass over it costs
+    less than ranking the batch's rows (tail_cap is 0 there), and a
+    batch with more distinct rows than the cap. ``distinct_tail=False``
+    keeps the dense tail whatever the shapes: the trainer's choice under
+    -mesh, not a user's. Where the tail ranks the
     batch's rows it does so in FRONT of the step, and the forward gather
     reads through the distinct rows too (gather_rows: each read out of the
     table once, the slots out of a compact copy of them).
@@ -1244,7 +1245,7 @@ def make_fm_step_minibatch(loss: Loss, optimizer: Optimizer,
     ran, the batch's distinct rows and whether the gather read through
     them (TAIL_STATS).
 
-    Semantics delta (documented, same as the FFM fused/parts paths):
+    Semantics delta (documented, same as the FFM fused path):
     adaptive accumulators see the square of the SUMMED minibatch
     gradient rather than per-occurrence squares. Per-occurrence L2 is
     unchanged — it folds into the slab gradient BEFORE the scatter,

@@ -19,8 +19,8 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 import chip_smoke  # noqa: E402
 
-# the smallest geometry the parts layout takes (F*K = 128), learnable in
-# six steps; K=2 windows so two full windows + a ragged tail stay cheap
+# the joint layout at a toy geometry (2048 table rows), learnable in six
+# steps; K=2 windows so two full windows + a ragged tail stay cheap
 TOY = dict(dims=1 << 16, fields=32, factors=4, batch=256, vocab=4,
            n_batches=6, stream_batches=5)
 CHIP_DEFAULTS = "-eta0 0.02 -steps_per_dispatch 2 -ingest_workers 2"
@@ -34,7 +34,7 @@ def trained():
 
 def test_train_leg(trained):
     trainer, _, rep = trained
-    assert trainer.layout == "parts"
+    assert trainer.layout == "joint"
     assert rep["packed_input"] and rep["steps_per_dispatch"] == 2
     assert rep["megabatches_staged"] == 3 and rep["singles_flushed"] == 1
     assert rep["loss_last_window"] < rep["loss_first_window"]
@@ -54,9 +54,7 @@ def test_mesh_leg(trained):
     _, _, one = trained
     rep = chip_smoke.mesh_leg(TOY, one["first_window_losses"],
                               extra_opts=CHIP_DEFAULTS)
-    assert rep["joint"]["layout"] == "joint"
-    assert rep["parts"]["layout"] == "parts"
-    assert rep["joint"]["shard_devices"] == rep["parts"]["shard_devices"] == 4
+    assert rep["layout"] == "joint" and rep["shard_devices"] == 4
 
 
 def test_script_refuses_without_a_tpu():

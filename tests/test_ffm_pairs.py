@@ -15,12 +15,11 @@ from hivemall_tpu.io.sparse import SparseDataset
 from hivemall_tpu.models.ffm_pairs import ObservedPairs
 from hivemall_tpu.models.fm import FFMTrainer
 
-B, L, F, K, DIMS = 128, 16, 16, 8, 1 << 16   # F*K = 128: parts takes it
+B, L, F, K, DIMS = 128, 16, 16, 8, 1 << 16
 
 
 def _cfg(layout):
-    extra = {"joint": "-halffloat", "dense": "-ffm_table dense",
-             "parts": "-halffloat -ffm_table parts"}[layout]
+    extra = {"joint": "-halffloat", "dense": "-ffm_table dense"}[layout]
     dims = 1 << 10 if layout == "dense" else DIMS
     return (f"-dims {dims} -factors {K} -fields {F} -mini_batch {B} "
             f"-opt adagrad -classification -seed 5 {extra}")
@@ -63,7 +62,7 @@ def _assert_pairs(trainer, batches):
 
 
 @pytest.mark.parametrize("workers", [1, 2])
-@pytest.mark.parametrize("layout", ["joint", "parts"])
+@pytest.mark.parametrize("layout", ["joint"])
 def test_observed_pairs_after_fit_stream_equal_the_parents(layout, workers):
     ds = _ds()
     t = FFMTrainer(_cfg(layout) + f" -ingest_workers {workers}")

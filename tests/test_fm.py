@@ -472,6 +472,85 @@ def test_ffm_interaction_option_validated_any_layout():
         FFMTrainer("-dims 1000 -fields 4 -ffm_interaction fieldmajor")
 
 
+@pytest.mark.parametrize("table", ["parts", "jiont"])
+def test_ffm_table_option_takes_joint_dense_or_auto(table):
+    with pytest.raises(ValueError, match=r"joint\|dense\|auto"):
+        FFMTrainer(f"-dims 4096 -factors 4 -fields 8 -opt adagrad "
+                   f"-halffloat -ffm_table {table}")
+
+
+@pytest.mark.parametrize("distinct_tail", [True, False],
+                         ids=["distinct", "dense"])
+@pytest.mark.parametrize("fieldmajor", [False, True],
+                         ids=["pairs", "fieldmajor"])
+def test_ffm_joint_step_l2_matches_autodiff_oracle(fieldmajor,
+                                                    distinct_tail):
+    """One SGD step of the joint fused step with -lambda0, -lambda_w and
+    -lambda_v non-zero moves T by eta times the gradient jax.grad takes of
+    an independent objective: the dense-cube scorer (ffm_score) over
+    features read out of the hashed rows, plus 0.5 * lambda * x^2 for
+    every PRESENT occurrence (val != 0, row not masked) of a feature's
+    weight and latent vectors, and 0.5 * lambda0 * w0^2 once. A repeated
+    feature, a padded slot and a masked row are in the batch."""
+    import jax
+    import jax.numpy as jnp
+    from hivemall_tpu.ops.fm import (ffm_row_hash, ffm_score,
+                                     make_ffm_step_fused)
+    from hivemall_tpu.ops.losses import get_loss
+    from hivemall_tpu.ops.optimizers import make_optimizer
+
+    F, K, Mr, N, eta = 4, 3, 16384, 40, 0.1   # tail_cap offers 128 rows
+    FK, W = F * K, F * K + 8
+    lam0, lam_w, lam_v = 0.03, 0.02, 0.05
+    rng = np.random.default_rng(11)
+    T = np.zeros((Mr, W), np.float32)          # pad lanes stay zero
+    T[:, :FK + 1] = rng.normal(0, 0.3, (Mr, FK + 1))
+    w0 = np.float32(0.2)
+    B, L = 16, 2 * F                           # two occurrences a field
+    idx = rng.integers(1, N, (B, L)).astype(np.int32)
+    idx[0, 5] = idx[0, 1]                      # a feature twice in a row
+    idx[2, 3] = 0                              # a padded slot
+    val = np.where(idx != 0, rng.uniform(0.5, 1.5, (B, L)), 0.0
+                   ).astype(np.float32)
+    field = np.tile(np.arange(L, dtype=np.int32) % F, (B, 1))
+    label = np.where(np.arange(B) % 3, 1.0, -1.0).astype(np.float32)
+    row_mask = np.ones(B, np.float32)
+    row_mask[3] = 0.0                          # a masked row
+    loss = get_loss("logloss")
+
+    def objective(w0f, Tf):
+        rows_n = ffm_row_hash(jnp.arange(N, dtype=jnp.int32), Mr)
+        phi = ffm_score(w0f, Tf[rows_n, FK],
+                        Tf[rows_n, :FK].reshape(N, F, K), idx, val, field)
+        data = (loss.loss(phi, label) * row_mask).sum()
+        slab = Tf[ffm_row_hash(jnp.asarray(idx), Mr)]
+        pm = (val != 0) * row_mask[:, None]
+        l2 = (pm * (lam_v * (slab[..., :FK] ** 2).sum(-1)
+                    + lam_w * slab[..., FK] ** 2)).sum()
+        return data + 0.5 * l2 + 0.5 * lam0 * w0f ** 2
+
+    g0, gT = jax.grad(objective, argnums=(0, 1))(jnp.float32(w0),
+                                                 jnp.asarray(T))
+    assert np.abs(np.asarray(gT)[:, FK + 1:]).max() == 0.0
+
+    step = make_ffm_step_fused(
+        loss, make_optimizer("sgd", eta_scheme="fixed", eta0=eta, reg="no"),
+        (lam0, lam_w, lam_v), F, K, fieldmajor=fieldmajor,
+        distinct_tail=distinct_tail)
+    params = {"T": jnp.asarray(T), "w0": jnp.asarray(w0)}
+    state = {"T": {}, "w0": {}}
+    batch = (jnp.asarray(idx), jnp.asarray(val), jnp.asarray(label),
+             jnp.asarray(row_mask))
+    if not fieldmajor:
+        batch += (jnp.asarray(field),)
+    new, _, loss_sum, stats = step(params, state, 1.0, *batch)
+    assert int(stats["tail_distinct_steps"]) == int(distinct_tail)
+    np.testing.assert_allclose((T - np.asarray(new["T"])) / eta,
+                               np.asarray(gT), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose((w0 - float(new["w0"])) / eta, float(g0),
+                               rtol=1e-5)
+
+
 def test_fm_fused_layout_matches_split():
     """-fm_table fused (one [N,K+pad] row: V|w) is the same optimization as
     the split w/V layout — same data, same seed => matching tables."""

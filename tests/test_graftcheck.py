@@ -1703,15 +1703,18 @@ def test_gc11_cross_module_missed_by_single_module_scan(tmp_path):
 def test_gc11_repo_donation_population(repo_index):
     """Non-vacuity pin: the repo's donate_argnums population (the ops/
     scannable cores, make_megastep, the models/ step factories) is in
-    the index — at least 4 donated defs and 4 donating factories (6 and
-    6 before PR 37 took word2vec's three second steps out)."""
+    the index — at least 4 donated defs and the 3 donating factories,
+    each named."""
     idx = repo_index
     donated_defs = [s for s in idx.functions.values()
                     if s.donated_positions]
     factories = [s for s in idx.functions.values() if s.returns_donated]
     assert len(donated_defs) >= 4
-    assert len(factories) >= 4
-    assert ("hivemall_tpu/ops/scan.py", "make_megastep") in \
+    assert len(factories) >= 3
+    assert {("hivemall_tpu/ops/scan.py", "make_megastep"),
+            ("hivemall_tpu/models/word2vec.py", "_step_cached"),
+            ("hivemall_tpu/models/classifier.py",
+             "AdaGradRDATrainer._make_rda_step")} <= \
         {s.fid for s in factories}
     # and the traced-parameter closure is populated (GC09 non-vacuity)
     assert len(idx.traced) >= 200

@@ -179,64 +179,6 @@ def test_mf_mesh_matches_single_device():
     np.testing.assert_allclose(preds0, preds1, rtol=1e-4, atol=1e-5)
 
 
-def test_parts_layout_shards_over_mesh():
-    """-ffm_table parts -mesh dp=2,tp=4 (VERDICT r3 next #2): fields shard
-    over tp (rank-local slab gathers), batch over dp with a G psum before
-    the XLA optimizer tail. Equivalence to the single-chip fused kernel is
-    asserted in FUNCTION SPACE (epoch loss + scores): raw T2 entries can
-    differ by O(eta) where bf16 gradient rounding flips near-zero grads
-    through AdaGrad's G/(|G|+eps)."""
-    import numpy as np
-    from hivemall_tpu.io.sparse import SparseDataset
-    from hivemall_tpu.models.fm import FFMTrainer
-
-    B, L, F, K, dims, n = 256, 8, 8, 16, 1 << 12, 512
-    rng = np.random.default_rng(2)
-    idx = rng.integers(1, dims, (n, L)).astype(np.int32)
-    fld = np.tile(np.arange(L, dtype=np.int32), (n, 1))
-    lab = (rng.integers(0, 2, n) * 2 - 1).astype(np.float32)
-    indptr = np.arange(0, n * L + 1, L, dtype=np.int64)
-    ds = SparseDataset(idx.ravel(), indptr, np.ones(n * L, np.float32),
-                       lab, fld.ravel())
-    cfg = (f"-dims {dims} -factors {K} -fields {F} -mini_batch {B} "
-           "-opt adagrad -classification -halffloat -ffm_table parts "
-           "-seed 5")
-    a = FFMTrainer(cfg)
-    a.fit(ds, epochs=1, shuffle=False, prefetch=False)
-    b = FFMTrainer(cfg + " -mesh dp=2,tp=4")
-    b.fit(ds, epochs=1, shuffle=False, prefetch=False)
-    ss = b.params["T2"].sharding.shard_shape(b.params["T2"].shape)
-    assert ss[0] == (F * b.MRF * 2) // 4, ss     # tp=4 field partitions
-    la, lb = a.cumulative_loss, b.cumulative_loss
-    assert abs(la - lb) / max(abs(la), 1e-9) < 1e-3, (la, lb)
-    pa = np.asarray(a.predict(ds))
-    pb = np.asarray(b.predict(ds))
-    assert np.abs(pa - pb).max() < 0.02, np.abs(pa - pb).max()
-    # gradient SCALE parity: shard_map transposes psum to psum, so an
-    # unowned (replicated) data loss would make every slab cotangent tp-x
-    # and the AdaGrad accumulators tp^2-x (~16 here). The S2 ratio is the
-    # sharp detector AdaGrad's scale-invariance hides from loss/scores.
-    Sa = np.asarray(a.opt_state["T2"]["gg"], np.float64)
-    Sb = np.asarray(b.opt_state["T2"]["gg"], np.float64)
-    touched = Sa > 1e-12
-    med = float(np.median(Sb[touched] / Sa[touched]))
-    assert 0.9 < med < 1.1, med
-
-
-def test_parts_mesh_option_validation():
-    import pytest
-    from hivemall_tpu.models.fm import FFMTrainer
-
-    with pytest.raises(ValueError, match="divisible by the tp axis"):
-        FFMTrainer("-dims 4096 -factors 16 -fields 8 -mini_batch 256 "
-                   "-opt adagrad -classification -halffloat "
-                   "-ffm_table parts -mesh dp=2,tp=3")
-    with pytest.raises(ValueError, match="128\\*dp"):
-        FFMTrainer("-dims 4096 -factors 16 -fields 8 -mini_batch 192 "
-                   "-opt adagrad -classification -halffloat "
-                   "-ffm_table parts -mesh dp=2,tp=4")
-
-
 # --- started as a CLI user starts it (PR 31): state born on the mesh, ---
 # --- input staged on the mesh ahead of compute ---------------------------
 

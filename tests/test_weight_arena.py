@@ -229,18 +229,6 @@ def test_oob_feature_id_clamps_like_xla(linear_setup):
         assert np.isfinite(linear_setup["arena"].margin_fn(prec)(b)).all()
 
 
-def test_ffm_parts_unsupported(tmp_path):
-    from hivemall_tpu.models.fm import FFMTrainer
-    from hivemall_tpu.ops.fm_pallas import parts_supported
-    if not parts_supported(8, 2, "adagrad", np.float32):
-        pytest.skip("parts layout unsupported on this backend")
-    t = FFMTrainer("-dims 4096 -factors 2 -fields 8 -classification "
-                   "-ffm_table parts")
-    t.fit(_ffm_dataset())
-    with pytest.raises(wa.ArenaUnsupported):
-        t.serving_tables()
-
-
 # --- parse-only facade -------------------------------------------------------
 
 def test_make_parser_hashes_identically():
@@ -486,22 +474,20 @@ def test_gate_scores_quantized_and_publishes(gated_dir):
 def test_gate_quantized_fails_unsupported_family_without_holdout(tmp_path):
     """A quantized gate with NO validation data at all must still fail
     a candidate whose family has no arena mapping — passing it would
-    wedge every quantized replica on reload (review-caught edge)."""
-    from hivemall_tpu.models.fm import FFMTrainer
-    from hivemall_tpu.ops.fm_pallas import parts_supported
+    wedge every quantized replica on reload (review-caught edge). The
+    AROW family keeps a covariance beside its weights and has no
+    serving_tables() surface."""
+    from hivemall_tpu.models.classifier import AROWTrainer
     from hivemall_tpu.serve.promote import PromotionGate
-    if not parts_supported(8, 2, "adagrad", np.float32):
-        pytest.skip("parts layout unsupported on this backend")
-    t = FFMTrainer("-dims 4096 -factors 2 -fields 8 -classification "
-                   "-ffm_table parts")
+    t = AROWTrainer("-dims 4096")
+    assert not hasattr(t, "serving_tables")
     t._t = 1
     p = _save(tmp_path, t)
-    report = PromotionGate(
-        "train_ffm",
-        "-dims 4096 -factors 2 -fields 8 -classification "
-        "-ffm_table parts", precision="int8").evaluate(p)
+    report = PromotionGate("train_arow", "-dims 4096",
+                           precision="int8").evaluate(p)
     assert report["verdict"] == "fail"
-    assert any("unusable" in r for r in report["reasons"]), report
+    assert any("unusable" in r and "ArenaUnsupported" in r
+               for r in report["reasons"]), report
 
 
 def test_gate_rejects_over_error_quantized_candidate(gated_dir):

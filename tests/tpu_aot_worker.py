@@ -21,7 +21,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
-from hivemall_tpu.ops import fm, fm_pallas, pallas_hist, rows_pallas
+from hivemall_tpu.ops import fm, pallas_hist, rows_pallas
 from hivemall_tpu.ops.losses import get_loss
 from hivemall_tpu.ops.optimizers import make_optimizer
 
@@ -33,7 +33,7 @@ rows_pallas.use_kernels_default = lambda: True
 TOPO = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 DEV0 = SingleDeviceSharding(TOPO.devices[0])
 
-F, K, MRF, HP = 40, 4, 8192, 2          # flagship geometry (chip_smoke FULL)
+K = 4                                   # the flagship's factors
 LAMS = (0.01, 0.01, 0.01)
 
 
@@ -41,64 +41,8 @@ def _sds(shape, dtype, sharding=DEV0):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _parts_state(sh_t=DEV0, sh_0=DEV0):
-    rows = F * MRF * HP
-    params = {"T2": _sds((rows, 128), jnp.bfloat16, sh_t),
-              "w0": _sds((), jnp.float32, sh_0)}
-    opt = {"T2": {"gg": _sds((rows, 128), jnp.float32, sh_t)},
-           "w0": {"gg": _sds((), jnp.float32, sh_0)}}
-    return params, opt
-
-
-def parts_step():
-    B = 32768
-    step = fm_pallas.make_parts_step(
-        get_loss("logloss"), lambda t: 0.1, LAMS, F, K, MRF, unit_val=True,
-        interpret=False)
-    params, opt = _parts_state()
-    step.lower(params, opt, _sds((), jnp.float32),
-               _sds((B, F), jnp.int32), _sds((B,), jnp.float32),
-               _sds((B,), jnp.float32)).compile()
-
-
-def _mesh_2x2():
-    return Mesh(np.asarray(TOPO.devices).reshape(2, 2), ("dp", "tp"))
-
-
 def _mesh_1x4():
     return Mesh(np.asarray(TOPO.devices).reshape(1, 4), ("dp", "tp"))
-
-
-def parts_accum_kernel_2x2():
-    """The sharded step's accumulate kernel at its per-rank flagship
-    shapes (B=32768 over dp=2, F=40 over tp=2), one instance per device."""
-    mesh = _mesh_2x2()
-    Bd, Fl, chunk = 32768 // 2, F // 2, 2048
-    kern = fm_pallas._make_scatter_accum_kernel(Bd, Fl, Fl, MRF, HP, chunk,
-                                                interpret=False)
-    every = P(("dp", "tp"))
-    fn = jax.jit(jax.shard_map(kern, mesh=mesh, in_specs=(every, every),
-                               out_specs=every, check_vma=False))
-    sh = NamedSharding(mesh, every)
-    fn.lower(_sds((4 * Fl, Bd // 128, 128), jnp.int32, sh),
-             _sds((4 * Fl, Bd * HP // 16, 16, 128), jnp.bfloat16, sh)
-             ).compile()
-
-
-def parts_step_sharded():
-    B = 32768
-    mesh = _mesh_2x2()
-    step = fm_pallas.make_parts_step_sharded(
-        get_loss("logloss"), lambda t: 0.1, LAMS, F, K, MRF, mesh,
-        unit_val=True, interpret=False)
-
-    def ns(*spec):
-        return NamedSharding(mesh, P(*spec))
-    params, opt = _parts_state(ns("tp", None), ns())
-    step.lower(params, opt, _sds((), jnp.float32, ns()),
-               _sds((B, F), jnp.int32, ns("dp", None)),
-               _sds((B,), jnp.float32, ns("dp")),
-               _sds((B,), jnp.float32, ns("dp"))).compile()
 
 
 def _slab_gathers(text, slab):
@@ -604,8 +548,7 @@ def hist_sorted():
         _hist(pallas_hist.level_histogram_sorted, m, False)
 
 
-CASES = {f.__name__: f for f in (parts_step, parts_accum_kernel_2x2,
-                                 parts_step_sharded, fm_minibatch_step,
+CASES = {f.__name__: f for f in (fm_minibatch_step,
                                  ffm_joint_megastep, ffm_joint_megastep_tp4,
                                  hist_flat, hist_dense,
                                  hist_sorted, state_init, linear_megastep,
