@@ -7,8 +7,10 @@ distinct-row tail, -dims 2^28 -halffloat), `logreg_criteo.stream`
 (AdaGrad-RDA, -dims 2^28), `skipgram_enwiki.stream_text` (-dim 300 on
 2,519,370 words) and the four-chip cell `ffm_criteo_joint_tp4.stream_mesh`'s
 megastep (-dims 2^30 -halffloat -mesh dp=1,tp=4, lowered over four host CPU
-devices; the text does not depend on which devices). Fourteen lines;
-nothing is compiled or run, so it says nothing about time.
+devices; the text does not depend on which devices), and
+`mf_hugewiki.stream_ratings`'s megastep (-factors 100 on 6,260,326 users
+and 39,780 items). Fifteen lines; nothing is compiled or run, so it says
+nothing about time.
 
 A perf PR that must not move a cell compares two trees (PRs 34, 36, 38):
 
@@ -37,11 +39,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from hivemall_tpu.models import mf
 from hivemall_tpu.models import word2vec as w2v
 from hivemall_tpu.ops import fm
 from hivemall_tpu.ops.linear import make_linear_step
 from hivemall_tpu.ops.losses import get_loss
-from hivemall_tpu.ops.optimizers import make_optimizer
+from hivemall_tpu.ops.optimizers import make_optimizer, make_optimizer_cached
 from hivemall_tpu.ops.scan import make_megastep
 from hivemall_tpu.parallel.mesh import make_mesh
 
@@ -124,6 +127,17 @@ def main():
         w0, S((KS,), jnp.int32, sharding=everywhere),
         S((KS, B, L), jnp.int32, sharding=everywhere), None,
         S((KS, B), jnp.float32, sharding=everywhere), None, None))
+
+    U, I, Bm = 6_260_326, 39_780, 131072
+    opt = make_optimizer_cached("adagrad", "fixed", 0.01, 10_000, 0.1)
+    Ut, It = S((U, mf.row_lanes(100)), jnp.float32), \
+        S((I, mf.row_lanes(100)), jnp.float32)
+    show("mf_mega", make_megastep(
+        mf._step_cached(100, 3.0, 0.01, True, opt, True).core,
+        none_val=True).lower(
+            {"U": Ut, "I": It}, {"U": {"gg": Ut}, "I": {"gg": It}}, f0, ts,
+            S((KS, Bm, 2), jnp.int32), None, S((KS, Bm), jnp.float32), None,
+            None))
 
 
 if __name__ == "__main__":

@@ -56,7 +56,11 @@ def _load_input(args, trainer):
         # CSR columns, so epoch >= 2 / restarts skip Parquet read + parse
         opts = getattr(trainer, "opts", None)
         cache_dir = opts.get("shard_cache_dir") if opts is not None else None
-        return ParquetStream(path, cache_dir=cache_dir, **kw), True
+        # a factor trainer reads a table of scalar id columns (MF: user,
+        # item; the label column is the rating)
+        id_cols = getattr(trainer, "ID_COLUMNS", None)
+        return ParquetStream(path, cache_dir=cache_dir, id_cols=id_cols,
+                             **kw), True
     if path.endswith((".parquet", ".pq")):
         from ..io.arrow import read_parquet
         return read_parquet(path, **kw), False

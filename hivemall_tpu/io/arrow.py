@@ -16,6 +16,9 @@ Two supported schemas per table:
   pre-parsed CSR — `indices: list<int32>` + optional `values: list<float>`
     (+ `fields: list<int32>`) + label column: zero parse cost, the Criteo
     fast path.
+  scalar id columns — ``id_cols`` names integer columns read as a row of
+    that many unit-valued ids, in that order, + label column: a ratings
+    table (`user`, `item`, `rating`) for the factor trainers.
 """
 
 from __future__ import annotations
@@ -82,7 +85,9 @@ def _parse_string_features(flat: np.ndarray, *, dims: Optional[int],
 def table_to_dataset(table, *, feature_col: str = "features",
                      label_col: str = "label",
                      dims: Optional[int] = None, ffm: bool = False,
-                     num_fields: int = 64) -> SparseDataset:
+                     num_fields: int = 64,
+                     id_cols: Optional[Sequence[str]] = None
+                     ) -> SparseDataset:
     """One pyarrow Table -> SparseDataset (schemas per module docstring)."""
     import pyarrow as pa
     names = set(table.column_names)
@@ -90,6 +95,15 @@ def table_to_dataset(table, *, feature_col: str = "features",
     # it (read-only), not copied on the decode thread
     labels = table.column(label_col).to_numpy(
         zero_copy_only=False).astype(np.float32, copy=False)
+
+    if id_cols:                         # a row of len(id_cols) ids
+        L, n = len(id_cols), len(labels)
+        indices = np.empty((n, L), np.int32)
+        for j, c in enumerate(id_cols):
+            indices[:, j] = table.column(c).to_numpy(zero_copy_only=False)
+        return SparseDataset(indices.reshape(-1),
+                             np.arange(0, n * L + 1, L, dtype=np.int64),
+                             np.ones(n * L, np.float32), labels)
 
     if "indices" in names:              # pre-parsed CSR fast path
         col = table.column("indices").combine_chunks()
@@ -249,10 +263,13 @@ class ParquetStream:
     def __init__(self, path: str, *, feature_col: str = "features",
                  label_col: str = "label", dims: Optional[int] = None,
                  ffm: bool = False, num_fields: int = 64,
-                 decode_ahead: int = 1, cache_dir: Optional[str] = None):
+                 decode_ahead: int = 1, cache_dir: Optional[str] = None,
+                 id_cols: Optional[Sequence[str]] = None):
         self.files = _parquet_files(path)
         self._kw = dict(feature_col=feature_col, label_col=label_col,
                         dims=dims, ffm=ffm, num_fields=num_fields)
+        if id_cols:             # only here: the other schemas' cache keys
+            self._kw["id_cols"] = tuple(id_cols)        # stay as they were
         # decode-ahead: while training consumes the current shard's batches,
         # a reader thread decodes the NEXT decode_ahead shards (Parquet
         # read + string parse + hashing — pyarrow releases the GIL on the
@@ -350,6 +367,8 @@ class ParquetStream:
         a fully warm traversal never opens the source Parquet bytes at
         all."""
         import pyarrow.parquet as pq
+        if self._kw.get("id_cols"):
+            return len(self._kw["id_cols"])
         m = 1
         for f in self.files:
             if self._cache is not None:

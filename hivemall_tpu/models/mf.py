@@ -1,5 +1,6 @@
 """Matrix factorization — train_mf_sgd / train_mf_adagrad / train_bprmf
-(BASELINE config #3).
+(BASELINE config #3), on the normal path: ``catalog ->
+LearnerBase.fit_stream -> _dispatch_mega``.
 
 Reference (SURVEY.md §3.7): hivemall.mf.OnlineMatrixFactorizationUDTF (base:
 streaming (user, item, rating) SGD over rank-k P/Q tables with biases and
@@ -7,24 +8,64 @@ global mean -mu), MatrixFactorizationSGDUDTF / MatrixFactorizationAdaGradUDTF,
 BPRMatrixFactorizationUDTF (implicit feedback (u, pos, neg) ranking), and the
 MFPredictUDF / BPRMFPredictUDF scorers.
 
-TPU shape: P[U,K], Q[I,K], b_u[U], b_i[I] dense tables in HBM; one jitted
-value_and_grad step per (user, item, rating) minibatch; within-batch duplicate
-ids accumulate via scatter-add (gradient accumulation of the reference's
-sequential per-row updates).
+What streams in is a batch of ratings: ``SparseBatch.idx`` ``[B, 2]``
+(user, item), ``label`` the rating (BPR: ``idx`` ``[B, 3]`` (user, pos,
+neg)). A warehouse table of scalar columns streams through
+``io.arrow.ParquetStream(path, id_cols=("user", "item"),
+label_col="rating")``; ``process`` / ``close`` and ``fit(users, items,
+ratings)`` feed the same step.
+
+TPU shape: two tables, users ``U`` and items ``I``, a row stored 128 lanes
+(``row_lanes``): the k factors, then the bias, then zeros that stay zero
+(their gradient is a product with them). The model is ``P[U,k]``, ``bu[U]``,
+``Q[I,k]``, ``bi[I]`` (``serving_tables``). One step a minibatch: the
+squared error of ``mu + p_u.q_i + b_u + b_i`` and L2 ``-lambda`` on the rows
+the batch reads, per occurrence; the gradients of a row's duplicates are
+summed and the optimizer (SGD, or per-element AdaGrad ``w -= eta0 * g /
+(sqrt(gg) + 1e-6)``) sees the sum. Each table goes through ops/fm.py's
+distinct-row machinery on its own: ``rank_rows`` -> ``gather_rows`` ->
+forward and backward -> ``rows_update``, so a step reads and writes the
+rows its batch touched and no others; the user table's capacity comes from
+``tail_cap``, the item table's (small against the batch) from
+``whole_table_cap``. Under ``-mesh`` both take the dense tail, GSPMD's cut
+of it. ``train_bprmf`` keeps a dense step over the same two tables.
+
+What the step counts about itself (folded with the loss, the registry's
+``train`` section): ``mf_user_rows`` / ``mf_item_rows``, each table's
+distinct rows (0 for a step that did not rank them), and the tail's
+counters, a step counted distinct only where both tables took that tail.
 """
 
 from __future__ import annotations
 
+from functools import lru_cache, partial
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.options import OptionSpec, Parsed
+from ..io.sparse import SparseBatch
+from ..obs.devprof import instrument_factory as _instrument
+from ..ops.fm import (gather_rows, rank_rows, rows_update, tail_cap,
+                      whole_table_cap)
+from ..ops.optimizers import make_optimizer_cached
+from ..ops.scan import scannable
+from ..utils.options import OptionSpec
+from .base import LearnerBase, learner_option_spec
 
 __all__ = ["MFTrainer", "MFAdaGradTrainer", "BPRMFTrainer", "mf_predict",
-           "bprmf_predict"]
+           "bprmf_predict", "row_lanes"]
+
+LANES = 128
+TABLES = ("U", "I")
+
+
+def row_lanes(k: int) -> int:
+    """Lanes a table row is stored with: the k factors and the bias,
+    rounded up to whole 128-lane tiles (the row kernel's rows,
+    ops/rows_pallas.py)."""
+    return -(-(int(k) + 1) // LANES) * LANES
 
 
 def _mf_spec(name: str) -> OptionSpec:
@@ -33,130 +74,253 @@ def _mf_spec(name: str) -> OptionSpec:
     s.add("mu", "mean_rating", type=float, default=0.0, help="global mean")
     s.add("eta0", "eta", type=float, default=0.01, help="learning rate")
     s.add("lambda", type=float, default=0.03, help="L2 regularization")
-    s.add("iters", "iterations", type=int, default=1, help="epochs")
-    s.add("mini_batch", type=int, default=1024, help="minibatch size")
     s.add("users", "max_users", type=int, default=1 << 20,
           help="user table size")
     s.add("items", "max_items", type=int, default=1 << 20,
           help="item table size")
     s.add("sigma", type=float, default=0.1, help="factor init stddev")
-    s.add("seed", type=int, default=31, help="init seed")
     s.flag("disable_bias", help="drop user/item bias terms")
-    s.flag("halffloat", help="bf16 factor tables")
-    s.add("mesh", default=None,
-          help="shard training over a device mesh, e.g. 'dp=2,tp=4' "
-               "(batch over dp, P/Q/bias tables over tp) or 'auto'")
+    s.flag("halffloat", help="bf16 factor tables (the bias lane too)")
     return s
 
 
-class MFTrainer:
+# -- the steps ---------------------------------------------------------------
+
+def _lanes_upto(W: int, n: int):
+    return jnp.arange(W) < n
+
+
+def _tail_caps(n: int, params, opt_state, optimizer, distinct: bool):
+    """Each table's ranking capacity (static): 0 for the dense tail."""
+    caps = {}
+    for name in TABLES:
+        R, W = params[name].shape
+        if not distinct:
+            caps[name] = 0
+            continue
+        leaves = jax.tree_util.tree_leaves(opt_state[name])
+        itemsize = min(a.dtype.itemsize for a in (params[name], *leaves))
+        caps[name] = (tail_cap(n, R, W, itemsize)
+                      or whole_table_cap(n, R, W))
+    return caps
+
+
+def _both_tables(stats: Dict[str, dict], compact: Dict[str, jax.Array]):
+    """TAIL_STATS of a step over two tables from each table's own: a
+    branch counted only where both took it, the distinct rows summed;
+    and each table's distinct rows under its own name."""
+    u, i = stats["U"], stats["I"]
+    both = {k: jnp.minimum(u[k], i[k])
+            for k in ("tail_distinct_steps", "scatter_compact_steps")}
+    return {**both, "tail_dense_steps": 1 - both["tail_distinct_steps"],
+            "distinct_rows": u["distinct_rows"] + i["distinct_rows"],
+            "gather_compact_steps": jnp.minimum(compact["U"], compact["I"]),
+            "mf_user_rows": u["distinct_rows"],
+            "mf_item_rows": i["distinct_rows"]}
+
+
+@_instrument("mf", "step")
+@lru_cache(maxsize=64)
+def _step_cached(k: int, mu: float, lam: float, use_bias: bool, optimizer,
+                 distinct: bool):
+    """The MF step, `ops.scan`'s contract: `(params, opt_state, t, idx,
+    val, label, mask) -> (params, opt_state, loss, stats)` with `idx`
+    [B, 2] (user, item) and `label` the ratings. Cached on its scalars,
+    so trainers of one configuration share one compile."""
+
+    def batch_loss(pu, qi, r, mask):
+        W = pu.shape[-1]
+        pred = mu + jnp.where(_lanes_upto(W, k), pu * qi, 0.0).sum(-1)
+        if use_bias:
+            pred = pred + pu[:, k] + qi[:, k]
+        err = (r - pred) * mask
+        read = _lanes_upto(W, k + use_bias)
+        reg = (mask[:, None] * jnp.where(read, pu * pu + qi * qi, 0.0)).sum()
+        return 0.5 * (err * err).sum() + lam * reg
+
+    def core(params, opt_state, t, idx, val, label, mask):
+        rows = {"U": idx[:, 0], "I": idx[:, 1]}
+        caps = _tail_caps(idx.shape[0], params, opt_state, optimizer,
+                          distinct)
+        ranks, slab, compact = {}, {}, {}
+        for name in TABLES:
+            ranks[name] = rank_rows(rows[name], params[name],
+                                    opt_state[name], optimizer, caps[name])
+            slab[name], compact[name] = gather_rows(params[name], rows[name],
+                                                    ranks[name])
+        with jax.named_scope("hm.grad"):
+            loss, grads = jax.value_and_grad(batch_loss, argnums=(0, 1))(
+                slab["U"].astype(jnp.float32), slab["I"].astype(jnp.float32),
+                label, mask)
+        new_p, new_s, stats = {}, {}, {}
+        for name, g in zip(TABLES, grads):
+            new_p[name], new_s[name], stats[name] = rows_update(
+                params[name], opt_state[name], rows[name], g, optimizer, t,
+                ranks[name])
+        return new_p, new_s, loss, _both_tables(stats, compact)
+
+    return scannable(partial(jax.jit, donate_argnums=(0, 1))(core), core)
+
+
+@_instrument("mf", "bpr_step")
+@lru_cache(maxsize=64)
+def _bpr_step_cached(k: int, lam: float, optimizer):
+    """train_bprmf's step: `idx` [B, 3] (user, pos, neg), loss -log
+    sigmoid(x_upos - x_uneg), x_ui = p_u.q_i + b_i, L2 on the rows read;
+    the gradient of the whole tables and the optimizer's update of every
+    row (the dense step)."""
+
+    def core(params, opt_state, t, idx, val, label, mask):
+        u, i, j = idx[:, 0], idx[:, 1], idx[:, 2]
+
+        def batch_loss(p):
+            pu, qi, qj = p["U"][u], p["I"][i], p["I"][j]
+            W = pu.shape[-1]
+            fac, item = _lanes_upto(W, k), _lanes_upto(W, k + 1)
+            x = (jnp.where(fac, pu * (qi - qj), 0.0).sum(-1)
+                 + qi[:, k] - qj[:, k])
+            reg = mask[:, None] * (jnp.where(fac, pu * pu, 0.0)
+                                   + jnp.where(item, qi * qi + qj * qj, 0.0))
+            return (jax.nn.softplus(-x) * mask).sum() + lam * reg.sum()
+
+        f32 = {n: params[n].astype(jnp.float32) for n in TABLES}
+        with jax.named_scope("hm.grad"):
+            loss, grads = jax.value_and_grad(batch_loss)(f32)
+        new_p, new_s = {}, {}
+        with jax.named_scope("hm.update"):
+            for n in TABLES:
+                w, new_s[n] = optimizer.update(f32[n], grads[n],
+                                               opt_state[n], t)
+                new_p[n] = w.astype(params[n].dtype)
+        return new_p, new_s, loss
+
+    return scannable(partial(jax.jit, donate_argnums=(0, 1))(core), core)
+
+
+@lru_cache(maxsize=64)
+def _state_init(U: int, I: int, k: int, sigma: float, dtype: str, optimizer):
+    """The initial tables as ONE function of the key, the same object for
+    the same shape (`LearnerBase._make_state` jits it: the tables are born
+    on the chip): the factors normal(0, sigma) (the key split in two, the
+    user table's half first), bias and the other lanes zero; the
+    optimizer's state of each table."""
+    W = row_lanes(k)
+
+    def init(key):
+        params = {}
+        for name, rows, part in zip(TABLES, (U, I), jax.random.split(key)):
+            f = jax.random.normal(part, (rows, k)) * sigma
+            params[name] = jnp.pad(f, ((0, 0), (0, W - k))).astype(dtype)
+        return params, {n: optimizer.init(params[n].shape) for n in TABLES}
+    return init
+
+
+# -- the trainers ------------------------------------------------------------
+
+class MFTrainer(LearnerBase):
     """SQL: train_mf_sgd — reference hivemall.mf.MatrixFactorizationSGDUDTF."""
 
     NAME = "train_mf_sgd"
-    ADAGRAD = False
+    CLASSIFICATION = False
+    OPTIMIZER = "sgd"
+    #: the id columns of a row, as a warehouse table names them (the CLI
+    #: streams a Parquet directory of them, the label column the rating)
+    ID_COLUMNS = ("user", "item")
+
+    _KEEP = ("iters", "mini_batch", "ingest_workers", "ingest_pool",
+             "steps_per_dispatch", "seed", "mesh", "loadmodel",
+             "checkpoint_dir", "checkpoint_every", "checkpoint_keep",
+             "telemetry_every", "obs_port")
 
     @classmethod
     def spec(cls) -> OptionSpec:
-        return _mf_spec(cls.NAME)
+        s = _mf_spec(cls.NAME)
+        base = learner_option_spec(cls.NAME, classification=False,
+                                   default_loss="squaredloss")
+        for o in base.options:
+            if o.name in cls._KEEP:
+                s.options.append(o)
+        for o in s.options:             # Hivemall's own defaults
+            if o.name == "mini_batch":
+                o.default = 1024
+            elif o.name == "seed":
+                o.default, o.help = 31, "init seed"
+        s.add("dims", type=int, default=0,
+              help="unused: the tables have -users and -items rows")
+        return s
 
-    def __init__(self, options: str = ""):
-        self.opts: Parsed = self.spec().parse(options)
+    # -- state ---------------------------------------------------------------
+    def _init_state(self) -> None:
         o = self.opts
+        if o.loadmodel:
+            raise ValueError(f"{self.NAME} has no -loadmodel: its model is "
+                             "two factor tables, not a weight table")
         self.k = int(o.factors)
         # bracket access: "items" would hit dict.items on the Parsed namespace
         self.U, self.I = int(o["users"]), int(o["items"])
-        dtype = jnp.bfloat16 if o.halffloat else jnp.float32
-        key = jax.random.PRNGKey(int(o.seed))
-        k1, k2 = jax.random.split(key)
-        sig = float(o.sigma)
-        self.params = {
-            "P": (jax.random.normal(k1, (self.U, self.k)) * sig).astype(dtype),
-            "Q": (jax.random.normal(k2, (self.I, self.k)) * sig).astype(dtype),
-            "bu": jnp.zeros(self.U, jnp.float32),
-            "bi": jnp.zeros(self.I, jnp.float32),
-        }
-        self.gg = ({k: jnp.zeros(v.shape, jnp.float32)
-                    for k, v in self.params.items()} if self.ADAGRAD else None)
-        self.mesh = None
-        if o.mesh:
-            self._apply_mesh(str(o.mesh))
+        if self.mesh is not None:        # table rows over 'tp'
+            self._tp_sizes |= {self.U, self.I}
+        self._opt = make_optimizer_cached(self.OPTIMIZER, "fixed",
+                                          float(o.eta0), 10_000, 0.1)
+        dtype = "bfloat16" if o.halffloat else "float32"
+        self.params, self.opt_state = self._make_state(
+            _state_init(self.U, self.I, self.k, float(o.sigma), dtype,
+                        self._opt),
+            jax.random.PRNGKey(int(o.seed)))
         self._step = self._make_step()
-        self._t = 0
         self._buf: List[Tuple[int, int, float]] = []
         self._all: List[Tuple[int, int, float]] = []
-        # device-side loss accumulation: fetching the loss value every step
-        # would put one host round-trip on each dispatch (the step itself is
-        # async); fold into the host float sparingly instead
-        self._loss_pending = jnp.zeros(())
-        self._loss_host = 0.0
-        self.n_seen = 0
-
-    # -- mesh sharding (SURVEY.md §3.17): batch over dp, tables over tp ------
-    def _apply_mesh(self, spec: str) -> None:
-        """GSPMD-shard the MF state: P/Q factor tables and biases split
-        their id axis over 'tp' (feature-dim sharding), minibatches split
-        rows over 'dp' (XLA inserts the gradient psum). The same jitted
-        step runs unchanged — mirrors LearnerBase._apply_mesh."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ..parallel.mesh import make_mesh, parse_mesh_spec
-        dp, tp = parse_mesh_spec(spec)
-        if int(self.opts.mini_batch) % dp:
-            raise ValueError(
-                f"-mini_batch {self.opts.mini_batch} must be divisible by "
-                f"the dp axis ({dp})")
-        self.mesh = make_mesh(dp=dp, tp=tp)
-
-        def shard(v):
-            spec_ = P(*(["tp"] + [None] * (v.ndim - 1)))
-            return jax.device_put(v, NamedSharding(self.mesh, spec_))
-        self.params = {k: shard(v) for k, v in self.params.items()}
-        if self.gg is not None:
-            self.gg = {k: shard(v) for k, v in self.gg.items()}
-
-    def _shard_inputs(self, arrays):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return tuple(jax.device_put(a, NamedSharding(self.mesh, P("dp")))
-                     for a in arrays)
 
     def _make_step(self):
         o = self.opts
-        lam = float(o["lambda"])
-        eta0 = float(o.eta0)
-        mu = float(o.mu)
-        use_bias = not o.disable_bias
-        adagrad = self.ADAGRAD
+        return _step_cached(self.k, float(o.mu), float(o["lambda"]),
+                            not o.disable_bias, self._opt,
+                            self.mesh is None)
 
-        @jax.jit
-        def step(params, gg, t, u, i, r, mask):
-            def batch_loss(p):
-                pu = p["P"][u].astype(jnp.float32)        # [B, K]
-                qi = p["Q"][i].astype(jnp.float32)
-                pred = mu + (pu * qi).sum(-1)
-                if use_bias:
-                    pred = pred + p["bu"][u] + p["bi"][i]
-                err = (r - pred) * mask
-                reg = lam * ((pu * pu).sum() + (qi * qi).sum()
-                             + ((p["bu"][u] ** 2).sum()
-                                + (p["bi"][i] ** 2).sum() if use_bias else 0.0))
-                return 0.5 * (err * err).sum() + reg
+    def _finalized_weights(self) -> np.ndarray:
+        raise NotImplementedError(f"{self.NAME}'s model is two tables")
 
-            loss, grads = jax.value_and_grad(batch_loss)(params)
-            new_p, new_gg = {}, {}
-            for k in params:
-                g = grads[k].astype(jnp.float32)
-                if adagrad:
-                    g2 = gg[k] + g * g
-                    upd = eta0 * g / (jnp.sqrt(g2) + 1e-6)
-                    new_gg[k] = g2
-                else:
-                    upd = eta0 * g
-                new_p[k] = (params[k].astype(jnp.float32) - upd
-                            ).astype(params[k].dtype)
-            return new_p, (new_gg if adagrad else gg), loss
+    def _table(self, name: str, cols) -> np.ndarray:
+        """Columns ``cols`` of every row of table ``name``, float32."""
+        return np.asarray(self.params[name][:, cols].astype(jnp.float32))
 
-        return step
+    def _rows(self, name: str, ids) -> np.ndarray:
+        """Rows ``ids`` of table ``name``, float32, gathered on the
+        device."""
+        at = jnp.asarray(np.asarray(ids, np.int32))
+        return np.asarray(self.params[name][at].astype(jnp.float32))
+
+    @property
+    def cum_loss(self) -> float:
+        """The loss summed over every step so far."""
+        self._fold_loss()
+        return self._loss_sum
+
+    # -- the feed: a batch of ratings is its id columns and the ratings ------
+    def _preprocess_batch(self, batch: SparseBatch) -> SparseBatch:
+        return SparseBatch(np.asarray(batch.idx, np.int32), None,
+                           batch.label, n_valid=batch.n_valid)
+
+    def _train_batch(self, batch: SparseBatch):
+        self.params, self.opt_state, loss, *stats = self._step(
+            self.params, self.opt_state, float(self._t),
+            jnp.asarray(batch.idx), None, jnp.asarray(batch.label),
+            batch.row_mask)
+        self._stats_pending += stats
+        return loss
+
+    def _batch_of(self, rows: np.ndarray) -> SparseBatch:
+        """A padded batch of ``rows`` [n, 3]: the ids, then the rating
+        (BPR: three ids, the negative item in the rating's place)."""
+        B, n = int(self.opts.mini_batch), len(rows)
+        idx = np.zeros((B, len(self.ID_COLUMNS)), np.int32)
+        label = np.zeros(B, np.float32)
+        if len(self.ID_COLUMNS) == 2:
+            idx[:n] = rows[:, :2]
+            label[:n] = rows[:, 2]
+        else:
+            idx[:n] = rows
+        return SparseBatch(idx, None, label, n_valid=n if n < B else None)
 
     # -- UDTF lifecycle ------------------------------------------------------
     def process(self, user: int, item: int, rating: float) -> None:
@@ -164,176 +328,60 @@ class MFTrainer:
         if len(self._buf) >= int(self.opts.mini_batch):
             self._flush()
 
-    # -- full-state checkpointing (io.checkpoint bundles, SURVEY.md §6) ------
-    # Bundles capture model + optimizer state and counters; the -iters
-    # replay buffer is NOT serialized (matching the reference, where task
-    # retry replays the input split rather than restoring scratch).
-    def _checkpoint_arrays(self):
-        tree = {"params": self.params}
-        if self.gg is not None:
-            tree["gg"] = self.gg
-        return tree
-
-    def _restore_arrays(self, tree) -> None:
-        self.params = tree["params"]
-        if "gg" in tree:
-            self.gg = tree["gg"]
-
-    def _checkpoint_scalars(self):
-        return {"cum_loss": self.cum_loss, "n_seen": self.n_seen}
-
-    def _restore_scalars(self, scalars) -> None:
-        self._loss_host = float(scalars["cum_loss"])
-        self._loss_pending = jnp.zeros(())
-        self.n_seen = int(scalars["n_seen"])
-
-    def save_bundle(self, path: str) -> None:
-        from ..io.checkpoint import save_bundle
-        self._flush()                  # buffered rows train before we snapshot
-        save_bundle(self, path)
-
-    def load_bundle(self, path: str) -> None:
-        from ..io.checkpoint import load_bundle
-        load_bundle(self, path)
-
     def _flush(self) -> None:
         if not self._buf:
             return
-        chunk = self._buf
-        self._buf = []
+        chunk, self._buf = self._buf, []
         if int(self.opts.iters) > 1:
             self._all.extend(chunk)
-        self._dispatch(chunk)
-
-    def _dispatch(self, chunk: List[Tuple[int, int, float]]) -> None:
-        B = int(self.opts.mini_batch)
-        u = np.zeros(B, np.int32)
-        i = np.zeros(B, np.int32)
-        r = np.zeros(B, np.float32)
-        m = np.zeros(B, np.float32)
-        n = len(chunk)
-        u[:n] = [c[0] for c in chunk]
-        i[:n] = [c[1] for c in chunk]
-        r[:n] = [c[2] for c in chunk]
-        m[:n] = 1.0
-        if self.mesh is not None:
-            u, i, r, m = self._shard_inputs((u, i, r, m))
-        self.params, self.gg, loss = self._step(
-            self.params, self.gg, float(self._t), u, i, r, m)
-        self._post_step(loss, n)
-
-    def _post_step(self, loss, n: int) -> None:
-        self._t += 1
-        self._loss_pending = self._loss_pending + loss
-        if self._t % 256 == 0:
-            self._fold_loss()
-        self.n_seen += n
-
-    def _fold_loss(self) -> None:
-        self._loss_host += float(self._loss_pending)
-        self._loss_pending = jnp.zeros(())
-
-    @property
-    def cum_loss(self) -> float:
-        self._fold_loss()
-        return self._loss_host
+        self._dispatch(self._batch_of(np.asarray(chunk, np.float64)))
 
     def close(self) -> Iterator[Tuple]:
         self._flush()
-        iters = int(self.opts.iters)
+        iters, bs = int(self.opts.iters), int(self.opts.mini_batch)
         if iters > 1 and self._all:
             rng = np.random.default_rng(42)
-            bs = int(self.opts.mini_batch)
-            for ep in range(1, iters):
-                order = rng.permutation(len(self._all))
+            rows = np.asarray(self._all, np.float64)
+            for _ in range(1, iters):
+                order = rng.permutation(len(rows))
                 for s in range(0, len(order), bs):
-                    self._dispatch([self._all[j] for j in order[s:s + bs]])
+                    self._dispatch(self._batch_of(rows[order[s:s + bs]]))
+        self._emit_train_done()
         yield from self.model_rows()
 
-    # third fit column dtype: ratings (f32) for explicit MF, the negative
-    # ITEM ID (i32) for BPR — lets the columnar fast path below serve both
-    _COL3_DTYPE = np.float32
+    def save_bundle(self, path: str) -> None:
+        self._flush()                  # buffered rows train before we snapshot
+        super().save_bundle(path)
 
-    def fit(self, users: np.ndarray, items: np.ndarray, ratings: np.ndarray,
-            *, epochs: Optional[int] = None, shuffle: bool = True
-            ) -> "MFTrainer":
+    def fit(self, users, items, ratings, *, epochs: Optional[int] = None,
+            shuffle: bool = True) -> "MFTrainer":
+        """Columns of ratings (BPR: the negative items in ``ratings``'
+        place), ``epochs`` passes (default -iters), each in a fresh random
+        order unless ``shuffle`` is False: the same batches `process`
+        would make, through `fit_stream`."""
         epochs = int(self.opts.iters) if epochs is None else epochs
         bs = int(self.opts.mini_batch)
-        n = len(users)
+        # a caller's device columns are fetched once, before any step
+        rows = np.stack([np.asarray(users, np.float64),
+                         np.asarray(items, np.float64),
+                         np.asarray(ratings, np.float64)], 1)
         rng = np.random.default_rng(42)
-        if self.mesh is not None or n < bs:
-            # sharded placement (and tiny inputs) keep the row path
-            for ep in range(epochs):
-                order = rng.permutation(n) if shuffle else np.arange(n)
-                for s in range(0, n, bs):
-                    take = order[s:s + bs]
-                    self._dispatch(list(zip(users[take], items[take],
-                                            ratings[take])))
-            return self
-        # columnar fast path: the row path built THREE 65k-element python
-        # lists per step and re-crossed h2d every batch (measured: it held
-        # train_mf at ~750k ex/s while the step alone sustains multiples).
-        # Stage each epoch's permuted columns on device ONCE and feed the
-        # step device slices; the short tail reuses the row path.
-        # Callers may pass DEVICE arrays (jnp) to skip the h2d entirely
-        # across repeated fits — shuffling then permutes on device.
-        dev_in = not isinstance(users, np.ndarray) and hasattr(
-            users, "devices")
-        if dev_in:
-            u = jnp.asarray(users, jnp.int32)
-            i = jnp.asarray(items, jnp.int32)
-            r = jnp.asarray(ratings, self._COL3_DTYPE)
-        else:
-            u = np.ascontiguousarray(users, np.int32)
-            i = np.ascontiguousarray(items, np.int32)
-            r = np.ascontiguousarray(ratings, self._COL3_DTYPE)
-        md = jnp.ones(bs, jnp.float32)
-        ud = id_ = rd = None              # staged once unless shuffling
-        nb = n - n % bs
-        for ep in range(epochs):
-            if shuffle:
-                order = rng.permutation(n)
-                if dev_in:
-                    oj = jnp.asarray(order.astype(np.int32))
-                    uo = io_ = ro = None        # device-side permute
-                    ud, id_, rd = u[oj], i[oj], r[oj]
-                else:
-                    uo, io_, ro = u[order], i[order], r[order]
-                    ud, id_, rd = (jnp.asarray(uo), jnp.asarray(io_),
-                                   jnp.asarray(ro))
-            else:
-                uo, io_, ro = u, i, r
-                if ud is None:            # identical columns: ONE h2d
-                    ud, id_, rd = (jnp.asarray(u), jnp.asarray(i),
-                                   jnp.asarray(r))
-            for s in range(0, nb, bs):
-                self.params, self.gg, loss = self._step(
-                    self.params, self.gg, float(self._t),
-                    ud[s:s + bs], id_[s:s + bs], rd[s:s + bs], md)
-                self._post_step(loss, bs)
-            if nb < n:
-                if uo is None or not isinstance(uo, np.ndarray):
-                    # device input: fetch ONLY the tail rows for the row
-                    # path, not the whole permuted columns — a bounded
-                    # once-per-epoch remainder fetch, not per step
-                    # graftcheck: disable=GC07
-                    tails = (np.asarray(ud[nb:]), np.asarray(id_[nb:]),
-                             np.asarray(rd[nb:]))  # graftcheck: disable=GC07
-                else:
-                    tails = (uo[nb:], io_[nb:], ro[nb:])
-                self._dispatch(list(zip(*tails)))
-        return self
+
+        def batches():
+            for _ in range(epochs):
+                order = rng.permutation(len(rows)) if shuffle \
+                    else np.arange(len(rows))
+                for s in range(0, len(order), bs):
+                    yield self._batch_of(rows[order[s:s + bs]])
+        return self.fit_stream(batches(), convert_labels=False)
 
     # -- scoring / emission --------------------------------------------------
     def predict(self, users, items) -> np.ndarray:
-        p = self.params
-        u = np.asarray(users, np.int32)
-        i = np.asarray(items, np.int32)
-        pu = np.asarray(p["P"].astype(jnp.float32))[u]
-        qi = np.asarray(p["Q"].astype(jnp.float32))[i]
-        out = float(self.opts.mu) + (pu * qi).sum(-1)
+        k = self.k
+        pu, qi = self._rows("U", users), self._rows("I", items)
+        out = float(self.opts.mu) + (pu[:, :k] * qi[:, :k]).sum(-1)
         if not self.opts.disable_bias:
-            out = out + np.asarray(p["bu"])[u] + np.asarray(p["bi"])[i]
+            out = out + pu[:, k] + qi[:, k]
         return out.astype(np.float32)
 
     # -- weight-arena publishing (io.weight_arena "factor" family) -----------
@@ -344,36 +392,43 @@ class MFTrainer:
         margin kernels — factor scoring gathers TWO embedding rows per
         pair instead of one weight row per feature."""
         self._flush()                  # buffered rows train before export
-        p = self.params
+        k = self.k
         use_bias = not self.opts.disable_bias
-        meta = {"family": "factor", "k": self.k,
+        meta = {"family": "factor", "k": k,
                 "mu": float(self.opts.mu),
                 "user_bias": use_bias, "item_bias": use_bias,
                 "classification": False}
-        tables = {"P": np.asarray(p["P"].astype(jnp.float32)),
-                  "Q": np.asarray(p["Q"].astype(jnp.float32))}
+        tables = {"P": self._table("U", slice(0, k)),
+                  "Q": self._table("I", slice(0, k))}
         if use_bias:
-            tables["bu"] = np.asarray(p["bu"], np.float32)
-            tables["bi"] = np.asarray(p["bi"], np.float32)
+            tables["bu"] = self._table("U", k)
+            tables["bi"] = self._table("I", k)
         return meta, tables
+
+    def save_model(self, path: str) -> None:
+        """The model table `close` emits, a row a line, tab-separated."""
+        with open(path, "w") as f:
+            for row in self.model_rows():
+                f.write("\t".join(str(x) for x in row) + "\n")
 
     def model_rows(self) -> Iterator[Tuple]:
         """(idx, Pu|None, Qi|None, bu, bi) rows, users then items, only
         touched ids (nonzero factors)."""
-        P = np.asarray(self.params["P"].astype(jnp.float32))
-        Q = np.asarray(self.params["Q"].astype(jnp.float32))
-        bu = np.asarray(self.params["bu"])
-        bi = np.asarray(self.params["bi"])
-        for uid in np.nonzero(np.abs(P).sum(-1) > 0)[0]:
-            yield (int(uid), P[uid].tolist(), None, float(bu[uid]), None)
-        for iid in np.nonzero(np.abs(Q).sum(-1) > 0)[0]:
-            yield (int(iid), None, Q[iid].tolist(), None, float(bi[iid]))
+        k = self.k
+        Ut, It = self._table("U", slice(0, k + 1)), \
+            self._table("I", slice(0, k + 1))
+        for uid in np.nonzero(np.abs(Ut[:, :k]).sum(-1) > 0)[0]:
+            yield (int(uid), Ut[uid, :k].tolist(), None, float(Ut[uid, k]),
+                   None)
+        for iid in np.nonzero(np.abs(It[:, :k]).sum(-1) > 0)[0]:
+            yield (int(iid), None, It[iid, :k].tolist(), None,
+                   float(It[iid, k]))
 
 
 class MFAdaGradTrainer(MFTrainer):
     """SQL: train_mf_adagrad — reference hivemall.mf.MatrixFactorizationAdaGradUDTF."""
     NAME = "train_mf_adagrad"
-    ADAGRAD = True
+    OPTIMIZER = "adagrad"
 
 
 class BPRMFTrainer(MFTrainer):
@@ -383,78 +438,32 @@ class BPRMFTrainer(MFTrainer):
     -log sigmoid(x_upos - x_uneg) with x_ui = p_u.q_i + b_i (item bias only).
     """
     NAME = "train_bprmf"
-    ADAGRAD = False
-    _COL3_DTYPE = np.int32       # third fit column = negative item id
+    ID_COLUMNS = ("user", "pos_item", "neg_item")
 
     def _make_step(self):
-        o = self.opts
-        lam = float(o["lambda"])
-        eta0 = float(o.eta0)
-
-        @jax.jit
-        def step(params, gg, t, u, i, j, mask):
-            def batch_loss(p):
-                pu = p["P"][u].astype(jnp.float32)
-                qi = p["Q"][i].astype(jnp.float32)
-                qj = p["Q"][j].astype(jnp.float32)
-                x = ((pu * (qi - qj)).sum(-1)
-                     + p["bi"][i] - p["bi"][j])
-                nll = jax.nn.softplus(-x) * mask
-                reg = lam * ((pu * pu).sum() + (qi * qi).sum()
-                             + (qj * qj).sum()
-                             + (p["bi"][i] ** 2).sum()
-                             + (p["bi"][j] ** 2).sum())
-                return nll.sum() + reg
-
-            loss, grads = jax.value_and_grad(batch_loss)(params)
-            new_p = {k: (params[k].astype(jnp.float32)
-                         - eta0 * grads[k].astype(jnp.float32)
-                         ).astype(params[k].dtype) for k in params}
-            return new_p, gg, loss
-
-        return step
+        return _bpr_step_cached(self.k, float(self.opts["lambda"]),
+                                self._opt)
 
     def process(self, user: int, pos_item: int, neg_item: int) -> None:
         # third slot carries the negative item id (int), not a rating
         super().process(user, pos_item, float(neg_item))
 
-    def _dispatch(self, chunk) -> None:
-        B = int(self.opts.mini_batch)
-        u = np.zeros(B, np.int32)
-        i = np.zeros(B, np.int32)
-        j = np.zeros(B, np.int32)
-        m = np.zeros(B, np.float32)
-        n = len(chunk)
-        u[:n] = [c[0] for c in chunk]
-        i[:n] = [c[1] for c in chunk]
-        j[:n] = [int(c[2]) for c in chunk]
-        m[:n] = 1.0
-        if self.mesh is not None:
-            u, i, j, m = self._shard_inputs((u, i, j, m))
-        self.params, self.gg, loss = self._step(
-            self.params, self.gg, float(self._t), u, i, j, m)
-        self._post_step(loss, n)
-
     def predict(self, users, items) -> np.ndarray:
-        p = self.params
-        u = np.asarray(users, np.int32)
-        i = np.asarray(items, np.int32)
-        pu = np.asarray(p["P"].astype(jnp.float32))[u]
-        qi = np.asarray(p["Q"].astype(jnp.float32))[i]
-        return ((pu * qi).sum(-1) + np.asarray(p["bi"])[i]).astype(np.float32)
+        k = self.k
+        pu, qi = self._rows("U", users), self._rows("I", items)
+        return ((pu[:, :k] * qi[:, :k]).sum(-1) + qi[:, k]).astype(np.float32)
 
     def serving_tables(self) -> Tuple[dict, Dict[str, np.ndarray]]:
         """BPR's score has no global mean and no user bias — the pairwise
         ranking loss cancels both; only the item bias survives."""
         self._flush()
-        p = self.params
-        meta = {"family": "factor", "k": self.k, "mu": 0.0,
+        k = self.k
+        meta = {"family": "factor", "k": k, "mu": 0.0,
                 "user_bias": False, "item_bias": True,
                 "classification": False}
-        tables = {"P": np.asarray(p["P"].astype(jnp.float32)),
-                  "Q": np.asarray(p["Q"].astype(jnp.float32)),
-                  "bi": np.asarray(p["bi"], np.float32)}
-        return meta, tables
+        return meta, {"P": self._table("U", slice(0, k)),
+                      "Q": self._table("I", slice(0, k)),
+                      "bi": self._table("I", k)}
 
 
 # --- predict UDFs (join-side reassembly, SURVEY.md §3.7 row 5) -------------

@@ -917,6 +917,25 @@ def tail_cap(n: int, R: int, W: int = 128, itemsize: int = 4) -> int:
             or cap // LIST_MULTIPLE * LIST_MULTIPLE)
 
 
+def whole_table_cap(n: int, R: int, W: int = 128) -> int:
+    """The distinct-row tail's capacity for a table SMALL against the
+    batch (MF's item table: 39,780 rows against 131,072 slots), which
+    `tail_cap` prices at 0 because a pass over the table is cheaper than
+    ranking the batch. Its readings were taken before the compact sum was
+    a kernel: they leave out the slots' scatter-add, common to both tails
+    then, which the dense tail still gives to XLA (13.8-20.6 ns a slot on
+    a v5e) and the distinct one to `scatter_rows` (~7). Where one pass of
+    that sum holds the whole table, the list holds as many rows as the
+    batch can touch, cut to whole blocks of the row kernel (a list is no
+    longer than its table): 0 for a larger table, which keeps `tail_cap`'s
+    rule."""
+    if R > scatter_rows_cap(W):
+        return 0
+    cap = min(n, R)
+    return (cap // BLOCK_ROWS * BLOCK_ROWS
+            or cap // LIST_MULTIPLE * LIST_MULTIPLE)
+
+
 # --- the gather through the distinct rows --------------------------------------
 # XLA's row gather costs by the ROW and by where its operand lives: 10.2 ns a
 # row out of HBM, out of the 2 GiB table as out of a 145 MB one, and 1.8-2.0

@@ -227,7 +227,8 @@ def test_bundle_rejects_mismatch(tmp_path):
 
 
 def test_mf_resume_equals_continuous(tmp_path):
-    """Non-LearnerBase trainer (MF AdaGrad) bundles via the same protocol."""
+    """The factor trainer (MF AdaGrad: two 128-lane tables and their
+    AdaGrad sums) bundles via the same protocol."""
     from hivemall_tpu.models.mf import MFAdaGradTrainer
     rng = np.random.default_rng(5)
     opts = "-factors 4 -users 30 -items 20 -mini_batch 8 -seed 2"
@@ -238,7 +239,7 @@ def test_mf_resume_equals_continuous(tmp_path):
     for u, i, r in trips:
         cont.process(u, i, r)
     cont._flush()
-    ref = np.asarray(cont.params["P"], np.float32)
+    ref = np.asarray(cont.params["U"], np.float32)
 
     first = MFAdaGradTrainer(opts)
     for u, i, r in trips[:40]:
@@ -252,7 +253,7 @@ def test_mf_resume_equals_continuous(tmp_path):
     for u, i, r in trips[40:]:
         second.process(u, i, r)
     second._flush()
-    np.testing.assert_allclose(np.asarray(second.params["P"], np.float32),
+    np.testing.assert_allclose(np.asarray(second.params["U"], np.float32),
                                ref, rtol=1e-6, atol=1e-7)
 
 

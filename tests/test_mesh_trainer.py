@@ -169,10 +169,11 @@ def test_mf_mesh_matches_single_device():
     t1 = MFAdaGradTrainer(opts + " -mesh dp=2,tp=4")
     assert t1.mesh is not None
     t1.fit(u, i, r, shuffle=False)
-    P1 = np.asarray(t1.params["P"], np.float32)
-    shard = t1.params["P"].sharding.shard_shape(t1.params["P"].shape)
-    assert shard[0] == U // 4        # tp=4 row sharding
-    np.testing.assert_allclose(np.asarray(t0.params["P"], np.float32), P1,
+    P1 = np.asarray(t1.params["U"], np.float32)
+    for table, rows in (("U", U), ("I", I)):
+        leaf = t1.params[table]
+        assert leaf.sharding.shard_shape(leaf.shape)[0] == rows // 4  # tp=4
+    np.testing.assert_allclose(np.asarray(t0.params["U"], np.float32), P1,
                                rtol=1e-4, atol=1e-5)
     preds0 = t0.predict(u[:32], i[:32])
     preds1 = t1.predict(u[:32], i[:32])

@@ -116,6 +116,16 @@ def test_skipgram_megastep_compiles_for_v5e_within_a_chip():
     _compile("skipgram_megastep", timeout=600)
 
 
+def test_mf_megastep_compiles_for_v5e_within_a_chip():
+    """`train_mf_adagrad -factors 100 -users 6260326 -items 39780` (the
+    cell mf_hugewiki.stream_ratings, 131,072 ratings a step): the jitted
+    initialiser and the megastep, both tables through the distinct-row
+    tail (four Mosaic kernels, both compact tables in fast memory), no
+    whole table copied, state and temporaries inside one chip (~25 s of
+    XLA compile)."""
+    _compile("mf_megastep", timeout=600)
+
+
 @pytest.mark.slow
 def test_sorted_histogram_kernel_compiles_for_v5e():
     """The sorted histogram (20-46 s per shape): outside the tier-1

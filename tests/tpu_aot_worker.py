@@ -520,6 +520,64 @@ def skipgram_megastep():
         < 15.75 * 2 ** 30
 
 
+def mf_megastep():
+    """train_mf_adagrad at the geometry of the benchmark's cell
+    mf_hugewiki.stream_ratings (-factors 100 -users 6260326 -items 39780,
+    rows stored 128 float32 lanes; 8 steps of 131,072 ratings, here two):
+    the state's jitted initialiser (6.45 GB, born on the chip) and the
+    megastep. Both tables go through the distinct-row tail: the user
+    table's capacity from `tail_cap`, the item table's from
+    `whole_table_cap`; each has its compact table in fast memory and its
+    two Mosaic kernels (`scatter_rows`, `update_rows`: four in all); no
+    whole table is copied or relayouted, every phase scope names ops, and
+    state and temporaries stay inside one chip (the temporaries are the
+    user table's dense tail, 3.28 GB read here)."""
+    from hivemall_tpu.models import mf
+    from hivemall_tpu.ops.optimizers import make_optimizer_cached
+    from hivemall_tpu.ops.scan import make_megastep
+    U, I, k, B, ks = 6_260_326, 39_780, 100, 131072, 2
+    W = mf.row_lanes(k)
+    assert W == 128
+    opt = make_optimizer_cached("adagrad", "fixed", 0.01, 10_000, 0.1)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    tables = {"U": DEV0, "I": DEV0}
+    init = jax.jit(mf._state_init(U, I, k, 0.1, "float32", opt),
+                   out_shardings=(tables, {"U": {"gg": DEV0},
+                                           "I": {"gg": DEV0}})).lower(
+        _sds(key.shape, key.dtype)).compile().memory_analysis()
+    state = 2 * 4 * W * (U + I)
+    assert 0 <= init.output_size_in_bytes - state < 65536 \
+        and init.temp_size_in_bytes == 0, init
+    caps = {"U": fm.tail_cap(B, U), "I": fm.whole_table_cap(B, I)}
+    assert caps == {"U": B, "I": 38912} and fm.tail_cap(B, I) == 0, caps
+    step = mf._step_cached(k, 3.0, 0.01, True, opt, True)
+    Ut, It = _sds((U, W), jnp.float32), _sds((I, W), jnp.float32)
+    compiled = make_megastep(step.core, none_val=True).lower(
+        {"U": Ut, "I": It}, {"U": {"gg": Ut}, "I": {"gg": It}},
+        _sds((), jnp.float32), _sds((ks,), jnp.int32),
+        _sds((ks, B, 2), jnp.int32), None, _sds((ks, B), jnp.float32), None,
+        None).compile()
+    text = compiled.as_text()
+    for scope in ("hm.gather", "hm.grad", "hm.scatter", "hm.update"):
+        assert f"/{scope}/" in text, f"no op under {scope}"
+    kernels = text.count("tpu_custom_call")
+    assert kernels == 4, \
+        f"{kernels} Mosaic kernels, expected scatter_rows and update_rows " \
+        "a table"
+    for t, cap in caps.items():
+        spaces = _compact_table_spaces(text, f"f32[{cap},{W}]")
+        assert spaces == {"S(1)"}, f"{t}'s compact table lives in {spaces}"
+    for R in (U, I):
+        shape = f"f32[{R},{W}]"
+        assert shape + "{1,0" in text and shape + "{0,1" not in text
+        assert not re.findall(r"= %s\S* copy\(" % re.escape(shape), text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 3.4e9, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+
+
 N, D, BINS = 1 << 20, 28, 64            # HIGGS-shaped trees, cut to 1M rows
 
 
@@ -552,7 +610,7 @@ CASES = {f.__name__: f for f in (fm_minibatch_step,
                                  ffm_joint_megastep, ffm_joint_megastep_tp4,
                                  hist_flat, hist_dense,
                                  hist_sorted, state_init, linear_megastep,
-                                 skipgram_megastep)}
+                                 skipgram_megastep, mf_megastep)}
 
 if __name__ == "__main__":
     for name in sys.argv[1:]:
